@@ -322,6 +322,13 @@ def main() -> None:
                          "artifact here")
     args = ap.parse_args()
 
+    from bench import device_summary
+    from sonata_tpu.utils.jax_cache import enable_persistent_compile_cache
+
+    enable_persistent_compile_cache()
+    # the platform JAX selected, named before any metric; a backend that
+    # does not initialize is JAX's own uncaught error (no fallback)
+    print(json.dumps({"device": device_summary()}))
     if args.cache_artifact:
         run_cache_arm(args.cache_artifact)
         return
@@ -329,33 +336,9 @@ def main() -> None:
         run_ledger_arm(args.ledger_artifact)
         return
 
-    from bench import accelerator_ready_with_retries
-
-    if accelerator_ready_with_retries() is None:
-        # one parseable error line per metric this script would report
-        for metric, unit in (
-                ("streaming_ttfb_p50", "ms"),
-                ("concurrent_streaming_audio_s_per_s",
-                 "audio_seconds_per_second"),
-                ("streaming_ttfb_p50_at_4_streams", "ms"),
-                ("streaming_ttfb_p50_at_8_streams", "ms"),
-                ("stream_decode_coalescing_ratio", "requests_per_dispatch"),
-                ("stream_stage_coalescing_ratio", "requests_per_dispatch"),
-                ("dispatch_policy_coalesce", "bool"),
-                ("trace_overhead", "ratio_traced_over_untraced"),
-                ("scope_overhead", "ratio_scoped_over_unscoped")):
-            print(json.dumps({
-                "metric": metric, "value": None, "unit": unit,
-                "vs_baseline": None,
-                "error": "accelerator backend unavailable (init timeout)",
-            }))
-        return
-
     from sonata_tpu.models import PiperVoice
     from sonata_tpu.synth import SpeechSynthesizer
-    from sonata_tpu.utils.jax_cache import enable_persistent_compile_cache
 
-    enable_persistent_compile_cache()
     voice = PiperVoice.random(seed=0, audio={"sample_rate": 22050,
                                              "quality": "high"})
     synth = SpeechSynthesizer(voice)

@@ -334,10 +334,8 @@ def main(argv=None) -> int:
     configure_logging(env_level_var="SONATA_LOG")
     # repeat CLI invocations reuse compiled executables from disk instead
     # of re-paying the cold XLA compile on every run
-    from ..utils.jax_cache import (
-        enable_persistent_compile_cache, pin_platform_from_env)
+    from ..utils.jax_cache import enable_persistent_compile_cache
 
-    pin_platform_from_env()  # SONATA_PLATFORM=cpu|tpu|...
     enable_persistent_compile_cache()
     args = build_parser().parse_args(argv)
     if args.log_level or args.log_format:

@@ -48,6 +48,7 @@ from pathlib import Path
 from typing import Iterator, Optional
 
 import grpc
+import jax
 
 from .. import __version__
 from ..core import FailedToLoadResource, OperationError, SonataError
@@ -66,7 +67,11 @@ from ..serving import ledger as ledger_mod
 from ..serving import tenancy as tenancy_mod
 from ..serving import warmup as serving_warmup
 from ..serving.logs import configure_logging
-from ..synth import AudioOutputConfig, SpeechSynthesizer
+from ..synth import (
+    AudioOutputConfig,
+    SpeechSynthesizer,
+    resolve_batch_mode,
+)
 from ..utils.profiling import RtfCounter
 from . import grpc_messages as pb
 
@@ -368,7 +373,9 @@ class SonataGrpcService:
         # time, so the serving shape (coalescing on/off, batch/wait knobs,
         # probe constants) is in the log before traffic arrives
         try:
-            log.info("voice %s %s", vid, voice.dispatch_policy.describe())
+            policy = voice.dispatch_policy
+            log.info("voice %s %s; batch mode=%s", vid, policy.describe(),
+                     resolve_batch_mode(policy))
         except Exception:  # policy must never block serving
             log.exception("dispatch-policy resolution failed "
                           "(serving continues on defaults)")
@@ -1416,6 +1423,15 @@ def create_server(port: Optional[int] = None, *, mesh=None, seed: int = 0,
     # gRPC trailing metadata (see serving/mesh.py)
     from ..serving.mesh import resolve_node_id
     runtime.set_node_id(resolve_node_id(f"{host}:{bound}"))
+    # peak device memory, where the backend reports it (a TPU does; the
+    # CPU backend's memory_stats() is None and the series is omitted)
+    peak = runtime.registry.gauge(
+        "sonata_device_memory_peak_bytes",
+        "Peak bytes in use on each local device since process start "
+        "(memory_stats peak_bytes_in_use).")
+    for d in jax.local_devices():
+        peak.labels(device=str(d.id)).set_function(
+            lambda d=d: (d.memory_stats() or {}).get("peak_bytes_in_use"))
     # metrics/health HTTP plane: explicit port > SONATA_METRICS_PORT >
     # disabled (0 binds an ephemeral port, runtime.http_port has it)
     http_port = runtime.start_http(metrics_port)
@@ -1431,13 +1447,10 @@ def main(argv=None) -> int:
     configure_logging(env_level_var="SONATA_GRPC")
     # compiled executables persist across boots; with --prewarm, a re-boot
     # loads its shapes from disk in seconds instead of re-running XLA
-    from ..utils.jax_cache import (
-        enable_persistent_compile_cache, pin_platform_from_env)
+    from ..utils.jax_cache import enable_persistent_compile_cache
 
-    pin_platform_from_env()  # SONATA_PLATFORM=cpu|tpu|...
-    cache_dir = enable_persistent_compile_cache()
-    if cache_dir:
-        log.info("persistent compile cache: %s", cache_dir)
+    log.info("persistent compile cache: %s",
+             enable_persistent_compile_cache())
     import argparse
 
     ap = argparse.ArgumentParser(prog="sonata-tpu-grpc")
@@ -1504,6 +1517,11 @@ def main(argv=None) -> int:
         configure_logging(args.log_level, args.log_format,
                           env_level_var="SONATA_GRPC")
     faults.warn_if_armed(log)
+    # names the device before any voice loads: a backend that does not
+    # initialize is JAX's own start-up error here, never a CPU run
+    devices = jax.devices()
+    log.info("devices: platform=%s device_kind=%s count=%d",
+             devices[0].platform, devices[0].device_kind, len(devices))
 
     mesh = None
     if args.mesh_devices:

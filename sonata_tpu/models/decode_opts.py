@@ -4,7 +4,7 @@ Two independent, env-gated speedups for the HiFi-GAN decode path, both
 measured by ``tools/bench_cpu.py`` arms and parity-gated against float32
 (tests/test_decode_opts.py):
 
-**Fused decode epilogue** (``SONATA_FUSED_EPILOGUE=pallas|lax|off``,
+**Fused decode epilogue** (``SONATA_FUSED_EPILOGUE=lax|off``,
 default ``lax``): the streaming pipeline used to ship every decoded
 window back to the host as float32 and run the per-chunk epilogue there
 — slice to the emitted range, crossfade taper
@@ -16,11 +16,8 @@ device program as the window decode* (one jitted executable per
 (width, batch rung) — see ``PiperVoice._decode_windows_fused_fn``), so
 one dispatch returns quantized, already-tapered samples plus the
 per-row peak for exact host-side dequantization.  ``lax`` composes the
-epilogue from jnp ops (portable, the default everywhere); ``pallas``
-lowers the epilogue to a Pallas TPU kernel (accelerator-targeted — on
-a CPU backend it runs in interpret mode, which tests use for parity;
-production CPU deployments should keep ``lax``); ``off`` restores the
-host-side epilogue.
+epilogue from jnp ops, which XLA fuses into the decode's last stage;
+``off`` restores the host-side epilogue.
 
 **int8 weight-only decoder quantization** (``SONATA_DECODE_QUANT=int8``,
 default off): per-output-channel symmetric int8 quantization of every
@@ -36,7 +33,6 @@ registry's split-default rule).
 
 from __future__ import annotations
 
-import functools
 import os
 from typing import Optional
 
@@ -51,14 +47,14 @@ from ..core import OperationError
 # ---------------------------------------------------------------------------
 
 FUSED_EPILOGUE_ENV = "SONATA_FUSED_EPILOGUE"
-FUSED_EPILOGUE_MODES = ("pallas", "lax", "off")
+FUSED_EPILOGUE_MODES = ("lax", "off")
 
 DECODE_QUANT_ENV = "SONATA_DECODE_QUANT"
 
 
 def resolve_fused_epilogue(setting: Optional[str] = None,
                            env: Optional[dict] = None) -> str:
-    """``pallas`` | ``lax`` | ``off``; a typo fails loudly (the
+    """``lax`` | ``off``; a typo fails loudly (the
     SONATA_BATCH_MODE contract: a fleet silently running the wrong
     epilogue arm is a perf regression nobody would see)."""
     if setting is None:
@@ -120,8 +116,9 @@ def _quantize_rows(tapered):
     return q, peak
 
 
-def _lax_epilogue(wav, lo, hi, fade: int):
-    """jnp composition of the fused epilogue (the default arm).
+def fused_epilogue(wav, lo, hi, fade: int):
+    """Crossfade taper + peak-scaled i16 quantize, composed from jnp ops
+    inside the decode's device program.
 
     ``wav``: [B, S] float32 decoded windows; ``lo``/``hi``: [B] int32
     sample bounds of each row's emitted slice.  Returns
@@ -129,62 +126,6 @@ def _lax_epilogue(wav, lo, hi, fade: int):
     idx = jnp.arange(wav.shape[-1], dtype=jnp.int32)[None, :]
     gains = _taper_gains(idx, lo[:, None], hi[:, None], fade)
     return _quantize_rows(wav * gains)
-
-
-def _pallas_epilogue_kernel(fade: int, lo_ref, hi_ref, wav_ref,
-                            q_ref, peak_ref):
-    """One grid step per batch row: taper + quantize a [1, S] window.
-
-    Scalars (lo/hi/peak) live in SMEM; the window rides VMEM.  The math
-    is the shared :func:`_taper_gains`/:func:`_quantize_rows` pair, so
-    the two arms cannot drift."""
-    wav = wav_ref[...]                                   # [1, S]
-    idx = jax.lax.broadcasted_iota(jnp.int32, wav.shape, 1)
-    gains = _taper_gains(idx, lo_ref[0], hi_ref[0], fade)
-    q, peak = _quantize_rows(wav * gains)
-    q_ref[...] = q
-    peak_ref[0, 0] = peak[0]
-
-
-def _pallas_epilogue(wav, lo, hi, fade: int):
-    """Pallas-lowered epilogue (accelerator arm).  On a CPU backend the
-    kernel runs in interpret mode — correct but slow, intended only for
-    the parity tests; production CPU keeps the ``lax`` arm."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    b, s = wav.shape
-    kernel = functools.partial(_pallas_epilogue_kernel, fade)
-    q, peak = pl.pallas_call(
-        kernel,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (i,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda i: (i,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, s), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, s), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0),
-                         memory_space=pltpu.SMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, s), jnp.int16),
-            jax.ShapeDtypeStruct((b, 1), jnp.float32),
-        ],
-        interpret=jax.default_backend() == "cpu",
-    )(lo, hi, wav)
-    return q, peak[:, 0]
-
-
-def fused_epilogue(wav, lo, hi, fade: int, *, mode: str):
-    """Dispatch to the requested arm (``mode`` is static at trace time:
-    one compiled program per arm, never a runtime branch)."""
-    if mode == "pallas":
-        return _pallas_epilogue(wav, lo, hi, fade)
-    return _lax_epilogue(wav, lo, hi, fade)
 
 
 def dequantize_chunk(q, peak):

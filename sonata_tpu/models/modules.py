@@ -304,10 +304,7 @@ def transformer_seq_parallel(x, mask, p, *, n_heads, window, mesh):
 
     from ..parallel.mesh import DATA_AXIS, SEQ_AXIS
     from ..parallel.ring import halo_exchange, ring_rel_attention_sharded
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     def attn_local(x_loc, mask_loc, lp):
         b, t, c = x_loc.shape
@@ -367,14 +364,16 @@ def init_wn(rng, *, hidden, kernel, dilation_rate, n_layers, gin_channels=0):
     return p
 
 
-def wn(x, mask, p, *, kernel, dilation_rate, n_layers, g=None, conv=None):
+def wn(x, mask, p, *, kernel, dilation_rate, n_layers, g=None, conv=None,
+       mesh=None):
     """Non-causal WaveNet: dilated convs, gated tanh units, residual+skip.
 
     ``x: [B, T, H]``; ``g: [B, 1, gin]`` speaker conditioning or None.
     The gate runs through :func:`sonata_tpu.ops.gate.fused_gate` — a Pallas
     kernel on TPU, plain jnp elsewhere.  ``conv`` overrides the dilated
     conv primitive (sequence-sharded callers inject a halo-exchange
-    version); pointwise convs never need halos and stay plain.
+    version); pointwise convs never need halos and stay plain.  ``mesh``
+    rides through to the gate (data-sharded jit callers only).
     """
     conv = conv or conv1d
     hidden = x.shape[-1]
@@ -386,7 +385,7 @@ def wn(x, mask, p, *, kernel, dilation_rate, n_layers, g=None, conv=None):
         g_l = None
         if g is not None and "cond" in p:
             g_l = lax.dynamic_slice_in_dim(g_all, i * 2 * hidden, 2 * hidden, axis=2)
-        acts = gate_op(x_in, g_l)
+        acts = gate_op(x_in, g_l, mesh=mesh)
         rs = conv1d(acts, p["res_skip"][i])
         if i < n_layers - 1:
             x = (x + rs[..., :hidden]) * mask

@@ -28,12 +28,8 @@ from __future__ import annotations
 
 import math
 
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 
 from ..parallel.mesh import DATA_AXIS, SEQ_AXIS
 from ..parallel.ring import halo_exchange

@@ -66,6 +66,7 @@ def _build(name: str, *, embed_python: bool = False) -> Optional[Path]:
     if proc.returncode != 0:
         log.warning("native build of %s failed:\n%s", name, proc.stderr[-2000:])
         return None
+    log.info("native build of %s: compiled %s", name, lib)
     return lib
 
 
@@ -97,6 +98,8 @@ def _load(name: str, *, embed_python: bool = False) -> Optional[ctypes.CDLL]:
                     except OSError as e2:
                         log.warning("cannot load rebuilt %s: %s",
                                     lib_path, e2)
+        if handle is not None:
+            log.info("native library %s loaded", name)
         _CACHE[name] = handle
         return handle
 

@@ -25,13 +25,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:  # pallas is TPU/interpret-only in some builds; degrade gracefully
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except ImportError:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 _BLOCK_ROWS = 256
 
@@ -57,7 +53,10 @@ def fused_gate_pallas(y, *, interpret: bool = False):
 
     out = pl.pallas_call(
         _gate_kernel,
-        out_shape=jax.ShapeDtypeStruct((a.shape[0], hidden), y.dtype),
+        # inside a shard_map the output varies over the same mesh axes
+        # as the input (empty set anywhere else)
+        out_shape=jax.ShapeDtypeStruct((a.shape[0], hidden), y.dtype,
+                                       vma=jax.typeof(a).vma),
         grid=(n_blocks,),
         in_specs=[
             pl.BlockSpec((_BLOCK_ROWS, hidden), lambda i: (i, 0),
@@ -78,10 +77,21 @@ def fused_gate_reference(y):
     return jnp.tanh(y[..., :hidden]) * jax.nn.sigmoid(y[..., hidden:])
 
 
-def fused_gate(x, g=None):
+def fused_gate(x, g=None, mesh=None):
     """Gated activation with optional conditioning: ``x: [B, T, 2H]``,
-    ``g: [B, 1, 2H]`` or None.  Pallas on TPU, jnp elsewhere."""
+    ``g: [B, 1, 2H]`` or None.  Pallas on TPU, jnp elsewhere.
+
+    ``mesh``: the voice's device mesh when the caller is a data-sharded
+    ``jax.jit`` (XLA cannot partition a Mosaic kernel by itself, so each
+    chip runs the kernel on its own batch rows under a ``shard_map``);
+    None on one device and inside an enclosing ``shard_map``."""
     y = x if g is None else x + g
-    if _HAS_PALLAS and jax.default_backend() == "tpu":
+    if jax.default_backend() != "tpu":
+        return fused_gate_reference(y)
+    if mesh is None:
         return fused_gate_pallas(y)
-    return fused_gate_reference(y)
+    from ..parallel.mesh import DATA_AXIS
+
+    spec = P(DATA_AXIS)
+    return jax.shard_map(fused_gate_pallas, mesh=mesh, in_specs=spec,
+                         out_specs=spec)(y)

@@ -23,27 +23,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from .mesh import SEQ_AXIS
-
-
-def _axis_size(axis_name: str) -> int:
-    """Static size of a mapped axis, across jax versions.
-
-    ``jax.lax.axis_size`` only exists in newer jax; on older releases
-    (e.g. 0.4.x, this environment) the static size is reachable via
-    ``jax.core.axis_frame``, which returns the size itself as an int
-    (newer intermediates return a frame object carrying ``.size``)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    from jax.core import axis_frame
-
-    frame = axis_frame(axis_name)
-    return frame if isinstance(frame, int) else frame.size
 
 
 def _block_attend(q, k, v, mask, m_prev, l_prev, acc_prev, scale,
@@ -81,7 +63,7 @@ def ring_attention_sharded(q, k, v, kv_valid, *, axis_name: str = SEQ_AXIS):
     kv_valid: [B, T_local] float/bool — 1 for real positions (padding mask
     travels with its K/V shard around the ring).
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     scale = q.shape[-1] ** -0.5
     b, h, tq, d = q.shape
@@ -126,7 +108,7 @@ def ring_rel_attention_sharded(q, k, v, kv_valid, rel_k, rel_v, *,
     rel_k, rel_v: [2*window+1, D] (position ``r`` ⇔ offset ``r - window``).
     Must run inside ``shard_map`` over ``axis_name``.
     """
-    n = _axis_size(axis_name)  # static: unrolled ring schedule
+    n = lax.axis_size(axis_name)  # static: unrolled ring schedule
     idx = lax.axis_index(axis_name)
     scale = q.shape[-1] ** -0.5
     b, h, t_loc, d = q.shape
@@ -177,7 +159,7 @@ def halo_exchange(x, pad_left: int, pad_right: int, *,
     The permutes are non-circular: device 0's left halo and device n-1's
     right halo stay zero (``ppermute`` fills non-received slots with 0).
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     parts = []
     if pad_left:
         left = lax.ppermute(x[:, -pad_left:], axis_name,

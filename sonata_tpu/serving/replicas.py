@@ -107,16 +107,22 @@ def env_replica_count() -> int:
 def resolve_replica_count(replicas: Optional[int] = None,
                           n_devices: Optional[int] = None) -> int:
     """How many replicas to run: explicit arg > ``SONATA_REPLICAS`` >
-    one per local device; always clamped to [1, local device count]."""
+    one per local device.  A count above the local device count is an
+    error, not a clamp: ``--replicas 4`` on one chip must not come up as
+    one replica without a word."""
     if n_devices is None:
         import jax
 
-        n_devices = max(len(jax.local_devices()), 1)
+        n_devices = len(jax.local_devices())
     if replicas is None or replicas <= 0:
         replicas = _env_int(REPLICAS_ENV, 0)
     if replicas <= 0:
-        replicas = n_devices
-    return max(1, min(replicas, n_devices))
+        return n_devices
+    if replicas > n_devices:
+        raise OperationError(
+            f"{replicas} replicas requested but this process has "
+            f"{n_devices} local device(s)")
+    return replicas
 
 
 def resolve_replica_devices(replicas: Optional[int] = None) -> list:

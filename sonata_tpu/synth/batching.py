@@ -94,7 +94,7 @@ def resolve_batch_mode(policy=None, env: Optional[dict] = None) -> str:
 #: loop's worker dispatches iteration k+1's device program while a
 #: finisher thread blocks on iteration k's result fetch — the same
 #: two-thread trick the wave coalescers already use, carried to the
-#: persistent loop so remote-chip links overlap transfer with compute.
+#: persistent loop so the result transfer overlaps the next compute.
 #: ``0`` forces the synchronous shape (the bench A/B arm).
 ITER_PIPELINE_ENV = "SONATA_ITER_PIPELINE"
 

@@ -6,10 +6,9 @@ time as batch 1 — so funneling N concurrent requests into ONE padded
 device program converts contention into throughput.  On a host-CPU
 backend the same architecture *loses*: XLA:CPU executes the batch rows
 essentially serially, the canonical-batch padding (b ∈ {1, max}) is real
-compute, and the gather window is pure added latency.  The repo's own
-committed artifact (``BENCH_STREAMING_CPU_r05.json``) measured the
-default coalescing config at 2.6x the TTFB of coalescing-off under 8
-concurrent CPU streams (33.7 s vs 13.0 s) and 0.66 vs 0.98 audio-s/s.
+compute, and the gather window is pure added latency (a round-5 run on a
+2-vCPU host, recorded in CHANGES.md PR 1, had the coalescing config at
+2.6x the TTFB of coalescing-off under 8 concurrent CPU streams).
 
 This module makes the framework act on its own measurements instead of
 hard-coded constants (the Orca/vLLM adaptive-batching lineage, PAPERS.md
@@ -26,8 +25,8 @@ hard-coded constants (the Orca/vLLM adaptive-batching lineage, PAPERS.md
   per-request dispatch, the reference's thread-per-stream serving shape
   (``grpc/src/main.rs:381-409``), with no probe paid.  TPU/GPU → the
   tuned coalescing defaults, with the probe refining the gather windows
-  (a slow host link stretches per-dispatch overhead, so waiting longer
-  to gather a fuller batch is cheap relative to the dispatch itself).
+  (the larger the per-dispatch overhead, the cheaper it is to wait a
+  little longer and gather a fuller batch).
 
 Env overrides always win over the probe (A/B work must stay possible):
 
@@ -152,7 +151,13 @@ class DispatchPolicy:
         return d
 
     def describe(self) -> str:
-        """One log line: the decision and where it came from."""
+        """One log line: the decision, where it came from, and the
+        probe's measurement when one ran."""
+        probe = ""
+        if self.probe is not None:
+            probe = (f" probe(n={self.probe.n} "
+                     f"t1={self.probe.t1_ms:.3f}ms "
+                     f"tn={self.probe.tn_ms:.3f}ms)")
         return (f"dispatch policy [{self.backend}]: "
                 f"coalesce={'on' if self.coalesce else 'off'} "
                 f"(decode b{self.stream_decode_max_batch}/"
@@ -160,7 +165,8 @@ class DispatchPolicy:
                 f"stage b{self.stream_stage_max_batch}/"
                 f"{self.stream_stage_max_wait_ms:g}ms, "
                 f"sched b{self.scheduler_max_batch}/"
-                f"{self.scheduler_max_wait_ms:g}ms) via {self.source}")
+                f"{self.scheduler_max_wait_ms:g}ms) via {self.source}"
+                f"{probe}")
 
 
 # ---------------------------------------------------------------------------
@@ -273,10 +279,10 @@ def _coalescing_policy(backend: str, source: str,
                        probe: Optional[ProbeResult] = None
                        ) -> DispatchPolicy:
     """The accelerator defaults; with a probe, the gather windows scale
-    with measured per-dispatch overhead (a dispatch over a slow tunnel
-    costs tens of ms — waiting a little longer to gather a fuller batch
-    is then nearly free), floored at the pinned defaults so a fast local
-    chip keeps the exact shipped constants."""
+    with measured per-dispatch overhead (the more a dispatch costs, the
+    cheaper it is to wait a little longer for a fuller batch), floored
+    at the pinned defaults so a chip with negligible per-dispatch
+    overhead keeps the exact shipped constants."""
     d = dict(COALESCING_DEFAULTS)
     if probe is not None:
         ovh = probe.per_dispatch_ms
@@ -343,8 +349,8 @@ def resolve_policy(shape_key: tuple = (), *,
     # -- auto ------------------------------------------------------------
     if backend == "cpu":
         # fast path: no probe.  XLA:CPU runs batch rows ~serially, so the
-        # coalescers' padding + gather window are pure overhead — measured
-        # 2.6x TTFB loss at 8 streams (BENCH_STREAMING_CPU_r05.json).
+        # coalescers' padding + gather window are pure overhead (2.6x
+        # TTFB loss at 8 streams on a 2-vCPU host, CHANGES.md PR 1).
         return _per_request_policy(backend, "auto:cpu-backend")
     try:
         probe = probe_fn(shape_key, backend=backend)

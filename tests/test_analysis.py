@@ -447,8 +447,7 @@ def test_real_tree_knob_parity_proves_the_fixed_drifts():
     assert diags == [], "\n".join(d.format() for d in diags)
     collected = knobs.collect_knobs(ctx)
     documented = knobs.doc_knob_tokens(ctx)
-    for name in ("SONATA_ESPEAKNG_DATA_DIRECTORY", "SONATA_PLATFORM",
-                 "SONATA_TCONV"):
+    for name in ("SONATA_ESPEAKNG_DATA_DIRECTORY", "SONATA_TCONV"):
         assert name in documented, f"{name} row lost from the docs"
         assert collected[name].reads, f"{name} no longer read in code"
     assert "SONATA_PROFILE" not in documented  # re-wired to /debug/profile

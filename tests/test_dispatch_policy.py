@@ -81,14 +81,14 @@ def test_serial_probe_result_disables_coalescing():
 
 
 def test_slow_dispatch_probe_stretches_gather_windows():
-    """Per-dispatch overhead beyond the wait window (a tunneled chip)
-    stretches the gather windows — bounded — while a fast chip keeps the
+    """Per-dispatch overhead beyond the wait window stretches the gather
+    windows — bounded — while a chip with negligible overhead keeps the
     exact defaults (previous test)."""
-    def tunneled_probe(shape_key, backend=None):
+    def slow_dispatch_probe(shape_key, backend=None):
         # 40ms fixed dispatch overhead, cheap per-item scaling
         return ProbeResult(backend=backend, n=8, t1_ms=41.0, tn_ms=48.0)
 
-    p = resolve_policy(backend="tpu", env={}, probe_fn=tunneled_probe)
+    p = resolve_policy(backend="tpu", env={}, probe_fn=slow_dispatch_probe)
     assert p.coalesce is True
     assert p.stream_decode_max_wait_ms == 10.0   # clamped ceiling
     assert p.stream_stage_max_wait_ms == 25.0    # clamped ceiling

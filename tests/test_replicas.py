@@ -73,12 +73,23 @@ def test_resolve_replica_count_env(monkeypatch):
     monkeypatch.delenv("SONATA_REPLICAS", raising=False)
     assert resolve_replica_count(None, n_devices=8) == 8
     assert resolve_replica_count(3, n_devices=8) == 3
-    assert resolve_replica_count(99, n_devices=8) == 8  # clamped
     monkeypatch.setenv("SONATA_REPLICAS", "2")
     assert resolve_replica_count(None, n_devices=8) == 2
     assert resolve_replica_count(5, n_devices=8) == 5  # explicit beats env
     monkeypatch.setenv("SONATA_REPLICAS", "junk")
     assert resolve_replica_count(None, n_devices=4) == 4
+
+
+def test_resolve_replica_count_rejects_more_than_devices(monkeypatch):
+    """An explicit count above the device count — by argument or by
+    ``SONATA_REPLICAS`` — is an error, never a silent clamp."""
+    monkeypatch.delenv("SONATA_REPLICAS", raising=False)
+    with pytest.raises(OperationError, match="4 replicas .* 1 local"):
+        resolve_replica_count(4, n_devices=1)
+    monkeypatch.setenv("SONATA_REPLICAS", "9")
+    with pytest.raises(OperationError, match="9 replicas .* 8 local"):
+        resolve_replica_count(None, n_devices=8)
+    assert resolve_replica_count(8, n_devices=8) == 8
 
 
 # ---------------------------------------------------------------------------

@@ -463,7 +463,7 @@ def test_aot_cache_dir_override_and_default(tmp_path, monkeypatch):
     assert aot_cache_dir() == str(override)
     assert override.is_dir()
     monkeypatch.delenv("SONATA_AOT_CACHE")
-    monkeypatch.setenv("SONATA_JAX_CACHE_DIR", str(tmp_path / "jc"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jc"))
     d = aot_cache_dir()
     assert d == str(tmp_path / "jc" / "aot")
 

@@ -23,7 +23,7 @@ Produces the committed ``FLEET_rNN.json`` artifact (folded into
 
 Backends boot via ``tools/serving_smoke.py --mesh-node-boot`` (the same
 pinned-port node boot the CI mesh phase and bench_mesh use), sharing
-one ``SONATA_JAX_CACHE_DIR`` so boots after the first are warm.
+one ``JAX_COMPILATION_CACHE_DIR`` so boots after the first are warm.
 
 Run: ``JAX_PLATFORMS=cpu python tools/bench_fleet.py --out FLEET_r01.json``
 
@@ -131,7 +131,7 @@ def cache_main(args) -> int:
 
     def boot(i: int) -> subprocess.Popen:
         env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   SMOKE_VOICE_CFG=cfg, SONATA_JAX_CACHE_DIR=cache,
+                   SMOKE_VOICE_CFG=cfg, JAX_COMPILATION_CACHE_DIR=cache,
                    SONATA_SYNTH_CACHE_MB="16",
                    MESH_NODE_GRPC_PORT=str(ports[i][0]),
                    MESH_NODE_METRICS_PORT=str(ports[i][1]))
@@ -346,7 +346,7 @@ def tenancy_main(args) -> int:
 
     def boot(i: int, tenants: str | None) -> subprocess.Popen:
         env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   SMOKE_VOICE_CFG=cfg, SONATA_JAX_CACHE_DIR=cache,
+                   SMOKE_VOICE_CFG=cfg, JAX_COMPILATION_CACHE_DIR=cache,
                    MESH_NODE_GRPC_PORT=str(ports[i][0]),
                    MESH_NODE_METRICS_PORT=str(ports[i][1]))
         env.pop("SONATA_TENANTS", None)
@@ -610,7 +610,7 @@ def main() -> int:
 
     def boot(i: int) -> subprocess.Popen:
         env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   SMOKE_VOICE_CFG=cfg, SONATA_JAX_CACHE_DIR=cache,
+                   SMOKE_VOICE_CFG=cfg, JAX_COMPILATION_CACHE_DIR=cache,
                    MESH_NODE_GRPC_PORT=str(ports[i][0]),
                    MESH_NODE_METRICS_PORT=str(ports[i][1]))
         return subprocess.Popen(

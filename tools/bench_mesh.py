@@ -19,7 +19,7 @@ Produces the committed ``MESH_rNN.json`` artifact (folded into
 
 Backends boot via ``tools/serving_smoke.py --mesh-node-boot`` (the same
 pinned-port node boot the CI mesh phase uses), sharing one
-``SONATA_JAX_CACHE_DIR`` so boots after the first are warm.
+``JAX_COMPILATION_CACHE_DIR`` so boots after the first are warm.
 
 Run: ``JAX_PLATFORMS=cpu python tools/bench_mesh.py --out MESH_r01.json``
 """
@@ -81,7 +81,7 @@ def main() -> int:
 
     def boot(i: int) -> subprocess.Popen:
         env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   SMOKE_VOICE_CFG=cfg, SONATA_JAX_CACHE_DIR=cache,
+                   SMOKE_VOICE_CFG=cfg, JAX_COMPILATION_CACHE_DIR=cache,
                    MESH_NODE_GRPC_PORT=str(ports[i][0]),
                    MESH_NODE_METRICS_PORT=str(ports[i][1]))
         return subprocess.Popen(

@@ -24,7 +24,7 @@ Checks (exit 0 only if all hold):
    and device;
 8. warm-restart check (ISSUE 9): two boots with the bucket-lattice
    warmup (``SONATA_WARMUP_LATTICE=minimal``) against one populated
-   ``SONATA_JAX_CACHE_DIR`` — the second boot's time-to-ready must be
+   ``JAX_COMPILATION_CACHE_DIR`` — the second boot's time-to-ready must be
    materially faster (the persistent compile cache carries the
    executables), ``sonata_runtime_cold_compiles_total`` must stay 0
    under the smoke's traffic mix on both boots, and
@@ -105,7 +105,7 @@ def warm_restart_boot() -> int:
     boot — voice load, calibration + bucket-lattice warmup, the smoke
     traffic mix — reporting one ``WARMBOOT {json}`` line.  The cache
     dir, lattice mode, and voice config arrive via the parent's env
-    (``SONATA_JAX_CACHE_DIR`` / ``SONATA_WARMUP_LATTICE`` /
+    (``JAX_COMPILATION_CACHE_DIR`` / ``SONATA_WARMUP_LATTICE`` /
     ``SMOKE_VOICE_CFG``); the persistent compile cache is configured
     BEFORE the first compile, like a real process boot."""
     import jax
@@ -735,7 +735,7 @@ def main(args=None) -> int:
                     SONATA_ITER_PIPELINE="1",
                     SONATA_DISPATCH_POLICY="on",
                     SONATA_WARMUP_LATTICE="full",
-                    SONATA_JAX_CACHE_DIR=iter_cache,
+                    JAX_COMPILATION_CACHE_DIR=iter_cache,
                     JAX_PLATFORMS="cpu",
                     SMOKE_VOICE_CFG=cfg)
     p = subprocess.run(
@@ -779,7 +779,7 @@ def main(args=None) -> int:
     # process, and the JAX persistent compile cache only engages when
     # configured before the process's first compile (configuring it
     # mid-process after earlier phases compiled is silently inert).
-    # Boot 1 runs against an initially-EMPTY SONATA_JAX_CACHE_DIR
+    # Boot 1 runs against an initially-EMPTY JAX_COMPILATION_CACHE_DIR
     # (genuinely cold, populates it); boot 2 warms from disk.
     import json
     import subprocess
@@ -788,12 +788,12 @@ def main(args=None) -> int:
     cache_dir = tempfile.mkdtemp(prefix="smoke_jax_cache")
     # workers pinned to 1: the A/B below isolates the CACHE effect
     # (XLA persistent cache + the AOT executable store, both rooted in
-    # SONATA_JAX_CACHE_DIR) on time-to-ready, so both boots must share
+    # JAX_COMPILATION_CACHE_DIR) on time-to-ready, so both boots must share
     # one compile configuration — a wider cold boot would flatter the
     # ratio.  The warm boot deserializes AOT executables instead of
     # retracing, which is what makes the ratio robust on a noisy host.
     boot_env = dict(os.environ,
-                    SONATA_JAX_CACHE_DIR=cache_dir,
+                    JAX_COMPILATION_CACHE_DIR=cache_dir,
                     SONATA_WARMUP_LATTICE="minimal",
                     SONATA_WARMUP_WORKERS="1",
                     JAX_PLATFORMS="cpu",
@@ -848,7 +848,7 @@ def main(args=None) -> int:
             "notes": ("serving_smoke warm-restart phase: two subprocess "
                       "boots, SONATA_WARMUP_LATTICE=minimal, "
                       "SONATA_WARMUP_WORKERS=1 (controlled A/B), one "
-                      "shared initially-empty SONATA_JAX_CACHE_DIR "
+                      "shared initially-empty JAX_COMPILATION_CACHE_DIR "
                       "rooting both the XLA persistent cache and the "
                       "AOT executable store — the warm boot "
                       "deserializes executables instead of retracing; "
@@ -893,7 +893,7 @@ def main(args=None) -> int:
     def boot_node(i: int, empty: bool = False) -> subprocess.Popen:
         env = dict(os.environ, JAX_PLATFORMS="cpu",
                    SMOKE_VOICE_CFG=cfg,
-                   SONATA_JAX_CACHE_DIR=mesh_cache,
+                   JAX_COMPILATION_CACHE_DIR=mesh_cache,
                    MESH_NODE_GRPC_PORT=str(node_ports[i][0]),
                    MESH_NODE_METRICS_PORT=str(node_ports[i][1]),
                    MESH_NODE_EMPTY="1" if empty else "0")
@@ -1303,7 +1303,7 @@ def main(args=None) -> int:
     def boot_fc_node(i: int) -> subprocess.Popen:
         env = dict(os.environ, JAX_PLATFORMS="cpu",
                    SMOKE_VOICE_CFG=cfg,
-                   SONATA_JAX_CACHE_DIR=mesh_cache,
+                   JAX_COMPILATION_CACHE_DIR=mesh_cache,
                    SONATA_SYNTH_CACHE_MB="8",
                    MESH_NODE_GRPC_PORT=str(fc_ports[i][0]),
                    MESH_NODE_METRICS_PORT=str(fc_ports[i][1]),
@@ -1524,7 +1524,7 @@ def main(args=None) -> int:
     tn_log = open(os.path.join(mesh_cache, "tnnode0.log"), "w")
     tn_env = dict(os.environ, JAX_PLATFORMS="cpu",
                   SMOKE_VOICE_CFG=cfg,
-                  SONATA_JAX_CACHE_DIR=mesh_cache,
+                  JAX_COMPILATION_CACHE_DIR=mesh_cache,
                   SONATA_TENANTS=tn_table,
                   MESH_NODE_GRPC_PORT=str(tn_ports[0]),
                   MESH_NODE_METRICS_PORT=str(tn_ports[1]),
@@ -1699,7 +1699,7 @@ def main(args=None) -> int:
     lg_log = open(os.path.join(mesh_cache, "lgnode0.log"), "w")
     lg_env = dict(os.environ, JAX_PLATFORMS="cpu",
                   SMOKE_VOICE_CFG=cfg,
-                  SONATA_JAX_CACHE_DIR=mesh_cache,
+                  JAX_COMPILATION_CACHE_DIR=mesh_cache,
                   SONATA_LEDGER_MB="4",
                   MESH_NODE_GRPC_PORT=str(lg_ports[0]),
                   MESH_NODE_METRICS_PORT=str(lg_ports[1]),
