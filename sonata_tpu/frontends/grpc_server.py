@@ -1429,9 +1429,16 @@ def create_server(port: Optional[int] = None, *, mesh=None, seed: int = 0,
         "sonata_device_memory_peak_bytes",
         "Peak bytes in use on each local device since process start "
         "(memory_stats peak_bytes_in_use).")
+    reserved = runtime.registry.gauge(
+        "sonata_device_memory_peak_reserved_bytes",
+        "Peak bytes reserved on each local device since process start, "
+        "programs' temporaries included (memory_stats "
+        "peak_bytes_reserved): the peak that was reached.")
     for d in jax.local_devices():
         peak.labels(device=str(d.id)).set_function(
             lambda d=d: (d.memory_stats() or {}).get("peak_bytes_in_use"))
+        reserved.labels(device=str(d.id)).set_function(
+            lambda d=d: (d.memory_stats() or {}).get("peak_bytes_reserved"))
     # metrics/health HTTP plane: explicit port > SONATA_METRICS_PORT >
     # disabled (0 binds an ephemeral port, runtime.http_port has it)
     http_port = runtime.start_http(metrics_port)

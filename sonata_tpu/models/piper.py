@@ -894,11 +894,14 @@ class PiperVoice(BaseModel):
                 lengths[i] = int(wl[row])
                 row_ms[i] = ms
 
-        # direct callers (no scheduler) get their device work as a
-        # "dispatch" span too; under the batch scheduler this is a no-op
-        # (the worker thread carries no trace context — the scheduler
-        # records the shared dispatch span itself)
-        with tracing.span("dispatch", sentences=n, groups=len(chunks)):
+        # direct callers (no scheduler: the stock path) get their device
+        # work as a "dispatch" span that opens the annotation channel
+        # itself, so the groups below land in it and the scope counts the
+        # dispatch once; under the batch scheduler, which holds the
+        # channel and records the shared span, this is a no-op (the
+        # worker thread carries no trace context)
+        with tracing.dispatch_span(voice=self.scope_voice, sentences=n,
+                                   groups=len(chunks)):
             while gi < len(chunks) or pending:
                 # until the frame estimator has a real observation, keep
                 # one dispatch in flight: a cold underestimate would
@@ -1130,11 +1133,14 @@ class PiperVoice(BaseModel):
         """
         wav = vits.decode(params, hp, z, g=g, mesh=mesh,
                           compute_dtype=compute_dtype)
-        wav_lengths = y_lengths * hp.hop_length
-        valid = (jnp.arange(wav.shape[1])[None, :] < wav_lengths[:, None])
-        peak = jnp.max(jnp.abs(wav) * valid, axis=1, keepdims=True)
-        scale = 32767.0 / jnp.maximum(peak, 0.01)
-        wav_i16 = jnp.clip(wav * scale, -32768.0, 32767.0).astype(jnp.int16)
+        with jax.named_scope("epilogue"):
+            wav_lengths = y_lengths * hp.hop_length
+            valid = (jnp.arange(wav.shape[1])[None, :]
+                     < wav_lengths[:, None])
+            peak = jnp.max(jnp.abs(wav) * valid, axis=1, keepdims=True)
+            scale = 32767.0 / jnp.maximum(peak, 0.01)
+            wav_i16 = jnp.clip(wav * scale, -32768.0,
+                               32767.0).astype(jnp.int16)
         return wav_i16, wav_lengths, peak[:, 0]
 
     def _acoustics_fn(self, b: int, t: int, f: int):
@@ -1499,8 +1505,10 @@ class PiperVoice(BaseModel):
         m_p, logs_p, w_ceil, x_mask = self._encode_fn(b, t)(*args)
         return m_p, logs_p, w_ceil, x_mask, sid, b, t
 
-    def _estimate_frame_bucket(self, weighted_ids: float) -> int:
-        """``weighted_ids``: max over rows of ``len(ids) * length_scale`` —
+    def _frame_budget(self, weighted_ids: float) -> tuple[int, float]:
+        """``(frames budgeted, the estimator's frames per id it used)``.
+
+        ``weighted_ids``: max over rows of ``len(ids) * length_scale`` —
         the true per-row frame driver (a batch mixing a long 1x row with a
         short 3x row must not be budgeted as long × 3x)."""
         with self._fpi_lock:
@@ -1510,8 +1518,12 @@ class PiperVoice(BaseModel):
         # headroom on top and pushed typical batches a whole frame bucket
         # up — every row then ships a ~2x transfer window back to the
         # host.  Underestimates are caught and cost one (rare) retry.
-        est = weighted_ids * fpi * 1.08
-        return bucket_for(max(int(est), 1), FRAME_BUCKETS)
+        return max(int(weighted_ids * fpi * 1.08), 1), fpi
+
+    def _estimate_frame_bucket(self, weighted_ids: float) -> int:
+        """The frame bucket :meth:`_frame_budget` rounds up to."""
+        return bucket_for(self._frame_budget(weighted_ids)[0],
+                          FRAME_BUCKETS)
 
     def _observe_frames(self, weighted_ids: float, frames: int) -> None:
         ratio = frames / max(weighted_ids, 1.0)
@@ -1553,46 +1565,63 @@ class PiperVoice(BaseModel):
         """Asynchronously dispatch one batch; returns a ticket for
         :meth:`_finish_batch`.  Split from the fetch so callers can keep
         several dispatches in flight (``speak_batch`` pipelines them)."""
+        t_start = time.perf_counter()
         n_real = len(ids_list)
-        ids, lens, b, t = self._pad_batch(ids_list)
-        sid = self._sid_array(sc, b, speakers)
-        nw, ls, ns, ls_host = self._scale_arrays(sc, b, scales)
-        weighted_ids = float(max(
-            len(row) * max(ls_host[i], 0.05)
-            for i, row in enumerate(ids_list)))
-        # one key for both dispatches: the overflow retry must reproduce the
-        # exact duration draw it measured, or the bigger bucket could clip
-        # a fresh, longer draw
-        rng = self._next_rng()
-        args = [self.params, ids, lens, rng, nw, ls, ns]
-        if sid is not None:
-            args.append(sid)
-        f = self._estimate_frame_bucket(weighted_ids)
-        with self._jit_lock:
-            cached = (b, t, f) in self._full_cache
-        # dispatch attribution for whoever opened the channel (the batch
-        # scheduler, around speak_batch): the padded shape this batch
-        # actually ran at, what the padding cost, and whether this shape
-        # paid an XLA compile — the single biggest TTFB outlier cause.
-        # Group-wise: one speak_batch may issue several device programs,
-        # and a cold group must never be shadowed by a later cached one
-        # non-default length scales change the frame estimate, so their
-        # shapes sit OUTSIDE the warmup lattice's coverage promise —
-        # flagged here so the scope's cold-compile containment doesn't
-        # report a legitimate scaled request as a coverage regression
-        scaled = any(abs(l - sc.length_scale) > 1e-9
-                     for l in ls_host[:n_real])
-        tracing.annotate_dispatch_group(
-            batch_bucket=b, text_bucket=t, frame_bucket=f, rows=n_real,
-            padding_rows=b - n_real,
-            padding_ratio=round((b - n_real) / b, 3),
-            compile="cached" if cached else "cold",
-            **({"scaled": True} if scaled else {}))
-        out = self._full_fn(b, t, f)(*args)  # async dispatch
-        self._prefetch_to_host(out)
+        with tracing.annotation("enqueue"):
+            ids, lens, b, t = self._pad_batch(ids_list)
+            sid = self._sid_array(sc, b, speakers)
+            nw, ls, ns, ls_host = self._scale_arrays(sc, b, scales)
+            weighted_ids = float(max(
+                len(row) * max(ls_host[i], 0.05)
+                for i, row in enumerate(ids_list)))
+            # one key for both dispatches: the overflow retry must
+            # reproduce the exact duration draw it measured, or the bigger
+            # bucket could clip a fresh, longer draw
+            rng = self._next_rng()
+            args = [self.params, ids, lens, rng, nw, ls, ns]
+            if sid is not None:
+                args.append(sid)
+            budget, fpi = self._frame_budget(weighted_ids)
+            f = bucket_for(budget, FRAME_BUCKETS)
+            with self._jit_lock:
+                cached = (b, t, f) in self._full_cache
+            # the one record of this device program, for whoever holds
+            # the dispatch channel (the batch scheduler around
+            # speak_batch, or speak_batch itself on the stock path) and
+            # for the always-on counters: the padded shape this batch
+            # actually ran at, what the padding cost, and whether this
+            # shape paid an XLA compile — the single biggest TTFB outlier
+            # cause.  Group-wise: one speak_batch may issue several device
+            # programs, and a cold group must never be shadowed by a later
+            # cached one.
+            # non-default length scales change the frame estimate, so
+            # their shapes sit OUTSIDE the warmup lattice's coverage
+            # promise — flagged here so the scope's cold-compile
+            # containment doesn't report a legitimate scaled request as a
+            # coverage regression
+            scaled = any(abs(l - sc.length_scale) > 1e-9
+                         for l in ls_host[:n_real])
+            group = tracing.annotate_dispatch_group(
+                batch_bucket=b, text_bucket=t, frame_bucket=f, rows=n_real,
+                padding_rows=b - n_real,
+                padding_ratio=round((b - n_real) / b, 3),
+                compile="cached" if cached else "cold",
+                **({"scaled": True} if scaled else {}))
+            t_launch = time.perf_counter()
+            out = self._full_fn(b, t, f)(*args)  # async dispatch
+            self._prefetch_to_host(out)
+        t_enqueue = time.perf_counter()
+        # what the headline does not aggregate stays on the group: the
+        # budget the bucket was chosen from, the estimate behind it, and
+        # how much of the enqueue was the jitted call itself, which is
+        # asynchronous only while the runtime has a free slot for one
+        # more program in flight (and compiles, on a cold shape)
+        group.update(frames_budget=budget, frames_per_id=round(fpi, 4),
+                     enqueue_ms=round((t_enqueue - t_start) * 1e3, 3),
+                     launch_ms=round((t_enqueue - t_launch) * 1e3, 3))
         return {"out": out, "args": args, "b": b, "t": t, "f": f,
                 "n_real": n_real, "weighted_ids": weighted_ids,
-                "t_enqueue": time.perf_counter()}
+                "t_enqueue": t_enqueue, "group": group}
 
     @staticmethod
     def _prefetch_to_host(out) -> None:
@@ -1609,22 +1638,38 @@ class PiperVoice(BaseModel):
         """Fetch a ticket's result; on frame-budget overflow re-dispatch
         once with a bucket that is known to fit (same RNG key → identical
         duration draw → identical audio)."""
-        # one batched fetch: device_get coalesces the per-array copies
-        wav_i16, wav_lengths, peaks, frames_needed = jax.device_get(
-            ticket["out"])
-        n_real = ticket["n_real"]
-        actual = int(frames_needed[:n_real].max())
-        self._observe_frames(ticket["weighted_ids"], actual)
-        if actual > ticket["f"]:  # overflow: audio was clipped; rerun
-            f = bucket_for(actual, FRAME_BUCKETS)
-            out = self._full_fn(ticket["b"], ticket["t"], f)(*ticket["args"])
-            # no prefetch here: the blocking fetch on the next line leaves
-            # nothing for an async D2H copy to overlap with
-            wav_i16, wav_lengths, peaks, frames_needed = jax.device_get(out)
-        wav_i16 = wav_i16[:n_real]
-        peaks = np.maximum(peaks[:n_real, None], 0.01)
-        # dequantize back to the model's original amplitudes
-        wav = wav_i16.astype(np.float32) * (peaks / 32767.0)
+        group = ticket["group"]
+        t_start = time.perf_counter()
+        with tracing.annotation("fetch"):
+            # one batched fetch: device_get coalesces the per-array copies
+            wav_i16, wav_lengths, peaks, frames_needed = jax.device_get(
+                ticket["out"])
+            n_real = ticket["n_real"]
+            needed = frames_needed[:n_real]
+            actual = int(needed.max())
+            self._observe_frames(ticket["weighted_ids"], actual)
+            if actual > ticket["f"]:  # overflow: audio was clipped; rerun
+                f = bucket_for(actual, FRAME_BUCKETS)
+                group.update(overflow=True, retry_bucket=f)
+                out = self._full_fn(ticket["b"], ticket["t"], f)(
+                    *ticket["args"])
+                # no prefetch here: the blocking fetch on the next line
+                # leaves nothing for an async D2H copy to overlap with
+                wav_i16, wav_lengths, peaks, frames_needed = \
+                    jax.device_get(out)
+        t_fetched = time.perf_counter()
+        with tracing.annotation("epilogue"):
+            wav_i16 = wav_i16[:n_real]
+            peaks = np.maximum(peaks[:n_real, None], 0.01)
+            # dequantize back to the model's original amplitudes
+            wav = wav_i16.astype(np.float32) * (peaks / 32767.0)
+        t_done = time.perf_counter()
+        # the frames a row needs do not depend on the bucket, so the
+        # clipped program already reported what the rerun then served
+        group.update(frames_needed=needed.tolist(),
+                     fetch_wait_ms=round((t_fetched - t_start) * 1e3, 3),
+                     epilogue_ms=round((t_done - t_fetched) * 1e3, 3))
+        tracing.record_device_group(group, self.scope_voice)
         return wav, wav_lengths[:n_real]
 
     # ------------------------------------------------------------------

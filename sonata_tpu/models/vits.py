@@ -195,6 +195,7 @@ def text_encoder(p: Params, hp: VitsHyperParams, ids, x_mask, mesh=None):
     return x, m_p, logs_p
 
 
+@jax.named_scope("duration_predictor")
 def duration_predictor_reverse(p: Params, hp: VitsHyperParams, x, x_mask,
                                rng, noise_w, g=None):
     """Stochastic duration predictor, inference (reverse-flow) path → logw.
@@ -244,6 +245,7 @@ def _conv_flow_reverse(pf: Params, hp: VitsHyperParams, z, mask, g):
     return jnp.concatenate([z0, x1[..., None] * mask], axis=-1)
 
 
+@jax.named_scope("encode_text")
 def encode_text(p: Params, hp: VitsHyperParams, ids, x_lengths, rng, *,
                 noise_w: float, length_scale: float, sid=None, mesh=None):
     """ids [B, T] → (m_p, logs_p [B, T, C], durations w_ceil [B, T], g).
@@ -292,6 +294,7 @@ def generate_path(w_ceil, x_mask, max_frames: int):
     return (upper & lower).astype(jnp.float32)
 
 
+@jax.named_scope("acoustics")
 def acoustics(p: Params, hp: VitsHyperParams, m_p, logs_p, w_ceil, x_mask,
               rng, *, noise_scale: float, max_frames: int, g=None,
               mesh=None):
@@ -337,6 +340,7 @@ def _use_seq_parallel(mesh, frames: int, hp: VitsHyperParams) -> bool:
     return frames // seq >= min_local_frames(hp)
 
 
+@jax.named_scope("flow_reverse")
 def flow_reverse(pf: Params, hp: VitsHyperParams, z, mask, g=None,
                  conv=None, mesh=None):
     """``mesh``: set only by a data-sharded ``jax.jit`` caller (the gate
@@ -361,6 +365,7 @@ def flow_reverse(pf: Params, hp: VitsHyperParams, z, mask, g=None,
 # stage 3: HiFi-GAN decoder
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("decode")
 def decode(p: Params, hp: VitsHyperParams, z, g=None, mesh=None,
            compute_dtype=None):
     """Latent ``z`` [B, F, C] → waveform [B, F * hop].
@@ -407,24 +412,30 @@ def decode_with(p: Params, hp: VitsHyperParams, z, g=None, conv=None,
         z = z.astype(compute_dtype)
         if g is not None:
             g = g.astype(compute_dtype)
-    x = conv(z, pd["conv_pre"])
-    if g is not None and "cond" in pd:
-        x = x + m.conv1d(g, pd["cond"])
+    # the stages carry names into the compiled program (metadata only), so
+    # a device trace is read by stage: pre, ups1..upsN (an upsampling
+    # convolution and its residual blocks), post
+    with jax.named_scope("pre"):
+        x = conv(z, pd["conv_pre"])
+        if g is not None and "cond" in pd:
+            x = x + m.conv1d(g, pd["cond"])
     n_kernels = len(hp.resblock_kernel_sizes)
     for i, (r_up, k_up) in enumerate(zip(hp.upsample_rates, hp.upsample_kernel_sizes)):
+        with jax.named_scope(f"ups{i + 1}"):
+            x = jax.nn.leaky_relu(x, m.LRELU_SLOPE)
+            x = tconv(x, pd["ups"][i], stride=r_up,
+                      padding=(k_up - r_up) // 2)
+            xs = None
+            for j in range(n_kernels):
+                block = pd["resblocks"][i * n_kernels + j]
+                y = _resblock1(block, x, hp.resblock_kernel_sizes[j],
+                               hp.resblock_dilation_sizes[j], conv=conv)
+                xs = y if xs is None else xs + y
+            x = xs / n_kernels
+    with jax.named_scope("post"):
         x = jax.nn.leaky_relu(x, m.LRELU_SLOPE)
-        x = tconv(x, pd["ups"][i], stride=r_up,
-                  padding=(k_up - r_up) // 2)
-        xs = None
-        for j in range(n_kernels):
-            block = pd["resblocks"][i * n_kernels + j]
-            y = _resblock1(block, x, hp.resblock_kernel_sizes[j],
-                           hp.resblock_dilation_sizes[j], conv=conv)
-            xs = y if xs is None else xs + y
-        x = xs / n_kernels
-    x = jax.nn.leaky_relu(x, m.LRELU_SLOPE)
-    x = conv(x, pd["conv_post"])
-    return jnp.tanh(x.astype(jnp.float32))[..., 0]  # [B, samples]
+        x = conv(x, pd["conv_post"])
+        return jnp.tanh(x.astype(jnp.float32))[..., 0]  # [B, samples]
 
 
 def _resblock1(block: Params, x, kernel: int, dilations, conv=None):

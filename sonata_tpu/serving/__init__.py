@@ -220,6 +220,10 @@ class ServingRuntime:
         for site in faults.SITES:
             fp.labels(site=site).set_function(
                 lambda s=site: faults.fires_total(s))
+        #: the device programs' own counters (frames by cause, overflow
+        #: reruns, host seconds per phase): counted where the programs
+        #: run, read here at scrape time
+        tracing.program_stats().bind_metrics(r)
         #: sonata-scope aggregation plane (ISSUE 7): rolling per-stage
         #: quantiles, SLO burn rates, dispatch padding-waste accounting,
         #: and the 1 Hz flight recorder.  SONATA_SCOPE=0 disables; the
@@ -383,6 +387,12 @@ class ServingRuntime:
                     "node (the actual-state signal the sonata-mesh "
                     "placement reconciler diffs against desired state).",
                     lambda: 1.0)
+        voice_gauge("sonata_frame_estimator_frames_per_id",
+                    "The frame-budget estimator's frames per phoneme id "
+                    "(a slowly decaying maximum of what was observed) as "
+                    "the voice's latest full-pipeline program was "
+                    "budgeted with it.",
+                    lambda: tracing.program_stats().frames_per_id(voice_id))
         if rtf_counter is not None:
             def stat(attr):
                 return lambda: float(getattr(rtf_counter.snapshot(), attr))

@@ -569,14 +569,19 @@ class _Handler(BaseHTTPRequestHandler):
             self._reply(400, b"seconds must be a number\n")
             return
         try:
-            log_dir = capture_profile(seconds)
+            capture = capture_profile(seconds)
         except RuntimeError as e:  # capture already running
             self._reply(409, (str(e) + "\n").encode())
             return
         except Exception as e:  # jax profiler unavailable on this build
             self._reply(503, f"profiler capture failed: {e}\n".encode())
             return
-        body = json.dumps({"log_dir": log_dir, "seconds": seconds,
+        # anchors: the host's wall and monotonic clocks around
+        # start_trace and stop_trace, so a reader puts the program's
+        # spans (wall_start + start_ms) on the trace's clock
+        body = json.dumps({"log_dir": capture["log_dir"],
+                           "seconds": seconds,
+                           "anchors": capture["anchors"],
                            "view": "tensorboard --logdir <log_dir> "
                                    "(or load into Perfetto/XProf)"})
         self._reply(200, body.encode("utf-8"),

@@ -963,6 +963,8 @@ def install(scope: Scope) -> None:
     from . import tracing
 
     tracing.set_trace_observer(_on_trace_finished)
+    # dispatches a model records itself (no scheduler in front of it)
+    tracing.set_dispatch_observer(note_dispatch)
 
 
 def uninstall(scope: Scope) -> None:
@@ -974,6 +976,7 @@ def uninstall(scope: Scope) -> None:
         from . import tracing
 
         tracing.set_trace_observer(None)
+        tracing.set_dispatch_observer(None)
 
 
 def installed() -> Optional[Scope]:
@@ -987,7 +990,8 @@ def _on_trace_finished(trace) -> None:
 
 
 def note_dispatch(duration_s: float, attrs: dict) -> None:
-    """Scheduler hook: one device dispatch finished (no-op — a single
+    """One device dispatch finished: called by the schedulers, and by the
+    tracing module for a model that was called directly (no-op — a single
     module-global read — when no scope is installed)."""
     scope = _installed
     if scope is not None:

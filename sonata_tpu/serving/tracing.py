@@ -26,9 +26,23 @@ Design constraints, in order:
   participating request's trace — same ``dispatch_id``, annotated with
   batch size, the co-batched peers' request ids, bucket shape, padding
   ratio, replica/device, and compile-vs-cached.  The model layer fills
-  the bucket/compile fields through :func:`annotate_dispatch`, a
-  contextvar channel the scheduler opens around ``speak_batch`` — no
-  tracer object ever threads through the model protocol.
+  the bucket/compile fields through :func:`annotate_dispatch_group`, a
+  contextvar channel opened around ``speak_batch`` by the scheduler, or
+  by the model's own :func:`dispatch_span` when it is called directly
+  (the stock path) — no tracer object ever threads through the model
+  protocol.
+- **One record per device program.**  The model describes every
+  full-pipeline program it runs (:func:`annotate_dispatch_group` when it
+  is enqueued, :func:`record_device_group` when its result is on the
+  host): padded shape, frames each row needed against the budget and the
+  bucket, overflow reruns, host time per phase.  The record lands in the
+  enclosing ``dispatch`` span's ``device_groups`` and, traced or not, in
+  :class:`ProgramStats`, the counters ``/metrics`` exports.
+- **Spans inside the profiler's trace.**  While ``/debug/profile`` holds
+  a ``jax.profiler`` capture, :func:`span` and :func:`annotation` mirror
+  the program's boundaries into it as ``sonata:<name>`` events carrying
+  ``request_id`` / ``dispatch_id``, so host spans and device operations
+  share one file and one clock.
 
 Finished traces export three ways:
 
@@ -40,9 +54,9 @@ Finished traces export three ways:
    (``SONATA_TRACE_RECENT``/``SONATA_TRACE_SLOWEST``), served from the
    metrics HTTP plane at ``/debug/traces`` and ``/debug/slowest``.
 
-``SONATA_TRACE=0`` disables tracing entirely (default: on; measured
-overhead on the streaming bench is within noise — see
-BENCH_STREAMING_CPU_r09.json ``trace_overhead``).
+``SONATA_TRACE=0`` disables tracing entirely (default: on; what the
+spans cost on a chip, and what a profiler capture costs on top, is
+measured in PERF.md, "Where the time goes").
 """
 
 from __future__ import annotations
@@ -59,6 +73,8 @@ import time
 import uuid
 from collections import deque
 from typing import Iterator, Optional
+
+from ..utils import profiling
 
 log = logging.getLogger("sonata.trace")
 
@@ -347,6 +363,12 @@ def _reset(token) -> None:
         pass
 
 
+#: the request-level spans that :func:`span` mirrors into the profiler's
+#: trace while a capture runs: once per request, never per row (the
+#: device-group phases go through :func:`annotation`)
+PROFILED_SPANS = frozenset(("phonemize", "encode-ids", "stream-emit"))
+
+
 @contextlib.contextmanager
 def span(name: str, **attrs) -> Iterator:
     """Record a child span of the current context; no-op without a trace.
@@ -363,7 +385,10 @@ def span(name: str, **attrs) -> Iterator:
     sp = trace.new_span(name, parent=parent, attrs=attrs)
     token = _CTX.set((trace, sp))
     try:
-        yield sp
+        with (profiling.annotation("sonata:" + name,
+                                   request_id=trace.request_id)
+              if name in PROFILED_SPANS else profiling.NO_ANNOTATION):
+            yield sp
     except BaseException as e:
         sp.annotate(error=f"{type(e).__name__}: {e}")
         raise
@@ -394,15 +419,13 @@ def dispatch_scope(attrs: dict) -> Iterator[dict]:
 
 
 def annotate_dispatch(**attrs) -> None:
-    """Attach attributes to the active dispatch span, if any (no-op
-    outside a :func:`dispatch_scope` — e.g. direct ``speak_batch``
-    calls)."""
+    """Attach attributes to the active dispatch span, if any."""
     d = _DISPATCH.get()
     if d is not None:
         d.update(attrs)
 
 
-def annotate_dispatch_group(**attrs) -> None:
+def annotate_dispatch_group(**attrs) -> dict:
     """Like :func:`annotate_dispatch`, for models whose one
     ``speak_batch`` call issues SEVERAL device programs (bucket groups).
 
@@ -411,15 +434,20 @@ def annotate_dispatch_group(**attrs) -> None:
     outlier-relevant ones worst-case — ``compile`` is ``cold`` if ANY
     group compiled, ``padding_ratio`` is the max — so a cold first group
     followed by cached ones can never be misread as a cached dispatch.
+
+    Returns the group's record (the dict ``device_groups`` holds, or a
+    free one when no channel is open), for the model to complete once
+    the program's result is on the host and hand to
+    :func:`record_device_group`.
     """
     d = _DISPATCH.get()
     if d is None:
-        return
+        return attrs
     groups = d.setdefault("device_groups", [])
-    groups.append(dict(attrs))
+    groups.append(attrs)
     if len(groups) == 1:
         d.update(attrs)
-        return
+        return attrs
     if attrs.get("compile") == "cold":
         d["compile"] = "cold"
     if "padding_ratio" in attrs:
@@ -429,6 +457,197 @@ def annotate_dispatch_group(**attrs) -> None:
         # any scaled group puts the whole dispatch outside the warmup
         # lattice's coverage promise (cold-compile containment skips it)
         d["scaled"] = True
+    return attrs
+
+
+def annotation(phase: str):
+    """One phase of a device group (``enqueue``, ``fetch``, ``epilogue``)
+    as a ``sonata:<phase>`` event in the profiler's trace, carrying the
+    open dispatch's ids; a no-op context outside a capture."""
+    d = _DISPATCH.get() or {}
+    ids = {"dispatch_id": d.get("dispatch_id"),
+           "request_id": ",".join(d.get("request_ids", ()))}
+    return profiling.annotation(
+        "sonata:" + phase, **{k: v for k, v in ids.items() if v})
+
+
+@contextlib.contextmanager
+def dispatch_span(voice: Optional[str] = None, **attrs) -> Iterator:
+    """The ``dispatch`` span of a model called with or without a
+    scheduler in front of it.
+
+    Under a scheduler's :func:`dispatch_scope` this is :func:`span` and
+    nothing else: the scheduler records the shared span and the scope's
+    accounting once for the whole call.  Called directly (the stock
+    path), the model opens the channel itself: the span gets a
+    ``dispatch_id`` and everything the groups annotate, and the dispatch
+    is counted exactly once by the installed scope, traced or not, under
+    the trace's ``voice`` (else ``voice``, the model's own label).
+    """
+    if _DISPATCH.get() is not None:
+        with span("dispatch", **attrs) as sp:
+            yield sp
+        return
+    chan: dict = {"dispatch_id": new_id()}
+    trace = current_trace()
+    if trace is not None:
+        chan["request_ids"] = [trace.request_id]
+        voice = trace.attrs.get("voice") or voice
+    if voice:
+        chan["voice"] = voice
+    t0 = time.monotonic()
+    with span("dispatch", **attrs) as sp, dispatch_scope(chan):
+        try:
+            yield sp
+        finally:
+            sp.annotate(**chan)
+    observer = _DISPATCH_OBSERVER
+    if observer is not None:
+        observer(time.monotonic() - t0, chan)
+
+
+#: called with ``(seconds, attrs)`` of every dispatch a model recorded
+#: itself (:func:`dispatch_span` outside a scheduler); the scope installs
+#: its ``note_dispatch`` here, as it installs the finished-trace hook
+_DISPATCH_OBSERVER: Optional[callable] = None
+
+
+def set_dispatch_observer(fn) -> None:
+    global _DISPATCH_OBSERVER
+    _DISPATCH_OBSERVER = fn
+
+
+# ---------------------------------------------------------------------------
+# one record per device program (span attributes and always-on counters)
+# ---------------------------------------------------------------------------
+
+#: why a frame the device computed was computed; the parts of one program
+#: sum to its padded ``batch_bucket * frame_bucket``
+FRAME_PARTS = ("served", "ragged", "headroom", "bucket", "dummy_rows",
+               "retried")
+HOST_PHASES = ("enqueue", "fetch_wait", "epilogue")
+
+
+def frame_parts(group: dict) -> dict:
+    """The frames one device group computed, split by cause.
+
+    ``served``: what the rows needed (the audio that went out).
+    ``ragged``: rows shorter than the group's longest.  ``headroom``: the
+    frame budget (the estimator's running maximum and its safety factor)
+    above the longest row, clipped to the bucket.  ``bucket``: the
+    ladder's step above the budget.  ``dummy_rows``: rows that pad the
+    batch.  An overflowed program was clipped and thrown away, so it
+    counts whole under ``retried``; its rerun was budgeted at what was
+    measured, so it has no headroom.
+    """
+    b, n, f = group["batch_bucket"], group["rows"], group["frame_bucket"]
+    needs = group["frames_needed"]
+    served, longest = sum(needs), max(needs)
+    budget = group["frames_budget"]
+    parts = dict.fromkeys(FRAME_PARTS, 0)
+    if group.get("overflow"):
+        parts["retried"] = b * f
+        f = group["retry_bucket"]
+        budget = longest
+    budget = min(max(budget, longest), f)
+    parts.update(served=served, ragged=n * longest - served,
+                 headroom=n * (budget - longest), bucket=n * (f - budget),
+                 dummy_rows=(b - n) * f)
+    return parts
+
+
+class ProgramStats:
+    """Process-lifetime counters of the full-pipeline device programs,
+    fed by :func:`record_device_group` whether or not a trace is active
+    (one lock and a dozen adds per program) and read by ``/metrics``."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._frames = dict.fromkeys(FRAME_PARTS, 0)
+        self._host_s = dict.fromkeys(HOST_PHASES, 0.0)
+        self._groups = 0
+        self._overflow_retries = 0
+        self._frames_per_id: dict = {}
+
+    def record(self, group: dict, voice: Optional[str] = None) -> None:
+        parts = frame_parts(group)
+        with self._lock:
+            self._groups += 1
+            for part, frames in parts.items():
+                self._frames[part] += frames
+            for phase in HOST_PHASES:
+                self._host_s[phase] += group[phase + "_ms"] / 1e3
+            if group.get("overflow"):
+                self._overflow_retries += 1
+            self._frames_per_id[voice or ""] = group["frames_per_id"]
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return {"groups": self._groups,
+                    "overflow_retries": self._overflow_retries,
+                    "frames": dict(self._frames),
+                    "host_seconds": dict(self._host_s),
+                    "frames_per_id": dict(self._frames_per_id)}
+
+    def frames_per_id(self, voice: str) -> Optional[float]:
+        """The frame-budget estimator's value at the voice's latest
+        program (``None`` before the first)."""
+        with self._lock:
+            return self._frames_per_id.get(voice)
+
+    def bind_metrics(self, registry) -> None:
+        """Attach the counters to a registry as scrape-time callbacks
+        (process-lifetime series: nothing per voice, nothing to tear
+        down).  A callback reads one number, which needs no lock."""
+        frames = registry.counter(
+            "sonata_dispatch_frames_total",
+            "Frames computed by full-pipeline device programs, by cause: "
+            "served (what the rows needed), ragged (rows shorter than "
+            "their group's longest), headroom (frame budget above the "
+            "longest row), bucket (frame bucket above the budget), "
+            "dummy_rows (batch padding), retried (clipped programs that "
+            "were rerun).  The parts of a program sum to its padded "
+            "batch x frames.")
+        for part in FRAME_PARTS:
+            frames.labels(part=part).set_function(
+                lambda p=part: float(self._frames[p]))
+        host = registry.counter(
+            "sonata_dispatch_host_seconds_total",
+            "Host seconds per phase of a device program: enqueue (pad, "
+            "transfer, asynchronous dispatch), fetch_wait (blocked on the "
+            "result), epilogue (dequantise and slice).")
+        for phase in HOST_PHASES:
+            host.labels(phase=phase).set_function(
+                lambda p=phase: self._host_s[p])
+        registry.counter(
+            "sonata_dispatch_groups_total",
+            "Full-pipeline device groups finished (one program each, two "
+            "on an overflow)."
+        ).set_function(lambda: float(self._groups))
+        registry.counter(
+            "sonata_dispatch_overflow_retries_total",
+            "Device groups whose frame bucket was too small for a row, so "
+            "the program ran again in a larger one."
+        ).set_function(lambda: float(self._overflow_retries))
+
+
+_program_stats = ProgramStats()
+
+
+def program_stats() -> ProgramStats:
+    """The process's one :class:`ProgramStats` (programs are counted
+    where they run, whichever runtime or caller started them)."""
+    return _program_stats
+
+
+def record_device_group(group: dict, voice: Optional[str] = None) -> None:
+    """A device group's program has finished and ``group`` (the record
+    :func:`annotate_dispatch_group` returned) is complete: count it, and
+    flag an overflow on the open dispatch's headline."""
+    d = _DISPATCH.get()
+    if d is not None and group.get("overflow"):
+        d["overflow"] = True
+    _program_stats.record(group, voice)
 
 
 # ---------------------------------------------------------------------------

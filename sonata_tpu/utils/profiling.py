@@ -12,8 +12,8 @@ from __future__ import annotations
 import contextlib
 import threading
 import time
-from dataclasses import dataclass, field
-from typing import Iterator, Optional
+from dataclasses import dataclass
+from typing import Optional
 
 
 @dataclass
@@ -128,60 +128,78 @@ class Histogram:
                                  total=total, sum=s)
 
 
-@contextlib.contextmanager
-def trace(log_dir: str) -> Iterator[None]:
-    """Capture a jax.profiler device trace into ``log_dir`` (view with
-    Tensorboard/XProf)."""
-    import jax
-
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
 #: the jax profiler cannot nest captures; serialize /debug/profile hits
 _PROFILE_LOCK = threading.Lock()
 
+#: true while :func:`capture_profile` holds a capture: what
+#: :func:`annotation` reads, so that outside a capture a mirrored span
+#: costs this one flag read
+_capturing = False
 
-def capture_profile(seconds: float, log_dir: Optional[str] = None) -> str:
-    """Capture a ``jax.profiler`` device trace for ``seconds`` and return
-    the log directory (view with Tensorboard/XProf or Perfetto).
+NO_ANNOTATION = contextlib.nullcontext()
+
+
+def annotation(name: str, **ids):
+    """A host span inside the profiler's own trace, while
+    :func:`capture_profile` holds a capture; a shared no-op context
+    otherwise.  ``ids`` (strings or numbers, e.g. ``dispatch_id``) are
+    stored with the event, so a reader joins host spans to the program's
+    own records by id and to device operations by time: one file, one
+    clock."""
+    if not _capturing:
+        return NO_ANNOTATION
+    import jax
+
+    return jax.profiler.TraceAnnotation(name, **ids)
+
+
+def _clock_anchor() -> dict:
+    """The wall clock and the monotonic clock (the one the program's
+    spans use) read together."""
+    return {"wall": time.time(), "monotonic": time.monotonic()}
+
+
+def capture_profile(seconds: float, log_dir: Optional[str] = None) -> dict:
+    """Capture a ``jax.profiler`` device trace for ``seconds``.  Returns
+    ``{"log_dir", "anchors"}``: the directory to view with
+    Tensorboard/XProf or Perfetto, and the host's clocks read immediately
+    before ``start_trace`` was called (``start_called``), after it
+    returned (``start_returned``), and around ``stop_trace``
+    (``stop_called``, ``stop_returned``).  The trace's own clock starts
+    between the first two (on a v5e a quarter of a millisecond after the
+    first, PERF.md), so a reader places wall-clock times on it without
+    fitting anything.
 
     What the metrics plane's ``/debug/profile?seconds=`` endpoint runs:
     the tracing layer answers *where a request's wall time went*; this
-    answers *what the device was doing meanwhile*.  Raises
-    ``RuntimeError`` when a capture is already running (the profiler
-    cannot nest).
+    answers *what the device was doing meanwhile*, and while it runs the
+    program's spans are mirrored into the same file (:func:`annotation`).
+    Raises ``RuntimeError`` when a capture is already running (the
+    profiler cannot nest).
     """
     import tempfile
 
+    import jax
+
+    global _capturing
     seconds = min(max(float(seconds), 0.1), 60.0)
     if log_dir is None:
         log_dir = tempfile.mkdtemp(prefix="sonata_profile_")
     if not _PROFILE_LOCK.acquire(blocking=False):
         raise RuntimeError("a profiler capture is already running")
+    anchors = {}
     try:
-        with trace(log_dir):
+        anchors["start_called"] = _clock_anchor()
+        jax.profiler.start_trace(log_dir)
+        try:
+            anchors["start_returned"] = _clock_anchor()
+            _capturing = True
             time.sleep(seconds)
+        finally:
+            _capturing = False
+            anchors["stop_called"] = _clock_anchor()
+            jax.profiler.stop_trace()
+            anchors["stop_returned"] = _clock_anchor()
     finally:
         _PROFILE_LOCK.release()
-    return log_dir
-
-
-@contextlib.contextmanager
-def timed(label: str, sink: Optional[list] = None) -> Iterator[None]:
-    """Wall-clock a block; append ``(label, seconds)`` to ``sink`` or log."""
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = time.perf_counter() - t0
-        if sink is not None:
-            sink.append((label, dt))
-        else:
-            import logging
-
-            logging.getLogger("sonata.profiling").debug(
-                "%s: %.1f ms", label, dt * 1e3)
+    return {"log_dir": log_dir, "anchors": anchors}
