@@ -10,7 +10,15 @@ Design notes (TPU-first, not a port):
   blocks, HiFi-GAN decoder) natively in JAX so XLA owns fusion/layout.
 - Everything is ``[batch, time, channels]`` (NTC): the lane dimension maps to
   channels, convs lower to MXU matmuls, and no transposes are needed between
-  attention and conv blocks.
+  attention and conv blocks.  That holds down to :data:`LANES` (128)
+  channels.  Below, XLA still puts channels on the minor axis and pads it to
+  the tile: ``f32[8,196608,32]{2,0,1:T(8,128)}`` moves four times its bytes
+  through HBM and fills a quarter of a 128 x 128 weight tile.  So the
+  HiFi-GAN stages under 128 channels run *time-folded*: ``r = 128 // C``
+  consecutive time steps ride the channel axis, ``[B, T / r, r * C]``, and
+  each convolution's weights are folded to match (:func:`fold_conv`,
+  :func:`fold_conv_transpose`; the rule is :func:`fold_factor`).  It is the
+  same sum of the same products; only the float32 summation order differs.
 - Params are plain nested dicts (a JAX pytree).  Each block has
   ``init_*(rng, ...) -> params`` and a pure ``apply`` function, so the whole
   model jits/pjits and weights import cleanly from Piper torch checkpoints.
@@ -89,6 +97,8 @@ def conv_transpose1d(x, p, *, stride: int, padding: int):
     textbook ``lhs_dilation`` lowering makes the MXU multiply mostly
     zeros — ``stride-1`` of every ``stride`` dilated input positions are
     stuffing — an ~8x FLOP waste at Piper's first upsample stage.
+    (A time-folded decoder stage takes the sub-pixel kernel directly,
+    :func:`fold_conv_transpose`, and does not come through here.)
     """
     k = p["w"].shape[0]
     if (k - stride == 2 * padding and stride > 1
@@ -116,8 +126,19 @@ def conv_transpose1d_subpixel(x, p, *, stride: int, padding: int):
     Requires the exact-upsample geometry ``(T-1)s - 2p + K == T*s``, i.e.
     ``K - s == 2p`` (all Piper/HiFi-GAN stages satisfy this).
     """
-    w = p["w"]  # [K, C_in, C_out]
-    k, c_in, c_out = w.shape
+    wsub, d_lo, d_hi = _subpixel_kernel(p["w"], stride, padding)
+    y = lax.conv_general_dilated(
+        x, wsub, window_strides=(1,), padding=[(-d_lo, d_hi)],
+        dimension_numbers=("NHC", "HIO", "NHC"),
+    )  # [B, T, s*C_out]
+    return fold_time(y, stride, 1) + p["b"]
+
+
+def _subpixel_kernel(w, stride: int, padding: int):
+    """The dense kernel of :func:`conv_transpose1d_subpixel`:
+    ``[taps, C_in, stride * C_out]`` (phase-major output channels), with
+    the first and last tap's offset in input steps."""
+    k, c_in, c_out = w.shape  # [K, C_in, C_out]
     s = stride
     wf = jnp.flip(w, 0)
     # tap range over d for any phase r: j = s*d + (k-1-padding-r) in [0, k)
@@ -133,14 +154,88 @@ def conv_transpose1d_subpixel(x, p, *, stride: int, padding: int):
             j = s * d + c
             if 0 <= j < k:
                 wsub = wsub.at[d - d_lo, :, r, :].set(wf[j])
-    wsub = wsub.reshape(taps, c_in, s * c_out)
-    y = lax.conv_general_dilated(
-        x, wsub, window_strides=(1,), padding=[(-d_lo, d_hi)],
-        dimension_numbers=("NHC", "HIO", "NHC"),
-    )  # [B, T, s*C_out]
-    b_, t_, _ = y.shape
-    y = y.reshape(b_, t_ * s, c_out)
-    return y + p["b"]
+    return wsub.reshape(taps, c_in, s * c_out), d_lo, d_hi
+
+
+# ---------------------------------------------------------------------------
+# time folded into the channel axis (narrow HiFi-GAN stages)
+# ---------------------------------------------------------------------------
+
+#: the minor-axis tile of a TPU: vector lanes, the HBM tile's width and the
+#: edge of the MXU's weight tile
+LANES = 128
+
+
+def fold_factor(channels: int, length: int) -> int:
+    """How many consecutive time steps a ``[B, length, channels]``
+    activation carries in its channel axis: enough to fill the lanes, if
+    the shapes divide; 1 (unfolded) otherwise and from 128 channels up."""
+    if channels >= LANES or LANES % channels:
+        return 1
+    r = LANES // channels
+    return r if length % r == 0 else 1
+
+
+def fold_time(x, r_from: int, r_to: int):
+    """``[B, T / r_from, r_from * C]`` -> ``[B, T / r_to, r_to * C]``: the
+    same values in the same row-major order.  Free in the graph, a
+    physical relayout on a tiled device: a stage folds once and unfolds
+    once, never in between."""
+    if r_from == r_to:
+        return x
+    b, n, c = x.shape
+    return x.reshape(b, n * r_from // r_to, c * r_to // r_from)
+
+
+def fold_conv(p: Params, r: int, *, dilation: int = 1,
+              first: Optional[int] = None) -> Params:
+    """The convolution ``p`` (``SAME``, dilation ``dilation``) on
+    ``[B, T, C]``, as the parameters of a plain ``SAME`` convolution on
+    the time-folded ``[B, T / r, r * C]``.
+
+    Tap ``j`` of ``p`` reads input step ``t + first + dilation * j``
+    (``first`` defaults to ``SAME``'s).  Output phase ``p_`` of folded step
+    ``m`` is time ``r * m + p_``; what it reads at offset ``o`` is phase
+    ``pi`` of folded step ``m + q`` with ``r * q + pi = p_ + o``.  So with
+    the kernel laid out densely over offsets, ``wd[o]``, the folded weight
+    at (tap ``q``, input phase ``pi``, output phase ``p_``) is
+    ``wd[r * q + pi - p_]``: a Toeplitz matrix in ``(r * q + pi, p_)``,
+    built below by the skew that a tile-and-reshape gives (no gather, no
+    per-tap update; 39 convolutions are folded per lessac-high program).
+    The folded taps are symmetric, ``2 * reach + 1``, so ``SAME`` holds and
+    the zeros beyond the ends of the sequence are the same zeros.
+    """
+    w = p["w"]
+    k, c_in, c_out = w.shape
+    if first is None:
+        first = -(((k - 1) * dilation + 1) // 2)
+    last = first + dilation * (k - 1)
+    reach = max(math.ceil(-first / r), math.ceil(last / r), 0)
+    taps = 2 * reach + 1
+    # wd over offsets [-r*reach, r*reach], then r more zeros: the skew
+    # wraps negative differences pi - p_ into them
+    wd = lax.pad(w, jnp.zeros((), w.dtype),
+                 [(r * reach + first, r * reach - last + r, dilation - 1),
+                  (0, 0, 0), (0, 0, 0)])
+    # row p_ of the reshape starts r*taps*p_ into a sequence of period
+    # r*taps + 1, so its column u holds wd[u - p_]
+    skew = jnp.tile(wd, (r, 1, 1))[: r * r * taps]
+    wf = skew.reshape(r, taps, r, c_in, c_out)       # [p_, q, pi, ci, co]
+    wf = wf.transpose(1, 2, 3, 0, 4).reshape(taps, r * c_in, r * c_out)
+    return {"w": wf, "b": jnp.tile(p["b"], r)}
+
+
+def fold_conv_transpose(p: Params, r: int, *, stride: int,
+                        padding: int) -> Params:
+    """The transposed convolution ``p`` from an input folded by ``r`` (1:
+    unfolded), as the parameters of a plain ``SAME`` convolution whose
+    output is folded by ``r * stride``.  The sub-pixel form *is* that
+    convolution at ``r = 1`` (its ``[B, T, stride * C_out]`` is the output
+    folded by ``stride``); a folded input folds its kernel like any
+    other.  Needs the sub-pixel geometry, ``K - stride == 2 * padding``."""
+    wsub, d_lo, _ = _subpixel_kernel(p["w"], stride, padding)
+    return fold_conv({"w": wsub, "b": jnp.tile(p["b"], stride)}, r,
+                     first=d_lo)
 
 
 def layer_norm(x, p, eps: float = 1e-5):

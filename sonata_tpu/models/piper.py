@@ -1615,8 +1615,12 @@ class PiperVoice(BaseModel):
         # budget the bucket was chosen from, the estimate behind it, and
         # how much of the enqueue was the jitted call itself, which is
         # asynchronous only while the runtime has a free slot for one
-        # more program in flight (and compiles, on a cold shape)
+        # more program in flight (and compiles, on a cold shape); and
+        # what is static in the compiled shape: per upsample stage, the
+        # time steps folded into the channel axis
         group.update(frames_budget=budget, frames_per_id=round(fpi, 4),
+                     decode_fold=vits.decode_fold(self.params["dec"],
+                                                  self.hp, f, self.mesh),
                      enqueue_ms=round((t_enqueue - t_start) * 1e3, 3),
                      launch_ms=round((t_enqueue - t_launch) * 1e3, 3))
         return {"out": out, "args": args, "b": b, "t": t, "f": f,

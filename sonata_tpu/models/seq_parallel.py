@@ -18,6 +18,10 @@ unsharded conv sees):
   the input by ``h = ceil((k−1−pad)/r)`` frames per side, run the same
   lhs-dilated conv, trim ``h·r`` output samples per side — exactly the
   global result, locally.
+- Time-folded stages (under 128 channels, ``vits.decode_fold``): every
+  convolution there, the transposed one included, is a plain ``SAME``
+  convolution over folded steps and takes the first rule; its halo is a
+  whole number of folded steps.
 
 Numerics match the unsharded :func:`vits.flow_reverse` / :func:`vits.decode`
 (tested in ``tests/test_parallel.py``).  The reference has no counterpart:
@@ -73,22 +77,31 @@ def min_local_frames(hp: VitsHyperParams) -> int:
     ``halo_exchange`` is neighbor-only, so each stage needs
     ``local_len >= halo``; sample-rate halos (resblock dilated convs,
     transposed-conv extensions) divide back by the cumulative upsample
-    product to frame units.
+    product to frame units.  A time-folded stage (``vits.decode_fold``)
+    exchanges whole folded steps, so its halos round up to its fold.
     """
-    need = (7 - 1) // 2 + 1  # conv_pre/conv_post kernel 7 at frame rate
+    def halo(reach: int, fold: int) -> int:
+        return fold * math.ceil(reach / fold) + 1
+
+    need = (7 - 1) // 2 + 1  # conv_pre kernel 7 at frame rate
     need = max(need, (hp.flow_kernel_size - 1) // 2 + 1)  # WN convs
-    prod = 1
-    res_halo = max((k * d - d) // 2 + 1
-                   for k, dils in zip(hp.resblock_kernel_sizes,
-                                      hp.resblock_dilation_sizes)
-                   for d in dils)
-    for r, k in zip(hp.upsample_rates, hp.upsample_kernel_sizes):
+    prod = fold = 1
+    for i, (r, k) in enumerate(zip(hp.upsample_rates,
+                                   hp.upsample_kernel_sizes)):
         pad = (k - r) // 2
-        h = max(math.ceil((k - 1 - pad) / r), 0) + 1
-        need = max(need, math.ceil(h / prod))  # tconv input halo
+        h = max(math.ceil((k - 1 - pad) / r), 0)
+        need = max(need, math.ceil(halo(h, fold) / prod))  # tconv input
         prod *= r
+        # the fold the stage's channels allow, whatever its length: the
+        # larger halo
+        fold = m.fold_factor(hp.upsample_initial_channel // 2 ** (i + 1),
+                             m.LANES)
+        res_halo = max(halo((k_ * d - d) // 2, fold)
+                       for k_, dils in zip(hp.resblock_kernel_sizes,
+                                           hp.resblock_dilation_sizes)
+                       for d in dils)
         need = max(need, math.ceil(res_halo / prod))
-    return need
+    return max(need, math.ceil(halo((7 - 1) // 2, fold) / prod))  # conv_post
 
 
 def _flow_reverse_local(pf: Params, hp: VitsHyperParams, z, mask, g):

@@ -371,9 +371,10 @@ def decode(p: Params, hp: VitsHyperParams, z, g=None, mesh=None,
     """Latent ``z`` [B, F, C] → waveform [B, F * hop].
 
     The FLOPs live here (upsampling convs); channels shrink as time grows,
-    keeping every conv an MXU-friendly matmul over the channel dim.  With
-    a seq-axis mesh the frames (and output samples) shard across chips
-    (:mod:`.seq_parallel`).
+    and from 128 channels down the stages run time-folded
+    (:func:`decode_fold`), keeping every conv a matmul over a full
+    128-wide channel dim.  With a seq-axis mesh the frames (and output
+    samples) shard across chips (:mod:`.seq_parallel`).
 
     ``compute_dtype``: optional reduced-precision policy for the conv
     stack (``jnp.bfloat16`` keeps the MXU in its native mode — one
@@ -389,11 +390,34 @@ def decode(p: Params, hp: VitsHyperParams, z, g=None, mesh=None,
     return decode_with(p, hp, z, g=g, compute_dtype=compute_dtype)
 
 
+def decode_fold(pd: Params, hp: VitsHyperParams, frames: int,
+                mesh=None) -> list[int]:
+    """Per upsample stage, how many time steps its activations fold into
+    the channel axis (:func:`modules.fold_factor`; 1 = unfolded), for a
+    decoder over ``frames`` frames (:func:`decode` on ``mesh``: one
+    shard's, where the frames shard).  From shapes alone: the stage's
+    channels (its bias, so a quantized tree reads the same), its length,
+    and whether its transposed convolution has the sub-pixel geometry
+    that produces the folded form."""
+    if _use_seq_parallel(mesh, frames, hp):
+        frames //= mesh.shape["seq"]
+    folds, length = [], frames
+    for up, r_up, k_up in zip(pd["ups"], hp.upsample_rates,
+                              hp.upsample_kernel_sizes):
+        length *= r_up
+        subpixel = r_up > 1 and (k_up - r_up) % 2 == 0
+        folds.append(m.fold_factor(up["b"].shape[0], length)
+                     if subpixel else 1)
+    return folds
+
+
 def decode_with(p: Params, hp: VitsHyperParams, z, g=None, conv=None,
                 tconv=None, compute_dtype=None):
     """:func:`decode` body with injectable conv primitives — the
     sequence-sharded path passes halo-exchange versions, so the model
-    math exists exactly once."""
+    math exists exactly once.  A time-folded stage (:func:`decode_fold`)
+    is plain ``SAME`` convolutions too and goes through the same ``conv``;
+    ``tconv`` serves the unfolded stages."""
     conv = conv or m.conv1d
     tconv = tconv or (lambda x, p_, *, stride, padding:
                       m.conv_transpose1d(x, p_, stride=stride,
@@ -412,6 +436,7 @@ def decode_with(p: Params, hp: VitsHyperParams, z, g=None, conv=None,
         z = z.astype(compute_dtype)
         if g is not None:
             g = g.astype(compute_dtype)
+    folds = decode_fold(pd, hp, z.shape[1])
     # the stages carry names into the compiled program (metadata only), so
     # a device trace is read by stage: pre, ups1..upsN (an upsampling
     # convolution and its residual blocks), post
@@ -420,31 +445,52 @@ def decode_with(p: Params, hp: VitsHyperParams, z, g=None, conv=None,
         if g is not None and "cond" in pd:
             x = x + m.conv1d(g, pd["cond"])
     n_kernels = len(hp.resblock_kernel_sizes)
+    r = 1  # x is [B, T / r, r * C] from here on
     for i, (r_up, k_up) in enumerate(zip(hp.upsample_rates, hp.upsample_kernel_sizes)):
         with jax.named_scope(f"ups{i + 1}"):
             x = jax.nn.leaky_relu(x, m.LRELU_SLOPE)
-            x = tconv(x, pd["ups"][i], stride=r_up,
-                      padding=(k_up - r_up) // 2)
+            pad = (k_up - r_up) // 2
+            if folds[i] == 1:
+                x = tconv(m.fold_time(x, r, 1), pd["ups"][i], stride=r_up,
+                          padding=pad)
+            else:
+                # the sub-pixel convolution's own output is the folded
+                # form: with the published rates (8, 8, 2, 2) the stages
+                # under 128 channels follow each other without a reshape
+                x = conv(x, m.fold_conv_transpose(
+                    pd["ups"][i], r, stride=r_up, padding=pad))
+                x = m.fold_time(x, r * r_up, folds[i])
+            r = folds[i]
             xs = None
             for j in range(n_kernels):
                 block = pd["resblocks"][i * n_kernels + j]
                 y = _resblock1(block, x, hp.resblock_kernel_sizes[j],
-                               hp.resblock_dilation_sizes[j], conv=conv)
+                               hp.resblock_dilation_sizes[j], conv=conv,
+                               fold=r)
                 xs = y if xs is None else xs + y
             x = xs / n_kernels
     with jax.named_scope("post"):
         x = jax.nn.leaky_relu(x, m.LRELU_SLOPE)
-        x = conv(x, pd["conv_post"])
-        return jnp.tanh(x.astype(jnp.float32))[..., 0]  # [B, samples]
+        x = _conv_folded(conv, x, pd["conv_post"], r)  # [B, T / r, r]
+        x = x.reshape(x.shape[0], -1)  # the one unfold: [B, samples]
+        return jnp.tanh(x.astype(jnp.float32))
 
 
-def _resblock1(block: Params, x, kernel: int, dilations, conv=None):
+def _conv_folded(conv, x, p: Params, fold: int, dilation: int = 1):
+    """``conv`` of ``p`` on an activation folded by ``fold``."""
+    if fold == 1:
+        return conv(x, p, dilation=dilation)
+    return conv(x, m.fold_conv(p, fold, dilation=dilation))
+
+
+def _resblock1(block: Params, x, kernel: int, dilations, conv=None,
+               fold: int = 1):
     conv = conv or m.conv1d
     for c1, c2, d in zip(block["convs1"], block["convs2"], dilations):
         y = jax.nn.leaky_relu(x, m.LRELU_SLOPE)
-        y = conv(y, c1, dilation=d)
+        y = _conv_folded(conv, y, c1, fold, d)
         y = jax.nn.leaky_relu(y, m.LRELU_SLOPE)
-        y = conv(y, c2)
+        y = _conv_folded(conv, y, c2, fold)
         x = x + y
     return x
 

@@ -12,6 +12,7 @@ import urllib.request
 import numpy as np
 import pytest
 
+from sonata_tpu.models import vits
 from sonata_tpu.serving import MetricsRegistry, ServingRuntime, tracing
 from sonata_tpu.serving import scope as scope_mod
 from sonata_tpu.serving.scope import Scope
@@ -27,8 +28,8 @@ TEXT = ("Hello world. This is a longer sentence for the test. Short. "
         "One more sentence of middling length.")
 GROUP_FIELDS = {"batch_bucket", "text_bucket", "frame_bucket", "rows",
                 "padding_rows", "padding_ratio", "compile", "frames_needed",
-                "frames_budget", "frames_per_id", "enqueue_ms", "launch_ms",
-                "fetch_wait_ms", "epilogue_ms"}
+                "frames_budget", "frames_per_id", "decode_fold",
+                "enqueue_ms", "launch_ms", "fetch_wait_ms", "epilogue_ms"}
 
 
 def frames_computed(groups) -> int:
@@ -195,6 +196,21 @@ def test_forced_overflow_is_one_retry_and_the_same_audio():
     assert delta["frames"]["headroom"] == 0
     assert sum(delta["frames"].values()) == frames_computed([g])
     np.testing.assert_array_equal(clipped, sound)
+
+
+def test_the_group_says_how_its_decoder_folded():
+    """Static per compiled shape, so recorded where shapes are: the tiny
+    voice's two stages have 32 and 16 channels."""
+    voice = tiny_voice()
+    tracer = tracing.Tracer(enabled=True, log_sink="0")
+    trace, _ = traced(tracer, lambda: voice.speak_batch(
+        list(voice.phonemize_text(TEXT))))
+    (span,) = dispatch_spans(trace)
+    assert "decode_fold" not in span.attrs      # not a headline field
+    for g in span.attrs["device_groups"]:
+        assert g["decode_fold"] == [4, 8]
+        assert g["decode_fold"] == vits.decode_fold(
+            voice.params["dec"], voice.hp, g["frame_bucket"])
 
 
 def test_a_compile_after_warmup_counts_on_the_stock_path(scope):

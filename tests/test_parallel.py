@@ -14,6 +14,7 @@ import pytest
 from sonata_tpu.parallel import make_mesh, ring_attention
 from sonata_tpu.models import PiperVoice
 
+import voices
 from voices import tiny_voice
 
 # The 6 mesh-numeric equivalence tests in this file were xfailed between
@@ -314,6 +315,33 @@ def test_frame_domain_seq_parallel_matches_unsharded():
         np.testing.assert_allclose(
             np.asarray(decode_sp(p, hp, z, mesh)),
             np.asarray(vits.decode(p, hp, z)), atol=2e-5)
+
+
+def test_min_local_frames_counts_the_folded_halo():
+    """A time-folded stage exchanges whole folded steps.  Kernel 11 at
+    dilation 5 reaches 25 samples; at 32 channels (four steps a fold) that
+    is 7 folded steps = 28 samples, and one spare: 29 samples at twice the
+    frame rate = 15 frames (13 unfolded).  The decode sharded at 16, the
+    smallest count above it whose shard still folds, equals the unsharded
+    one."""
+    from sonata_tpu.models import vits
+    from sonata_tpu.models.seq_parallel import decode_sp, min_local_frames
+
+    v = tiny_voice(seed=4, model=dict(
+        voices.TINY_MODEL, upsample_rates=(2, 2),
+        upsample_kernel_sizes=(4, 4), resblock_kernel_sizes=(11,),
+        resblock_dilation_sizes=((1, 5),)))
+    hp, p = v.hp, v.params
+    assert min_local_frames(hp) == 15
+    assert min_local_frames(tiny_voice().hp) == 4    # conv_pre's, as before
+    mesh = make_mesh(8, seq_parallel=2)
+    assert not vits._use_seq_parallel(mesh, 28, hp)
+    assert vits._use_seq_parallel(mesh, 32, hp)
+    assert vits.decode_fold(p["dec"], hp, 32, mesh) == [4, 8]
+    z = jax.random.normal(jax.random.PRNGKey(0),
+                          (mesh.shape["data"], 32, hp.inter_channels))
+    np.testing.assert_allclose(np.asarray(decode_sp(p, hp, z, mesh)),
+                               np.asarray(vits.decode(p, hp, z)), atol=2e-5)
 
 
 def test_full_batch_hlo_shards_frame_domain():
