@@ -163,9 +163,9 @@ class Generator:
         info = self.client.load_voice(job["voice_path"])
         self.voice_id, self.sample_rate = info["voice_id"], info["sample_rate"]
         speaker = None
-        if job.get("num_speakers", 1) > 1:
+        if job["voice"]["num_speakers"] > 1:
             speaker = random.Random(self.seed * 31 + 5).randrange(
-                job["num_speakers"])
+                job["voice"]["num_speakers"])
             self.client.set_options(self.voice_id, speaker=str(speaker))
         threads = [threading.Thread(target=self.caller, daemon=True)
                    for _ in range(int(self.traffic["callers"]))]
@@ -250,7 +250,7 @@ class Generator:
         audio_path = Path(self.job["out_dir"]) / "sampled_audio.npz"
         with open(audio_path, "wb") as f:
             np.savez(f, **arrays)
-        return {"t_window": t0, "seconds": self.job["seconds"],
+        out = {"t_window": t0, "seconds": self.job["seconds"],
                 "sample_rate": self.sample_rate, "speaker": speaker,
                 "attempted": len(started),
                 "failed": sum(1 for r in started if not r["ok"]),
@@ -261,11 +261,15 @@ class Generator:
                 "answers_per_second": [
                     sum(1 for r in self.records if int(r["t_end"]) == k)
                     for k in range(int(t1) + 1)],
-                "estimator_replay": shapes.replay_estimator(
-                    self.records, self.traffic["paragraphs"],
-                    int(self.job["hop"]), t0, t1),
                 "sampled_audio": str(audio_path),
                 "wall_origin": time.time() - self.now()}
+        if self.job["voice"].get("frame_budget_estimator"):
+            # a model of the stock path's estimator: only where the
+            # voice's writer says the path has one
+            out["estimator_replay"] = shapes.replay_estimator(
+                self.records, self.traffic["paragraphs"],
+                int(self.job["voice"]["samples_per_frame"]), t0, t1)
+        return out
 
 
 def main(argv: list) -> int:

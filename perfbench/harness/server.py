@@ -65,7 +65,8 @@ class Server:
         self.log_path = Path(work_dir) / "server.log"
         self.memory_path = Path(work_dir) / "memory_stats.json"
         fill = {"voice": voice_path, "grpc_port": str(self.grpc_port),
-                "metrics_port": str(self.metrics_port)}
+                "metrics_port": str(self.metrics_port),
+                "work_dir": str(work_dir)}
         spec = config["server"]
         self.cmd = [sys.executable] + [a.format(**fill)
                                        for a in spec["argv"]]

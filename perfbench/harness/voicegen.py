@@ -206,14 +206,30 @@ def unflatten(flat: dict):
     return listify(root)
 
 
-def write_voice(out_dir, voice_json: dict, weights: dict) -> Path:
+def write_voice(out_dir, config: dict) -> Path:
     """``voice.onnx.json`` + ``voice.npz`` under ``out_dir``; returns the
     config path the server is started with."""
     out = Path(out_dir)
     out.mkdir(parents=True, exist_ok=True)
     config_path = out / "voice.onnx.json"
-    config_path.write_text(json.dumps(voice_json))
-    flat = build_params(voice_json, **weights)
+    config_path.write_text(json.dumps(config["voice"]))
+    flat = build_params(config["voice"], **config["weights"])
     with open(out / "voice.npz", "wb") as f:
         np.savez(f, **flat)
     return config_path
+
+
+def describe(config: dict) -> dict:
+    """What the harness has to know of the voice: the generator's hop, how
+    many speakers a run may draw from, the graph's sizes for the cost
+    functions, and that the stock path budgets a dispatch's frames by an
+    estimator (``shapes.replay_estimator`` is a model of it)."""
+    dims = model_dims(config["voice"])
+    return {"samples_per_frame": math.prod(dims["upsample_rates"]),
+            "num_speakers": int(config["voice"].get("num_speakers", 1)),
+            "dims": dims, "frame_budget_estimator": True}
+
+
+def reference_params(config: dict):
+    """The voice's parameters as the reference takes them: nested, numpy."""
+    return unflatten(build_params(config["voice"], **config["weights"]))

@@ -5,8 +5,10 @@
         --trace <0|1>
 
 Everything about a cell is data found by name in ``BENCHMARK.json``: the
-configuration's file, ``perfbench/traffic/<traffic>.json`` and, for a traced
-run, ``perfbench/metrics/<metric>.py``.  This process never imports jax: the
+configuration's file and the files it names (the voice's writer, the
+reference, the comparison: ``harness/parts.py``), its limits,
+``<path>/traffic/<traffic>.json`` and, for a traced run,
+``<path>/metrics/<metric>.py``.  This process never imports jax: the
 server child holds the chip during the window, the check child after it.
 The last line of standard output is the result; a run that finds no
 accelerator, too few chips or a broken harness prints none and exits
@@ -16,9 +18,7 @@ non-zero.
 from __future__ import annotations
 
 import argparse
-import importlib.util
 import json
-import math
 import os
 import shutil
 import subprocess
@@ -32,8 +32,8 @@ ROOT = Path(__file__).resolve().parent.parent
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
+from perfbench.harness import parts  # noqa: E402
 from perfbench.harness import server as srv  # noqa: E402
-from perfbench.harness import voicegen  # noqa: E402
 
 ACCELERATORS = ("tpu", "gpu")
 
@@ -51,9 +51,8 @@ def load_cell(benchmark: dict, workload: str, root: Path) -> dict:
     entry = next(c for c in benchmark["configs"]
                  if c["name"] == cell["config"])
     paths = benchmark["paths"]
-    candidates = [root / p / "traffic" / f"{cell['traffic']}.json"
-                  for p in paths]
-    traffic_file = next((f for f in candidates if f.exists()), None)
+    traffic_file = parts.find(root, paths, "traffic",
+                              f"{cell['traffic']}.json")
     if traffic_file is None:
         raise HarnessError(f"no traffic file for {cell['traffic']!r}")
 
@@ -66,18 +65,6 @@ def load_cell(benchmark: dict, workload: str, root: Path) -> dict:
             "end_to_end": [m for m in benchmark["end_to_end"] if applies(m)],
             "per_layer": [m for m in benchmark["per_layer"] if applies(m)],
             "paths": paths}
-
-
-def load_reader(root: Path, paths: list, name: str):
-    for p in paths:
-        file = root / p / "metrics" / f"{name}.py"
-        if file.exists():
-            spec = importlib.util.spec_from_file_location(
-                "perfbench_metric_" + name.replace(".", "_"), file)
-            module = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(module)
-            return module.read
-    raise HarnessError(f"no reader file for the metric {name!r}")
 
 
 def cache_entries(root: Path) -> int:
@@ -127,8 +114,8 @@ def spawn_module(module: str, job: dict, work: Path, root: Path, env=None):
 
 
 def spans_on_wall_clock(traces: list, names=("phonemize", "encode-ids",
-                                             "dispatch", "stream-emit",
-                                             "rpc")) -> list:
+                                             "dispatch",
+                                             "stream-emit")) -> list:
     """Server spans with their start and end in wall-clock seconds."""
     out = []
     for t in traces:
@@ -155,13 +142,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                             or root / "BENCHMARK.json").read_text())
     cell = load_cell(benchmark, workload, root)
     config, traffic = cell["config"], cell["traffic"]
-    limits_all = json.loads(
-        (ROOT / "perfbench" / "reference" / "limits.json").read_text())
-    # a configuration's own limits over the default ones; null takes one out
-    limits = {k: v for k, v in dict(
-        limits_all["default"],
-        **limits_all.get(cell["cell"]["config"], {})).items()
-        if v is not None}
+    limits = parts.load_limits(root, cell["paths"], cell["cell"]["config"])
+    writer = parts.load(root, cell["paths"], config, "writer")
+    voice = writer.describe(config)
     work = Path(tempfile.mkdtemp(prefix="perfbench_"))
     server = gen = check = profile = None
     files = []
@@ -169,8 +152,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     env.pop("BENCH_RUN", None)
     try:
         t_setup = time.monotonic()
-        voice_path = voicegen.write_voice(
-            work / "voice", config["voice"], config["weights"])
+        voice_path = writer.write_voice(work / "voice", config)
         server = srv.Server(root, config, str(voice_path), platform, work)
         server.wait_ready(900.0)
         device = server.device()
@@ -184,10 +166,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             "root": str(root), "grpc_port": server.grpc_port,
             "voice_path": str(voice_path), "traffic": traffic, "seed": seed,
             "seconds": seconds, "out_dir": str(work),
-            "num_speakers": config["voice"]["num_speakers"],
-            "hop": math.prod(voicegen.model_dims(
-                config["voice"])["upsample_rates"])}, work, root,
-            env)
+            "voice": voice}, work, root, env)
         files.append(err)
         lines = LineReader(gen)
         warm = lines.wait_for(
@@ -207,7 +186,13 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             raise HarnessError(f"the generator failed: {done}\n"
                                + (work / "loadgen.err").read_text()[-2000:])
         metrics_after, entries_after = server.metrics(), cache_entries(root)
-        traces = server.traces() if trace else []
+        # after the window and the replay: what the server recorded of the
+        # replayed requests goes to the comparison in every run
+        traces = server.traces()
+        replayed = {r["rid"] for r in done["sampled"]}
+        sampled_spans = {t["request_id"]: {"wall_start": t["wall_start"],
+                                           "spans": t["spans"]}
+                         for t in traces if t["request_id"] in replayed}
         server.stop()
         # the runtime counts live arrays and what programs reserve for
         # their temporaries apart, each with a peak of its own; the larger
@@ -223,10 +208,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         spans = [s for s in spans_on_wall_clock(traces)
                  if wall0 <= s["end"] <= wall0 + seconds]
         check_job = {
-            "root": str(root), "config_file": cell["config_entry"]["file"],
+            "root": str(root), "paths": cell["paths"],
+            "config_file": cell["config_entry"]["file"],
             "seed": seed, "words": traffic["words"],
             "sampled": done["sampled"],
             "sampled_audio": done["sampled_audio"],
+            "sampled_spans": sampled_spans, "work_dir": str(work),
             "speaker": done["speaker"], "limits": limits,
             "rows": traffic["check"].get("rows")}
         if profile is not None:
@@ -254,7 +241,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                 c["value"] is not None and c["value"] <= c["limit"]
                 for c in compared.values())
         run = {"workload": workload, "seed": seed, "seconds": seconds,
-               "config": config, "dims": voicegen.model_dims(config["voice"]),
+               "config": config, "dims": voice["dims"],
                "traffic": traffic, "generator": done, "warm": warm,
                "setup_s": setup_s, "metrics_before": metrics_before,
                "metrics_after": metrics_after, "spans": spans,
@@ -267,7 +254,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             if m["name"] == "setup_s":
                 value = setup_s
             else:
-                value = load_reader(root, cell["paths"], m["name"])(run)
+                value = parts.load_reader(root, cell["paths"],
+                                          m["name"])(run)
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
         dev_out = dict(result["device"], memory_peak_bytes=peak)
@@ -291,9 +279,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                            latency_s=[took[len(took) // 2],
                                       took[int(len(took) * 0.95)],
                                       took[-1]] if took else None,
-
                            completed=done["completed"],
-                           estimator_replay=done["estimator_replay"],
                            answers_per_second=done["answers_per_second"],
                            sampled_seqs=[r["seq"] for r in done["sampled"]],
                            audio_s=done["audio_s"],
@@ -301,6 +287,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                            request_errors=[r.get("error") for r in
                                            done["records"]
                                            if not r["ok"]][:3])
+        if "estimator_replay" in done:
+            out["info"]["estimator_replay"] = done["estimator_replay"]
         out["compared"] = compared
         return out
     finally:

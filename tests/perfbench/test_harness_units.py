@@ -11,8 +11,8 @@ from pathlib import Path
 
 import pytest
 
-from perfbench.harness import (costs, server, shapes, stats, textgen, trace,
-                               voicegen, wire)
+from perfbench.harness import (costs, parts, server, shapes, stats, textgen,
+                               trace, voicegen, wire)
 
 ROOT = Path(__file__).resolve().parents[2]
 DATA = Path(__file__).resolve().parent / "data"
@@ -176,7 +176,8 @@ def test_every_cell_finds_its_files_by_name(entry):
         assert NAME.match(m["name"]) and re.match(r"^[A-Za-z0-9_/%.\-]{1,16}$",
                                                   m["unit"])
         if m["name"] != "setup_s":
-            assert callable(run.load_reader(ROOT, cell["paths"], m["name"]))
+            assert callable(parts.load_reader(ROOT, cell["paths"],
+                                              m["name"]))
     assert len(entry["why"]) <= 200 and entry["chips"] in (1, 4)
 
 

@@ -8,6 +8,7 @@ from pathlib import Path
 import pytest
 
 from perfbench import run
+from perfbench.harness import parts
 
 ROOT = Path(__file__).resolve().parent.parent.parent
 BENCH = Path(__file__).resolve().parent / "data" / \
@@ -19,7 +20,7 @@ NEW = SHARES + ["sched.overflow_retries.batch",
 
 
 def reader(name):
-    return run.load_reader(ROOT, ["perfbench", "tests/perfbench"], name)
+    return parts.load_reader(ROOT, ["perfbench", "tests/perfbench"], name)
 
 
 def page(frames: dict, groups=0.0, retries=0.0, host=None) -> dict:
@@ -91,6 +92,11 @@ def test_every_new_metric_is_declared_with_a_reader():
 
 
 def test_a_traced_run_of_the_tiny_cell_reports_them():
+    """What holds in any window, on a loaded machine too.  Which requests a
+    2 s window holds hangs on the machine's load, and with them whether a
+    noisy duration clips a row (a rerun, a new bucket, a compile) and
+    whether another process adds an entry to the shared compile cache: none
+    of that is asserted to be nought."""
     out = run.run_cell("tiny.paragraph", 3000000011, 2.0, True,
                        benchmark_file=BENCH, platform="cpu",
                        require_accelerator=False)
@@ -100,11 +106,14 @@ def test_a_traced_run_of_the_tiny_cell_reports_them():
     for name in NEW:
         assert isinstance(got[name], float)
     total, ragged, headroom, bucket = (got[n] for n in SHARES)
+    # the split of the total into its five causes, the two that a quiet
+    # window leaves at nought read by the tests' own readers
+    dummy_rows = got["sched.pad_dummy_rows_share.batch"]
+    retried = got["sched.pad_retried_share.batch"]
     assert 0.0 < total < 100.0
-    # whole paragraphs in full batches, none clipped once warm: the total
-    # is its three parts (dummy rows and reruns would come on top)
-    assert total == pytest.approx(ragged + headroom + bucket, abs=1e-9)
-    assert min(ragged, headroom, bucket) >= 0.0
-    assert got["sched.overflow_retries.batch"] == 0.0
+    assert total == pytest.approx(
+        ragged + headroom + bucket + dummy_rows + retried, abs=1e-9)
+    assert min(ragged, headroom, bucket, dummy_rows, retried) >= 0.0
+    assert got["sched.overflow_retries.batch"] >= 0.0
     assert got["fetch.host_ms_per_dispatch.batch"] > 0.0
-    assert got["warmup.cold_compiles_in_window.batch"] == 0.0
+    assert got["warmup.cold_compiles_in_window.batch"] >= 0.0

@@ -75,7 +75,7 @@ def test_durations_priors_and_waveform_agree_with_the_program(setup):
 
 
 def test_candidates_recover_a_moved_duration():
-    from perfbench.reference.check import candidates
+    from perfbench.reference.vits_check import candidates
 
     w = np.array([2.5, 2.999, 3.4, 1.2, 0.0])
     base = np.ceil(w[:4]).astype(int)
