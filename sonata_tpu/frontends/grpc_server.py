@@ -373,9 +373,12 @@ class SonataGrpcService:
         # time, so the serving shape (coalescing on/off, batch/wait knobs,
         # probe constants) is in the log before traffic arrives
         try:
-            policy = voice.dispatch_policy
-            log.info("voice %s %s; batch mode=%s", vid, policy.describe(),
-                     resolve_batch_mode(policy))
+            # a voice without one (a unit voice: its step loop is its
+            # batching) has no serving shape to resolve
+            policy = getattr(voice, "dispatch_policy", None)
+            if policy is not None:
+                log.info("voice %s %s; batch mode=%s", vid,
+                         policy.describe(), resolve_batch_mode(policy))
         except Exception:  # policy must never block serving
             log.exception("dispatch-policy resolution failed "
                           "(serving continues on defaults)")
@@ -1410,8 +1413,12 @@ def create_server(port: Optional[int] = None, *, mesh=None, seed: int = 0,
     service = SonataGrpcService(mesh=mesh, seed=seed,
                                 continuous_batching=continuous_batching,
                                 runtime=runtime, replicas=replicas)
-    server = grpc.server(ThreadPoolExecutor(max_workers=max_workers,
-                                            thread_name_prefix="sonata_grpc"))
+    # an admitted request holds a handler thread until its last message,
+    # so the pool holds at least what admission lets execute at once
+    # (with fewer, --max-in-flight above the pool was a ceiling never met)
+    server = grpc.server(ThreadPoolExecutor(
+        max_workers=max(max_workers, runtime.admission.max_in_flight),
+        thread_name_prefix="sonata_grpc"))
     server.add_generic_rpc_handlers((_Handler(service),))
     bound = server.add_insecure_port(f"{host}:{port}")
     if bound == 0:

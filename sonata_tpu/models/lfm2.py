@@ -1,0 +1,457 @@
+"""An LFM2-MoE backbone (``model_type: lfm2_moe``) for step-wise generation.
+
+Every layer is ``h += op(rms(h)); h += ffn(rms(h))``: ``op`` is a gated
+short convolution (``conv`` layers, state: the last ``conv_L_cache`` columns
+of ``B * x``) or grouped-query attention with per-head RMS norm on q and k
+before RoPE (``full_attention`` layers, state: keys and values); ``ffn`` is a
+dense SwiGLU in the first ``num_dense_layers`` layers and a sigmoid-routed
+mixture of SwiGLU experts in the others.  A final RMS norm, then the head,
+tied to the embedding.
+
+Two programs over one set of weights: :func:`prefill` runs one row's prompt
+whole, writes its state into a slot of the cache and samples the row's
+first token; :func:`step` advances every slot by one token.  Both keep the
+generation state on the device (:func:`new_cache`): the host says which
+slots are live and reads nothing back to decide the next launch.
+
+**Precision** (what the comparison of a benchmark cell is held to): weights
+are bfloat16; every matrix product takes bfloat16 inputs and accumulates in
+float32 (the products of the expert layer's router excepted: float32 at
+``highest``, so that near-ties flip rarely); the residual stream, RMS norms,
+router scores, the softmax, the depthwise convolution and its state, and the
+logits are float32; keys and values are cached in bfloat16, and the softmax's
+probabilities enter their product with the values as bfloat16.
+
+**The expert layer knows which experts it holds** (``held = (first,
+count)``): it scores and chooses over all ``num_experts``, and its result is
+the sum over the chosen experts *it holds* of weight x expert(u): with
+``(0, num_experts)`` the whole layer, otherwise the share of one chip of an
+expert-parallel deployment (the shares of all chips add up to the whole
+layer; nothing stands in for the absent ones).  Only the chosen experts'
+products are computed: the assignments are sorted by expert and go through
+``jax.lax.ragged_dot``, a grouped matmul (on a TPU a Mosaic kernel).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+Params = dict
+BF16 = jnp.bfloat16
+F32 = jnp.float32
+
+
+@dataclasses.dataclass(frozen=True)
+class Lfm2Config:
+    """The backbone's published ``config.json`` keys that shape the graph."""
+
+    hidden_size: int
+    num_attention_heads: int
+    num_key_value_heads: int
+    rope_theta: float
+    conv_L_cache: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    num_experts: int
+    num_experts_per_tok: int
+    norm_topk_prob: bool
+    use_expert_bias: bool
+    routed_scaling_factor: float
+    num_dense_layers: int
+    norm_eps: float
+    vocab_size: int
+    layer_types: tuple
+
+    @classmethod
+    def from_dict(cls, d: dict) -> "Lfm2Config":
+        if d.get("conv_bias"):
+            raise ValueError("conv_bias: true is not supported")
+        layers = tuple(d["layer_types"][:int(d["num_hidden_layers"])])
+        if len(layers) != int(d["num_hidden_layers"]) or set(layers) - {
+                "conv", "full_attention"}:
+            raise ValueError(f"layer_types {layers} do not give "
+                             f"{d['num_hidden_layers']} known layers")
+        return cls(
+            hidden_size=int(d["hidden_size"]),
+            num_attention_heads=int(d["num_attention_heads"]),
+            num_key_value_heads=int(d["num_key_value_heads"]),
+            rope_theta=float(d["rope_parameters"]["rope_theta"]),
+            conv_L_cache=int(d["conv_L_cache"]),
+            intermediate_size=int(d["intermediate_size"]),
+            moe_intermediate_size=int(d["moe_intermediate_size"]),
+            num_experts=int(d["num_experts"]),
+            num_experts_per_tok=int(d["num_experts_per_tok"]),
+            norm_topk_prob=bool(d["norm_topk_prob"]),
+            use_expert_bias=bool(d["use_expert_bias"]),
+            routed_scaling_factor=float(d["routed_scaling_factor"]),
+            num_dense_layers=int(d["num_dense_layers"]),
+            norm_eps=float(d["norm_eps"]),
+            vocab_size=int(d["vocab_size"]),
+            layer_types=layers)
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    def layers_of(self, kind: str) -> list:
+        return [i for i, k in enumerate(self.layer_types) if k == kind]
+
+    @property
+    def expert_layers(self) -> list:
+        return list(range(self.num_dense_layers, len(self.layer_types)))
+
+
+@dataclasses.dataclass(frozen=True)
+class UnitIds:
+    """How the vocabulary is split: ids below ``first_id`` are the prompt's
+    (phoneme ids), the others are acoustic units; ``stop_id`` is the unit
+    that ends a row."""
+
+    first_id: int
+    stop_id: int
+
+
+# ---------------------------------------------------------------------------
+# weights
+# ---------------------------------------------------------------------------
+
+def pack_layer(raw: dict) -> Params:
+    """One layer from its tensors under the published names (``op.wq``,
+    ``ffn.w1`` ... as nested dicts, bfloat16) to the layout the programs
+    read: ``w1 | w3`` and ``wq | wk | wv`` side by side, so that each is one
+    product; norms, the depthwise kernel, the router and its bias float32."""
+    op, ffn = raw["op"], raw["ffn"]
+    if "in_proj" in op:
+        op_p = {"in_proj": op["in_proj"], "conv_w": op["conv_w"].astype(F32),
+                "out_proj": op["out_proj"]}
+    else:
+        op_p = {"wqkv": jnp.concatenate([op["wq"], op["wk"], op["wv"]], -1),
+                "wo": op["wo"], "q_norm": op["q_norm"].astype(F32),
+                "k_norm": op["k_norm"].astype(F32)}
+    ffn_p = {"w13": jnp.concatenate([ffn["w1"], ffn["w3"]], -1),
+             "w2": ffn["w2"]}
+    if "router" in ffn:
+        ffn_p.update(router=ffn["router"].astype(F32),
+                     expert_bias=ffn["expert_bias"].astype(F32))
+    return {"op_norm": raw["op_norm"].astype(F32),
+            "ffn_norm": raw["ffn_norm"].astype(F32),
+            "op": op_p, "ffn": ffn_p}
+
+
+# ---------------------------------------------------------------------------
+# pieces
+# ---------------------------------------------------------------------------
+
+def mm(x, w):
+    """bfloat16 inputs, float32 accumulation."""
+    return jnp.dot(x.astype(BF16), w, preferred_element_type=F32)
+
+
+def rms_norm(x, w, eps: float):
+    return x * lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
+
+
+def apply_rope(x, positions, theta: float):
+    """``x`` ``[N, heads, d]`` at ``positions`` ``[N]`` (rotate-half)."""
+    d = x.shape[-1]
+    inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=F32) / d)
+    ang = positions.astype(F32)[:, None] * inv[None, :]
+    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)[:, None, :]
+    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)[:, None, :]
+    x1, x2 = x[..., :d // 2], x[..., d // 2:]
+    return x * cos + jnp.concatenate([-x2, x1], -1) * sin
+
+
+def _qkv(u, p, cfg: Lfm2Config, positions):
+    n = u.shape[0]
+    heads, kv, d = cfg.num_attention_heads, cfg.num_key_value_heads, \
+        cfg.head_dim
+    qkv = mm(u, p["wqkv"])
+    q = qkv[:, :heads * d].reshape(n, heads, d)
+    k = qkv[:, heads * d:(heads + kv) * d].reshape(n, kv, d)
+    v = qkv[:, (heads + kv) * d:].reshape(n, kv, d)
+    q = apply_rope(rms_norm(q, p["q_norm"], cfg.norm_eps), positions,
+                   cfg.rope_theta)
+    k = apply_rope(rms_norm(k, p["k_norm"], cfg.norm_eps), positions,
+                   cfg.rope_theta)
+    return q, k.astype(BF16), v.astype(BF16)
+
+
+def conv_op_seq(u, p, n):
+    """A row's prompt ``[T, H]`` whole; also the operator's state after
+    ``n`` tokens: the last ``conv_L_cache`` columns of ``B * x``."""
+    with jax.named_scope("conv_op"):
+        k = p["conv_w"].shape[0]
+        t = u.shape[0]
+        b, c, x = jnp.split(mm(u, p["in_proj"]), 3, axis=-1)
+        bx = jnp.pad(b * x, ((k, 0), (0, 0)))
+        conv = sum(bx[j + 1:j + 1 + t] * p["conv_w"][j] for j in range(k))
+        state = lax.dynamic_slice_in_dim(bx, n, k, axis=0)
+        return mm(c * conv, p["out_proj"]), state
+
+
+def conv_op_step(u, p, state):
+    """One token of every slot ``[S, H]`` against the slots' states
+    ``[S, conv_L_cache, H]``."""
+    with jax.named_scope("conv_op"):
+        b, c, x = jnp.split(mm(u, p["in_proj"]), 3, axis=-1)
+        state = jnp.concatenate([state[:, 1:], (b * x)[:, None]], axis=1)
+        conv = jnp.einsum("skh,kh->sh", state, p["conv_w"])
+        return mm(c * conv, p["out_proj"]), state
+
+
+def attn_op_seq(u, p, cfg: Lfm2Config):
+    """A row's prompt whole (causal); also its keys and values."""
+    with jax.named_scope("attn_op"):
+        t = u.shape[0]
+        kv, d = cfg.num_key_value_heads, cfg.head_dim
+        g = cfg.num_attention_heads // kv
+        pos = jnp.arange(t)
+        q, k, v = _qkv(u, p, cfg, pos)
+        q = q.reshape(t, kv, g, d).astype(BF16)
+        scores = jnp.einsum("qkgd,pkd->kgqp", q, k,
+                            preferred_element_type=F32) / jnp.sqrt(F32(d))
+        causal = pos[:, None] >= pos[None, :]
+        probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), -1)
+        out = jnp.einsum("kgqp,pkd->qkgd", probs.astype(BF16), v,
+                         preferred_element_type=F32)
+        return mm(out.reshape(t, -1), p["wo"]), k, v
+
+
+def attn_op_step(u, p, cfg: Lfm2Config, k_cache, v_cache, pos):
+    """One token of every slot at its position ``pos`` ``[S]``, through
+    the slots' keys and values ``[S, P, kv, d]``."""
+    with jax.named_scope("attn_op"):
+        s, span = k_cache.shape[0], k_cache.shape[1]
+        kv, d = cfg.num_key_value_heads, cfg.head_dim
+        g = cfg.num_attention_heads // kv
+        q, k, v = _qkv(u, p, cfg, pos)
+        rows = jnp.arange(s)
+        k_cache = k_cache.at[rows, pos].set(k)
+        v_cache = v_cache.at[rows, pos].set(v)
+        q = q.reshape(s, kv, g, d).astype(BF16)
+        scores = jnp.einsum("skgd,spkd->skgp", q, k_cache,
+                            preferred_element_type=F32) / jnp.sqrt(F32(d))
+        seen = jnp.arange(span)[None, :] <= pos[:, None]
+        probs = jax.nn.softmax(
+            jnp.where(seen[:, None, None, :], scores, -jnp.inf), -1)
+        out = jnp.einsum("skgp,spkd->skgd", probs.astype(BF16), v_cache,
+                         preferred_element_type=F32)
+        return mm(out.reshape(s, -1), p["wo"]), k_cache, v_cache
+
+
+def swiglu(u, w13, w2):
+    a, b = jnp.split(mm(u, w13), 2, axis=-1)
+    return mm(jax.nn.silu(a) * b, w2)
+
+
+def dense_ffn(u, p):
+    with jax.named_scope("dense_ffn"):
+        return swiglu(u, p["w13"], p["w2"])
+
+
+def route(u, p, cfg: Lfm2Config):
+    """The experts chosen ``[N, k]`` and their weights ``[N, k]``:
+    selection by ``sigmoid + expert_bias``, weights the unbiased sigmoid of
+    the chosen, normalised."""
+    with jax.named_scope("moe_route"):
+        scores = jax.nn.sigmoid(jnp.dot(u, p["router"], precision="highest"))
+        pick = scores + p["expert_bias"] if cfg.use_expert_bias else scores
+        _, chosen = lax.top_k(pick, cfg.num_experts_per_tok)
+        weights = jnp.take_along_axis(scores, chosen, axis=-1)
+        if cfg.norm_topk_prob:
+            weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-6)
+        return chosen, weights * cfg.routed_scaling_factor
+
+
+def moe_ffn(u, p, cfg: Lfm2Config, held: Optional[tuple] = None,
+            valid=None):
+    """The expert layer over tokens ``u`` ``[N, H]``.
+
+    Returns the layer's output (the sum over each token's chosen experts
+    that this layer holds), the experts chosen ``[N, k]``, and the load
+    ``[3]`` over the valid tokens: distinct experts chosen, the most
+    assignments any one expert got, assignments in all.  ``valid`` ``[N]``
+    masks padding and empty slots: they cost no expert product and count
+    for nothing."""
+    n, top = u.shape[0], cfg.num_experts_per_tok
+    first, count = held if held is not None else (0, cfg.num_experts)
+    if p["w13"].shape[0] != count:
+        raise ValueError(f"held = {held} but the layer holds "
+                         f"{p['w13'].shape[0]} experts")
+    chosen, weights = route(u, p, cfg)
+    with jax.named_scope("moe_experts"):
+        valid = jnp.ones((n,), bool) if valid is None else valid
+        expert = chosen.reshape(-1)
+        counted = jnp.repeat(valid, top)
+        local = expert - first
+        mine = counted & (local >= 0) & (local < count)
+        # assignments sorted by expert, those of experts held elsewhere (and
+        # of padding) last, outside every group
+        group = jnp.where(mine, local, count)
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.bincount(group, length=count + 1)[:count].astype(
+            jnp.int32)
+        x = u.astype(BF16)[order // top]
+        a, b = jnp.split(lax.ragged_dot(x, p["w13"], sizes,
+                                        preferred_element_type=F32), 2, -1)
+        y = lax.ragged_dot((jax.nn.silu(a) * b).astype(BF16), p["w2"], sizes,
+                           preferred_element_type=F32)
+        y = jnp.where(mine[order][:, None],
+                      y * weights.reshape(-1)[order][:, None], 0.0)
+        out = y[jnp.argsort(order)].reshape(n, top, -1).sum(1)
+        loads = jnp.bincount(jnp.where(counted, expert, cfg.num_experts),
+                             length=cfg.num_experts + 1)[:cfg.num_experts]
+        load = jnp.stack([jnp.sum(loads > 0), jnp.max(loads),
+                          jnp.sum(loads)]).astype(jnp.int32)
+    return out, chosen, load
+
+
+def _ffn_half(h, p, i: int, cfg: Lfm2Config, held, valid, routes: list,
+              loads: list):
+    """``h + ffn(rms(h))`` of layer ``i``; an expert layer appends the
+    experts chosen and its load."""
+    u = rms_norm(h, p["ffn_norm"], cfg.norm_eps)
+    if i < cfg.num_dense_layers:
+        return h + dense_ffn(u, p["ffn"])
+    out, chosen, load = moe_ffn(u, p["ffn"], cfg, held, valid)
+    routes.append(chosen)
+    loads.append(load)
+    return h + out
+
+
+def _head(h, params, cfg: Lfm2Config):
+    with jax.named_scope("head"):
+        u = rms_norm(h, params["norm_f"], cfg.norm_eps)
+        return lax.dot_general(u.astype(BF16), params["embed"],
+                               (((1,), (1,)), ((), ())),
+                               preferred_element_type=F32)
+
+
+def sample(logits, temperature, key, units: UnitIds):
+    """A unit id per row of ``logits`` ``[N, V]``: the largest logit over
+    the unit ids where ``temperature`` is 0, else a draw from
+    ``softmax(logits / temperature)`` over them.  The stop unit is
+    suppressed: a row ends at its frame budget, which the host counts."""
+    ids = jnp.arange(logits.shape[-1])
+    allowed = (ids >= units.first_id) & (ids != units.stop_id)
+    masked = jnp.where(allowed, logits, -jnp.inf)
+    greedy = jnp.argmax(masked, -1)
+    safe = jnp.maximum(temperature, 1e-6)[:, None]
+    drawn = jax.random.categorical(key, masked / safe, axis=-1)
+    return jnp.where(temperature > 0, drawn, greedy).astype(jnp.int32)
+
+
+# ---------------------------------------------------------------------------
+# the generation state and the two programs
+# ---------------------------------------------------------------------------
+
+def new_cache(cfg: Lfm2Config, slots: int, positions: int) -> dict:
+    """The state of ``slots`` rows of at most ``positions`` tokens: keys
+    and values of the attention layers, the convolution layers' columns,
+    and per slot the next token, its position, the units sampled so far
+    and the experts every token chose."""
+    kv_shape = (slots, positions, cfg.num_key_value_heads, cfg.head_dim)
+    n_attn, n_conv = (len(cfg.layers_of("full_attention")),
+                      len(cfg.layers_of("conv")))
+    return {
+        "k": [jnp.zeros(kv_shape, BF16) for _ in range(n_attn)],
+        "v": [jnp.zeros(kv_shape, BF16) for _ in range(n_attn)],
+        "conv": [jnp.zeros((slots, cfg.conv_L_cache, cfg.hidden_size), F32)
+                 for _ in range(n_conv)],
+        "token": jnp.zeros((slots,), jnp.int32),
+        "pos": jnp.zeros((slots,), jnp.int32),
+        "count": jnp.zeros((slots,), jnp.int32),
+        "units": jnp.zeros((slots, positions), jnp.int32),
+        "routes": jnp.zeros((slots, positions, len(cfg.expert_layers),
+                             cfg.num_experts_per_tok), jnp.int8),
+    }
+
+
+def prefill(params: Params, cache: dict, ids, n, slot, temperature, key, *,
+            cfg: Lfm2Config, units: UnitIds, held=None):
+    """One row joins: its prompt ``ids`` ``[T]`` (``n`` real) runs whole,
+    its state goes into ``slot`` and its first unit is sampled from the
+    logits at the prompt's last position.  Returns the cache, those logits
+    ``[V]`` and the expert layers' load ``[expert layers, 3]``."""
+    t = ids.shape[0]
+    valid = jnp.arange(t) < n
+    cache = dict(cache, k=list(cache["k"]), v=list(cache["v"]),
+                 conv=list(cache["conv"]))
+    h = params["embed"][ids].astype(F32)
+    i_attn = i_conv = 0
+    routes, loads = [], []
+    for i, kind in enumerate(cfg.layer_types):
+        p = params["layers"][i]
+        u = rms_norm(h, p["op_norm"], cfg.norm_eps)
+        if kind == "conv":
+            op, state = conv_op_seq(u, p["op"], n)
+            cache["conv"][i_conv] = cache["conv"][i_conv].at[slot].set(state)
+            i_conv += 1
+        else:
+            op, k, v = attn_op_seq(u, p["op"], cfg)
+            cache["k"][i_attn] = lax.dynamic_update_slice(
+                cache["k"][i_attn], k[None], (slot, 0, 0, 0))
+            cache["v"][i_attn] = lax.dynamic_update_slice(
+                cache["v"][i_attn], v[None], (slot, 0, 0, 0))
+            i_attn += 1
+        h = _ffn_half(h + op, p, i, cfg, held, valid, routes, loads)
+    last = lax.dynamic_slice_in_dim(h, n - 1, 1, axis=0)
+    logits = _head(last, params, cfg)
+    unit = sample(logits, temperature[None], key, units)[0]
+    cache["token"] = cache["token"].at[slot].set(unit)
+    cache["pos"] = cache["pos"].at[slot].set(n)
+    cache["count"] = cache["count"].at[slot].set(1)
+    cache["units"] = cache["units"].at[slot, 0].set(unit)
+    cache["routes"] = lax.dynamic_update_slice(
+        cache["routes"], jnp.stack(routes, 1).astype(jnp.int8)[None],
+        (slot, 0, 0, 0))
+    return cache, logits[0], jnp.stack(loads)
+
+
+def step(params: Params, cache: dict, live, temperature, step_no, *,
+         cfg: Lfm2Config, units: UnitIds, seed: int = 0, held=None):
+    """Every slot advances by one token: the slot's last unit goes in at
+    its position through the slot's state, and the next unit is sampled.
+    ``live`` ``[S]`` says which slots hold a row: the others are computed
+    (the shape is static) but cost no expert product, count for nothing
+    and do not advance.  Returns the cache, the logits ``[S, V]`` and the
+    expert layers' load ``[expert layers, 3]``."""
+    cache = dict(cache, k=list(cache["k"]), v=list(cache["v"]),
+                 conv=list(cache["conv"]))
+    pos = cache["pos"]
+    h = params["embed"][cache["token"]].astype(F32)
+    i_attn = i_conv = 0
+    routes, loads = [], []
+    for i, kind in enumerate(cfg.layer_types):
+        p = params["layers"][i]
+        u = rms_norm(h, p["op_norm"], cfg.norm_eps)
+        if kind == "conv":
+            op, cache["conv"][i_conv] = conv_op_step(
+                u, p["op"], cache["conv"][i_conv])
+            i_conv += 1
+        else:
+            op, cache["k"][i_attn], cache["v"][i_attn] = attn_op_step(
+                u, p["op"], cfg, cache["k"][i_attn], cache["v"][i_attn], pos)
+            i_attn += 1
+        h = _ffn_half(h + op, p, i, cfg, held, live, routes, loads)
+    logits = _head(h, params, cfg)
+    key = jax.random.fold_in(jax.random.PRNGKey(seed), step_no)
+    unit = sample(logits, temperature, key, units)
+    rows = jnp.arange(live.shape[0])
+    span = cache["units"].shape[1]
+    cache["routes"] = cache["routes"].at[rows, pos].set(
+        jnp.stack(routes, 1).astype(jnp.int8))
+    cache["units"] = cache["units"].at[
+        rows, jnp.minimum(cache["count"], span - 1)].set(
+        jnp.where(live, unit, 0))
+    cache["token"] = jnp.where(live, unit, cache["token"])
+    # an empty slot stays where it is, inside the cache
+    cache["pos"] = jnp.where(live, jnp.minimum(pos + 1, span - 1), pos)
+    cache["count"] = jnp.where(live, cache["count"] + 1, cache["count"])
+    return cache, logits, jnp.stack(loads)

@@ -224,6 +224,9 @@ class ServingRuntime:
         #: reruns, host seconds per phase): counted where the programs
         #: run, read here at scrape time
         tracing.program_stats().bind_metrics(r)
+        #: step-wise generation (a unit voice's step loop): steps, slots,
+        #: prefill tokens, rows, host seconds and the expert layers' load
+        tracing.step_stats().bind_metrics(r)
         #: sonata-scope aggregation plane (ISSUE 7): rolling per-stage
         #: quantiles, SLO burn rates, dispatch padding-waste accounting,
         #: and the 1 Hz flight recorder.  SONATA_SCOPE=0 disables; the

@@ -651,6 +651,142 @@ def record_device_group(group: dict, voice: Optional[str] = None) -> None:
 
 
 # ---------------------------------------------------------------------------
+# step-wise generation: what a step loop records (spans and counters)
+# ---------------------------------------------------------------------------
+
+#: host phases of a step loop's iteration, none of them blocked on the
+#: device: ``launch`` (the step program's asynchronous call), ``admit``
+#: (prefills enqueued), ``retire`` (vocoder programs enqueued)
+AR_HOST_PHASES = ("launch", "admit", "retire")
+
+
+class StepStats:
+    """Process-lifetime counters of step-wise generation (a voice's step
+    loop: :mod:`sonata_tpu.synth.steploop`), fed by the loop whether or not
+    a trace is active and read by ``/metrics``.  ``layers`` are the expert
+    layers' indices in the backbone, known once a loop has started."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.steps = 0
+        self.slot_steps = {"live": 0, "empty": 0}
+        self.prefill_tokens = 0
+        self.rows = {"admitted": 0, "retired": 0}
+        self.host_s = dict.fromkeys(AR_HOST_PHASES, 0.0)
+        #: per expert layer: assignments, distinct experts summed over
+        #: steps, the fullest expert's assignments summed over steps
+        self.moe: dict = {}
+        self.slots_in_use = 0
+        self._registry = None
+
+    def record_steps(self, group: dict) -> None:
+        """A group of steps (the attributes of its ``dispatch`` span)."""
+        with self._lock:
+            self.steps += group["steps"]
+            self.slot_steps["live"] += group["live_slot_steps"]
+            self.slot_steps["empty"] += (group["steps"] * group["slots"]
+                                         - group["live_slot_steps"])
+            for phase in AR_HOST_PHASES:
+                self.host_s[phase] += group["host_ms"][phase] / 1e3
+            self._add_loads(group["layers"], group["assignments"],
+                            group["experts_touched"],
+                            group["max_expert_assignments"])
+
+    def record_prefill(self, tokens: int, layers, loads) -> None:
+        """One row admitted: its prompt's tokens and what they chose."""
+        with self._lock:
+            self.prefill_tokens += tokens
+            self.rows["admitted"] += 1
+            self._add_loads(layers, [int(l[2]) for l in loads],
+                            [int(l[0]) for l in loads],
+                            [int(l[1]) for l in loads])
+
+    def _add_loads(self, layers, assignments, touched, fullest) -> None:
+        new = [layer for layer in layers if layer not in self.moe]
+        for layer, a, t, m in zip(layers, assignments, touched, fullest):
+            sums = self.moe.setdefault(layer, [0, 0, 0])
+            sums[0] += a
+            sums[1] += t
+            sums[2] += m
+        if new and self._registry is not None:
+            self._bind_layers(new)
+
+    def record_retired(self) -> None:
+        with self._lock:
+            self.rows["retired"] += 1
+
+    def bind_metrics(self, registry) -> None:
+        """Scrape-time callbacks, as :meth:`ProgramStats.bind_metrics`;
+        the expert layers' series appear with the first loop's first
+        record (a process that serves no such voice exports none)."""
+        registry.counter(
+            "sonata_ar_steps_total",
+            "Step programs run by step-wise generation loops (one step "
+            "advances every slot by one token)."
+        ).set_function(lambda: float(self.steps))
+        slot_steps = registry.counter(
+            "sonata_ar_slot_steps_total",
+            "Slots computed by step programs, by state: live (a row's "
+            "token) or empty (masked padding of the static shape).")
+        for state in ("live", "empty"):
+            slot_steps.labels(state=state).set_function(
+                lambda s=state: float(self.slot_steps[s]))
+        registry.counter(
+            "sonata_ar_prefill_tokens_total",
+            "Prompt tokens run by prefill programs."
+        ).set_function(lambda: float(self.prefill_tokens))
+        rows = registry.counter(
+            "sonata_ar_rows_total",
+            "Rows (sentences) of step-wise generation, by event: admitted "
+            "(prefilled into a slot) or retired (frame budget reached, "
+            "units handed to the vocoder).")
+        for event in ("admitted", "retired"):
+            rows.labels(event=event).set_function(
+                lambda e=event: float(self.rows[e]))
+        host = registry.counter(
+            "sonata_ar_host_seconds_total",
+            "Host seconds of a step loop's iterations, none blocked on the "
+            "device, by phase: launch (the step's asynchronous call), admit "
+            "(prefills enqueued), retire (vocoder programs enqueued).")
+        for phase in AR_HOST_PHASES:
+            host.labels(phase=phase).set_function(
+                lambda p=phase: self.host_s[p])
+        registry.gauge(
+            "sonata_ar_slots_in_use",
+            "Slots of step-wise generation loops that hold a row."
+        ).set_function(lambda: float(self.slots_in_use))
+        self._registry = registry
+        with self._lock:
+            self._bind_layers(list(self.moe))
+
+    def _bind_layers(self, layers) -> None:
+        r = self._registry
+        series = (
+            ("sonata_moe_assignments_total",
+             "Token-to-expert assignments of an expert layer (prefill and "
+             "steps)."),
+            ("sonata_moe_experts_touched_total",
+             "Distinct experts chosen in an expert layer, summed over its "
+             "programs (over sonata_ar_steps_total: experts a step reads)."),
+            ("sonata_moe_max_expert_assignments_total",
+             "Assignments of the fullest expert of an expert layer, summed "
+             "over its programs (over assignments: the load's skew)."))
+        for k, (name, text) in enumerate(series):
+            metric = r.counter(name, text)
+            for layer in layers:
+                metric.labels(layer=str(layer)).set_function(
+                    lambda l=layer, k=k: float(self.moe[l][k]))
+
+
+_step_stats = StepStats()
+
+
+def step_stats() -> StepStats:
+    """The process's one :class:`StepStats`."""
+    return _step_stats
+
+
+# ---------------------------------------------------------------------------
 # finished-trace observer (the scope aggregation plane's feed)
 # ---------------------------------------------------------------------------
 

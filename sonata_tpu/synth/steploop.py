@@ -1,0 +1,416 @@
+"""Step-wise generation: one loop per voice over a fixed set of slots.
+
+:class:`~sonata_tpu.synth.batching.IterationLoop` batches independent
+window decodes and keeps nothing between iterations.  An autoregressive
+voice is the other case: a row (one sentence) lives for hundreds of steps,
+every step yields one unit a row and no audio, and what carries a row from
+step to step (keys and values, convolution columns) stays on the device in
+the slot the row was given.  The loop, on a thread of its own:
+
+1. **admit**: waiting rows take free slots, one prefill program each (per
+   arrival: the prompt runs whole, writes the slot's state and samples the
+   row's first unit);
+2. **step**: one program over all ``S`` slots, a static shape, empty slots
+   masked and counted;
+3. **retire**: a row leaves when it has its frame budget of units; its
+   units go to the vocoder program (enqueued, not awaited: a finisher
+   thread fetches the audio and resolves the row's future), its slot is
+   free for the next admit.
+
+The host reads nothing back to decide a launch: a row's length is known
+when it joins, so liveness is counted, not fetched.  The loop keeps one step
+queued behind the running one and waits for the step before (its expert
+load, a few numbers), which bounds the run-ahead and times the steps.
+
+**Slots** (:class:`SlotTable`) are the state manager's host half: which
+slot holds which row.  The device half, the arrays two kinds of state live
+in, belongs to the engine (``new_cache``).
+
+**Tracing.**  A step serves every live row, so its span belongs to no one
+request: the loop records its steps on a trace it owns (``ar-steps``,
+closed every few seconds), one ``dispatch`` span per group of
+:data:`STEP_GROUP` steps with ``kind: step`` and the group's sums (``steps``,
+``live_slot_steps``, ``slots``, ``kv_positions`` the live rows attended
+over, per expert layer ``assignments``, ``experts_touched`` and
+``max_expert_assignments``, ``host_ms`` by phase).  Each
+prefill and each vocoder launch is a ``dispatch`` span (``kind: prefill`` |
+``vocode``) in the trace of the request the row belongs to; both end when
+what their program produced is on the host (a prefill's load, a row's
+samples), and a vocoder's says what the row needed and what it was padded
+to (``frames_needed``, ``frames_bucket``) and what the finisher thread
+spent on it (``fetch_wait_ms`` until the program had run, ``finish_ms`` of
+its own work after).  The always-on counters are
+:class:`~sonata_tpu.serving.tracing.StepStats`.
+
+The engine (a voice: :class:`~sonata_tpu.models.unit_voice.UnitVoice`)
+gives ``slots``, ``expert_layers``, ``new_cache()``, ``prefill(cache, slot,
+ids, temperature)``, ``step(cache, live, temperature, step_no)``,
+``vocode(cache, slot, units)``, ``wait_audio(out)``, ``fetch_audio(out,
+units)``, ``row_record(cache, slot)`` and ``take_rows(logits, rows)``.
+"""
+
+from __future__ import annotations
+
+import collections
+import logging
+import os
+import threading
+import time
+from concurrent.futures import Future
+from pathlib import Path
+from typing import Optional
+
+import numpy as np
+
+from ..core import OperationError
+from ..serving import tracing
+
+log = logging.getLogger("sonata.steploop")
+
+#: steps summed into one ``dispatch`` span of the loop's own trace
+STEP_GROUP = 32
+#: the loop's trace is finished (and a new one begun) this often
+TRACE_SECONDS = 4.0
+#: a flagged row's logits are kept at its first unit, its last, and every
+#: this many units between
+DUMP_EVERY = 32
+#: rows whose logits one gather program takes
+DUMP_ROWS = 8
+
+DUMP_DIR_ENV = "SONATA_AR_DUMP_DIR"
+DUMP_PREFIX_ENV = "SONATA_AR_DUMP_RID_PREFIX"
+
+
+class SlotTable:
+    """Which slot holds which row (lowest free slot first)."""
+
+    def __init__(self, slots: int):
+        self.rows: list = [None] * slots
+
+    def take(self, row) -> Optional[int]:
+        for slot, held in enumerate(self.rows):
+            if held is None:
+                self.rows[slot] = row
+                return slot
+        return None
+
+    def release(self, slot: int) -> None:
+        self.rows[slot] = None
+
+    def live(self) -> list:
+        return [row for row in self.rows if row is not None]
+
+    @property
+    def in_use(self) -> int:
+        return sum(row is not None for row in self.rows)
+
+
+class Row:
+    """One sentence in flight: ``ids`` the prompt, ``budget`` the units it
+    decodes (its frames), ``temperature`` its sampling."""
+
+    def __init__(self, ids: list, budget: int, temperature: float):
+        self.ids = ids
+        self.budget = int(budget)
+        self.temperature = float(temperature)
+        self.future: Future = Future()
+        self.context = tracing.current()
+        self.request_id = (self.context[0].request_id
+                           if self.context else None)
+        self.t_submit = time.monotonic()
+        self.slot: Optional[int] = None
+        self.units = 0
+        #: flagged rows only: ``(unit index, gather, row of the gather)``
+        self.dump: Optional[list] = None
+
+    def span(self, start: float, end: float, **attrs) -> None:
+        if self.context is not None:
+            trace, parent = self.context
+            trace.new_span("dispatch", parent=parent, start=start, end=end,
+                           attrs=attrs)
+
+
+class StepLoop:
+    def __init__(self, engine, *, name: str = ""):
+        self.engine = engine
+        self.name = name
+        self.slots = SlotTable(engine.slots)
+        self.stats = tracing.step_stats()
+        self.layers = list(engine.expert_layers)
+        dump_dir = os.environ.get(DUMP_DIR_ENV)
+        self._dump_dir = Path(dump_dir) if dump_dir else None
+        self._dump_prefix = os.environ.get(DUMP_PREFIX_ENV, "")
+        self._cond = threading.Condition()
+        self._waiting: collections.deque = collections.deque()
+        self._closed = False
+        self._draining = False
+        self._finish: collections.deque = collections.deque()
+        self._finish_cond = threading.Condition()
+        self._step_no = 0
+        self._trace = None
+        self._trace_began = 0.0
+        self._trace_seq = 0
+        self._group = None
+        #: prefills enqueued whose load has not been read yet (read with
+        #: the next step's, so that an admit does not wait for the device):
+        #: ``(row, when admitted, the span's attributes, load)``
+        self._admitted: list = []
+        self._thread = threading.Thread(
+            target=self._run, name=f"sonata_steploop_{name}", daemon=True)
+        self._finisher = threading.Thread(
+            target=self._run_finisher, name=f"sonata_stepfin_{name}",
+            daemon=True)
+        self._thread.start()
+        self._finisher.start()
+
+    # -- callers -------------------------------------------------------------
+    def submit(self, ids: list, budget: int, temperature: float) -> Future:
+        """A row joins the queue; its future resolves to what the engine's
+        ``fetch_audio`` gives once its units have been through the
+        vocoder."""
+        row = Row(ids, budget, temperature)
+        if self._dump_dir is not None and row.request_id and \
+                row.request_id.startswith(self._dump_prefix):
+            row.dump = []
+        with self._cond:
+            if self._closed or self._draining:
+                raise OperationError("the voice's step loop is closed")
+            self._waiting.append(row)
+            self._cond.notify()
+        return row.future
+
+    def start_draining(self) -> None:
+        """Refuse new rows; those in flight finish."""
+        with self._cond:
+            self._draining = True
+
+    def close(self) -> None:
+        with self._cond:
+            self._closed = True
+            self._cond.notify_all()
+        self._thread.join(timeout=30.0)
+        with self._finish_cond:
+            self._finish_cond.notify_all()
+        self._finisher.join(timeout=30.0)
+
+    # -- the loop ------------------------------------------------------------
+    def _run(self) -> None:
+        failure = "the voice's step loop was closed"
+        try:
+            self._loop()
+        except Exception as e:  # the loop must fail its rows, not hang them
+            log.exception("step loop %s failed", self.name)
+            failure = f"step loop failed: {type(e).__name__}: {e}"
+        with self._cond:
+            self._closed = True
+            rows = list(self._waiting) + self.slots.live()
+            self._waiting.clear()
+        for row in rows:
+            if not row.future.done():
+                row.future.set_exception(OperationError(failure))
+
+    def _loop(self) -> None:
+        engine = self.engine
+        cache = engine.new_cache()
+        pending = None          # the step before: (its load, when launched)
+        while True:
+            with self._cond:
+                while not self._closed and not self._waiting \
+                        and not self.slots.in_use:
+                    if pending is not None or self._group is not None:
+                        break
+                    self._cond.wait()
+                if self._closed:
+                    return
+                arrivals = []
+                while self._waiting and self.slots.in_use + len(
+                        arrivals) < engine.slots:
+                    arrivals.append(self._waiting.popleft())
+            t0 = time.perf_counter()
+            for row in arrivals:
+                cache = self._admit(cache, row)
+            t1 = time.perf_counter()
+            rows = self.slots.live()
+            if not rows:
+                # nothing to step: what is still in flight is waited for,
+                # and the steps recorded so far go out
+                self._settle(pending)
+                pending = None
+                self._close_group(time.monotonic())
+                self._roll_trace(force=True)
+                continue
+            live = np.zeros((engine.slots,), bool)
+            temperature = np.zeros((engine.slots,), np.float32)
+            attended = 0
+            for row in rows:
+                live[row.slot] = True
+                temperature[row.slot] = row.temperature
+                attended += len(row.ids) + row.units
+            launched = time.monotonic()
+            cache, logits, load = engine.step(cache, live, temperature,
+                                              self._step_no)
+            load.copy_to_host_async()
+            self._step_no += 1
+            t2 = time.perf_counter()
+            due = []
+            for row in rows:
+                row.units += 1
+                if row.dump is not None and (
+                        (row.units - 1) % DUMP_EVERY == 0
+                        or row.units == row.budget):
+                    due.append(row)
+            for k in range(0, len(due), DUMP_ROWS):
+                part = due[k:k + DUMP_ROWS]
+                got = engine.take_rows(logits, [r.slot for r in part]
+                                       + [0] * (DUMP_ROWS - len(part)))
+                got.copy_to_host_async()
+                for j, row in enumerate(part):
+                    row.dump.append((row.units - 1, got, j))
+            for row in rows:
+                if row.units >= row.budget:
+                    self._retire(cache, row)
+            t3 = time.perf_counter()
+            self._settle(pending)
+            pending = (load, launched, len(rows), attended,
+                       {"admit": t1 - t0, "launch": t2 - t1,
+                        "retire": t3 - t2})
+
+    def _admit(self, cache, row: Row):
+        engine = self.engine
+        start = time.monotonic()
+        slot = self.slots.take(row)
+        row.slot = slot
+        cache, logits, load, shape = engine.prefill(cache, slot, row.ids,
+                                                    row.temperature)
+        if row.dump is not None:
+            logits.copy_to_host_async()
+            row.dump.append((0, logits, None))
+        row.units = 1
+        load.copy_to_host_async()
+        self._admitted.append((row, start, dict(
+            shape, kind="prefill", rows=1, tokens=len(row.ids), slot=slot,
+            wait_ms=round((start - row.t_submit) * 1e3, 3)), load))
+        self.stats.slots_in_use = self.slots.in_use
+        if row.units >= row.budget:
+            self._retire(cache, row)
+        return cache
+
+    def _retire(self, cache, row: Row) -> None:
+        engine = self.engine
+        start = time.monotonic()
+        out, shape = engine.vocode(cache, row.slot, row.budget)
+        record = None
+        if row.dump is not None:
+            record = engine.row_record(cache, row.slot)
+        self.slots.release(row.slot)
+        self.stats.record_retired()
+        self.stats.slots_in_use = self.slots.in_use
+        with self._finish_cond:
+            self._finish.append((row, out, shape, record, start))
+            self._finish_cond.notify()
+
+    def _settle(self, pending) -> None:
+        """The step before the one just launched has finished: fetch its
+        load (this is where the loop waits for the device) and add it to
+        the group's sums."""
+        admitted, self._admitted = self._admitted, []
+        for row, start, attrs, load in admitted:
+            self.stats.record_prefill(attrs["tokens"], self.layers,
+                                      np.asarray(load))
+            row.span(start, time.monotonic(), **attrs)
+        if pending is None:
+            return
+        load, launched, live, attended, host = pending
+        loads = np.asarray(load)
+        now = time.monotonic()
+        g = self._group
+        if g is None:
+            g = self._group = {
+                "start": launched, "steps": 0, "live_slot_steps": 0,
+                "kv_positions": 0,
+                "assignments": [0] * len(self.layers),
+                "experts_touched": [0] * len(self.layers),
+                "max_expert_assignments": [0] * len(self.layers),
+                "host_ms": dict.fromkeys(tracing.AR_HOST_PHASES, 0.0)}
+        g["steps"] += 1
+        g["live_slot_steps"] += live
+        g["kv_positions"] += attended
+        for k in range(len(self.layers)):
+            g["experts_touched"][k] += int(loads[k][0])
+            g["max_expert_assignments"][k] += int(loads[k][1])
+            g["assignments"][k] += int(loads[k][2])
+        for phase, seconds in host.items():
+            g["host_ms"][phase] += seconds * 1e3
+        if g["steps"] >= STEP_GROUP:
+            self._close_group(now)
+            self._roll_trace()
+
+    def _close_group(self, end: float) -> None:
+        g, self._group = self._group, None
+        if g is None:
+            return
+        start = g.pop("start")
+        g["host_ms"] = {k: round(v, 3) for k, v in g["host_ms"].items()}
+        g.update(kind="step", slots=self.engine.slots, layers=self.layers)
+        self.stats.record_steps(g)
+        if self._trace is None:
+            self._trace = tracing.default_tracer().start_trace(
+                "ar-steps", request_id=f"ar-steps-{self.name}-"
+                                       f"{self._trace_seq}",
+                voice=self.name)
+            self._trace_began = start
+            self._trace_seq += 1
+        if self._trace is not None:
+            self._trace.new_span("dispatch", start=start, end=end, attrs=g)
+
+    def _roll_trace(self, force: bool = False) -> None:
+        if self._trace is not None and (
+                force or time.monotonic() - self._trace_began
+                > TRACE_SECONDS):
+            self._trace.finish("ok")
+            self._trace = None
+
+    # -- the finisher ----------------------------------------------------------
+    def _run_finisher(self) -> None:
+        while True:
+            with self._finish_cond:
+                while not self._finish and not self._closed:
+                    self._finish_cond.wait(0.5)
+                if not self._finish:
+                    return
+                row, out, shape, record, start = self._finish.popleft()
+            try:
+                popped = time.monotonic()
+                self.engine.wait_audio(out)
+                ready = time.monotonic()
+                result = self.engine.fetch_audio(out, row.budget)
+                end = time.monotonic()
+                row.span(start, end, kind="vocode", rows=1,
+                         frames_needed=row.budget,
+                         fetch_wait_ms=round((ready - popped) * 1e3, 3),
+                         finish_ms=round((end - ready) * 1e3, 3), **shape)
+                if row.dump is not None:
+                    self._write_dump(row, record, shape)
+                row.future.set_result(result)
+            except Exception as e:  # one row's failure is that row's
+                log.exception("vocoding a row failed")
+                row.future.set_exception(OperationError(
+                    f"vocoding failed: {type(e).__name__}: {e}"))
+
+    def _write_dump(self, row: Row, record, shape: dict) -> None:
+        """What the timed path produced for a flagged row, for whoever
+        holds it against a reference: the prompt, every unit chosen, the
+        experts every token chose, and the float32 logits over the whole
+        vocabulary behind the units of ``logit_units``."""
+        units, routes = (np.asarray(a) for a in record)
+        n, count = len(row.ids), row.budget
+        index = [j for j, _, _ in row.dump]
+        logits = np.stack([np.asarray(a) if k is None else np.asarray(a)[k]
+                           for _, a, k in row.dump])
+        self._dump_dir.mkdir(parents=True, exist_ok=True)
+        path = self._dump_dir / f"{row.request_id}.{id(row):x}.npz"
+        with open(path, "wb") as f:
+            np.savez(f, ids=np.asarray(row.ids, np.int32),
+                     units=units[:count], routes=routes[:n + count - 1],
+                     logit_units=np.asarray(index, np.int32), logits=logits,
+                     frames_bucket=np.int32(shape["frames_bucket"]))
