@@ -104,8 +104,25 @@ class PiperVoice(BaseModel):
         # (width, batch rung) — see _decode_windows_fused_fn.
         self.fused_epilogue = decode_opts.resolve_fused_epilogue(
             fused_epilogue)
-        self.params = params
         self.mesh = mesh  # jax.sharding.Mesh → batch rides the data axis
+        # the weights live on the device from here on, under the placement
+        # the programs expect (a mesh: the per-leaf shardings _jit
+        # declares), so no program call hands them over again; leaves
+        # that are placed already (init_vits, a replica's) stay where
+        # they are, and the host copy goes with the caller's reference
+        if mesh is None:
+            self.params = jax.device_put(params)
+        else:
+            from ..parallel.mesh import param_shardings
+
+            self.params = jax.device_put(
+                params, param_shardings(mesh, params))
+        #: bytes of the weight tree a program call would still take from
+        #: the host (read once, here: a dispatch record adds it to its
+        #: ``upload_bytes`` without walking the tree)
+        self._weights_host_bytes = sum(
+            leaf.nbytes for leaf in jax.tree_util.tree_leaves(self.params)
+            if not isinstance(leaf, jax.Array))
         # Reduced-precision policy for the HiFi-GAN conv stack (the FLOPs):
         # "bfloat16" keeps the MXU in its native single-pass mode.  Audio
         # leaves the graph float32 either way (vits.decode_with casts back
@@ -268,9 +285,11 @@ class PiperVoice(BaseModel):
                            seed_offset: int = 0) -> "PiperVoice":
         """A copy of this voice pinned to one device (replica-pool serving).
 
-        ``jax.device_put`` commits the params to ``device``; every jitted
-        dispatch then runs on that chip (a committed operand places the
-        whole computation), so N replicas built from one loaded voice
+        ``jax.device_put`` commits the params to ``device`` (a copy from
+        the chip this voice's own tree lives on, or the same buffers
+        where that chip is ``device``); every jitted dispatch then runs
+        there (a committed operand places the whole computation), so N
+        replicas built from one loaded voice
         occupy N chips with independent executables, RNG streams
         (``seed_offset`` keeps replica draws distinct), and jit caches —
         the isolation the pool's circuit breaker relies on.  Mutually
@@ -696,11 +715,12 @@ class PiperVoice(BaseModel):
         boot's serialized executable loads in ~0.3 s with zero
         retracing; a cold shape compiles via
         ``jit(...).lower().compile()`` and serializes for the next
-        boot.  Either way the executable is installed into
-        ``_full_cache`` — the exact cache real traffic dispatches
-        through (the compiled object is callable with the same
-        arguments as the jitted function, and takes params as an
-        argument, so one blob serves every voice with these dims).
+        boot.  Either way the executable runs once on the dummy
+        arguments and is installed into ``_full_cache`` — the exact
+        cache real traffic dispatches through (the compiled object is
+        callable with the same arguments as the jitted function, and
+        takes params as an argument, so one blob serves every voice
+        with these dims).
         Falls back to a dummy-argument jit call (which rides JAX's own
         persistent compile cache) when AOT is disabled, a mesh is
         attached, or anything in the AOT path fails.  Bypasses
@@ -747,8 +767,17 @@ class PiperVoice(BaseModel):
             aot_dir = aot_cache_dir()
             if aot_dir is not None:
                 try:
-                    if self._warm_shape_aot(shape, args, aot_dir):
-                        return
+                    executable = self._aot_executable(shape, args, aot_dir)
+                    # run it once, as the jit warm-up below does: a blob
+                    # can load and still not run (XLA:CPU's, when the
+                    # executable it was written from came out of the
+                    # persistent compile cache), and that has to raise
+                    # here, where the jit path can take over, not in
+                    # traffic
+                    jax.block_until_ready(executable(*args))
+                    with self._jit_lock:
+                        self._full_cache[(b, t, f)] = executable
+                    return
                 except Exception as e:
                     import logging
 
@@ -789,12 +818,12 @@ class PiperVoice(BaseModel):
         return hashlib.blake2b(repr(parts).encode(),
                                digest_size=16).hexdigest()
 
-    def _warm_shape_aot(self, shape: tuple[int, int, int], args: list,
-                        aot_dir: str) -> bool:
-        """Load (or build + serialize) one shape's AOT executable and
-        install it in ``_full_cache``.  Concurrent writers race safely
-        (atomic tmp + rename); a corrupt blob raises and the caller
-        falls back to the jit path."""
+    def _aot_executable(self, shape: tuple[int, int, int], args: list,
+                        aot_dir: str):
+        """One shape's AOT executable: loaded from the store, or built
+        and serialized into it.  Concurrent writers race safely (atomic
+        tmp + rename); a corrupt blob raises and the caller falls back
+        to the jit path."""
         import pickle
 
         from jax.experimental.serialize_executable import (
@@ -807,17 +836,19 @@ class PiperVoice(BaseModel):
         if os.path.exists(path):
             with open(path, "rb") as fh:
                 payload, in_tree, out_tree = pickle.load(fh)
-            executable = deserialize_and_load(payload, in_tree, out_tree)
-        else:
-            fn = self._full_fn(b, t, f)
-            executable = fn.lower(*args).compile()
-            tmp = f"{path}.tmp{os.getpid()}.{threading.get_ident()}"
-            with open(tmp, "wb") as fh:
-                pickle.dump(serialize(executable), fh)
-            os.replace(tmp, path)
-        with self._jit_lock:
-            self._full_cache[(b, t, f)] = executable
-        return True
+            # loaded for the device the weights live on: left to its
+            # default the loader spreads the executable over every local
+            # device, and each call then wants one shard per device
+            return deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=list(jax.tree_util.tree_leaves(
+                    self.params)[0].devices()))
+        executable = self._full_fn(b, t, f).lower(*args).compile()
+        tmp = f"{path}.tmp{os.getpid()}.{threading.get_ident()}"
+        with open(tmp, "wb") as fh:
+            pickle.dump(serialize(executable), fh)
+        os.replace(tmp, path)
+        return executable
 
     # Cap on rows per device dispatch: beyond this, padding waste and
     # compile sizes grow without amortizing any more fixed latency.
@@ -1617,10 +1648,15 @@ class PiperVoice(BaseModel):
         # asynchronous only while the runtime has a free slot for one
         # more program in flight (and compiles, on a cold shape); and
         # what is static in the compiled shape: per upsample stage, the
-        # time steps folded into the channel axis
+        # time steps folded into the channel axis; and what this launch
+        # handed over from the host: the arguments made above from host
+        # values, and whatever part of the weights is not resident (sizes
+        # from shapes: nothing waits on the device)
         group.update(frames_budget=budget, frames_per_id=round(fpi, 4),
                      decode_fold=vits.decode_fold(self.params["dec"],
                                                   self.hp, f, self.mesh),
+                     upload_bytes=self._weights_host_bytes + sum(
+                         a.nbytes for a in args[1:]),
                      enqueue_ms=round((t_enqueue - t_start) * 1e3, 3),
                      launch_ms=round((t_enqueue - t_launch) * 1e3, 3))
         return {"out": out, "args": args, "b": b, "t": t, "f": f,

@@ -35,7 +35,8 @@ Design constraints, in order:
   full-pipeline program it runs (:func:`annotate_dispatch_group` when it
   is enqueued, :func:`record_device_group` when its result is on the
   host): padded shape, frames each row needed against the budget and the
-  bucket, overflow reruns, host time per phase.  The record lands in the
+  bucket, overflow reruns, host time per phase, bytes handed over from
+  the host at launch.  The record lands in the
   enclosing ``dispatch`` span's ``device_groups`` and, traced or not, in
   :class:`ProgramStats`, the counters ``/metrics`` exports.
 - **Spans inside the profiler's trace.**  While ``/debug/profile`` holds
@@ -567,6 +568,7 @@ class ProgramStats:
         self._host_s = dict.fromkeys(HOST_PHASES, 0.0)
         self._groups = 0
         self._overflow_retries = 0
+        self._upload_bytes = 0
         self._frames_per_id: dict = {}
 
     def record(self, group: dict, voice: Optional[str] = None) -> None:
@@ -579,6 +581,7 @@ class ProgramStats:
                 self._host_s[phase] += group[phase + "_ms"] / 1e3
             if group.get("overflow"):
                 self._overflow_retries += 1
+            self._upload_bytes += group["upload_bytes"]
             self._frames_per_id[voice or ""] = group["frames_per_id"]
 
     def snapshot(self) -> dict:
@@ -587,6 +590,7 @@ class ProgramStats:
                     "overflow_retries": self._overflow_retries,
                     "frames": dict(self._frames),
                     "host_seconds": dict(self._host_s),
+                    "upload_bytes": self._upload_bytes,
                     "frames_per_id": dict(self._frames_per_id)}
 
     def frames_per_id(self, voice: str) -> Optional[float]:
@@ -619,6 +623,12 @@ class ProgramStats:
         for phase in HOST_PHASES:
             host.labels(phase=phase).set_function(
                 lambda p=phase: self._host_s[p])
+        registry.counter(
+            "sonata_dispatch_upload_bytes_total",
+            "Bytes device programs were handed from the host at launch: "
+            "ids, lengths, scales and the key, a few kilobytes a "
+            "program; a voice's weights only if they were not resident."
+        ).set_function(lambda: float(self._upload_bytes))
         registry.counter(
             "sonata_dispatch_groups_total",
             "Full-pipeline device groups finished (one program each, two "

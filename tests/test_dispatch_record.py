@@ -9,10 +9,11 @@ import json
 import threading
 import urllib.request
 
+import jax
 import numpy as np
 import pytest
 
-from sonata_tpu.models import vits
+from sonata_tpu.models import from_config_path, vits
 from sonata_tpu.serving import MetricsRegistry, ServingRuntime, tracing
 from sonata_tpu.serving import scope as scope_mod
 from sonata_tpu.serving.scope import Scope
@@ -22,14 +23,15 @@ from sonata_tpu.utils import profiling
 from sonata_tpu.utils.buckets import FRAME_BUCKETS, bucket_for
 from tools import profile_report
 
-from voices import tiny_voice
+from voices import tiny_voice, write_tiny_voice
 
 TEXT = ("Hello world. This is a longer sentence for the test. Short. "
         "One more sentence of middling length.")
 GROUP_FIELDS = {"batch_bucket", "text_bucket", "frame_bucket", "rows",
                 "padding_rows", "padding_ratio", "compile", "frames_needed",
                 "frames_budget", "frames_per_id", "decode_fold",
-                "enqueue_ms", "launch_ms", "fetch_wait_ms", "epilogue_ms"}
+                "upload_bytes", "enqueue_ms", "launch_ms", "fetch_wait_ms",
+                "epilogue_ms"}
 
 
 def frames_computed(groups) -> int:
@@ -147,6 +149,38 @@ def test_stock_path_dispatch_span_says_what_it_ran():
     assert delta["frames"]["served"] == sum(needed)
     assert tracing.program_stats().frames_per_id("model-label") == \
         attrs["device_groups"][-1]["frames_per_id"]
+
+
+def test_a_program_uploads_its_arguments_not_the_weights(tmp_path):
+    """``upload_bytes`` of a voice loaded from an ``.npz`` (numpy arrays
+    out of ``np.load``, where ``tiny_voice()`` starts from device arrays):
+    the ids, lengths, scales and key of each program, and the series
+    beside the host seconds advances by the same."""
+    voice = from_config_path(write_tiny_voice(tmp_path))
+    weights = sum(leaf.nbytes for leaf in
+                  jax.tree_util.tree_leaves(voice.params))
+    assert weights > 64 * 1024
+    registry = MetricsRegistry()
+    tracing.program_stats().bind_metrics(registry)
+
+    def series() -> float:
+        (line,) = [l for l in registry.render().splitlines()
+                   if l.startswith("sonata_dispatch_upload_bytes_total ")]
+        return float(line.split()[1])
+
+    tracer = tracing.Tracer(enabled=True, log_sink="0")
+    before = series()
+    trace, _ = traced(
+        tracer, lambda: voice.speak_batch(list(voice.phonemize_text(TEXT))),
+        request_id="upload-1")
+    (span,) = dispatch_spans(trace)
+    groups = span.attrs["device_groups"]
+    for g in groups:
+        # b x t ids, and per row a length and three scales, and the key
+        assert g["upload_bytes"] == (
+            g["batch_bucket"] * (g["text_bucket"] + 4) * 4 + 8)
+        assert g["upload_bytes"] < 64 * 1024
+    assert series() - before == sum(g["upload_bytes"] for g in groups)
 
 
 def test_counters_count_without_a_trace():
