@@ -18,10 +18,7 @@ it then fails or hangs):
    full depth one cold compile of a full-pipeline shape takes 23–74 s on a
    v5e, and even the ``minimal`` lattice would not fit this script's time
    limit (PERF.md, "Where the time goes");
-2. *kernel phase* (holds the chip): the Pallas gate kernel, compiled at the
-   row counts the warmup lattice uses, against the jnp reference; the
-   compiled flow program must contain the Mosaic custom call;
-3. *server* (holds the chip): ``python -m sonata_tpu.frontends.grpc_server
+2. *server* (holds the chip): ``python -m sonata_tpu.frontends.grpc_server
    --voice … --continuous-batching --metrics-port …`` — the documented
    deployment command.  This process is the gRPC client: batched,
    realtime and eight concurrent realtime requests over the wire, every
@@ -30,10 +27,10 @@ it then fails or hangs):
 
 Every child runs with ``JAX_PLATFORMS=tpu``, so a missing chip is JAX's own
 start-up error and never a CPU run.  ``--rehearse`` is the only way off the
-chip: the same phases with a tiny voice, ``JAX_PLATFORMS=cpu`` and the
-Pallas kernel in interpret mode, for debugging the command before chip time
-is spent.  Any failed check exits non-zero; the last line of standard
-output is ``{"ok": true, "device": {...}}`` only when every phase passed.
+chip: the same phases with a tiny voice and ``JAX_PLATFORMS=cpu``, for
+debugging the command before chip time is spent.  Any failed check exits
+non-zero; the last line of standard output is ``{"ok": true, "device":
+{...}}`` only when every phase passed.
 """
 
 from __future__ import annotations
@@ -70,8 +67,7 @@ WARMUP_BUDGET_S = 600.0
 ARM_KNOBS = ("SONATA_BATCH_MODE", "SONATA_ITER_PIPELINE",
              "SONATA_FUSED_EPILOGUE", "SONATA_DECODE_QUANT",
              "SONATA_COMPUTE_DTYPE", "SONATA_TCONV",
-             "SONATA_DISPATCH_POLICY", "SONATA_STREAM_COALESCE",
-             "SONATA_DONATE")
+             "SONATA_DISPATCH_POLICY")
 
 #: depth cut of the lessac-high voice, no width touched: text-encoder
 #: layers 6→2, flow coupling layers 4→2 (one flip pair), WaveNet layers
@@ -88,18 +84,6 @@ TINY_MODEL = dict(
     upsample_kernel_sizes=[8, 8], resblock_kernel_sizes=[3],
     resblock_dilation_sizes=[[1, 3]], dp_filter_channels=32,
     gin_channels=16, flow_n_layers=2, flow_wn_layers=2)
-
-#: (batch, frames) of the gate kernel's input: B*T from 64 rows up to the
-#: lattice's largest full-pipeline shape (16, 2048)
-GATE_SHAPES = ((1, 64), (1, 2048), (8, 128), (16, 512), (16, 2048))
-#: |tanh·sigmoid| <= 1 and both sides evaluate the two transcendentals in
-#: float32 through different approximations (Mosaic's vs XLA's); a kernel
-#: that computed in bfloat16 would miss by ~4e-3
-GATE_ATOL = 1e-4
-#: the flow stacks 16 gated layers between float32 convolutions (run at
-#: "highest" matmul precision on both sides), which amplifies the gate's
-#: last-digit differences; relative to the largest reference magnitude
-FLOW_RTOL = 1e-3
 
 SENTENCES = (
     "The quick brown fox jumps over the lazy dog near the river bank.",
@@ -205,85 +189,10 @@ def _device_facts() -> dict:
             "peak_bytes_in_use": stats.get("peak_bytes_in_use")}
 
 
-def child_kernels(config_path: str, interpret: bool) -> dict:
-    """The Pallas gate on the device against the jnp reference, alone and
-    inside the flow program the model compiles."""
-    import jax
-    import jax.numpy as jnp
-
-    from sonata_tpu.models import from_config_path, modules, vits
-    from sonata_tpu.ops.gate import fused_gate_pallas, fused_gate_reference
-    from sonata_tpu.utils.jax_cache import enable_persistent_compile_cache
-
-    enable_persistent_compile_cache()
-    facts = _device_facts()
-    voice = from_config_path(config_path)
-    hp = voice.hp
-    key = jax.random.PRNGKey(SEED)
-    reference = jax.jit(fused_gate_reference)
-    gate_rows = []
-    for b, t in GATE_SHAPES:
-        y = 3.0 * jax.random.normal(jax.random.fold_in(key, b * 10000 + t),
-                                    (b, t, 2 * hp.hidden_channels),
-                                    jnp.float32)
-        got = fused_gate_pallas(y, interpret=interpret)
-        err = float(jnp.max(jnp.abs(got - reference(y))))
-        gate_rows.append({"shape": [b, t, 2 * hp.hidden_channels],
-                          "max_abs_err": err})
-        if got.shape != (b, t, hp.hidden_channels):
-            raise SystemExit(f"gate kernel: shape {got.shape} at {(b, t)}")
-        if not bool(jnp.all(jnp.isfinite(got))) or not err <= GATE_ATOL:
-            raise SystemExit(
-                f"gate kernel: max |pallas - reference| = {err:g} at "
-                f"{(b, t)} exceeds {GATE_ATOL:g} (or non-finite output)")
-
-    # the flow program as the model builds it: the product code picks the
-    # kernel from the platform, so on a TPU the compiled text must hold
-    # the Mosaic custom call — the reference cannot stand in unnoticed
-    b, f = 2, 128
-    z = jax.random.normal(jax.random.fold_in(key, 1),
-                          (b, f, hp.inter_channels), jnp.float32)
-    mask = jnp.ones((b, f, 1), jnp.float32)
-    flow_params = voice.params["flow"]
-
-    def flow(params, z, mask):
-        return vits.flow_reverse(params, hp, z, mask)
-
-    text = jax.jit(flow).lower(flow_params, z, mask).compile().as_text()
-    has_mosaic = "tpu_custom_call" in text
-    if facts["platform"] == "tpu" and not has_mosaic:
-        raise SystemExit("flow program: no tpu_custom_call in the compiled "
-                         "text — the Pallas gate is not in the program")
-    with jax.default_matmul_precision("highest"):
-        got = jax.jit(flow)(flow_params, z, mask)
-        product_gate = modules.gate_op
-        modules.gate_op = lambda x, g=None, mesh=None: \
-            fused_gate_reference(x if g is None else x + g)
-        try:
-            reference_flow = jax.jit(lambda p, z, m: flow(p, z, m)).lower(
-                flow_params, z, mask).compile()
-        finally:
-            modules.gate_op = product_gate
-    if "tpu_custom_call" in reference_flow.as_text():
-        raise SystemExit("flow program: the reference side of the "
-                         "comparison holds the kernel too")
-    want = reference_flow(flow_params, z, mask)
-    scale = max(1.0, float(jnp.max(jnp.abs(want))))
-    flow_err = float(jnp.max(jnp.abs(got - want)))
-    if not flow_err <= FLOW_RTOL * scale:
-        raise SystemExit(f"flow program: max |kernel - reference| = "
-                         f"{flow_err:g} exceeds {FLOW_RTOL:g} x {scale:g}")
-    facts.update(_device_facts())
-    return {"device": facts, "interpret": interpret, "gate": gate_rows,
-            "gate_atol": GATE_ATOL, "flow_has_mosaic_call": has_mosaic,
-            "flow_max_abs_err": flow_err, "flow_ref_max_abs": scale,
-            "flow_rtol": FLOW_RTOL}
-
-
 def child_mesh_check(config_path: str, n_devices: int) -> dict:
     """One full-pipeline dispatch of the voice on an ``n_devices`` data
-    mesh: outputs sharded over distinct devices, the Mosaic gate inside,
-    the same audio as the unsharded program."""
+    mesh: outputs sharded over distinct devices, the same audio as the
+    unsharded program."""
     import jax
     import numpy as np
 
@@ -295,17 +204,12 @@ def child_mesh_check(config_path: str, n_devices: int) -> dict:
     facts = _device_facts()
     b, t, f = 2 * n_devices, 64, 256
     meshed = from_config_path(config_path, mesh=make_mesh(n_devices))
-    args = meshed._dummy_full_args(b, t)
-    fn = meshed._full_fn(b, t, f)
-    text = fn.lower(*args).compile().as_text()
-    wav, lengths, _peaks, _frames = fn(*args)
+    wav, lengths, _peaks, _frames = meshed._full_fn(b, t, f)(
+        *meshed._dummy_full_args(b, t))
     devices = sorted(str(s.device) for s in wav.addressable_shards)
     if len(set(devices)) != n_devices:
         raise SystemExit(f"mesh: output lives on {devices}, expected "
                          f"{n_devices} distinct devices")
-    has_mosaic = "tpu_custom_call" in text
-    if facts["platform"] == "tpu" and not has_mosaic:
-        raise SystemExit("mesh: no tpu_custom_call in the sharded program")
     single = from_config_path(config_path)
     wav1, lengths1, _p, _f = single._full_fn(b, t, f)(
         *single._dummy_full_args(b, t))
@@ -319,7 +223,7 @@ def child_mesh_check(config_path: str, n_devices: int) -> dict:
         raise SystemExit(f"mesh: audio differs from one device by "
                          f"{worst} int16 steps")
     return {"device": facts, "shape": [b, t, f],
-            "output_devices": devices, "has_mosaic_call": has_mosaic,
+            "output_devices": devices,
             "max_abs_diff_int16_vs_one_device": worst}
 
 
@@ -327,8 +231,6 @@ def run_child_phase(argv: list) -> int:
     phase, rest = argv[0], argv[1:]
     if phase == "write-voice":
         result = child_write_voice(rest[0], tiny=rest[1] == "tiny")
-    elif phase == "kernels":
-        result = child_kernels(rest[0], interpret=rest[1] == "interpret")
     elif phase == "mesh-check":
         result = child_mesh_check(rest[0], int(rest[1]))
     else:
@@ -757,8 +659,8 @@ def check_replicas(before: dict, after: dict, n: int) -> dict:
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--rehearse", action="store_true",
-                    help="tiny voice, JAX_PLATFORMS=cpu, Pallas in "
-                         "interpret mode: debug the command off the chip")
+                    help="tiny voice, JAX_PLATFORMS=cpu: debug the "
+                         "command off the chip")
     ap.add_argument("--lattice", choices=("full", "minimal", "off"),
                     default="minimal",
                     help="SONATA_WARMUP_LATTICE for the server.  The "
@@ -787,8 +689,7 @@ def main() -> int:
         extra_env["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={n_chips}")
     say(f"chip_smoke: platform={platform}"
-        + (" (REHEARSAL: tiny voice, Pallas interpret mode)"
-           if args.rehearse
+        + (" (REHEARSAL: tiny voice)" if args.rehearse
            else f" (lessac-high widths, depth cut: {DEPTH_CUT})")
         + f", lattice={args.lattice}, warmup budget={WARMUP_BUDGET_S:.0f} s")
 
@@ -831,22 +732,6 @@ def main() -> int:
         summary["voice"] = voice
         cfg = voice["config_path"]
 
-        kernels = spawn_phase(
-            "kernels", [cfg, "interpret" if args.rehearse else "compiled"],
-            child_env(platform, **extra_env), 600.0)
-        device = kernels["device"]
-        say(f"kernels: device {device['platform']} / {device['kind']} x "
-            f"{device['count']} (jax {device['jax']}); gate max abs err "
-            f"{max(r['max_abs_err'] for r in kernels['gate']):.3g} "
-            f"(atol {GATE_ATOL:g}); flow has Mosaic call: "
-            f"{kernels['flow_has_mosaic_call']}, max abs err "
-            f"{kernels['flow_max_abs_err']:.3g} vs |ref| "
-            f"{kernels['flow_ref_max_abs']:.3g}")
-        check(device["platform"] == platform,
-              f"kernel phase ran on {device['platform']}")
-        check(device["count"] >= n_chips,
-              f"{n_chips} chips asked for, {device['count']} present")
-        summary["kernels"] = kernels
         if args.mesh_devices:
             summary["mesh_check"] = spawn_phase(
                 "mesh-check", [cfg, str(args.mesh_devices)],
@@ -873,8 +758,11 @@ def main() -> int:
         say(f"server device: {boot['device']}; compile cache "
             f"{boot['cache_dir']}: {entries_start} → {entries_boot1} "
             f"entries")
-        check(boot["device"]["platform"] == platform,
-              f"server runs on {boot['device']['platform']}")
+        device = boot["device"]
+        check(device["platform"] == platform,
+              f"server runs on {device['platform']}")
+        check(device["count"] >= n_chips,
+              f"{n_chips} chips asked for, {device['count']} present")
         check(boot["lattice_mode"] == args.lattice,
               f"server warmed lattice mode {boot['lattice_mode']}")
         check(Path(boot["cache_dir"]) == cache_dir,

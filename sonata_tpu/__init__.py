@@ -1,8 +1,8 @@
 """sonata-tpu: a TPU-native neural text-to-speech serving framework.
 
 Capability-parity rebuild of mush42/sonata (see SURVEY.md) designed
-TPU-first: the VITS compute path is JAX/XLA (jit/pjit over a device mesh,
-Pallas for hot fused ops), the runtime around it is Python + C++ (phonemizer
+TPU-first: the VITS compute path is JAX/XLA (jit/pjit over a device
+mesh), the runtime around it is Python + C++ (phonemizer
 shim, prosody DSP, C ABI), and the frontends (CLI, gRPC, Python, C) mirror
 the reference's surface.
 """

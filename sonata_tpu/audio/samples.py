@@ -13,8 +13,7 @@ Behavioral parity notes (reference ``samples.rs`` line refs):
 - ``crossfade``: both-end taper applied per streaming chunk (``:144-157``).
 - ``lowpass_filter``/``highpass_filter``: *amplitude-threshold* filters, not
   spectral ones — the reference's are naive amplitude gates (``:158-171``)
-  and the streaming pipeline depends on that behavior, so we keep it (a real
-  spectral filter lives in :mod:`sonata_tpu.ops.signal`).
+  and the streaming pipeline depends on that behavior, so we keep it.
 - ``real_time_factor`` = inference_ms / audio duration_ms (``:253-260``).
 """
 

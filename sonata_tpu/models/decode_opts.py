@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import OperationError
+from . import vits
 
 # ---------------------------------------------------------------------------
 # knob resolution (single-module defaults)
@@ -107,7 +108,7 @@ def _taper_gains(idx, lo, hi, fade: int):
 
 
 def _quantize_rows(tapered):
-    """Peak-scaled i16, the ``_decode_quantize`` contract: per-row peak
+    """Peak-scaled i16, the :func:`decode_quantize` contract: per-row peak
     ships back so the host restores original amplitudes exactly (modulo
     the i16 grid), with the same 0.01 silence floor."""
     peak = jnp.max(jnp.abs(tapered), axis=-1)
@@ -133,6 +134,32 @@ def dequantize_chunk(q, peak):
     pre-quantization float32 amplitudes (the exact ``_finish_batch``
     dequantization contract, same 0.01 floor)."""
     return np.asarray(q, np.float32) * (max(float(peak), 0.01) / 32767.0)
+
+
+def decode_quantize(params, hp, z, y_lengths, g, mesh=None,
+                    compute_dtype=None):
+    """HiFi-GAN decode + on-device peak-scaled i16 quantization.
+
+    i16 quarters the host transfer, which dominates when the chip sits
+    behind a network link.  The per-row peak ships back too so the host
+    restores original amplitudes: relative loudness across sentences is
+    preserved, and the final WAV write still applies the reference's
+    single global normalization (samples.rs:51-75).
+
+    The single definition of the quantization contract: every program of
+    any voice family that decodes whole rows goes through here.
+    """
+    wav = vits.decode(params, hp, z, g=g, mesh=mesh,
+                      compute_dtype=compute_dtype)
+    with jax.named_scope("epilogue"):
+        wav_lengths = y_lengths * hp.hop_length
+        valid = (jnp.arange(wav.shape[1])[None, :]
+                 < wav_lengths[:, None])
+        peak = jnp.max(jnp.abs(wav) * valid, axis=1, keepdims=True)
+        scale = 32767.0 / jnp.maximum(peak, 0.01)
+        wav_i16 = jnp.clip(wav * scale, -32768.0,
+                           32767.0).astype(jnp.int16)
+    return wav_i16, wav_lengths, peak[:, 0]
 
 
 # ---------------------------------------------------------------------------

@@ -37,8 +37,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.gate import fused_gate as gate_op
-
 Params = dict
 
 LRELU_SLOPE = 0.1
@@ -459,16 +457,21 @@ def init_wn(rng, *, hidden, kernel, dilation_rate, n_layers, gin_channels=0):
     return p
 
 
-def wn(x, mask, p, *, kernel, dilation_rate, n_layers, g=None, conv=None,
-       mesh=None):
+def gate(y):
+    """The gated activation ``tanh(a) * sigmoid(b)`` over the two halves
+    of a WaveNet pre-activation ``y: [B, T, 2H]`` (conditioning added):
+    elementwise, so XLA fuses it into its neighbours."""
+    hidden = y.shape[-1] // 2
+    return jnp.tanh(y[..., :hidden]) * jax.nn.sigmoid(y[..., hidden:])
+
+
+def wn(x, mask, p, *, kernel, dilation_rate, n_layers, g=None, conv=None):
     """Non-causal WaveNet: dilated convs, gated tanh units, residual+skip.
 
     ``x: [B, T, H]``; ``g: [B, 1, gin]`` speaker conditioning or None.
-    The gate runs through :func:`sonata_tpu.ops.gate.fused_gate` — a Pallas
-    kernel on TPU, plain jnp elsewhere.  ``conv`` overrides the dilated
-    conv primitive (sequence-sharded callers inject a halo-exchange
-    version); pointwise convs never need halos and stay plain.  ``mesh``
-    rides through to the gate (data-sharded jit callers only).
+    ``conv`` overrides the dilated conv primitive (sequence-sharded
+    callers inject a halo-exchange version); pointwise convs never need
+    halos and stay plain.
     """
     conv = conv or conv1d
     hidden = x.shape[-1]
@@ -480,7 +483,7 @@ def wn(x, mask, p, *, kernel, dilation_rate, n_layers, g=None, conv=None,
         g_l = None
         if g is not None and "cond" in p:
             g_l = lax.dynamic_slice_in_dim(g_all, i * 2 * hidden, 2 * hidden, axis=2)
-        acts = gate_op(x_in, g_l, mesh=mesh)
+        acts = gate(x_in if g_l is None else x_in + g_l)
         rs = conv1d(acts, p["res_skip"][i])
         if i < n_layers - 1:
             x = (x + rs[..., :hidden]) * mask

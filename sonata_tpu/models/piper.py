@@ -42,16 +42,7 @@ from ..core import (
     Phonemes,
 )
 from ..serving import tracing
-from ..synth.batching import (
-    BatchingCore,
-    IterationLoop,
-    WorkItem,
-    drain_pending_futures,
-    effective_batch_mode,
-    resolve_batch_mode,
-    try_set_exception,
-    try_set_result,
-)
+from ..synth.batching import effective_batch_mode, resolve_batch_mode
 from ..text import text_to_phonemes
 from ..text.tashkeel import TashkeelEngine, get_default_engine
 from ..utils.buckets import (
@@ -62,11 +53,12 @@ from ..utils.buckets import (
     pad_to,
 )
 from ..utils.dispatch_policy import (
+    COALESCING_DEFAULTS,
     DispatchPolicy,
     resolve_policy,
-    should_donate,
 )
-from . import decode_opts, vits
+from ..utils.transfer import prefetch_to_host
+from . import decode_opts, shape_plan, vits
 from .chunker import CROSSFADE_SAMPLES, plan_chunks
 from .config import ModelConfig, SynthesisConfig, default_phoneme_id_map
 from .serialization import load_params
@@ -149,13 +141,14 @@ class PiperVoice(BaseModel):
         self._full_cache: dict = {}
         self._aco_cache: dict = {}
         self._dec_cache: dict = {}
-        self._stream_coalescer: "Optional[_StreamDecodeCoalescer]" = None
-        self._stage_coalescer: "Optional[_StreamStageCoalescer]" = None
+        # the stream engines (synth/stream_engines.py), built on first use
+        self._stream_coalescer = None
+        self._stage_coalescer = None
         #: iteration-mode engine (SONATA_BATCH_MODE=iteration): the
         #: persistent per-device decode loop; coexists with the
         #: dispatch-mode coalescer so the degradation ladder can force
         #: new streams back to dispatch mode while resident ones finish
-        self._iter_decoder: "Optional[_IterationStreamDecoder]" = None
+        self._iter_decoder = None
         #: voice id the serving runtime registered this model under —
         #: stamps the iteration loop's per-iteration scope attribution
         #: (the scheduler path carries it via trace_attrs instead)
@@ -175,14 +168,9 @@ class PiperVoice(BaseModel):
         self.drop_stats = {"symbols_total": 0, "symbols_dropped": 0,
                            "dropped": {}}
         self._warned_drops: set = set()
-        # adaptive frame-budget estimator for the single-dispatch path:
-        # running upper bound of frames per input id per unit length_scale.
-        # Start optimistic — an underestimate costs one overflow retry on
-        # the first batch, while an overestimate inflates every transfer
-        # (the wav buffer scales with the frame bucket).
-        self._frames_per_id = 2.5
-        self._fpi_observed = False  # first real observation landed?
-        self._fpi_lock = threading.Lock()
+        #: the frame budget of the single-dispatch path, and of the
+        #: stream stages (shape_plan owns the arithmetic)
+        self.frame_estimator = shape_plan.FrameEstimator()
         self._rng_lock = threading.Lock()
         self._rng_counter = 0
         self._seed = seed
@@ -484,10 +472,9 @@ class PiperVoice(BaseModel):
         # mid-request (the exact stall prewarm exists to prevent).
         # Iteration mode pads to the graduated ladder instead of the
         # canonical pair, so every rung up to max_batch warms.
-        if isinstance(co, _IterationStreamDecoder):
-            batch_set = {b for b in BATCH_BUCKETS if b <= co._max_batch}
-        else:
-            batch_set = {1, co._max_batch}
+        batch_set = shape_plan.window_decoder_batches(
+            "iteration" if co is self._iter_decoder else "dispatch",
+            co._max_batch)
         # each variant (fused vs plain) warms wherever it was seen — a
         # fused-default voice drains streams through wfused shapes while
         # direct decode() callers may still touch wbatch ones
@@ -526,11 +513,7 @@ class PiperVoice(BaseModel):
         # path, or the first post-warm stream lands one bucket over cold
         aco_targets = set(aco_seen)
         for fa in aco_seen:
-            if fa in FRAME_BUCKETS:
-                i = FRAME_BUCKETS.index(fa)
-                aco_targets.add(FRAME_BUCKETS[max(i - 1, 0)])
-                aco_targets.add(FRAME_BUCKETS[min(i + 1,
-                                                  len(FRAME_BUCKETS) - 1)])
+            aco_targets |= shape_plan.neighbor_frame_buckets(fa)
         for (eb, t) in enc_seen:
             # warm both the shape already seen (b=1 drains) and the
             # canonical coalesced-batch shape
@@ -577,135 +560,44 @@ class PiperVoice(BaseModel):
         """Compile the frame buckets adjacent to every cached
         full-pipeline shape (one blocking :meth:`warm_shape` each — the
         single place the dummy-argument signature lives)."""
-        from ..utils.buckets import FRAME_BUCKETS as _FB
-
         for (b, t, f) in list(self._full_cache):
-            if f not in _FB:
-                continue  # beyond-table bucket: no neighbor schedule
-            i = _FB.index(f)
-            for nf in {_FB[max(i - 1, 0)],
-                       _FB[min(i + 1, len(_FB) - 1)]} - {f}:
+            for nf in shape_plan.neighbor_frame_buckets(f):
                 self.warm_shape((b, t, nf))
 
     # ------------------------------------------------------------------
     # bucket-lattice AOT warmup (serving/warmup.py drives this contract)
     # ------------------------------------------------------------------
 
-    def lattice_shapes(self, mode: str = "full") -> list[tuple[int, int, int]]:
-        """Enumerate the (batch, text, frame) shapes a restart must warm.
-
-        The serving path compiles one executable per (b, t, f) bucket
-        triple (:meth:`_full_fn`); this enumerates the triples real
-        traffic can hit so the boot warmup compiles them *before*
-        readiness instead of the first unlucky request paying the
-        compile cliff (PR-4 measured cold 4556 ms vs cached 30 ms):
-
-        - text axis: every :data:`TEXT_BUCKETS` entry (any sentence
-          lands in one of them);
-        - frame axis: the RANGE of buckets the live frame estimator
-          can pick across the text bucket's id-length span (a sentence
-          in bucket 128 may hold anywhere from 97 to 128 ids, and the
-          frame estimate is linear in that length) — callers should
-          run one *real* calibration utterance first so the estimator
-          enumerates with an observed frames-per-id, not the
-          cold-start prior — plus the next bucket UP in every mode
-          (the estimator is a decaying upper bound that jumps up
-          *immediately* on a higher observation, so the first
-          post-warm sentence with a long duration draw lands there),
-          plus the bucket below the range in ``full`` mode (slow
-          downward decay under sustained traffic);
-        - batch axis: 1 (sequential / per-request dispatch), plus, in
-          ``full`` mode, the canonical coalesced batch the scheduler
-          pads multi-request groups to (if coalescing is enabled —
-          a CPU policy with max_batch 1 adds nothing).
-
-        ``minimal`` is the batch-1, estimated-bucket-only subset —
-        strictly contained in ``full``.  ``off`` returns [] (the
-        caller keeps the legacy one-utterance warmup).  Ordered
-        smallest-first so a budget expiry leaves the most common
-        shapes warm.
-        """
+    def lattice_shapes(self, mode: str = "full") -> list[tuple]:
+        """The shapes a restart must warm, smallest first: the
+        ``(batch, text, frame)`` triples real traffic can hit and, in
+        iteration mode, the ``("wdec", width, batch, has_sid)`` window
+        decoders (:func:`.shape_plan.lattice_shapes` has the rule).
+        :meth:`warm_shape` takes either kind."""
         if mode == "off":
-            return []
-        batches = {1}
-        if mode == "full":
-            try:
-                kw = self.dispatch_policy.scheduler_kwargs()
-                from ..utils.buckets import canonical_dispatch_batch
+            return []  # and no policy probe either
+        return shape_plan.lattice_shapes(
+            mode, self.frame_estimator,
+            float(self.get_fallback_synthesis_config().length_scale),
+            multi_speaker=self.multi_speaker, **self._lattice_policy())
 
-                canonical = canonical_dispatch_batch(kw["max_batch"])
-            except Exception:  # policy probe failure must not block boot
-                canonical = 1
-            if canonical > 1:
-                batches.add(canonical)
-        ls = float(self.get_fallback_synthesis_config().length_scale)
-        shapes: list[tuple[int, int, int]] = []
-        n_fb = len(FRAME_BUCKETS)
-        for ti, t in enumerate(TEXT_BUCKETS):
-            # shortest and longest id counts that pad to this bucket
-            lo_ids = TEXT_BUCKETS[ti - 1] + 1 if ti > 0 else 1
-            f_lo = self._estimate_frame_bucket(lo_ids * max(ls, 0.05))
-            f_hi = self._estimate_frame_bucket(t * max(ls, 0.05))
-            frames = {f_lo, f_hi}
-            if f_lo in FRAME_BUCKETS:
-                i_lo = FRAME_BUCKETS.index(f_lo)
-                # an f_hi past the table (bucket_for returns top-bucket
-                # multiples there) still needs the reachable IN-TABLE
-                # run warmed — clamping to the top keeps the range
-                # covered instead of silently skipping it
-                i_hi = (FRAME_BUCKETS.index(f_hi)
-                        if f_hi in FRAME_BUCKETS else n_fb - 1)
-                # the whole reachable range, plus one bucket up (the
-                # estimator jumps up immediately on a higher
-                # observation); full also covers one below (slow decay)
-                if mode == "full":
-                    i_lo = max(i_lo - 1, 0)
-                frames.update(
-                    FRAME_BUCKETS[i]
-                    for i in range(i_lo, min(i_hi + 2, n_fb)))
-            for b in sorted(batches):
-                for f in sorted(frames):
-                    shapes.append((b, t, f))
-        shapes.sort(key=lambda s: (s[1], s[0], s[2]))
-        shapes.extend(self._iteration_lattice_shapes(mode))
-        return shapes
-
-    def _iteration_lattice_shapes(self, mode: str) -> list:
-        """Iteration-mode window-decoder shapes, appended to the lattice
-        when ``SONATA_BATCH_MODE`` resolves to iteration.
-
-        The persistent decode loop pads each iteration to the *graduated*
-        batch ladder (1, 2, 4, ..., max) instead of dispatch mode's
-        canonical {1, max} — that is where its padding-waste win comes
-        from — so every rung x reachable window width must be warm or the
-        first mid-occupancy iteration pays a cold compile the PR-9
-        containment would rightly flag.  Tagged ``("wdec", width, batch,
-        has_sid)`` tuples; :meth:`warm_shape` understands them.
-        ``minimal`` keeps batch 1 only (single-resident-stream serving);
-        iteration-mode deployments should warm ``full``.
-        """
+    def _lattice_policy(self) -> dict:
+        """What the lattice reads of the dispatch policy.  Neither a
+        probe failure nor a typo'd ``SONATA_BATCH_MODE`` (which fails
+        loudly at stream time) may block boot: the lattice is then the
+        batch-1 triples, or the triples alone."""
         try:
             policy = self.dispatch_policy
-            if resolve_batch_mode(policy) != "iteration":
-                return []
-            kwargs = policy.stream_decode_kwargs()
-        except Exception:  # policy probe failure must not block boot
-            return []
-        max_b = kwargs["max_batch"]
-        if max_b <= 1:
-            from ..utils.dispatch_policy import COALESCING_DEFAULTS
-
-            max_b = COALESCING_DEFAULTS["stream_decode_max_batch"]
-        ladder = [b for b in BATCH_BUCKETS if b <= max_b]
-        if mode == "minimal":
-            ladder = [1]
-        # reachable widths: chunk windows bucket through FRAME_BUCKETS
-        # and the chunk-growth schedule caps at 1024 frames plus padding,
-        # so 1536 is the largest bucket a plan can produce
-        widths = [w for w in FRAME_BUCKETS if w <= 1536]
-        has_sid = bool(self.multi_speaker)
-        return [("wdec", w, b, has_sid)
-                for w in widths for b in ladder]
+        except Exception:
+            return {"scheduler_max_batch": 1, "stream_decode_max_batch": 1,
+                    "batch_mode": None}
+        try:
+            batch_mode = resolve_batch_mode(policy)
+        except OperationError:
+            batch_mode = None
+        return {"scheduler_max_batch": policy.scheduler_max_batch,
+                "stream_decode_max_batch": policy.stream_decode_max_batch,
+                "batch_mode": batch_mode}
 
     def warm_shape(self, shape: tuple[int, int, int]) -> None:
         """Make one (b, t, f) full-pipeline shape hot before traffic.
@@ -725,11 +617,11 @@ class PiperVoice(BaseModel):
         persistent compile cache) when AOT is disabled, a mesh is
         attached, or anything in the AOT path fails.  Bypasses
         :meth:`_infer_batch` on purpose: dummy zeros must never feed
-        :meth:`_observe_frames`, or warmup would corrupt the frame
-        estimator the lattice was enumerated with.
+        the frame estimator, or warmup would corrupt what the lattice
+        was enumerated with.
 
         Iteration-mode shapes (``("wdec", width, batch, has_sid)`` from
-        :meth:`_iteration_lattice_shapes`) compile the batched window
+        :meth:`lattice_shapes`) compile the batched window
         decoder directly — a plain jit warm riding the persistent
         compile cache (no AOT store: the decoder program is small and
         retraces in well under a second).
@@ -890,7 +782,12 @@ class PiperVoice(BaseModel):
             raise OperationError(
                 f"scales list has {len(scales)} entries for {n} sentences")
 
-        chunks = self._plan_dispatch_groups(ids_list, sc, scales)
+        chunks = shape_plan.plan_dispatch_groups(
+            [len(ids) for ids in ids_list],
+            [sc.length_scale if scales is None or scales[i] is None
+             else scales[i].length_scale for i in range(n)],
+            min_batch=self.MIN_DISPATCH_BATCH,
+            max_batch=self.MAX_DISPATCH_BATCH)
 
         # Pipelined dispatch: enqueue up to PIPELINE_DEPTH device programs
         # ahead, then fetch in order.  The chip computes group k+1 while
@@ -939,7 +836,8 @@ class PiperVoice(BaseModel):
                 # otherwise clip every in-flight group and pay an overflow
                 # rerun for each, instead of the documented single
                 # first-batch retry
-                depth = self.PIPELINE_DEPTH if self._fpi_observed else 1
+                depth = (self.PIPELINE_DEPTH if self.frame_estimator.observed
+                         else 1)
                 while gi < len(chunks) and len(pending) < depth:
                     chunk = chunks[gi]
                     gi += 1
@@ -958,83 +856,6 @@ class PiperVoice(BaseModel):
                   inference_ms=row_ms[i])
             for i in range(n)
         ]
-
-    def _plan_dispatch_groups(self, ids_list: list[list[int]],
-                              sc: SynthesisConfig,
-                              scales=None) -> list[list[int]]:
-        """Partition sentence indices into device-dispatch groups.
-
-        Rows sort by estimated frame count, then split into contiguous
-        groups whose sizes are exact batch buckets (zero dummy rows — a
-        dummy row still copies a full frame-bucket window of samples back
-        to the host).  Group sizes cap at half the batch (min 8)
-        so at least two dispatches pipeline compute against result
-        transfer; sorted order keeps each group's frame bucket tight.
-        """
-        n = len(ids_list)
-
-        def est_frames(i) -> float:
-            # relative frame driver per row; the shared frames-per-id
-            # factor cancels in a sort, so it stays out of the key
-            ls = (scales[i].length_scale
-                  if scales is not None and i < len(scales)
-                  and scales[i] is not None else sc.length_scale)
-            return len(ids_list[i]) * max(float(ls), 0.05)
-
-        def split_by_text_bucket(group: list[int]) -> list[list[int]]:
-            """Split where a row's text bucket jumps past 2x the current
-            subgroup head's (re-based per subgroup — a 16→64→512 tier mix
-            splits twice): a frame-alike but text-length-wild mix (possible
-            with per-row length_scale overrides) would otherwise pad every
-            short row's text — and, worse, its frame-bucket transfer
-            window — to the outlier's size.  Same rule the pre-pipelining
-            packer applied; off-bucket subgroup sizes just pad a few dummy
-            rows."""
-            out: list[list[int]] = []
-            for i in group:
-                tb = bucket_for(len(ids_list[i]), TEXT_BUCKETS)
-                if not out or tb > 2 * bucket_for(
-                        len(ids_list[out[-1][0]]), TEXT_BUCKETS):
-                    out.append([i])
-                else:
-                    out[-1].append(i)
-            return out
-
-        order = sorted(range(n), key=est_frames)
-        if n < 2 * self.MIN_DISPATCH_BATCH:
-            return split_by_text_bucket(order)
-        # cap a group at half the batch (bucket-rounded down) so there are
-        # always ≥2 dispatches to pipeline; never below MIN or above MAX
-        half = max((n + 1) // 2, self.MIN_DISPATCH_BATCH)
-        cap = next(s for s in reversed(BATCH_BUCKETS) if s <= half)
-        cap = min(cap, self.MAX_DISPATCH_BATCH)
-        # decompose n into bucket sizes ≤ cap, smallest group first so the
-        # leftover (non-power-of-two) rows are the *short* ones
-        sizes: list[int] = []
-        rest = n
-        while rest:
-            take = min(cap, rest)
-            sizes.append(next((s for s in reversed(BATCH_BUCKETS)
-                               if s <= take), BATCH_BUCKETS[0]))
-            rest -= sizes[-1]
-        sizes.sort()
-        # a leftover smaller than MIN rides inside the next group as extra
-        # rows — but only while the merged group stays near its batch
-        # bucket: a few padding dummies cost less than a tiny dispatch's
-        # fixed cost, a few dozen cost more
-        while len(sizes) > 1 and sizes[0] < self.MIN_DISPATCH_BATCH:
-            merged = sizes[0] + sizes[1]
-            if (merged > self.MAX_DISPATCH_BATCH
-                    or bucket_for(merged, BATCH_BUCKETS) - merged
-                    > self.MIN_DISPATCH_BATCH):
-                break
-            small = sizes.pop(0)
-            sizes[0] += small
-        groups, pos = [], 0
-        for s in sizes:
-            groups.extend(split_by_text_bucket(order[pos:pos + s]))
-            pos += s
-        return groups
 
     # ------------------------------------------------------------------
     # staged inference
@@ -1148,32 +969,6 @@ class PiperVoice(BaseModel):
                 self._enc_cache[key] = fn
         return fn
 
-    @staticmethod
-    def _decode_quantize(params, hp, z, y_lengths, g, mesh=None,
-                         compute_dtype=None):
-        """HiFi-GAN decode + on-device peak-scaled i16 quantization.
-
-        i16 quarters the host transfer, which dominates when the chip sits
-        behind a network link.  The per-row peak ships back too so the host
-        restores original amplitudes — relative loudness across sentences is
-        preserved, and the final WAV write still applies the reference's
-        single global normalization (samples.rs:51-75).
-
-        The single definition of the quantization contract — every path that
-        decodes a full batch goes through here.
-        """
-        wav = vits.decode(params, hp, z, g=g, mesh=mesh,
-                          compute_dtype=compute_dtype)
-        with jax.named_scope("epilogue"):
-            wav_lengths = y_lengths * hp.hop_length
-            valid = (jnp.arange(wav.shape[1])[None, :]
-                     < wav_lengths[:, None])
-            peak = jnp.max(jnp.abs(wav) * valid, axis=1, keepdims=True)
-            scale = 32767.0 / jnp.maximum(peak, 0.01)
-            wav_i16 = jnp.clip(wav * scale, -32768.0,
-                               32767.0).astype(jnp.int16)
-        return wav_i16, wav_lengths, peak[:, 0]
-
     def _acoustics_fn(self, b: int, t: int, f: int):
         """Jitted stage 2 alone (streaming path: keep z on device)."""
         with self._jit_lock:
@@ -1243,7 +1038,7 @@ class PiperVoice(BaseModel):
                         params, hp, m_p, logs_p, w_ceil, x_mask, rng_noise,
                         noise_scale=noise_scale, max_frames=max_frames, g=g,
                         mesh=mesh)
-                    wav_i16, wav_lengths, peaks = self._decode_quantize(
+                    wav_i16, wav_lengths, peaks = decode_opts.decode_quantize(
                         params, hp, z, y_lengths, g, mesh=mesh,
                         compute_dtype=cdt)
                     return wav_i16, wav_lengths, peaks, frames_needed
@@ -1298,14 +1093,11 @@ class PiperVoice(BaseModel):
         prewarmable; the first round of concurrent traffic must never pay
         a mid-request XLA compile (a cold shape stalls every stream
         riding that dispatch for the length of the compile)."""
-        # the stacked [B, width, C] windows buffer is dead after the call,
-        # but XLA input/output aliasing needs an identically-sized output
-        # to reuse it and the [B, width*hop] waveform never matches — the
-        # annotation only produced per-compile "donated buffers were not
-        # usable" warnings (r05 streaming bench), so donation is now off
-        # unless SONATA_DONATE=1 forces it back on for A/B runs.
-        donate = should_donate()
-        key = ("wbatch", width, b, has_sid, donate)
+        # never donated: the stacked [B, width, C] windows buffer is dead
+        # after the call, but XLA input/output aliasing needs an
+        # identically-sized output to reuse it and the [B, width*hop]
+        # waveform never matches
+        key = self._wdec_cache_key(width, b, has_sid, fused=False)
         with self._jit_lock:
             fn = self._dec_cache.get(key)
             if fn is None:
@@ -1318,7 +1110,7 @@ class PiperVoice(BaseModel):
                     return vits.decode(params, hp, windows, g=g,
                                        compute_dtype=cdt)
 
-                fn = jax.jit(run, donate_argnums=(1,) if donate else ())
+                fn = jax.jit(run)
                 self._dec_cache[key] = fn
         return fn
 
@@ -1335,7 +1127,7 @@ class PiperVoice(BaseModel):
         host dequantizes and slices instead of tapering — the per-chunk
         epilogue leaves the TTFB path, and the result transfer halves
         (i16 + per-row peak instead of f32)."""
-        key = ("wfused", width, b, has_sid, self.fused_epilogue)
+        key = self._wdec_cache_key(width, b, has_sid, fused=True)
         with self._jit_lock:
             fn = self._dec_cache.get(key)
             if fn is None:
@@ -1365,14 +1157,14 @@ class PiperVoice(BaseModel):
             fused = self.fused_epilogue != "off"
         if fused:
             return ("wfused", width, b, has_sid, self.fused_epilogue)
-        return ("wbatch", width, b, has_sid, should_donate())
+        return ("wbatch", width, b, has_sid)
 
     @property
     def dispatch_policy(self) -> DispatchPolicy:
         """The resolved backend-adaptive dispatch policy (lazy, cached).
 
         Resolution order: an explicitly-passed policy > env overrides
-        (``SONATA_STREAM_COALESCE``, ``SONATA_DISPATCH_POLICY``) > the
+        (``SONATA_DISPATCH_POLICY``) > the
         backend fast path / cached dispatch-scaling probe — see
         :func:`sonata_tpu.utils.dispatch_policy.resolve_policy`.
         Resolved outside the jit lock: the probe may itself dispatch.
@@ -1441,27 +1233,27 @@ class PiperVoice(BaseModel):
                     # streams, so take the canonical coalescing batch
                     b = kwargs["max_batch"]
                     if b <= 1:
-                        from ..utils.dispatch_policy import (
-                            COALESCING_DEFAULTS)
-
                         b = COALESCING_DEFAULTS["stream_decode_max_batch"]
-                    self._iter_decoder = _IterationStreamDecoder(
+                    engines = _stream_engines()
+                    self._iter_decoder = engines._IterationStreamDecoder(
                         self, max_batch=b)
                 return self._iter_decoder
             if self._stream_coalescer is None:
-                self._stream_coalescer = _StreamDecodeCoalescer(
+                engines = _stream_engines()
+                self._stream_coalescer = engines._StreamDecodeCoalescer(
                     self, **kwargs)
             return self._stream_coalescer
 
     @property
-    def _stream_stages(self) -> "_StreamStageCoalescer":
+    def _stream_stages(self):
         kwargs = self.dispatch_policy.stream_stage_kwargs()
         with self._jit_lock:
             if self._voice_closed:
                 raise OperationError(
                     "voice is closed; streaming is unavailable")
             if self._stage_coalescer is None:
-                self._stage_coalescer = _StreamStageCoalescer(
+                engines = _stream_engines()
+                self._stage_coalescer = engines._StreamStageCoalescer(
                     self, **kwargs)
             return self._stage_coalescer
 
@@ -1536,43 +1328,6 @@ class PiperVoice(BaseModel):
         m_p, logs_p, w_ceil, x_mask = self._encode_fn(b, t)(*args)
         return m_p, logs_p, w_ceil, x_mask, sid, b, t
 
-    def _frame_budget(self, weighted_ids: float) -> tuple[int, float]:
-        """``(frames budgeted, the estimator's frames per id it used)``.
-
-        ``weighted_ids``: max over rows of ``len(ids) * length_scale`` —
-        the true per-row frame driver (a batch mixing a long 1x row with a
-        short 3x row must not be budgeted as long × 3x)."""
-        with self._fpi_lock:
-            fpi = self._frames_per_id
-        # fpi is itself a decaying UPPER bound over observed ratios, so the
-        # safety multiplier stays small: 1.25 stacked a second layer of
-        # headroom on top and pushed typical batches a whole frame bucket
-        # up — every row then ships a ~2x transfer window back to the
-        # host.  Underestimates are caught and cost one (rare) retry.
-        return max(int(weighted_ids * fpi * 1.08), 1), fpi
-
-    def _estimate_frame_bucket(self, weighted_ids: float) -> int:
-        """The frame bucket :meth:`_frame_budget` rounds up to."""
-        return bucket_for(self._frame_budget(weighted_ids)[0],
-                          FRAME_BUCKETS)
-
-    def _observe_frames(self, weighted_ids: float, frames: int) -> None:
-        ratio = frames / max(weighted_ids, 1.0)
-        with self._fpi_lock:
-            if not self._fpi_observed:
-                # first real observation replaces the cold-start prior —
-                # decaying down from a too-high prior at 0.5% per batch
-                # would overshoot the frame bucket (and its per-row
-                # transfer window) for hundreds of batches.  A 15% margin
-                # guards the pipelined groups dispatched right after this
-                # single sample: one low draw must not set a bound that
-                # makes every in-flight group overflow and rerun
-                self._frames_per_id = ratio * 1.15
-            else:
-                # decaying upper bound: shrinks slowly, jumps up immediately
-                self._frames_per_id = max(self._frames_per_id * 0.995, ratio)
-            self._fpi_observed = True
-
     def _infer_batch(self, ids_list: list[list[int]], sc: SynthesisConfig,
                      speakers: Optional[list[Optional[int]]] = None,
                      scales: "Optional[list[Optional[SynthesisConfig]]]"
@@ -1612,7 +1367,7 @@ class PiperVoice(BaseModel):
             args = [self.params, ids, lens, rng, nw, ls, ns]
             if sid is not None:
                 args.append(sid)
-            budget, fpi = self._frame_budget(weighted_ids)
+            budget, fpi = self.frame_estimator.budget(weighted_ids)
             f = bucket_for(budget, FRAME_BUCKETS)
             with self._jit_lock:
                 cached = (b, t, f) in self._full_cache
@@ -1640,7 +1395,7 @@ class PiperVoice(BaseModel):
                 **({"scaled": True} if scaled else {}))
             t_launch = time.perf_counter()
             out = self._full_fn(b, t, f)(*args)  # async dispatch
-            self._prefetch_to_host(out)
+            prefetch_to_host(out)
         t_enqueue = time.perf_counter()
         # what the headline does not aggregate stays on the group: the
         # budget the bucket was chosen from, the estimate behind it, and
@@ -1663,17 +1418,6 @@ class PiperVoice(BaseModel):
                 "n_real": n_real, "weighted_ids": weighted_ids,
                 "t_enqueue": t_enqueue, "group": group}
 
-    @staticmethod
-    def _prefetch_to_host(out) -> None:
-        """Start the device→host copy of a dispatch's outputs immediately.
-
-        The copy engine runs the D2H transfer as soon as the program
-        finishes, overlapping it with whatever computes next; the later
-        ``device_get`` then finds the host copy already materialized.
-        """
-        for a in (out if isinstance(out, (tuple, list)) else (out,)):
-            a.copy_to_host_async()
-
     def _finish_batch(self, ticket: dict):
         """Fetch a ticket's result; on frame-budget overflow re-dispatch
         once with a bucket that is known to fit (same RNG key → identical
@@ -1687,7 +1431,7 @@ class PiperVoice(BaseModel):
             n_real = ticket["n_real"]
             needed = frames_needed[:n_real]
             actual = int(needed.max())
-            self._observe_frames(ticket["weighted_ids"], actual)
+            self.frame_estimator.observe(ticket["weighted_ids"], actual)
             if actual > ticket["f"]:  # overflow: audio was clipped; rerun
                 f = bucket_for(actual, FRAME_BUCKETS)
                 group.update(overflow=True, retry_bucket=f)
@@ -1810,410 +1554,9 @@ class PiperVoice(BaseModel):
                 decoder.retire(handle)
 
 
-# the generic queue-drain helper moved into the batching core with the
-# rest of the gather/dispatch machinery; re-exported here because the
-# coalescer drain contract is pinned against this module
-_drain_pending_futures = drain_pending_futures
+def _stream_engines():
+    """The stream engines' module, imported where an engine is first
+    built (they import the batching core; the voice does not)."""
+    from ..synth import stream_engines
 
-
-def _assemble_window_dispatch(v: "PiperVoice", key, payloads: list,
-                              b: int):
-    """Build one window-decode group's (fn, args) padded to ``b`` rows —
-    the ONE place the (window, sid[, lo, hi]) payload layout is
-    consumed, shared by both engines so the fused contract cannot
-    desynchronize between them."""
-    width, has_sid, fused = key
-    pad = b - len(payloads)
-    windows = jnp.stack([p[0] for p in payloads]
-                        + [payloads[0][0]] * pad)
-    args = [v.params, windows]
-    if fused:
-        args += [jnp.asarray([p[2] for p in payloads]
-                             + [payloads[0][2]] * pad, jnp.int32),
-                 jnp.asarray([p[3] for p in payloads]
-                             + [payloads[0][3]] * pad, jnp.int32)]
-    if has_sid:
-        args.append(jnp.asarray(
-            [p[1] for p in payloads] + [payloads[0][1]] * pad,
-            dtype=jnp.int32))
-    fn = (v._decode_windows_fused_fn(width, b, has_sid) if fused
-          else v._decode_windows_batch_fn(width, b, has_sid))
-    return fn, args
-
-
-def _fetch_window_results(out, n: int, fused: bool) -> list:
-    """The finisher-side twin: blocking fetch + per-row unpack.  Fused
-    results are (i16 row, peak) pairs; plain results f32 rows."""
-    if fused:
-        q, peaks = jax.device_get(out)
-        q, peaks = np.asarray(q), np.asarray(peaks)
-        return [(q[i], float(peaks[i])) for i in range(n)]
-    return list(np.asarray(jax.device_get(out))[:n])
-
-
-class _StreamDecodeCoalescer:
-    """Shared dispatcher for streaming window decodes (dispatch mode).
-
-    The reference serves each realtime stream from its own blocking thread
-    (``grpc/src/main.rs:381-409``), so N concurrent streams contend for
-    the device with N independent decode calls per chunk wave.  Here every
-    stream's window decode funnels through one queue; the batching core
-    groups requests of equal window width (and same z frame-bucket shape)
-    that arrive within ``max_wait_ms`` and this class issues ONE batched
-    decode — under concurrent load the chunk cost approaches one dispatch
-    per wave instead of one per stream, while a lone stream pays only the
-    tiny wait window.
-
-    Since the batching-core unification the queue/gather/drain machinery
-    lives in :class:`~sonata_tpu.synth.batching.BatchingCore` (two-phase:
-    the dispatcher thread enqueues device programs back-to-back while the
-    finisher blocks on each async-prefetched result copy — a single
-    thread doing both serialized every wave behind the previous wave's
-    result fetch); this class keeps only the decode policy.
-    """
-
-    def __init__(self, voice: "PiperVoice", *, max_batch: int = 8,
-                 max_wait_ms: float = 2.0):
-        import weakref
-
-        # weak back-reference: the voice owns the coalescer; a strong ref
-        # here would pin the voice (and its params) to this thread's frame
-        # for process lifetime
-        self._voice_ref = weakref.ref(voice)
-        self._max_batch = max_batch
-        self._max_wait = max_wait_ms / 1000.0
-        self._reason = "stream-decode coalescer closed (voice unloaded)"
-        self._core = BatchingCore(
-            dispatch=self._dispatch, finish=self._finish,
-            max_batch=max_batch, max_wait_s=self._max_wait,
-            name="sonata_stream_decoder", keyed=True,
-            alive=lambda: self._voice_ref() is not None,
-            closed_reason=self._reason, poll_s=5.0)
-        self.stats = self._core.stats
-
-    # thread handles pinned by the close/teardown tests
-    @property
-    def _worker(self):
-        return self._core._worker
-
-    @property
-    def _finisher(self):
-        return self._core._finisher
-
-    def close(self) -> None:
-        """Stop both threads and fail any work still queued.
-
-        The core joins the worker before draining so nothing is added to
-        a queue after its drain; requests already dispatched to the
-        device resolve normally via the finisher before it exits."""
-        self._core.shutdown(join_timeout_s=10.0)
-
-    def submit(self, z_row, start: int, width: int, sid: "Optional[int]",
-               stream=None, epilogue=None):
-        """Enqueue a window decode; returns a Future of the [width*hop]
-        waveform — or, with ``epilogue=(lo, hi)`` (the fused-epilogue
-        arm), of an ``(i16 samples, peak)`` pair already tapered on
-        device.  ``z_row``: [F, C] device array.  ``stream`` is the
-        iteration-mode join handle — ignored here (dispatch mode has no
-        resident-stream state).
-
-        The window is sliced out of ``z_row`` here, eagerly (a tiny
-        on-device op), so everything behind the queue handles fixed
-        [width, C] windows regardless of the utterance's frame bucket —
-        see :meth:`PiperVoice._decode_windows_batch_fn`.  Fused and
-        plain submissions carry distinct keys (different executables
-        AND result types), so they never share a dispatch group."""
-        window = jax.lax.dynamic_slice_in_dim(
-            z_row, jnp.int32(start), width, axis=0)
-        fused = epilogue is not None
-        payload = ((window, sid, epilogue[0], epilogue[1]) if fused
-                   else (window, sid))
-        item = WorkItem(payload, key=(width, sid is not None, fused))
-        if self._core.closed:
-            try_set_exception(item.future, OperationError(self._reason))
-            return item.future
-        self._core.put(item)
-        return item.future
-
-    def decode(self, z_row, start: int, width: int,
-               sid: "Optional[int]") -> np.ndarray:
-        """Blocking variant of :meth:`submit`."""
-        return self.submit(z_row, start, width, sid).result()
-
-    def _dispatch(self, group: list):
-        v = self._voice_ref()
-        if v is None:
-            raise OperationError("voice was garbage-collected")
-        n = len(group)
-        # any multi-window group pads to ONE canonical batch size: the
-        # executable set is then exactly {b=1, b=max} — both prewarmed
-        # — so concurrency can never hit a cold compile mid-request.
-        # The padding rows' decode compute is cheap next to the
-        # XLA-compile stall a graduated bucket ladder risks per rung.
-        # (Iteration mode walks the graduated ladder instead — and warms
-        # every rung through the lattice; see _IterationStreamDecoder.)
-        b = self._max_batch if n > 1 else 1
-        fused = group[0].key[2]
-        fn, args = _assemble_window_dispatch(
-            v, group[0].key, [item.payload for item in group], b)
-        out = fn(*args)  # async dispatch
-        PiperVoice._prefetch_to_host(out)
-        self._core.bump("requests", n)
-        self._core.bump("dispatches")
-        # padding accounting, same keys as the iteration loop's stats —
-        # the bench's iteration-vs-dispatch A/B compares these directly
-        self._core.bump("rows", n)
-        self._core.bump("padded_rows", b - n)
-        return (out, fused)
-
-    def _finish(self, group: list, ticket) -> None:
-        out, fused = ticket
-        results = _fetch_window_results(out, len(group), fused)
-        for item, res in zip(group, results):
-            try_set_result(item.future, res)
-
-
-class _IterationStreamDecoder:
-    """Iteration-mode window decoder (``SONATA_BATCH_MODE=iteration``).
-
-    Same ``submit`` surface as :class:`_StreamDecodeCoalescer`, but the
-    engine underneath is the persistent
-    :class:`~sonata_tpu.synth.batching.IterationLoop`: a stream *joins*
-    the device's running batch once its encode lands, each of its window
-    decodes rides an iteration alongside every other resident stream's
-    rows, and the stream *retires* at an iteration boundary when it ends.
-    No wave-gather wait window, and the batch axis steps the graduated
-    bucket ladder (1, 2, 4, 8) — lattice-warmed, so occupancy-sized
-    dispatches stay recompile-free where dispatch mode overpads every
-    multi-stream wave to the canonical max.
-    """
-
-    def __init__(self, voice: "PiperVoice", *, max_batch: int = 8):
-        import weakref
-
-        self._voice_ref = weakref.ref(voice)
-        self._max_batch = max_batch
-        self._max_wait = 0.0  # no gather window: joins happen at
-        # iteration boundaries, not inside a wait loop
-        attrs = {}
-        device = getattr(voice, "device", None)
-        if device is not None:
-            attrs["device"] = str(device)
-        # two-phase: _dispatch enqueues the device program (async D2H
-        # prefetch started), _finish blocks on the result — with
-        # SONATA_ITER_PIPELINE (default on) the loop's finisher thread
-        # fetches iteration k while the worker dispatches k+1
-        self._loop = IterationLoop(self._dispatch, max_batch=max_batch,
-                                   name="sonata_iter_decode", attrs=attrs,
-                                   finish=self._finish)
-        self.stats = self._loop.stats
-
-    # -- stream lifecycle (stream_synthesis drives this) -----------------
-    def join(self, deadline=None):
-        return self._loop.join(deadline)
-
-    def retire(self, handle) -> None:
-        self._loop.retire(handle)
-
-    def start_draining(self) -> None:
-        self._loop.start_draining()
-
-    @property
-    def resident_streams(self) -> int:
-        return self._loop.resident_streams
-
-    def submit(self, z_row, start: int, width: int, sid: "Optional[int]",
-               stream=None, epilogue=None):
-        """Same eager-slice contract as the dispatch-mode coalescer
-        (incl. the fused-epilogue ``epilogue=(lo, hi)`` arm).  Without a
-        ``stream`` handle (direct callers, tools) the row rides as a
-        one-iteration stream that retires when its future resolves."""
-        window = jax.lax.dynamic_slice_in_dim(
-            z_row, jnp.int32(start), width, axis=0)
-        fused = epilogue is not None
-        payload = ((window, sid, epilogue[0], epilogue[1]) if fused
-                   else (window, sid))
-        key = (width, sid is not None, fused)
-        if stream is not None:
-            return self._loop.submit(stream, key, payload)
-        try:
-            handle = self._loop.join()
-        except OperationError as e:
-            # closed/draining: fail the future instead of raising — the
-            # same fail-fast contract as the dispatch-mode coalescer
-            from concurrent.futures import Future
-
-            fut: Future = Future()
-            fut.set_exception(e)
-            return fut
-        fut = self._loop.submit(handle, key, payload)
-        fut.add_done_callback(lambda _f: self._loop.retire(handle))
-        return fut
-
-    def decode(self, z_row, start: int, width: int,
-               sid: "Optional[int]") -> np.ndarray:
-        """Blocking variant of :meth:`submit`."""
-        return self.submit(z_row, start, width, sid).result()
-
-    def close(self) -> None:
-        self._loop.close()
-
-    # -- one iteration's device call (two-phase) ---------------------------
-    def _dispatch(self, key, payloads, b: int):
-        """DISPATCH phase: enqueue the iteration's device program and
-        start the async D2H copy, without blocking on the result — the
-        loop's finisher (``_finish``) fetches while the next iteration
-        dispatches (``SONATA_ITER_PIPELINE``)."""
-        v = self._voice_ref()
-        if v is None:
-            raise OperationError("voice was garbage-collected")
-        width, has_sid, fused = key
-        n = len(payloads)
-        cache_key = v._wdec_cache_key(width, b, has_sid, fused)
-        with v._jit_lock:
-            cached = cache_key in v._dec_cache
-        fn, args = _assemble_window_dispatch(v, key, payloads, b)
-        out = fn(*args)  # async dispatch
-        PiperVoice._prefetch_to_host(out)
-        attrs = {"frame_bucket": width, "text_bucket": 0,
-                 "compile": "cached" if cached else "cold"}
-        voice_label = getattr(v, "scope_voice", None)
-        if voice_label is not None:
-            attrs["voice"] = voice_label
-        return (out, n, fused), attrs
-
-    @staticmethod
-    def _finish(ticket):
-        """FINISH phase: the blocking fetch — the only host sync on the
-        iteration path, and it runs on the finisher thread so iteration
-        k+1's dispatch overlaps it."""
-        out, n, fused = ticket
-        return _fetch_window_results(out, n, fused)
-
-
-class _StreamStageCoalescer:
-    """Shared dispatcher for streaming encode+acoustics stages.
-
-    The window-decode coalescer (above) removed the per-chunk serialization
-    across concurrent streams, but every stream still paid its own serial
-    encode and acoustics dispatches at start — at 8 concurrent streams
-    those per-stream stages dominated TTFB.  Here stream *starts* that
-    arrive within ``max_wait_ms`` and share a text bucket become one
-    batched encode and one batched acoustics dispatch; per-row synthesis
-    scales and speaker ids ride the same row-wise arrays the batch path
-    uses, so streams with different configs still share a dispatch.
-
-    Pipeline shape mirrors the decode coalescer (and lives in the same
-    :class:`~sonata_tpu.synth.batching.BatchingCore`): a dispatcher
-    thread groups and enqueues device programs; a finisher thread blocks
-    on each group's (async-prefetched) frame counts, handles the rare
-    frame-budget retry, and resolves per-stream futures with their z row.
-    """
-
-    def __init__(self, voice: "PiperVoice", *, max_batch: int = 8,
-                 max_wait_ms: float = 8.0):
-        # max_wait is 4x the decode coalescer's: the stage runs once per
-        # stream (vs once per chunk), so a slightly longer gather window
-        # costs little TTFB but catches burst arrivals that thread
-        # scheduling spreads over a few milliseconds
-        import weakref
-
-        self._voice_ref = weakref.ref(voice)
-        self._max_batch = max_batch
-        self._max_wait = max_wait_ms / 1000.0
-        self._reason = "stream-stage coalescer closed (voice unloaded)"
-        self._core = BatchingCore(
-            dispatch=self._dispatch, finish=self._finish,
-            max_batch=max_batch, max_wait_s=self._max_wait,
-            name="sonata_stream_stages", keyed=True,
-            alive=lambda: self._voice_ref() is not None,
-            closed_reason=self._reason, poll_s=5.0)
-        self.stats = self._core.stats
-
-    @property
-    def _worker(self):
-        return self._core._worker
-
-    @property
-    def _finisher(self):
-        return self._core._finisher
-
-    def close(self) -> None:
-        """Stop both threads and fail any work still queued (see
-        :meth:`_StreamDecodeCoalescer.close`)."""
-        self._core.shutdown(join_timeout_s=10.0)
-
-    def start(self, ids: list, sc: SynthesisConfig):
-        """Blocking: run encode+acoustics for one stream (possibly batched
-        with others).  Returns ``(z_row, total_frames, f, sid0)`` where
-        ``z_row`` is the [f, C] on-device latent, ``total_frames`` the true
-        frame count, ``f`` the allocated frame bucket, and ``sid0`` the
-        row's speaker id (None on single-speaker voices)."""
-        if self._core.closed:
-            raise OperationError(self._reason)
-        item = WorkItem((ids, sc),
-                        key=(bucket_for(len(ids), TEXT_BUCKETS),))
-        self._core.put(item)
-        return item.future.result()
-
-    def _dispatch(self, group: list):
-        v = self._voice_ref()
-        if v is None:
-            raise OperationError("voice was garbage-collected")
-        ids_list = [item.payload[0] for item in group]
-        scs = [item.payload[1] for item in group]
-        # same canonical-batch rule as the decode coalescer: any
-        # multi-stream group pads to max_batch rows, so only the
-        # (b=1, b=max) encode/acoustics shapes exist and prewarm
-        # covers them completely
-        if len(group) > 1:
-            pad_rows = self._max_batch - len(group)
-            ids_list = ids_list + [[0]] * pad_rows
-            scs = scs + [scs[0]] * pad_rows
-        ids, lens, b, t = v._pad_batch(ids_list)
-        speakers = None
-        if v.multi_speaker:
-            speakers = [sc.speaker[1] if sc.speaker else 0 for sc in scs]
-        sid = v._sid_array(scs[0], b, speakers)
-        nw, ls, ns, ls_host = v._scale_arrays(scs[0], b, scales=scs)
-        weighted = max(len(row) * max(ls_host[i], 0.05)
-                       for i, row in enumerate(ids_list))
-        f = v._estimate_frame_bucket(weighted)
-        # one split key per dispatch, like the fused batch path — a
-        # frame-budget retry reuses it for identical audio
-        rng_enc, rng_aco = jax.random.split(v._next_rng())
-        enc_args = [v.params, ids, lens, rng_enc, nw, ls]
-        if sid is not None:
-            enc_args.append(sid)
-        m_p, logs_p, w_ceil, x_mask = v._encode_fn(b, t)(*enc_args)
-        # per-row frame counts: prefetched so the finisher's fetch
-        # rides behind the acoustics dispatch
-        frames_vec = jnp.sum(w_ceil.reshape(b, -1), axis=1)
-        frames_vec.copy_to_host_async()
-
-        def run_acoustics(bucket: int):
-            args = [v.params, m_p, logs_p, w_ceil, x_mask, rng_aco, ns]
-            if sid is not None:
-                args.append(sid)
-            return v._acoustics_fn(b, t, bucket)(*args)
-
-        z, _y_lengths = run_acoustics(f)
-        self._core.bump("requests", len(group))
-        self._core.bump("dispatches")
-        self._core.bump("rows", len(group))
-        self._core.bump("padded_rows", b - len(group))
-        return (z, frames_vec, f, weighted, speakers, run_acoustics)
-
-    def _finish(self, group: list, ticket) -> None:
-        z, frames_vec, f, weighted, speakers, run_acoustics = ticket
-        v = self._voice_ref()
-        frames = np.asarray(jax.device_get(frames_vec)).astype(int)
-        actual = int(frames[:len(group)].max())
-        if v is not None:
-            v._observe_frames(weighted, actual)
-        if actual > f and v is not None:  # clipped: redo, same rng
-            f = bucket_for(actual, FRAME_BUCKETS)
-            z, _ = run_acoustics(f)
-        for i, item in enumerate(group):
-            sid0 = speakers[i] if speakers is not None else None
-            try_set_result(item.future, (z[i], int(frames[i]), f, sid0))
+    return stream_engines

@@ -6,7 +6,7 @@ key-value cache and convolution state; a unit table maps ids to the
 generator's latent; and the HiFi-GAN generator a Piper voice runs
 (:func:`.vits.decode`, time-folded stages and all) turns frames into
 samples, through the same on-device int16 epilogue
-(:meth:`.piper.PiperVoice._decode_quantize`).
+(:func:`.decode_opts.decode_quantize`).
 
 The voice JSON says so with ``"family": "unit_lm"``
 (:func:`sonata_tpu.models.from_config_path`); beside Piper's keys
@@ -50,9 +50,9 @@ from ..core import AudioInfo, BaseModel, FailedToLoadResource, \
 from ..serving import tracing
 from ..text.phonemizer import text_to_phonemes
 from ..utils.buckets import FRAME_BUCKETS, TEXT_BUCKETS, bucket_for
-from . import lfm2
+from ..utils.transfer import prefetch_to_host
+from . import decode_opts, lfm2
 from .config import ModelConfig, SynthesisConfig
-from .piper import PiperVoice
 from .serialization import load_params, unflatten_params
 
 FAMILY = "unit_lm"
@@ -374,7 +374,7 @@ class UnitVoice(BaseModel):
             row = jax.lax.dynamic_slice(units, (slot, 0), (1, frames))
             valid = (jnp.arange(frames) < count)[None, :, None]
             z = jnp.where(valid, unit_table[row], 0.0)
-            return PiperVoice._decode_quantize(
+            return decode_opts.decode_quantize(
                 generator, hp, z, jnp.reshape(count, (1,)), None)
 
         return jax.jit(unit_vocode)
@@ -405,7 +405,7 @@ class UnitVoice(BaseModel):
         fn = self._program(("vocode", f), lambda: self._build_vocode(f))
         out = fn(self.generator, self.unit_table, cache["units"],
                  np.int32(slot), np.int32(units))
-        PiperVoice._prefetch_to_host(out)
+        prefetch_to_host(out)
         return out, {"batch_bucket": 1, "frames_bucket": f,
                      "compile": self._first_use(("vocode", f))}
 

@@ -313,7 +313,7 @@ def acoustics(p: Params, hp: VitsHyperParams, m_p, logs_p, w_ceil, x_mask,
 
         z = flow_reverse_sp(p["flow"], hp, z_p, y_mask, mesh, g=g)
     else:
-        z = flow_reverse(p["flow"], hp, z_p, y_mask, g=g, mesh=mesh)
+        z = flow_reverse(p["flow"], hp, z_p, y_mask, g=g)
     return z * y_mask, y_mask, y_lengths
 
 
@@ -342,11 +342,9 @@ def _use_seq_parallel(mesh, frames: int, hp: VitsHyperParams) -> bool:
 
 @jax.named_scope("flow_reverse")
 def flow_reverse(pf: Params, hp: VitsHyperParams, z, mask, g=None,
-                 conv=None, mesh=None):
-    """``mesh``: set only by a data-sharded ``jax.jit`` caller (the gate
-    kernel needs it, see :func:`sonata_tpu.ops.gate.fused_gate`); the
-    seq-parallel caller is already inside a ``shard_map`` and passes
-    none."""
+                 conv=None):
+    """``conv``: the seq-parallel caller, inside its ``shard_map``,
+    injects a halo-exchange convolution."""
     half = hp.inter_channels // 2
     for layer in reversed(pf["layers"]):
         z = z[..., ::-1]  # Flip (reverse order: undo the flip first)
@@ -354,7 +352,7 @@ def flow_reverse(pf: Params, hp: VitsHyperParams, z, mask, g=None,
         h = m.conv1d(z0, layer["pre"]) * mask
         h = m.wn(h, mask, layer["wn"], kernel=hp.flow_kernel_size,
                  dilation_rate=1, n_layers=hp.flow_wn_layers, g=g,
-                 conv=conv, mesh=mesh)
+                 conv=conv)
         mean = m.conv1d(h, layer["post"]) * mask
         z1 = (z1 - mean) * mask  # mean-only coupling, reverse
         z = jnp.concatenate([z0, z1], axis=-1)

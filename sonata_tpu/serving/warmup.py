@@ -10,8 +10,10 @@ every replica at once).
 
 This module drives the replacement:
 
-- the model enumerates its bucket lattice (``lattice_shapes(mode)``,
-  derived from :mod:`sonata_tpu.utils.buckets`) and compiles each shape
+- the model enumerates its bucket lattice (``lattice_shapes(mode)``; a
+  stock voice's rule is :func:`sonata_tpu.models.shape_plan.
+  lattice_shapes`, over the ladders of :mod:`sonata_tpu.utils.buckets`)
+  and compiles each shape
   ahead of traffic (``warm_shape`` — a synthetic dummy-argument
   dispatch through the same jit cache real traffic uses, which also
   lands every executable in the persistent compile cache so the

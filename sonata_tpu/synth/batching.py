@@ -3,7 +3,7 @@
 Before this module, three copies of the same machinery lived in the
 tree — :class:`~sonata_tpu.synth.scheduler.BatchScheduler` (sentence
 requests), the streaming window-decode coalescer, and the streaming
-encode+acoustics stage coalescer (both in :mod:`sonata_tpu.models.piper`).
+encode+acoustics stage coalescer (both now in :mod:`.stream_engines`).
 Each owned its own queue, gather loop, shutdown drain, and future
 bookkeeping, and the serving contracts (deadline-drop-before-pack, bounded
 shed, watchdog, crash containment) existed only where someone had
@@ -631,9 +631,10 @@ class IterationLoop:
     stream's, and they **retire** when the stream ends — no wave gather,
     no wait window, and the batch axis steps through the *graduated*
     bucket ladder (1, 2, 4, 8, ...) because the warmup lattice
-    enumerates every rung (``lattice_shapes`` grows the iteration-mode
-    shapes), so occupancy-sized dispatches stay recompile-free where the
-    wave path had to overpad to the canonical max.
+    enumerates every rung (:func:`sonata_tpu.models.shape_plan.
+    window_decoder_batches`), so occupancy-sized dispatches stay
+    recompile-free where the wave path had to overpad to the canonical
+    max.
 
     Owner hooks (one- or two-phase):
 

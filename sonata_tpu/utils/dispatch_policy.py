@@ -19,7 +19,7 @@ hard-coded constants (the Orca/vLLM adaptive-batching lineage, PAPERS.md
   batch 1 vs batch N (compiles excluded) and split the cost into
   per-dispatch overhead vs per-item scaling.
 - :func:`resolve_policy` — derive concrete knobs for both stream
-  coalescers (``models/piper.py``), the :class:`~sonata_tpu.synth.
+  coalescers (``synth/stream_engines.py``), the :class:`~sonata_tpu.synth.
   scheduler.BatchScheduler`, and the canonical stream batch bucket
   (:mod:`.buckets`).  Fast path: ``jax.default_backend() == "cpu"`` →
   per-request dispatch, the reference's thread-per-stream serving shape
@@ -28,17 +28,10 @@ hard-coded constants (the Orca/vLLM adaptive-batching lineage, PAPERS.md
   (the larger the per-dispatch overhead, the cheaper it is to wait a
   little longer and gather a fuller batch).
 
-Env overrides always win over the probe (A/B work must stay possible):
-
-- ``SONATA_STREAM_COALESCE=0|1`` (legacy knob, highest precedence;
-  honored only when explicitly set): 0 → per-request dispatch, 1 →
-  force the coalescing defaults.
-- ``SONATA_DISPATCH_POLICY=auto|on|off``: ``on``/``off`` force the
-  corresponding shape; ``auto`` (default) applies the backend fast path
-  + probe.
-
-``SONATA_DONATE=0|1`` gates buffer donation the same backend-adaptive
-way (see :func:`should_donate`).
+The env override always wins over the probe (A/B work must stay
+possible): ``SONATA_DISPATCH_POLICY=auto|on|off``.  ``on``/``off`` force
+the corresponding shape; ``auto`` (default) applies the backend fast
+path + probe.
 """
 
 from __future__ import annotations
@@ -115,9 +108,10 @@ class DispatchPolicy:
 
     ``coalesce`` is the headline decision; the per-subsystem knobs are
     what :class:`~sonata_tpu.models.piper.PiperVoice`, the stream
-    coalescers, and the batch scheduler actually consume.  ``source``
-    records *why* (env override / backend fast path / probe) so the
-    decision is visible in logs and bench artifacts.
+    engines (``synth/stream_engines.py``), the batch scheduler and the
+    warm-up lattice (``models/shape_plan.py``) actually consume.
+    ``source`` records *why* (env override / backend fast path / probe)
+    so the decision is visible in logs and bench artifacts.
     """
 
     backend: str
@@ -311,12 +305,8 @@ def resolve_policy(shape_key: tuple = (), *,
 
     Precedence (each layer wins over everything below it):
 
-    1. ``SONATA_STREAM_COALESCE`` **explicitly set** — the legacy A/B
-       knob: ``0`` → per-request dispatch, anything else → coalescing
-       defaults.  (Unset means "no opinion"; before the policy existed,
-       unset silently meant "on".)
-    2. ``SONATA_DISPATCH_POLICY=on|off`` — forced shape, no probe.
-    3. ``auto`` (default): backend fast path — CPU serves per-request
+    1. ``SONATA_DISPATCH_POLICY=on|off`` — forced shape, no probe.
+    2. ``auto`` (default): backend fast path — CPU serves per-request
        without paying a probe; other backends run the cached
        :func:`probe_dispatch_scaling` and keep coalescing only if the
        measured batch speedup clears :data:`MIN_BATCH_SPEEDUP`.
@@ -327,14 +317,6 @@ def resolve_policy(shape_key: tuple = (), *,
     env = os.environ if env is None else env
     backend = backend or _default_backend()
     probe_fn = probe_fn or probe_dispatch_scaling
-
-    legacy = env.get("SONATA_STREAM_COALESCE")
-    if legacy is not None:
-        if legacy == "0":
-            return _per_request_policy(
-                backend, "env:SONATA_STREAM_COALESCE=0")
-        return _coalescing_policy(
-            backend, f"env:SONATA_STREAM_COALESCE={legacy}")
 
     mode = env.get("SONATA_DISPATCH_POLICY", "auto").lower()
     if mode not in ("auto", "on", "off"):
@@ -365,25 +347,3 @@ def resolve_policy(shape_key: tuple = (), *,
     return _coalescing_policy(
         backend, f"auto:probe-speedup-{probe.batch_speedup:.2f}x",
         probe=probe)
-
-
-# ---------------------------------------------------------------------------
-# buffer donation gating
-# ---------------------------------------------------------------------------
-
-def should_donate() -> bool:
-    """Whether jitted dispatch paths should mark donatable buffers.
-
-    Default: off everywhere.  Investigation of the r05 streaming-bench
-    warning ("Some donated buffers were not usable: float32[8,128,192]")
-    showed the donated stacked-windows buffer can never alias the decode
-    output — XLA input/output aliasing requires identical byte size, and
-    [B, width, C] f32 ≠ [B, width*hop] f32 for every voice shape — so
-    the annotation was a per-compile warning with zero effect on any
-    backend.  ``SONATA_DONATE=1`` re-enables it for A/B measurement
-    (``tools/bench_cpu.py`` donation config); ``0`` forces it off.
-    """
-    setting = os.environ.get("SONATA_DONATE")
-    if setting is not None:
-        return setting != "0"
-    return False

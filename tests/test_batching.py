@@ -959,7 +959,7 @@ def test_iteration_stream_deadline_fails_alone(iteration_env):
 def test_ladder_forces_new_streams_to_dispatch_mode(iteration_env):
     """Level >= 1 routes NEW streams to the wave coalescer; recovery
     re-admits the iteration loop — per stream, no restart."""
-    from sonata_tpu.models.piper import (
+    from sonata_tpu.synth.stream_engines import (
         _IterationStreamDecoder,
         _StreamDecodeCoalescer,
     )

@@ -1,7 +1,6 @@
 """``chip_smoke.py`` end to end in rehearsal mode: the same phases the chip
-run makes (voice writer, kernel phase, the real server CLI driven over the
-wire, SIGTERM drain, log scan, warm second boot) with a tiny voice on the
-CPU and Pallas in interpret mode."""
+run makes (voice writer, the real server CLI driven over the wire, SIGTERM
+drain, log scan, warm second boot) with a tiny voice on the CPU."""
 
 import json
 import os

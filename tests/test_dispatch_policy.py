@@ -6,8 +6,8 @@ Pins the three layers of the ISSUE-1 contract:
   streaming bench measured the coalescers at 2.6x the TTFB of
   per-request dispatch at 8 streams), while a TPU-class backend keeps
   the tuned coalescing defaults bit-for-bit;
-- env overrides (``SONATA_STREAM_COALESCE``, ``SONATA_DISPATCH_POLICY``)
-  beat the probe, so A/B benchmarking stays possible;
+- the env override (``SONATA_DISPATCH_POLICY``) beats the probe, so A/B
+  benchmarking stays possible;
 - the dispatch-scaling probe runs once per (backend, shape) and is
   cached; its result is visible in the observability counters.
 """
@@ -22,7 +22,6 @@ from sonata_tpu.utils.dispatch_policy import (
     _clear_probe_cache,
     probe_dispatch_scaling,
     resolve_policy,
-    should_donate,
 )
 from voices import tiny_voice
 
@@ -121,20 +120,6 @@ def test_dispatch_policy_env_beats_probe():
                        probe_fn=_fast_tpu_probe(calls))
     assert p.coalesce is True and not calls
     assert p.stream_decode_kwargs() == {"max_batch": 8, "max_wait_ms": 2.0}
-
-
-def test_legacy_stream_coalesce_env_has_highest_precedence():
-    calls = []
-    p = resolve_policy(backend="tpu",
-                       env={"SONATA_STREAM_COALESCE": "0",
-                            "SONATA_DISPATCH_POLICY": "on"},
-                       probe_fn=_fast_tpu_probe(calls))
-    assert p.coalesce is False and not calls
-    p = resolve_policy(backend="cpu",
-                       env={"SONATA_STREAM_COALESCE": "1",
-                            "SONATA_DISPATCH_POLICY": "off"},
-                       probe_fn=_fast_tpu_probe(calls))
-    assert p.coalesce is True and not calls
 
 
 def test_invalid_policy_env_falls_back_to_auto():
@@ -280,24 +265,13 @@ def test_scheduler_reports_dispatch_counters():
 # donation gating
 # ---------------------------------------------------------------------------
 
-def test_donation_defaults_off_and_env_forces(monkeypatch):
-    monkeypatch.delenv("SONATA_DONATE", raising=False)
-    assert should_donate() is False  # unaliasable ⇒ warnings only
-    monkeypatch.setenv("SONATA_DONATE", "1")
-    assert should_donate() is True
-    monkeypatch.setenv("SONATA_DONATE", "0")
-    assert should_donate() is False
-
-
-def test_window_decoder_not_donated_by_default(monkeypatch):
-    """Companion to test_parallel.py::test_stream_window_decoder_donates_
-    windows: with SONATA_DONATE unset no arg carries the donation
-    annotation, so the r05 'donated buffers were not usable' warning
-    cannot fire."""
+def test_window_decoder_not_donated_by_default():
+    """No arg carries the donation annotation (the windows buffer can
+    never alias the waveform), so the r05 'donated buffers were not
+    usable' warning cannot fire."""
     import jax
     import jax.numpy as jnp
 
-    monkeypatch.delenv("SONATA_DONATE", raising=False)
     v = tiny_voice(seed=47)
     try:
         fn = v._decode_windows_batch_fn(16, 2, False)

@@ -206,7 +206,8 @@ def test_forced_overflow_is_one_retry_and_the_same_audio():
 
     def speak(frames_per_id):
         voice = tiny_voice()        # same seed: same first duration draw
-        voice._frames_per_id, voice._fpi_observed = frames_per_id, True
+        voice.frame_estimator.frames_per_id = frames_per_id
+        voice.frame_estimator.observed = True
         tracer = tracing.Tracer(enabled=True, log_sink="0")
         before = tracing.program_stats().snapshot()
         trace, audios = traced(tracer, lambda: voice.speak_batch(sentence))

@@ -1,15 +1,11 @@
-"""Pallas kernel tests (interpret mode on CPU; the compiled TPU lowering is
-exercised by bench/graft runs on real hardware)."""
+"""The WaveNet gate (:func:`sonata_tpu.models.modules.gate`): the one
+gated activation every platform runs."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from sonata_tpu.ops.gate import (
-    fused_gate,
-    fused_gate_pallas,
-    fused_gate_reference,
-)
+from sonata_tpu.models.modules import gate
 
 
 def _inputs(b=2, t=100, h=32, seed=0):
@@ -19,40 +15,20 @@ def _inputs(b=2, t=100, h=32, seed=0):
     return x, g
 
 
-def test_pallas_gate_matches_reference_interpret():
-    x, g = _inputs()
-    y = x + g
-    ref = fused_gate_reference(y)
-    out = fused_gate_pallas(y, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
-
-
-def test_pallas_gate_non_multiple_rows_and_unaligned_hidden():
-    # rows = 2*37 = 74 (not a 256 multiple); hidden 24 (not a lane multiple)
-    x, g = _inputs(b=2, t=37, h=24, seed=3)
-    y = x + g
-    out = fused_gate_pallas(y, interpret=True)
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(fused_gate_reference(y)),
-                               atol=1e-6)
-
-
-def test_dispatch_fallback_on_cpu():
+def test_gate_is_tanh_times_sigmoid_of_the_two_halves():
     x, g = _inputs(b=1, t=8, h=4)
-    out = fused_gate(x, g)  # cpu backend → jnp path
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(fused_gate_reference(x + g)),
-                               atol=1e-6)
-    # g omitted → no conditioning add at all
-    out2 = fused_gate(x)
-    np.testing.assert_allclose(np.asarray(out2),
-                               np.asarray(fused_gate_reference(x)),
-                               atol=1e-6)
+    y = np.asarray(x + g, np.float64)
+    want = np.tanh(y[..., :4]) / (1.0 + np.exp(-y[..., 4:]))
+    np.testing.assert_allclose(np.asarray(gate(x + g)), want, atol=1e-6)
+    # no conditioning: the pre-activation alone
+    y = np.asarray(x, np.float64)
+    want = np.tanh(y[..., :4]) / (1.0 + np.exp(-y[..., 4:]))
+    np.testing.assert_allclose(np.asarray(gate(x)), want, atol=1e-6)
 
 
 def test_gate_range_and_gradients():
     x, g = _inputs(b=1, t=16, h=8)
-    out = fused_gate_reference(x + g)
+    out = gate(x + g)
     assert float(jnp.abs(out).max()) <= 1.0  # tanh*sigmoid bounded
-    grads = jax.grad(lambda x: fused_gate_reference(x + g).sum())(x)
+    grads = jax.grad(lambda x: gate(x + g).sum())(x)
     assert bool(jnp.isfinite(grads).all())

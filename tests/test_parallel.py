@@ -401,40 +401,3 @@ def test_decode_sp_bfloat16_close_to_unsharded_bf16():
     # (bf16-vs-f32 closeness is pinned on the unsharded path in
     # test_vits_model.py::test_bfloat16_decode_close_to_float32; skipping
     # the extra f32 compile here keeps the suite compile budget down)
-
-
-def test_stream_window_decoder_donates_windows(monkeypatch):
-    """With ``SONATA_DONATE=1`` the batched window decoder donates its
-    stacked-windows input (HLO carries the buffer-donor/alias
-    annotation), and donated dispatch produces the same audio as an
-    undonated reference call.  Donation defaults OFF since the policy
-    round: the windows buffer can never alias the differently-sized
-    decode output, so the annotation only produced per-compile warnings
-    (see utils/dispatch_policy.should_donate)."""
-    import jax
-    import jax.numpy as jnp
-
-    from voices import tiny_voice
-
-    monkeypatch.setenv("SONATA_DONATE", "1")
-    v = tiny_voice(seed=31)
-    width, b = 16, 2
-    fn = v._decode_windows_batch_fn(width, b, False)
-    c = v.hp.inter_channels
-    w = jnp.ones((b, width, c), jnp.float32)
-    lowered = fn.lower(v.params, w)
-    # args_info is (params_tree, windows, ...); the windows leaf must be
-    # marked donated (platform-independent; CPU ignores it at runtime)
-    windows_info = jax.tree_util.tree_leaves(lowered.args_info)[
-        len(jax.tree_util.tree_leaves(v.params))]
-    assert windows_info.donated, "windows arg not marked donated"
-    params_donated = [i.donated for i in jax.tree_util.tree_leaves(
-        lowered.args_info)[:len(jax.tree_util.tree_leaves(v.params))]]
-    assert not any(params_donated), "params must never be donated"
-    out = np.asarray(fn(v.params, jnp.ones((b, width, c), jnp.float32)))
-    ref = np.asarray(
-        jax.jit(lambda p, win: __import__("sonata_tpu.models.vits",
-                                          fromlist=["decode"]).decode(
-            p, v.hp, win, g=None, compute_dtype=v.compute_dtype))(
-            v.params, jnp.ones((b, width, c), jnp.float32)))
-    np.testing.assert_allclose(out, ref, atol=1e-5)

@@ -329,7 +329,7 @@ def test_stream_decode_coalescer_correctness():
     import jax.numpy as jnp
     from concurrent.futures import wait
 
-    from sonata_tpu.models.piper import _StreamDecodeCoalescer
+    from sonata_tpu.synth.stream_engines import _StreamDecodeCoalescer
 
     v = tiny_voice(seed=9)
     # wide wait window so the 4 submissions deterministically coalesce
@@ -353,7 +353,7 @@ def test_stream_decode_coalescer_correctness():
 def test_concurrent_streams_share_dispatches():
     import threading
 
-    from sonata_tpu.models.piper import _StreamDecodeCoalescer
+    from sonata_tpu.synth.stream_engines import _StreamDecodeCoalescer
 
     v = tiny_voice(seed=5)
     # wide wait window: on a loaded 1-core host the four stream threads
@@ -383,7 +383,7 @@ def test_stream_stage_coalescer_batches_starts():
     drive correct chunk synthesis (round-2: stage coalescing)."""
     import threading
 
-    from sonata_tpu.models.piper import _StreamStageCoalescer
+    from sonata_tpu.synth.stream_engines import _StreamStageCoalescer
 
     v = tiny_voice(seed=7)
     v._stage_coalescer = _StreamStageCoalescer(v, max_wait_ms=300.0)
@@ -567,21 +567,21 @@ def test_coalescer_close_fails_queued_futures():
     from concurrent.futures import Future
 
     from sonata_tpu.core import OperationError
-    from sonata_tpu.models.piper import _drain_pending_futures
+    from sonata_tpu.synth.batching import drain_pending_futures
 
     q: "_queue.Queue" = _queue.Queue()
     f1, f2 = Future(), Future()
     q.put(("win", 16, None, f1))
     q.put(None)  # sentinel must be skipped
     q.put(("win", 16, None, f2))
-    _drain_pending_futures(q, lambda it: it[3], "closed in test")
+    drain_pending_futures(q, lambda it: it[3], "closed in test")
     for f in (f1, f2):
         assert isinstance(f.exception(timeout=0), OperationError)
     # list-of-futures extraction (the stage-results layout)
     q2: "_queue.Queue" = _queue.Queue()
     f3, f4 = Future(), Future()
     q2.put(([("ids", None, f3), ("ids", None, f4)], "z"))
-    _drain_pending_futures(q2, lambda it: [g[2] for g in it[0]],
+    drain_pending_futures(q2, lambda it: [g[2] for g in it[0]],
                            "closed in test")
     assert isinstance(f3.exception(timeout=0), OperationError)
     assert isinstance(f4.exception(timeout=0), OperationError)

@@ -259,7 +259,8 @@ def test_overflow_retry_reproduces_exact_durations():
     # same RNG sequence → identical audio)
     va = tiny_voice(seed=21)
     vb = tiny_voice(seed=21)
-    vb._frames_per_id = 0.01  # guarantees overflow on first dispatch
+    # guarantees overflow on first dispatch
+    vb.frame_estimator.frames_per_id = 0.01
     a = va.speak_one_sentence("ə lɑːŋɚ tɛst sɛntəns wɪð mɔːɹ wɜːdz.")
     b = vb.speak_one_sentence("ə lɑːŋɚ tɛst sɛntəns wɪð mɔːɹ wɜːdz.")
     assert len(a.samples) == len(b.samples)

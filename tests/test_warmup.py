@@ -186,14 +186,14 @@ def test_real_voice_warm_shape_compiles_the_cached_fn():
 
 
 def test_warm_shape_never_feeds_the_frame_estimator():
-    """warm_shape must bypass _observe_frames: zero-input dummy runs
-    would corrupt the estimator the lattice was enumerated with."""
+    """warm_shape must bypass the estimator's observe: zero-input dummy
+    runs would corrupt what the lattice was enumerated with."""
     v = tiny_voice(seed=7)
-    before = v._frames_per_id
-    observed_before = v._fpi_observed
+    before = v.frame_estimator.frames_per_id
+    observed_before = v.frame_estimator.observed
     v.warm_shape((1, 16, 64))
-    assert v._frames_per_id == before
-    assert v._fpi_observed == observed_before
+    assert v.frame_estimator.frames_per_id == before
+    assert v.frame_estimator.observed == observed_before
 
 
 # ---------------------------------------------------------------------------

@@ -10,7 +10,7 @@ import jax
 import numpy as np
 import pytest
 
-from sonata_tpu.models import PiperVoice, from_config_path
+from sonata_tpu.models import PiperVoice, from_config_path, shape_plan
 from sonata_tpu.models.decode_opts import decoder_is_quantized
 from sonata_tpu.models.serialization import load_params
 from sonata_tpu.parallel import make_mesh
@@ -113,7 +113,9 @@ def test_speak_batch_is_bit_identical_to_the_numpy_tree_call(voice_path):
 
     ids = [twin._encode_phonemes(p) for p in batch]
     sc = twin.get_fallback_synthesis_config()
-    groups = twin._plan_dispatch_groups(ids, sc)
+    groups = shape_plan.plan_dispatch_groups(
+        [len(i) for i in ids], [sc.length_scale] * len(ids),
+        min_batch=twin.MIN_DISPATCH_BATCH, max_batch=twin.MAX_DISPATCH_BATCH)
     # the twin walks speak_batch's order: with two groups at most, each
     # is enqueued after the one before it was fetched
     assert 1 <= len(groups) <= 2

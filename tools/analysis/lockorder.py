@@ -63,6 +63,7 @@ SCOPE_PREFIXES = (
     "sonata_tpu/synth",
     "sonata_tpu/frontends",
     "sonata_tpu/models/piper.py",
+    "sonata_tpu/models/shape_plan.py",
     "sonata_tpu/utils/profiling.py",
     "sonata_tpu/utils/dispatch_policy.py",
 )

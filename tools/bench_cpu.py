@@ -7,17 +7,14 @@ timings and counts, never device metrics:
 
 - batch RTF: sub-pixel transposed convs (default) vs the naive
   ``lhs_dilation`` lowering (``SONATA_TCONV=naive``), the bfloat16
-  decoder compute policy (``SONATA_COMPUTE_DTYPE=bfloat16``), and the
-  streaming window-decode buffer-donation annotation forced on
-  (``SONATA_DONATE=1``; default off — see
-  ``utils/dispatch_policy.should_donate``)
+  decoder compute policy (``SONATA_COMPUTE_DTYPE=bfloat16``)
 - batch RTF also covers the int8 weight-only decoder arm
   (``SONATA_DECODE_QUANT=int8``) next to bf16 — both parity-gated by
   tests (bf16: test_vits_model.py; int8: test_decode_opts.py)
 - streaming TTFB/throughput: the backend-adaptive dispatch policy's
   default (``auto`` → per-request dispatch on CPU) vs coalescing forced
   on (``SONATA_DISPATCH_POLICY=on``, the pre-policy default shape) vs
-  the legacy per-request override (``SONATA_STREAM_COALESCE=0``) — the
+  per-request dispatch forced (``SONATA_DISPATCH_POLICY=off``) — the
   last two bracket what the policy chooses between — plus the ISSUE-11
   precision/fusion arms (``SONATA_FUSED_EPILOGUE=off``,
   ``SONATA_DECODE_QUANT=int8``, ``SONATA_COMPUTE_DTYPE=bfloat16``).
@@ -50,11 +47,10 @@ from pathlib import Path
 REPO = Path(__file__).resolve().parent.parent
 
 BATCH_CONFIGS = (
-    ("baseline", {}),  # sub-pixel tconv, f32, donation off (the defaults)
+    ("baseline", {}),  # sub-pixel tconv, f32 (the defaults)
     ("naive_tconv", {"SONATA_TCONV": "naive"}),
     ("bf16", {"SONATA_COMPUTE_DTYPE": "bfloat16"}),
     ("int8", {"SONATA_DECODE_QUANT": "int8"}),  # weight-only decoder arm
-    ("donation", {"SONATA_DONATE": "1"}),
 )
 
 # streaming arms: the policy A/Bs (r06 lineage) plus the ISSUE-11
@@ -66,7 +62,7 @@ BATCH_CONFIGS = (
 STREAMING_CONFIGS = (
     ("default_policy", {}),  # SONATA_DISPATCH_POLICY=auto
     ("coalescing_forced_on", {"SONATA_DISPATCH_POLICY": "on"}),
-    ("coalescing_off", {"SONATA_STREAM_COALESCE": "0"}),
+    ("coalescing_off", {"SONATA_DISPATCH_POLICY": "off"}),
     ("fused_epilogue_off", {"SONATA_FUSED_EPILOGUE": "off"}),
     ("int8_decoder", {"SONATA_DECODE_QUANT": "int8"}),
     ("bf16_decoder", {"SONATA_COMPUTE_DTYPE": "bfloat16"}),
@@ -135,7 +131,7 @@ def main() -> None:
         base = rtf("baseline")
         # ratio > 1.0 ⇒ the baseline beats (is faster than) that config;
         # for naive_tconv that reads as "sub-pixel speedup"
-        for cfg in ("naive_tconv", "bf16", "int8", "donation"):
+        for cfg in ("naive_tconv", "bf16", "int8"):
             other = rtf(cfg)
             if base and other:
                 batch[f"{cfg}_vs_baseline_rtf_ratio"] = round(other / base, 3)

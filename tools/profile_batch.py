@@ -46,9 +46,9 @@ def main() -> None:
     nw, ls, ns, ls_host = voice._scale_arrays(sc, b)
     weighted = float(max(len(r) * max(ls_host[i], 0.05)
                          for i, r in enumerate(ids_list)))
-    f = voice._estimate_frame_bucket(weighted)
+    f = voice.frame_estimator.bucket(weighted)
     print(f"buckets: b={b} t={t} f={f} "
-          f"(frames_per_id={voice._frames_per_id:.2f})")
+          f"(frames_per_id={voice.frame_estimator.frames_per_id:.2f})")
     fn = voice._full_fn(b, t, f)
     rng = voice._next_rng()
     args = [voice.params, ids, lens, rng, nw, ls, ns]
