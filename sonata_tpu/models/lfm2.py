@@ -8,6 +8,13 @@ dense SwiGLU in the first ``num_dense_layers`` layers and a sigmoid-routed
 mixture of SwiGLU experts in the others.  A final RMS norm, then the head,
 tied to the embedding.
 
+The pieces (:func:`mm`, :func:`rms_norm`, :func:`apply_rope`, ``_qkv``,
+:func:`swiglu`, :func:`moe_ffn`, :func:`sample`) also serve the other
+backbones of a unit voice (:mod:`.sdar`).  What differs between them is a
+field of the configuration, read when a program is built: the router's
+scoring (``router_scoring``), ``head_dim`` where it is not ``hidden_size /
+heads``, and a head of its own (``tie_word_embeddings: false``).
+
 Two programs over one set of weights: :func:`prefill` runs one row's prompt
 whole, writes its state into a slot of the cache and samples the row's
 first token; :func:`step` advances every slot by one token.  Both keep the
@@ -66,6 +73,12 @@ class Lfm2Config:
     norm_eps: float
     vocab_size: int
     layer_types: tuple
+    head_dim: int
+    #: how the router scores: ``sigmoid`` (+ ``expert_bias`` for the
+    #: selection) or ``softmax`` over all experts
+    router_scoring: str = "sigmoid"
+    #: false: the head is a matrix of its own (``params["head"]``)
+    tie_word_embeddings: bool = True
 
     @classmethod
     def from_dict(cls, d: dict) -> "Lfm2Config":
@@ -92,11 +105,9 @@ class Lfm2Config:
             num_dense_layers=int(d["num_dense_layers"]),
             norm_eps=float(d["norm_eps"]),
             vocab_size=int(d["vocab_size"]),
-            layer_types=layers)
-
-    @property
-    def head_dim(self) -> int:
-        return self.hidden_size // self.num_attention_heads
+            layer_types=layers,
+            head_dim=int(d.get("head_dim") or int(d["hidden_size"])
+                         // int(d["num_attention_heads"])))
 
     def layers_of(self, kind: str) -> list:
         return [i for i, k in enumerate(self.layer_types) if k == kind]
@@ -110,10 +121,12 @@ class Lfm2Config:
 class UnitIds:
     """How the vocabulary is split: ids below ``first_id`` are the prompt's
     (phoneme ids), the others are acoustic units; ``stop_id`` is the unit
-    that ends a row."""
+    that ends a row; ``mask_id``, where a backbone has one, stands for a
+    position not yet decided and is never a unit."""
 
     first_id: int
     stop_id: int
+    mask_id: Optional[int] = None
 
 
 # ---------------------------------------------------------------------------
@@ -167,7 +180,7 @@ def apply_rope(x, positions, theta: float):
     return x * cos + jnp.concatenate([-x2, x1], -1) * sin
 
 
-def _qkv(u, p, cfg: Lfm2Config, positions):
+def _qkv(u, p, cfg, positions):
     n = u.shape[0]
     heads, kv, d = cfg.num_attention_heads, cfg.num_key_value_heads, \
         cfg.head_dim
@@ -205,8 +218,16 @@ def conv_op_step(u, p, state):
         return mm(c * conv, p["out_proj"]), state
 
 
-def attn_op_seq(u, p, cfg: Lfm2Config):
-    """A row's prompt whole (causal); also its keys and values."""
+def block_mask(positions, block: int = 1):
+    """``[T, T]``: row ``i`` sees column ``j`` iff ``j``'s block of ``block``
+    positions is not after ``i``'s (1: causal)."""
+    at = positions if block == 1 else positions // block
+    return at[:, None] >= at[None, :]
+
+
+def attn_op_seq(u, p, cfg: Lfm2Config, block: int = 1):
+    """A row's prompt whole; also its keys and values.  Causal between
+    blocks of ``block`` positions and whole inside one (1: causal)."""
     with jax.named_scope("attn_op"):
         t = u.shape[0]
         kv, d = cfg.num_key_value_heads, cfg.head_dim
@@ -216,8 +237,8 @@ def attn_op_seq(u, p, cfg: Lfm2Config):
         q = q.reshape(t, kv, g, d).astype(BF16)
         scores = jnp.einsum("qkgd,pkd->kgqp", q, k,
                             preferred_element_type=F32) / jnp.sqrt(F32(d))
-        causal = pos[:, None] >= pos[None, :]
-        probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), -1)
+        probs = jax.nn.softmax(
+            jnp.where(block_mask(pos, block), scores, -jnp.inf), -1)
         out = jnp.einsum("kgqp,pkd->qkgd", probs.astype(BF16), v,
                          preferred_element_type=F32)
         return mm(out.reshape(t, -1), p["wo"]), k, v
@@ -255,12 +276,22 @@ def dense_ffn(u, p):
         return swiglu(u, p["w13"], p["w2"])
 
 
-def route(u, p, cfg: Lfm2Config):
-    """The experts chosen ``[N, k]`` and their weights ``[N, k]``:
-    selection by ``sigmoid + expert_bias``, weights the unbiased sigmoid of
-    the chosen, normalised."""
+def route(u, p, cfg):
+    """The experts chosen ``[N, k]`` and their weights ``[N, k]``.
+    ``sigmoid``: selection by ``sigmoid + expert_bias``, weights the
+    unbiased sigmoid of the chosen, normalised.  ``softmax``: the largest
+    of a softmax over all experts, renormalised over the chosen where
+    ``norm_topk_prob``."""
     with jax.named_scope("moe_route"):
-        scores = jax.nn.sigmoid(jnp.dot(u, p["router"], precision="highest"))
+        logits = jnp.dot(u, p["router"], precision="highest")
+        if cfg.router_scoring == "softmax":
+            scores = jax.nn.softmax(logits, -1)
+            _, chosen = lax.top_k(scores, cfg.num_experts_per_tok)
+            weights = jnp.take_along_axis(scores, chosen, axis=-1)
+            if cfg.norm_topk_prob:
+                weights = weights / jnp.sum(weights, -1, keepdims=True)
+            return chosen, weights
+        scores = jax.nn.sigmoid(logits)
         pick = scores + p["expert_bias"] if cfg.use_expert_bias else scores
         _, chosen = lax.top_k(pick, cfg.num_experts_per_tok)
         weights = jnp.take_along_axis(scores, chosen, axis=-1)
@@ -269,7 +300,7 @@ def route(u, p, cfg: Lfm2Config):
         return chosen, weights * cfg.routed_scaling_factor
 
 
-def moe_ffn(u, p, cfg: Lfm2Config, held: Optional[tuple] = None,
+def moe_ffn(u, p, cfg, held: Optional[tuple] = None,
             valid=None):
     """The expert layer over tokens ``u`` ``[N, H]``.
 
@@ -325,12 +356,24 @@ def _ffn_half(h, p, i: int, cfg: Lfm2Config, held, valid, routes: list,
     return h + out
 
 
-def _head(h, params, cfg: Lfm2Config):
+def _head(h, params, cfg):
+    """Logits of ``h`` ``[N, H]``: the final norm, then the embedding's
+    transpose or, untied, the head's own matrix (``[V, H]`` too)."""
     with jax.named_scope("head"):
         u = rms_norm(h, params["norm_f"], cfg.norm_eps)
-        return lax.dot_general(u.astype(BF16), params["embed"],
+        w = params["embed"] if cfg.tie_word_embeddings else params["head"]
+        return lax.dot_general(u.astype(BF16), w,
                                (((1,), (1,)), ((), ())),
                                preferred_element_type=F32)
+
+
+def allowed_ids(vocab: int, units: UnitIds):
+    """``[V]``: the ids a row may choose."""
+    ids = jnp.arange(vocab)
+    allowed = (ids >= units.first_id) & (ids != units.stop_id)
+    if units.mask_id is not None:
+        allowed &= ids != units.mask_id
+    return allowed
 
 
 def sample(logits, temperature, key, units: UnitIds):
@@ -338,9 +381,7 @@ def sample(logits, temperature, key, units: UnitIds):
     the unit ids where ``temperature`` is 0, else a draw from
     ``softmax(logits / temperature)`` over them.  The stop unit is
     suppressed: a row ends at its frame budget, which the host counts."""
-    ids = jnp.arange(logits.shape[-1])
-    allowed = (ids >= units.first_id) & (ids != units.stop_id)
-    masked = jnp.where(allowed, logits, -jnp.inf)
+    masked = jnp.where(allowed_ids(logits.shape[-1], units), logits, -jnp.inf)
     greedy = jnp.argmax(masked, -1)
     safe = jnp.maximum(temperature, 1e-6)[:, None]
     drawn = jax.random.categorical(key, masked / safe, axis=-1)

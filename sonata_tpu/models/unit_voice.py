@@ -1,24 +1,33 @@
-"""A unit voice: an autoregressive acoustic front over a HiFi-GAN vocoder.
+"""A unit voice: a backbone that generates acoustic units over a HiFi-GAN
+vocoder.
 
-The sentence's phoneme ids are the prompt; an LFM2-MoE backbone
-(:mod:`.lfm2`) decodes one acoustic-unit id a frame through its
-key-value cache and convolution state; a unit table maps ids to the
-generator's latent; and the HiFi-GAN generator a Piper voice runs
-(:func:`.vits.decode`, time-folded stages and all) turns frames into
-samples, through the same on-device int16 epilogue
-(:func:`.decode_opts.decode_quantize`).
+The sentence's phoneme ids are the prompt; the backbone gives one
+acoustic-unit id a frame; a unit table maps ids to the generator's latent;
+and the HiFi-GAN generator a Piper voice runs (:func:`.vits.decode`,
+time-folded stages and all) turns frames into samples, through the same
+on-device int16 epilogue (:func:`.decode_opts.decode_quantize`).
+
+**The backbone** is chosen by ``backbone["model_type"]``
+(:data:`BACKBONES`): ``lfm2_moe`` (:mod:`.lfm2`) decodes one unit a row a
+step through its key-value cache and convolution state; ``sdar_moe``
+(:mod:`.sdar`) gives a block of units by denoising passes and a commit pass
+over a block that sees itself whole.  Both stand behind the engine surface
+:class:`~sonata_tpu.synth.steploop.StepLoop` names; what differs is in the
+two classes here, and nothing else of the voice forks.
 
 The voice JSON says so with ``"family": "unit_lm"``
 (:func:`sonata_tpu.models.from_config_path`); beside Piper's keys
 (``audio``, ``espeak``, ``inference``, ``phoneme_id_map``, ``model`` with
-the generator's sizes) it holds ``backbone`` (the LFM2 ``config.json``) and
-``units``: ``first_id`` (ids below it are phoneme ids, the others units),
-``stop_id`` and ``frames_per_id``.
+the generator's sizes) it holds ``backbone`` (the backbone's
+``config.json``) and ``units``: ``first_id`` (ids below it are phoneme ids,
+the others units), ``stop_id`` and ``frames_per_id``; for ``sdar_moe`` also
+``mask_id``, ``block_length`` and ``denoising_steps`` (default 4).
 
-**The length rule.**  A row decodes exactly ``round(frames_per_id *
-length_scale * ids)`` units: the stop unit is compared every step
-(``cache["stops"]``) but suppressed below that count and forced at it.
-Sampling is temperature ``noise_scale`` over the unit ids (0: greedy).
+**The length rule.**  A row gives exactly ``round(frames_per_id *
+length_scale * ids)`` units: the sampler never gives the stop unit, and the
+row ends at that count, which the host knows when the row joins (the
+backbone says how many launches that takes: :meth:`plan`).  Sampling is
+temperature ``noise_scale`` over the unit ids (0: greedy).
 
 **Weights live on the device.**  A voice directory holds real tensors
 (``tensors/<name>.bf16.npy`` / ``.f32.npy`` and ``generator.npz``, loaded
@@ -33,6 +42,7 @@ Every sentence of every request of a voice goes through the voice's one
 
 from __future__ import annotations
 
+import dataclasses
 import json
 import os
 import threading
@@ -51,7 +61,7 @@ from ..serving import tracing
 from ..text.phonemizer import text_to_phonemes
 from ..utils.buckets import FRAME_BUCKETS, TEXT_BUCKETS, bucket_for
 from ..utils.transfer import prefetch_to_host
-from . import decode_opts, lfm2
+from . import decode_opts, lfm2, sdar
 from .config import ModelConfig, SynthesisConfig
 from .serialization import load_params, unflatten_params
 
@@ -89,7 +99,7 @@ def load_tensor(directory: Path, name: str):
     raise FailedToLoadResource(f"no tensor {name!r} under {directory}")
 
 
-def load_weights(directory: Path, cfg: lfm2.Lfm2Config) -> dict:
+def load_weights(directory: Path, backbone) -> dict:
     """A voice directory's weights, one layer at a time."""
     tensors = directory / "tensors"
 
@@ -100,20 +110,228 @@ def load_weights(directory: Path, cfg: lfm2.Lfm2Config) -> dict:
         if not names:
             raise FailedToLoadResource(f"no tensors of layer {i} under "
                                        f"{tensors}")
-        return lfm2.pack_layer(unflatten_params(
+        return backbone.pack_layer(unflatten_params(
             {name.replace(".", "/"): load_tensor(tensors, prefix + name)
              for name in names}))
 
     generator = directory / "generator.npz"
     if not generator.exists():
         raise FailedToLoadResource(f"no generator weights at {generator}")
+    params = {
+        "embed": load_tensor(tensors, "embed"),
+        "norm_f": load_tensor(tensors, "norm_f").astype(jnp.float32),
+        "layers": [layer(i) for i in range(backbone.layers)]}
+    if not backbone.cfg.tie_word_embeddings:
+        params["head"] = load_tensor(tensors, "head")
     return {
-        "backbone": {
-            "embed": load_tensor(tensors, "embed"),
-            "norm_f": load_tensor(tensors, "norm_f").astype(jnp.float32),
-            "layers": [layer(i) for i in range(len(cfg.layer_types))]},
+        "backbone": params,
         "unit_table": load_tensor(tensors, "unit_table"),
         "generator": jax.device_put(load_params(generator))}
+
+
+@dataclasses.dataclass(frozen=True)
+class RowPlan:
+    """What a row will ask of the step loop, known when it joins: the loop
+    counts the row's launches and reads nothing back to decide one.  A row
+    runs ``block`` positions a launch and moves on by ``block`` units every
+    ``passes`` launches."""
+
+    launches: int           #: step programs the row lives through
+    budget: int             #: units the row is given
+    block: int = 1
+    passes: int = 1
+    first_units: int = 0    #: units it holds before its first launch
+    first_attended: int = 0     #: positions its first launch attends over
+
+    def units(self, done: int) -> int:
+        """Units the row holds after ``done`` launches."""
+        return min(self.budget, max(
+            0, self.first_units + done // self.passes * self.block))
+
+    def attended(self, done: int) -> int:
+        """Positions launch number ``done`` (from 0) attends over."""
+        return self.first_attended + done // self.passes * self.block
+
+    def commits(self, done: int) -> bool:
+        """Whether launch number ``done`` is the last pass over a block."""
+        return done % self.passes == self.passes - 1
+
+
+class Lfm2Backbone:
+    """``lfm2_moe``: the prefill samples a row's first unit, and every step
+    gives every live row one more."""
+
+    block_length, denoising_steps = 1, 0
+    pack_layer = staticmethod(lfm2.pack_layer)
+
+    def __init__(self, backbone: dict, units: dict, seed: int):
+        self.cfg = lfm2.Lfm2Config.from_dict(backbone)
+        self.units = lfm2.UnitIds(int(units["first_id"]),
+                                  int(units["stop_id"]))
+        self.layers = len(self.cfg.layer_types)
+        self.seed = seed
+
+    def new_cache(self, slots: int, positions: int) -> dict:
+        return lfm2.new_cache(self.cfg, slots, positions)
+
+    def positions_needed(self, n_ids: int, budget: int) -> int:
+        return n_ids + budget - 1
+
+    def plan(self, n_ids: int, budget: int) -> RowPlan:
+        return RowPlan(launches=budget - 1, budget=budget, first_units=1,
+                       first_attended=n_ids + 1)
+
+    def dumped(self, plan: RowPlan, done: int) -> bool:
+        """Whether a flagged row keeps what launch number ``done`` gave:
+        every 32nd unit and the last (launch ``d`` gives unit ``d + 1``)."""
+        return (done + 1) % 32 == 0 or done == plan.launches - 1
+
+    def build_step(self):
+        cfg, units, seed = self.cfg, self.units, self.seed
+
+        def lfm2_step(params, cache, live, temperature, step_no):
+            cache, logits, load = lfm2.step(
+                params, cache, live, temperature, step_no, cfg=cfg,
+                units=units, seed=seed)
+            return cache, (logits,), load
+
+        return jax.jit(lfm2_step, donate_argnums=(1,))
+
+    def build_prefill(self):
+        cfg, units, seed = self.cfg, self.units, self.seed
+
+        def lfm2_prefill(params, cache, ids, n, slot, temperature, row_no):
+            key = jax.random.fold_in(jax.random.PRNGKey(seed + 1), row_no)
+            cache, logits, load = lfm2.prefill(
+                params, cache, ids, n, slot, temperature, key, cfg=cfg,
+                units=units)
+            return cache, (logits,), load
+
+        return jax.jit(lfm2_prefill, donate_argnums=(1,))
+
+    def units_of(self, cache, n_ids: int) -> tuple:
+        """The array a row's units lie in and where they start."""
+        return cache["units"], 0
+
+    def record(self, cache, slot: int) -> tuple:
+        return cache["units"][slot], cache["routes"][slot]
+
+    def dump(self, ids: list, budget: int, kept: list, record) -> dict:
+        """Every unit chosen, the experts every token chose, and the
+        float32 logits over the whole vocabulary behind the units of
+        ``logit_units`` (the prefill gave unit 0, launch ``d`` unit
+        ``d + 1``)."""
+        units, routes = record
+        return {"units": units[:budget],
+                "routes": routes[:len(ids) + budget - 1],
+                "logit_units": np.asarray([d + 1 for d, _ in kept], np.int32),
+                "logits": np.stack([a[0] for _, a in kept])}
+
+
+class SdarBackbone:
+    """``sdar_moe``: the prefill keeps the prompt's whole blocks, and every
+    ``denoising_steps + 1`` passes give every live row a block of units."""
+
+    pack_layer = staticmethod(sdar.pack_layer)
+    #: a flagged row's logits are ``[B, V]`` a pass: every sixteenth block
+    DUMP_EVERY = 16
+
+    def __init__(self, backbone: dict, units: dict, seed: int):
+        self.cfg = sdar.SdarConfig.from_dict(backbone)
+        self.units = lfm2.UnitIds(int(units["first_id"]),
+                                  int(units["stop_id"]),
+                                  int(units["mask_id"]))
+        self.schedule = sdar.Schedule(
+            int(units["block_length"]),
+            int(units.get("denoising_steps", 4)), self.units.mask_id)
+        self.block_length = self.schedule.block_length
+        self.denoising_steps = self.schedule.denoising_steps
+        self.layers = self.cfg.num_hidden_layers
+        self.seed = seed
+
+    def new_cache(self, slots: int, positions: int) -> dict:
+        return sdar.new_cache(self.cfg, slots, positions)
+
+    def _blocks(self, n_ids: int, budget: int) -> int:
+        """Blocks a row generates: the first one opens with the prompt's
+        last ``n mod B`` ids, the last one may run past the budget."""
+        b = self.block_length
+        return -(-(n_ids % b + budget) // b)
+
+    def positions_needed(self, n_ids: int, budget: int) -> int:
+        b = self.block_length
+        return n_ids // b * b + self._blocks(n_ids, budget) * b
+
+    def plan(self, n_ids: int, budget: int) -> RowPlan:
+        b = self.block_length
+        return RowPlan(
+            launches=self._blocks(n_ids, budget) * self.schedule.passes,
+            budget=budget, block=b, passes=self.schedule.passes,
+            first_units=-(n_ids % b), first_attended=n_ids // b * b + b)
+
+    def dumped(self, plan: RowPlan, done: int) -> bool:
+        """Whether a flagged row keeps what launch number ``done`` gave:
+        every pass of its first block, of its last, and of every
+        :data:`DUMP_EVERY`-th between."""
+        block = done // plan.passes
+        return block % self.DUMP_EVERY == 0 \
+            or block == (plan.launches - 1) // plan.passes
+
+    def build_step(self):
+        cfg, schedule, units, seed = (self.cfg, self.schedule, self.units,
+                                      self.seed)
+
+        def sdar_pass(params, cache, live, temperature, step_no):
+            return sdar.block_pass(params, cache, live, temperature, step_no,
+                                   cfg=cfg, schedule=schedule, units=units,
+                                   seed=seed)
+
+        return jax.jit(sdar_pass, donate_argnums=(1,))
+
+    def build_prefill(self):
+        cfg, schedule = self.cfg, self.schedule
+
+        def sdar_prefill(params, cache, ids, n, slot, temperature, row_no):
+            cache, load = sdar.prefill(params, cache, ids, n, slot, cfg=cfg,
+                                       schedule=schedule)
+            return cache, None, load
+
+        return jax.jit(sdar_prefill, donate_argnums=(1,))
+
+    def units_of(self, cache, n_ids: int) -> tuple:
+        return cache["tokens"], n_ids
+
+    def record(self, cache, slot: int) -> tuple:
+        return (cache["tokens"][slot], cache["routes"][slot],
+                cache["unmasked_at"][slot])
+
+    def dump(self, ids: list, budget: int, kept: list, record) -> dict:
+        """The row's tokens as committed (prompt, units, the last block's
+        surplus), the experts every position chose in its commit pass, the
+        pass at which every position was unmasked, and for the launches of
+        ``passes`` the block as it went in, its float32 logits ``[B, V]``
+        and the experts it chose."""
+        tokens, routes, unmasked_at = record
+        t = self.positions_needed(len(ids), budget)
+        return {"tokens": tokens[:t], "routes": routes[:t],
+                "unmasked_at": unmasked_at[:t],
+                "passes": np.asarray([d for d, _ in kept], np.int32),
+                "seen": np.stack([a[0] for _, a in kept]),
+                "logits": np.stack([a[1] for _, a in kept]),
+                "pass_routes": np.stack([a[2] for _, a in kept]),
+                "block_length": np.int32(self.block_length),
+                "denoising_steps": np.int32(self.denoising_steps)}
+
+
+BACKBONES = {"lfm2_moe": Lfm2Backbone, "sdar_moe": SdarBackbone}
+
+
+def make_backbone(backbone: dict, units: dict, seed: int = 0):
+    kind = backbone.get("model_type")
+    if kind not in BACKBONES:
+        raise ValueError(f"model_type {kind!r} is not one of "
+                         f"{sorted(BACKBONES)}")
+    return BACKBONES[kind](backbone, units, seed)
 
 
 class UnitVoice(BaseModel):
@@ -124,12 +342,14 @@ class UnitVoice(BaseModel):
                  weights: dict, *, seed: int = 0):
         self.config = config
         self.hp = config.hyper
-        self.cfg = lfm2.Lfm2Config.from_dict(backbone)
-        self.units = lfm2.UnitIds(int(units["first_id"]),
-                                  int(units["stop_id"]))
+        self.backbone = make_backbone(backbone, units, int(seed))
+        self.cfg, self.units = self.backbone.cfg, self.backbone.units
+        self.block_length = self.backbone.block_length
+        self.denoising_steps = self.backbone.denoising_steps
         self.frames_per_id = float(units["frames_per_id"])
-        if not 0 < self.units.first_id <= self.units.stop_id \
-                < self.cfg.vocab_size:
+        vocab, mask = self.cfg.vocab_size, self.units.mask_id
+        if not 0 < self.units.first_id <= self.units.stop_id < vocab or not (
+                mask is None or self.units.first_id <= mask < vocab):
             raise OperationError(f"units {units} do not split a vocabulary "
                                  f"of {self.cfg.vocab_size}")
         # the two sizes every program's shape hangs on: the operator's,
@@ -146,7 +366,6 @@ class UnitVoice(BaseModel):
             raise OperationError(
                 f"unit table {self.unit_table.shape} is not vocabulary x "
                 f"latent ({self.cfg.vocab_size}, {self.hp.inter_channels})")
-        self._seed = int(seed)
         self._synth_lock = threading.Lock()
         self._synth_config = config.inference.copy()
         self._jit_lock = threading.Lock()
@@ -174,14 +393,14 @@ class UnitVoice(BaseModel):
         config = ModelConfig.from_dict(data, path=path)
         try:
             backbone, units = data["backbone"], data["units"]
-            cfg = lfm2.Lfm2Config.from_dict(backbone)
+            built = make_backbone(backbone, units)
         except (KeyError, ValueError) as e:
             raise FailedToLoadResource(
                 f"{path} is not a unit voice: {type(e).__name__}: {e}") from e
         with _PLACED_LOCK:
             weights = _PLACED.pop(str(path.resolve()), None)
         if weights is None:
-            weights = load_weights(path.parent, cfg)
+            weights = load_weights(path.parent, built)
         return cls(config, backbone, units, weights, seed=seed)
 
     # -- Model protocol ------------------------------------------------------
@@ -244,7 +463,8 @@ class UnitVoice(BaseModel):
             if max(ids) >= self.units.first_id:
                 raise OperationError("a phoneme id lies among the unit ids "
                                      f"(>= {self.units.first_id})")
-            if len(ids) + budget - 1 > self.positions:
+            if self.backbone.positions_needed(len(ids), budget) \
+                    > self.positions:
                 raise OperationError(
                     f"a sentence of {len(ids)} phoneme ids and {budget} "
                     f"frames does not fit a slot of {self.positions} "
@@ -312,7 +532,8 @@ class UnitVoice(BaseModel):
         return (below[-1] if below else 0) + 1
 
     def _fits(self, n_ids: int) -> bool:
-        return n_ids + self.frame_budget(n_ids) - 1 <= self.positions
+        return self.backbone.positions_needed(
+            n_ids, self.frame_budget(n_ids)) <= self.positions
 
     def warm_shape(self, shape: tuple) -> None:
         """Compile one program of :meth:`lattice_shapes`: a dummy dispatch
@@ -325,12 +546,17 @@ class UnitVoice(BaseModel):
             out = self.prefill(cache, 0, [0] * self._fewest_ids(shape[1]),
                                0.0)[:3]
         else:
-            out = self.vocode(cache, 0, shape[1])[0]
+            out = self.vocode(cache, 0, 1, shape[1])[0]
         jax.block_until_ready(out)
 
     # -- the step loop's engine ------------------------------------------------
     def new_cache(self) -> dict:
-        return lfm2.new_cache(self.cfg, self.slots, self.positions)
+        return self.backbone.new_cache(self.slots, self.positions)
+
+    def plan(self, n_ids: int, budget: int) -> RowPlan:
+        """What a row of ``n_ids`` prompt ids and ``budget`` units will ask
+        of the loop."""
+        return self.backbone.plan(n_ids, budget)
 
     def _program(self, key: tuple, build):
         """The jitted program of ``key``, built once."""
@@ -348,30 +574,15 @@ class UnitVoice(BaseModel):
             self._used.add(shape)
         return "cached" if seen else "cold"
 
-    def _build_step(self):
-        cfg, units, seed = self.cfg, self.units, self._seed
-
-        def lfm2_step(params, cache, live, temperature, step_no):
-            return lfm2.step(params, cache, live, temperature, step_no,
-                             cfg=cfg, units=units, seed=seed)
-
-        return jax.jit(lfm2_step, donate_argnums=(1,))
-
-    def _build_prefill(self):
-        cfg, units, seed = self.cfg, self.units, self._seed
-
-        def lfm2_prefill(params, cache, ids, n, slot, temperature, row_no):
-            key = jax.random.fold_in(jax.random.PRNGKey(seed + 1), row_no)
-            return lfm2.prefill(params, cache, ids, n, slot, temperature,
-                                key, cfg=cfg, units=units)
-
-        return jax.jit(lfm2_prefill, donate_argnums=(1,))
-
     def _build_vocode(self, frames: int):
         hp = self.hp
 
-        def unit_vocode(generator, unit_table, units, slot, count):
-            row = jax.lax.dynamic_slice(units, (slot, 0), (1, frames))
+        def unit_vocode(generator, unit_table, units, slot, start, count):
+            # a gather, not a dynamic_slice: a row's units may start behind
+            # its prompt, and a slice of ``frames`` from there may run past
+            # the row's end, where XLA would move the slice's start back
+            at = jnp.minimum(start + jnp.arange(frames), units.shape[1] - 1)
+            row = units[slot][at][None]
             valid = (jnp.arange(frames) < count)[None, :, None]
             z = jnp.where(valid, unit_table[row], 0.0)
             return decode_opts.decode_quantize(
@@ -380,7 +591,10 @@ class UnitVoice(BaseModel):
         return jax.jit(unit_vocode)
 
     def step(self, cache, live, temperature, step_no: int):
-        fn = self._program(("step",), self._build_step)
+        """One launch over every slot.  Returns the cache, what a flagged
+        row keeps of it (a tuple of arrays by slot) and the expert layers'
+        load."""
+        fn = self._program(("step",), self.backbone.build_step)
         return fn(self.params, cache, live, temperature, np.int32(step_no))
 
     def prefill(self, cache, slot: int, ids: list, temperature: float):
@@ -388,23 +602,24 @@ class UnitVoice(BaseModel):
         padded = np.zeros((t,), np.int32)
         padded[:len(ids)] = ids
         # one jitted function: a text bucket is a shape of its argument
-        fn = self._program(("prefill",), self._build_prefill)
+        fn = self._program(("prefill",), self.backbone.build_prefill)
         shape = {"text_bucket": t,
                  "compile": self._first_use(("prefill", t))}
         self._prefill_no += 1
-        cache, logits, load = fn(
+        cache, out, load = fn(
             self.params, cache, padded, np.int32(len(ids)), np.int32(slot),
             np.float32(temperature), np.int32(self._prefill_no))
-        return cache, logits, load, shape
+        return cache, out, load, shape
 
-    def vocode(self, cache, slot: int, units: int):
+    def vocode(self, cache, slot: int, n_ids: int, units: int):
         """The vocoder program of one retired row, enqueued: the slot's
         units through the unit table and the generator, at the row's
         frame bucket."""
         f = min(bucket_for(units, FRAME_BUCKETS), self.positions)
         fn = self._program(("vocode", f), lambda: self._build_vocode(f))
-        out = fn(self.generator, self.unit_table, cache["units"],
-                 np.int32(slot), np.int32(units))
+        held, start = self.backbone.units_of(cache, n_ids)
+        out = fn(self.generator, self.unit_table, held, np.int32(slot),
+                 np.int32(start), np.int32(units))
         prefetch_to_host(out)
         return out, {"batch_bucket": 1, "frames_bucket": f,
                      "compile": self._first_use(("vocode", f))}
@@ -421,13 +636,27 @@ class UnitVoice(BaseModel):
         return wav_i16[0, :int(wav_lengths[0])].astype(np.float32) * (
             peak / 32767.0)
 
+    def dumped(self, plan: RowPlan, done: int) -> bool:
+        """Whether a flagged row keeps what its launch number ``done``
+        gave: the dump thins by the backbone's rule."""
+        return self.backbone.dumped(plan, done)
+
     def row_record(self, cache, slot: int):
-        out = (cache["units"][slot], cache["routes"][slot])
+        out = self.backbone.record(cache, slot)
         for a in out:
             a.copy_to_host_async()
         return out
 
-    def take_rows(self, logits, rows: list):
+    def take_rows(self, kept: tuple, rows: list):
+        """The slots ``rows`` of what a launch gave (:meth:`step`)."""
         fn = self._program(("take",), lambda: jax.jit(
-            lambda logits, rows: logits[rows]))
-        return fn(logits, np.asarray(rows, np.int32))
+            lambda kept, rows: tuple(a[rows] for a in kept)))
+        return fn(kept, np.asarray(rows, np.int32))
+
+    def dump(self, ids: list, budget: int, kept: list, record) -> dict:
+        """What the timed path produced for a flagged row, as arrays: the
+        prompt, what the backbone recorded of the row, and what the kept
+        launches gave (``kept``: ``(launch, arrays)``, -1 the prefill)."""
+        record = tuple(np.asarray(a) for a in record)
+        return dict(self.backbone.dump(ids, budget, kept, record),
+                    ids=np.asarray(ids, np.int32))

@@ -684,6 +684,8 @@ class StepStats:
     def __init__(self):
         self._lock = threading.Lock()
         self.steps = 0
+        self.units = 0
+        self.row_passes = {"denoise": 0, "commit": 0}
         self.slot_steps = {"live": 0, "empty": 0}
         self.prefill_tokens = 0
         self.rows = {"admitted": 0, "retired": 0}
@@ -698,6 +700,9 @@ class StepStats:
         """A group of steps (the attributes of its ``dispatch`` span)."""
         with self._lock:
             self.steps += group["steps"]
+            self.units += group["units"]
+            self.row_passes["denoise"] += group["denoise_row_passes"]
+            self.row_passes["commit"] += group["commit_row_passes"]
             self.slot_steps["live"] += group["live_slot_steps"]
             self.slot_steps["empty"] += (group["steps"] * group["slots"]
                                          - group["live_slot_steps"])
@@ -707,10 +712,13 @@ class StepStats:
                             group["experts_touched"],
                             group["max_expert_assignments"])
 
-    def record_prefill(self, tokens: int, layers, loads) -> None:
-        """One row admitted: its prompt's tokens and what they chose."""
+    def record_prefill(self, tokens: int, layers, loads,
+                       units: int = 0) -> None:
+        """One row admitted: its prompt's tokens, what they chose, and the
+        units the prefill itself gave the row."""
         with self._lock:
             self.prefill_tokens += tokens
+            self.units += units
             self.rows["admitted"] += 1
             self._add_loads(layers, [int(l[2]) for l in loads],
                             [int(l[0]) for l in loads],
@@ -737,12 +745,29 @@ class StepStats:
         registry.counter(
             "sonata_ar_steps_total",
             "Step programs run by step-wise generation loops (one step "
-            "advances every slot by one token)."
+            "runs every slot's current block: one token, or one pass over "
+            "block_length positions)."
         ).set_function(lambda: float(self.steps))
+        registry.counter(
+            "sonata_ar_units_total",
+            "Units rows were left with by prefill and step programs (a "
+            "step is not a unit: a backbone that denoises blocks gives a "
+            "block of units every few steps, and none between)."
+        ).set_function(lambda: float(self.units))
+        row_passes = registry.counter(
+            "sonata_diff_row_passes_total",
+            "Live rows' launches of step programs, by phase: commit (the "
+            "last pass over a block: its units are the row's, its keys and "
+            "values stay; every launch of a backbone that decodes token by "
+            "token) or denoise (a pass that leaves the block unfinished).")
+        for phase in ("denoise", "commit"):
+            row_passes.labels(phase=phase).set_function(
+                lambda p=phase: float(self.row_passes[p]))
         slot_steps = registry.counter(
             "sonata_ar_slot_steps_total",
             "Slots computed by step programs, by state: live (a row's "
-            "token) or empty (masked padding of the static shape).")
+            "block: one token, or block_length positions) or empty (masked "
+            "padding of the static shape).")
         for state in ("live", "empty"):
             slot_steps.labels(state=state).set_function(
                 lambda s=state: float(self.slot_steps[s]))

@@ -1,26 +1,32 @@
 """Step-wise generation: one loop per voice over a fixed set of slots.
 
 :class:`~sonata_tpu.synth.batching.IterationLoop` batches independent
-window decodes and keeps nothing between iterations.  An autoregressive
-voice is the other case: a row (one sentence) lives for hundreds of steps,
-every step yields one unit a row and no audio, and what carries a row from
-step to step (keys and values, convolution columns) stays on the device in
-the slot the row was given.  The loop, on a thread of its own:
+window decodes and keeps nothing between iterations.  A unit voice is the
+other case: a row (one sentence) lives for hundreds of launches of one step
+program, no launch yields audio, and what carries a row from launch to
+launch (keys and values, convolution columns, a block half denoised) stays
+on the device in the slot the row was given.  The loop, on a thread of its
+own:
 
 1. **admit**: waiting rows take free slots, one prefill program each (per
-   arrival: the prompt runs whole, writes the slot's state and samples the
-   row's first unit);
+   arrival: the prompt runs whole and writes the slot's state);
 2. **step**: one program over all ``S`` slots, a static shape, empty slots
    masked and counted;
-3. **retire**: a row leaves when it has its frame budget of units; its
-   units go to the vocoder program (enqueued, not awaited: a finisher
-   thread fetches the audio and resolves the row's future), its slot is
-   free for the next admit.
+3. **retire**: a row leaves after its last launch; its units go to the
+   vocoder program (enqueued, not awaited: a finisher thread fetches the
+   audio and resolves the row's future), its slot is free for the next
+   admit.
 
-The host reads nothing back to decide a launch: a row's length is known
-when it joins, so liveness is counted, not fetched.  The loop keeps one step
-queued behind the running one and waits for the step before (its expert
-load, a few numbers), which bounds the run-ahead and times the steps.
+**A launch is not a unit.**  What a launch leaves a row with is the
+engine's to say, once, when the row joins (``engine.plan(ids, budget)``: the
+launches the row lives, the units it holds after each, the positions each
+attends over, which launches finish a block): one unit a launch for a
+backbone that decodes token by token, a block of units every few launches
+and nothing between for one that denoises blocks.  So the host still reads
+nothing back to decide a launch: liveness is counted, not fetched.  The
+loop keeps one step queued behind the running one and waits for the step
+before (its expert load, a few numbers), which bounds the run-ahead and
+times the steps.
 
 **Slots** (:class:`SlotTable`) are the state manager's host half: which
 slot holds which row.  The device half, the arrays two kinds of state live
@@ -30,10 +36,14 @@ in, belongs to the engine (``new_cache``).
 request: the loop records its steps on a trace it owns (``ar-steps``,
 closed every few seconds), one ``dispatch`` span per group of
 :data:`STEP_GROUP` steps with ``kind: step`` and the group's sums (``steps``,
-``live_slot_steps``, ``slots``, ``kv_positions`` the live rows attended
-over, per expert layer ``assignments``, ``experts_touched`` and
-``max_expert_assignments``, ``host_ms`` by phase).  Each
-prefill and each vocoder launch is a ``dispatch`` span (``kind: prefill`` |
+``live_slot_steps``, ``slots``, ``units`` the live rows were left with,
+``positions`` they ran, ``denoise_row_passes`` and ``commit_row_passes``
+(a row's launch that finishes a block commits), ``kv_positions`` the live
+rows attended over, per expert layer ``assignments``, ``experts_touched``
+and ``max_expert_assignments``, ``host_ms`` by phase) beside the engine's
+``block_length`` and ``denoising_steps``.  Each
+prefill (``blocks`` of the prompt kept whole, ``tail_ids`` left to the
+first generated block) and each vocoder launch is a ``dispatch`` span (``kind: prefill`` |
 ``vocode``) in the trace of the request the row belongs to; both end when
 what their program produced is on the host (a prefill's load, a row's
 samples), and a vocoder's says what the row needed and what it was padded
@@ -43,10 +53,13 @@ its own work after).  The always-on counters are
 :class:`~sonata_tpu.serving.tracing.StepStats`.
 
 The engine (a voice: :class:`~sonata_tpu.models.unit_voice.UnitVoice`)
-gives ``slots``, ``expert_layers``, ``new_cache()``, ``prefill(cache, slot,
-ids, temperature)``, ``step(cache, live, temperature, step_no)``,
-``vocode(cache, slot, units)``, ``wait_audio(out)``, ``fetch_audio(out,
-units)``, ``row_record(cache, slot)`` and ``take_rows(logits, rows)``.
+gives ``slots``, ``expert_layers``, ``block_length``, ``denoising_steps``,
+``new_cache()``, ``plan(n_ids, budget)``, ``prefill(cache, slot, ids,
+temperature)``, ``step(cache, live, temperature, step_no)``, ``vocode(cache,
+slot, n_ids, units)``, ``wait_audio(out)``, ``fetch_audio(out, units)`` and,
+for flagged rows, ``dumped(plan, done)`` (which launches a row keeps),
+``row_record(cache, slot)``, ``take_rows(kept, rows)`` and ``dump(ids,
+budget, kept, record)``.
 """
 
 from __future__ import annotations
@@ -71,11 +84,12 @@ log = logging.getLogger("sonata.steploop")
 STEP_GROUP = 32
 #: the loop's trace is finished (and a new one begun) this often
 TRACE_SECONDS = 4.0
-#: a flagged row's logits are kept at its first unit, its last, and every
-#: this many units between
-DUMP_EVERY = 32
-#: rows whose logits one gather program takes
+#: rows whose launch one gather program takes
 DUMP_ROWS = 8
+
+#: what a launch adds to its group's sums, row by row
+ROW_SUMS = ("live_slot_steps", "units", "positions", "denoise_row_passes",
+            "commit_row_passes", "kv_positions")
 
 DUMP_DIR_ENV = "SONATA_AR_DUMP_DIR"
 DUMP_PREFIX_ENV = "SONATA_AR_DUMP_RID_PREFIX"
@@ -107,20 +121,26 @@ class SlotTable:
 
 class Row:
     """One sentence in flight: ``ids`` the prompt, ``budget`` the units it
-    decodes (its frames), ``temperature`` its sampling."""
+    is given (its frames), ``temperature`` its sampling, ``plan`` what the
+    engine said it will take, ``done`` its launches so far."""
 
-    def __init__(self, ids: list, budget: int, temperature: float):
+    def __init__(self, ids: list, budget: int, temperature: float, plan):
         self.ids = ids
         self.budget = int(budget)
         self.temperature = float(temperature)
+        self.plan = plan
+        self.done = 0
         self.future: Future = Future()
         self.context = tracing.current()
         self.request_id = (self.context[0].request_id
                            if self.context else None)
         self.t_submit = time.monotonic()
         self.slot: Optional[int] = None
-        self.units = 0
-        #: flagged rows only: ``(unit index, gather, row of the gather)``
+        #: flagged rows only: ``[launch, (gathered arrays, row of the
+        #: gather)]`` (-1: the prefill's, row ``None``: the arrays are the
+        #: row's own); the loop moves the arrays to the host as it goes (one
+        #: store of the pair: the finisher may be reading), so that what is
+        #: kept does not fill the device
         self.dump: Optional[list] = None
 
     def span(self, start: float, end: float, **attrs) -> None:
@@ -168,7 +188,8 @@ class StepLoop:
         """A row joins the queue; its future resolves to what the engine's
         ``fetch_audio`` gives once its units have been through the
         vocoder."""
-        row = Row(ids, budget, temperature)
+        row = Row(ids, budget, temperature,
+                  self.engine.plan(len(ids), budget))
         if self._dump_dir is not None and row.request_id and \
                 row.request_id.startswith(self._dump_prefix):
             row.dump = []
@@ -241,37 +262,43 @@ class StepLoop:
                 continue
             live = np.zeros((engine.slots,), bool)
             temperature = np.zeros((engine.slots,), np.float32)
-            attended = 0
+            sums = dict.fromkeys(ROW_SUMS, 0)
             for row in rows:
                 live[row.slot] = True
                 temperature[row.slot] = row.temperature
-                attended += len(row.ids) + row.units
+                commits = row.plan.commits(row.done)
+                sums["live_slot_steps"] += 1
+                sums["positions"] += row.plan.block
+                sums["kv_positions"] += row.plan.attended(row.done)
+                sums["commit_row_passes"] += commits
+                sums["denoise_row_passes"] += not commits
+                sums["units"] += row.plan.units(row.done + 1) \
+                    - row.plan.units(row.done)
             launched = time.monotonic()
-            cache, logits, load = engine.step(cache, live, temperature,
-                                              self._step_no)
+            cache, kept, load = engine.step(cache, live, temperature,
+                                            self._step_no)
             load.copy_to_host_async()
             self._step_no += 1
             t2 = time.perf_counter()
-            due = []
-            for row in rows:
-                row.units += 1
-                if row.dump is not None and (
-                        (row.units - 1) % DUMP_EVERY == 0
-                        or row.units == row.budget):
-                    due.append(row)
+            due = [row for row in rows if row.dump is not None
+                   and engine.dumped(row.plan, row.done)]
+            gathers = []
             for k in range(0, len(due), DUMP_ROWS):
                 part = due[k:k + DUMP_ROWS]
-                got = engine.take_rows(logits, [r.slot for r in part]
+                got = engine.take_rows(kept, [r.slot for r in part]
                                        + [0] * (DUMP_ROWS - len(part)))
-                got.copy_to_host_async()
+                for a in got:
+                    a.copy_to_host_async()
                 for j, row in enumerate(part):
-                    row.dump.append((row.units - 1, got, j))
+                    row.dump.append([row.done, (got, j)])
+                    gathers.append(row.dump[-1])
             for row in rows:
-                if row.units >= row.budget:
+                row.done += 1
+                if row.done >= row.plan.launches:
                     self._retire(cache, row)
             t3 = time.perf_counter()
             self._settle(pending)
-            pending = (load, launched, len(rows), attended,
+            pending = (load, launched, sums, gathers,
                        {"admit": t1 - t0, "launch": t2 - t1,
                         "retire": t3 - t2})
 
@@ -280,25 +307,27 @@ class StepLoop:
         start = time.monotonic()
         slot = self.slots.take(row)
         row.slot = slot
-        cache, logits, load, shape = engine.prefill(cache, slot, row.ids,
-                                                    row.temperature)
-        if row.dump is not None:
-            logits.copy_to_host_async()
-            row.dump.append((0, logits, None))
-        row.units = 1
+        cache, kept, load, shape = engine.prefill(cache, slot, row.ids,
+                                                  row.temperature)
+        if row.dump is not None and kept is not None:
+            for a in kept:
+                a.copy_to_host_async()
+            row.dump.append([-1, (kept, None)])
         load.copy_to_host_async()
+        block = engine.block_length
         self._admitted.append((row, start, dict(
             shape, kind="prefill", rows=1, tokens=len(row.ids), slot=slot,
+            blocks=len(row.ids) // block, tail_ids=len(row.ids) % block,
             wait_ms=round((start - row.t_submit) * 1e3, 3)), load))
         self.stats.slots_in_use = self.slots.in_use
-        if row.units >= row.budget:
+        if row.plan.launches <= 0:
             self._retire(cache, row)
         return cache
 
     def _retire(self, cache, row: Row) -> None:
         engine = self.engine
         start = time.monotonic()
-        out, shape = engine.vocode(cache, row.slot, row.budget)
+        out, shape = engine.vocode(cache, row.slot, len(row.ids), row.budget)
         record = None
         if row.dump is not None:
             record = engine.row_record(cache, row.slot)
@@ -316,25 +345,29 @@ class StepLoop:
         admitted, self._admitted = self._admitted, []
         for row, start, attrs, load in admitted:
             self.stats.record_prefill(attrs["tokens"], self.layers,
-                                      np.asarray(load))
+                                      np.asarray(load), row.plan.units(0))
             row.span(start, time.monotonic(), **attrs)
         if pending is None:
             return
-        load, launched, live, attended, host = pending
+        load, launched, sums, gathers, host = pending
         loads = np.asarray(load)
         now = time.monotonic()
+        for entry in gathers:
+            # gathered behind a step that has finished: to the host, so that
+            # a flagged row's logits do not pile up on the device
+            arrays, j = entry[1]
+            entry[1] = ([np.asarray(a)[j] for a in arrays], None)
         g = self._group
         if g is None:
             g = self._group = {
-                "start": launched, "steps": 0, "live_slot_steps": 0,
-                "kv_positions": 0,
+                "start": launched, "steps": 0, **dict.fromkeys(ROW_SUMS, 0),
                 "assignments": [0] * len(self.layers),
                 "experts_touched": [0] * len(self.layers),
                 "max_expert_assignments": [0] * len(self.layers),
                 "host_ms": dict.fromkeys(tracing.AR_HOST_PHASES, 0.0)}
         g["steps"] += 1
-        g["live_slot_steps"] += live
-        g["kv_positions"] += attended
+        for key, value in sums.items():
+            g[key] += int(value)
         for k in range(len(self.layers)):
             g["experts_touched"][k] += int(loads[k][0])
             g["max_expert_assignments"][k] += int(loads[k][1])
@@ -351,7 +384,9 @@ class StepLoop:
             return
         start = g.pop("start")
         g["host_ms"] = {k: round(v, 3) for k, v in g["host_ms"].items()}
-        g.update(kind="step", slots=self.engine.slots, layers=self.layers)
+        g.update(kind="step", slots=self.engine.slots, layers=self.layers,
+                 block_length=self.engine.block_length,
+                 denoising_steps=self.engine.denoising_steps)
         self.stats.record_steps(g)
         if self._trace is None:
             self._trace = tracing.default_tracer().start_trace(
@@ -399,18 +434,15 @@ class StepLoop:
 
     def _write_dump(self, row: Row, record, shape: dict) -> None:
         """What the timed path produced for a flagged row, for whoever
-        holds it against a reference: the prompt, every unit chosen, the
-        experts every token chose, and the float32 logits over the whole
-        vocabulary behind the units of ``logit_units``."""
-        units, routes = (np.asarray(a) for a in record)
-        n, count = len(row.ids), row.budget
-        index = [j for j, _, _ in row.dump]
-        logits = np.stack([np.asarray(a) if k is None else np.asarray(a)[k]
-                           for _, a, k in row.dump])
+        holds it against a reference: what the engine makes of the row's
+        record and of the launches kept (``engine.dump``), and the frame
+        bucket the vocoder padded it to."""
+        kept = [(launch, [np.asarray(a) if j is None else np.asarray(a)[j]
+                          for a in arrays])
+                for launch, (arrays, j) in row.dump]
+        arrays = self.engine.dump(row.ids, row.budget, kept, record)
         self._dump_dir.mkdir(parents=True, exist_ok=True)
         path = self._dump_dir / f"{row.request_id}.{id(row):x}.npz"
         with open(path, "wb") as f:
-            np.savez(f, ids=np.asarray(row.ids, np.int32),
-                     units=units[:count], routes=routes[:n + count - 1],
-                     logit_units=np.asarray(index, np.int32), logits=logits,
-                     frames_bucket=np.int32(shape["frames_bucket"]))
+            np.savez(f, frames_bucket=np.int32(shape["frames_bucket"]),
+                     **arrays)
