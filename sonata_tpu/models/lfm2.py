@@ -36,7 +36,10 @@ the sum over the chosen experts *it holds* of weight x expert(u): with
 expert-parallel deployment (the shares of all chips add up to the whole
 layer; nothing stands in for the absent ones).  Only the chosen experts'
 products are computed: the assignments are sorted by expert and go through
-``jax.lax.ragged_dot``, a grouped matmul (on a TPU a Mosaic kernel).
+:func:`~sonata_tpu.ops.grouped_matmul.grouped_matmul`, which means what
+``jax.lax.ragged_dot`` means and off a TPU is it; on a TPU, at the few rows
+an expert a unit voice's programs have, it is this repo's kernel
+(:func:`expert_matmul` says which a program of a given size runs).
 """
 
 from __future__ import annotations
@@ -47,6 +50,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ..ops.grouped_matmul import grouped_matmul, implementation
 
 Params = dict
 BF16 = jnp.bfloat16
@@ -329,9 +334,10 @@ def moe_ffn(u, p, cfg, held: Optional[tuple] = None,
         sizes = jnp.bincount(group, length=count + 1)[:count].astype(
             jnp.int32)
         x = u.astype(BF16)[order // top]
-        a, b = jnp.split(lax.ragged_dot(x, p["w13"], sizes,
+        # rows behind the last group come back as anything: masked below
+        a, b = jnp.split(grouped_matmul(x, p["w13"], sizes,
                                         preferred_element_type=F32), 2, -1)
-        y = lax.ragged_dot((jax.nn.silu(a) * b).astype(BF16), p["w2"], sizes,
+        y = grouped_matmul((jax.nn.silu(a) * b).astype(BF16), p["w2"], sizes,
                            preferred_element_type=F32)
         y = jnp.where(mine[order][:, None],
                       y * weights.reshape(-1)[order][:, None], 0.0)
@@ -341,6 +347,18 @@ def moe_ffn(u, p, cfg, held: Optional[tuple] = None,
         load = jnp.stack([jnp.sum(loads > 0), jnp.max(loads),
                           jnp.sum(loads)]).astype(jnp.int32)
     return out, chosen, load
+
+
+def expert_matmul(cfg, tokens: int, held: Optional[tuple] = None) -> str:
+    """What the expert products of a program over ``tokens`` tokens run on
+    this backend: ``"grouped"`` (this repo's kernel, both products) or
+    ``"ragged_dot"``.  The shapes are :func:`moe_ffn`'s."""
+    rows = tokens * cfg.num_experts_per_tok
+    count = held[1] if held is not None else cfg.num_experts
+    h, i = cfg.hidden_size, cfg.moe_intermediate_size
+    both = {implementation(rows, count, k, n, BF16)
+            for k, n in ((h, 2 * i), (i, h))}
+    return "grouped" if both == {"grouped"} else "ragged_dot"
 
 
 def _ffn_half(h, p, i: int, cfg: Lfm2Config, held, valid, routes: list,

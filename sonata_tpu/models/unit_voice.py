@@ -358,6 +358,10 @@ class UnitVoice(BaseModel):
         self.positions = int(os.environ.get(POSITIONS_ENV)
                              or DEFAULT_POSITIONS)
         self.expert_layers = self.cfg.expert_layers
+        #: what the step program's expert products run (a step group's
+        #: span says it): known from the program's shape, before it is built
+        self.expert_matmul = lfm2.expert_matmul(
+            self.cfg, self.slots * self.block_length)
         self.params = weights["backbone"]
         self.unit_table = weights["unit_table"]
         self.generator = {"dec": weights["generator"]["dec"]}
@@ -604,6 +608,7 @@ class UnitVoice(BaseModel):
         # one jitted function: a text bucket is a shape of its argument
         fn = self._program(("prefill",), self.backbone.build_prefill)
         shape = {"text_bucket": t,
+                 "expert_matmul": lfm2.expert_matmul(self.cfg, t),
                  "compile": self._first_use(("prefill", t))}
         self._prefill_no += 1
         cache, out, load = fn(

@@ -1,0 +1,2 @@
+"""Kernels of this repo (Pallas), each in a module with its fallback off
+a TPU: ``grouped_matmul`` (the expert products of a unit voice)."""

@@ -673,6 +673,9 @@ def record_device_group(group: dict, voice: Optional[str] = None) -> None:
 #: device: ``launch`` (the step program's asynchronous call), ``admit``
 #: (prefills enqueued), ``retire`` (vocoder programs enqueued)
 AR_HOST_PHASES = ("launch", "admit", "retire")
+#: what a step or prefill program's expert products run (a span's
+#: ``expert_matmul``)
+EXPERT_MATMULS = ("grouped", "ragged_dot")
 
 
 class StepStats:
@@ -693,6 +696,10 @@ class StepStats:
         #: per expert layer: assignments, distinct experts summed over
         #: steps, the fullest expert's assignments summed over steps
         self.moe: dict = {}
+        #: launches by what their expert products ran and by program
+        self.expert_matmul = {(impl, program): 0
+                              for impl in EXPERT_MATMULS
+                              for program in ("step", "prefill")}
         self.slots_in_use = 0
         self._registry = None
 
@@ -703,6 +710,8 @@ class StepStats:
             self.units += group["units"]
             self.row_passes["denoise"] += group["denoise_row_passes"]
             self.row_passes["commit"] += group["commit_row_passes"]
+            self.expert_matmul[group["expert_matmul"], "step"] += group[
+                "steps"]
             self.slot_steps["live"] += group["live_slot_steps"]
             self.slot_steps["empty"] += (group["steps"] * group["slots"]
                                          - group["live_slot_steps"])
@@ -712,11 +721,13 @@ class StepStats:
                             group["experts_touched"],
                             group["max_expert_assignments"])
 
-    def record_prefill(self, tokens: int, layers, loads,
-                       units: int = 0) -> None:
-        """One row admitted: its prompt's tokens, what they chose, and the
-        units the prefill itself gave the row."""
+    def record_prefill(self, tokens: int, layers, loads, units: int = 0,
+                       expert_matmul: str = "ragged_dot") -> None:
+        """One row admitted: its prompt's tokens, what they chose, the
+        units the prefill itself gave the row, and what its program's
+        expert products ran."""
         with self._lock:
+            self.expert_matmul[expert_matmul, "prefill"] += 1
             self.prefill_tokens += tokens
             self.units += units
             self.rows["admitted"] += 1
@@ -791,6 +802,16 @@ class StepStats:
         for phase in AR_HOST_PHASES:
             host.labels(phase=phase).set_function(
                 lambda p=phase: self.host_s[p])
+        launches = registry.counter(
+            "sonata_moe_expert_matmul_total",
+            "Launches of step and prefill programs, by what their expert "
+            "products run: grouped (this repo's kernel: "
+            "sonata_tpu/ops/grouped_matmul.py) or ragged_dot (XLA's; every "
+            "launch off a TPU, and the shapes the kernel's tile rule "
+            "leaves to it).")
+        for impl, program in self.expert_matmul:
+            launches.labels(impl=impl, program=program).set_function(
+                lambda k=(impl, program): float(self.expert_matmul[k]))
         registry.gauge(
             "sonata_ar_slots_in_use",
             "Slots of step-wise generation loops that hold a row."

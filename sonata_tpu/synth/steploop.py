@@ -41,9 +41,10 @@ closed every few seconds), one ``dispatch`` span per group of
 (a row's launch that finishes a block commits), ``kv_positions`` the live
 rows attended over, per expert layer ``assignments``, ``experts_touched``
 and ``max_expert_assignments``, ``host_ms`` by phase) beside the engine's
-``block_length`` and ``denoising_steps``.  Each
+``block_length``, ``denoising_steps`` and ``expert_matmul`` (``grouped`` |
+``ragged_dot``: what the step program's expert products run).  Each
 prefill (``blocks`` of the prompt kept whole, ``tail_ids`` left to the
-first generated block) and each vocoder launch is a ``dispatch`` span (``kind: prefill`` |
+first generated block, ``expert_matmul`` of its own program) and each vocoder launch is a ``dispatch`` span (``kind: prefill`` |
 ``vocode``) in the trace of the request the row belongs to; both end when
 what their program produced is on the host (a prefill's load, a row's
 samples), and a vocoder's says what the row needed and what it was padded
@@ -54,7 +55,7 @@ its own work after).  The always-on counters are
 
 The engine (a voice: :class:`~sonata_tpu.models.unit_voice.UnitVoice`)
 gives ``slots``, ``expert_layers``, ``block_length``, ``denoising_steps``,
-``new_cache()``, ``plan(n_ids, budget)``, ``prefill(cache, slot, ids,
+``expert_matmul``, ``new_cache()``, ``plan(n_ids, budget)``, ``prefill(cache, slot, ids,
 temperature)``, ``step(cache, live, temperature, step_no)``, ``vocode(cache,
 slot, n_ids, units)``, ``wait_audio(out)``, ``fetch_audio(out, units)`` and,
 for flagged rows, ``dumped(plan, done)`` (which launches a row keeps),
@@ -345,7 +346,8 @@ class StepLoop:
         admitted, self._admitted = self._admitted, []
         for row, start, attrs, load in admitted:
             self.stats.record_prefill(attrs["tokens"], self.layers,
-                                      np.asarray(load), row.plan.units(0))
+                                      np.asarray(load), row.plan.units(0),
+                                      attrs["expert_matmul"])
             row.span(start, time.monotonic(), **attrs)
         if pending is None:
             return
@@ -386,7 +388,8 @@ class StepLoop:
         g["host_ms"] = {k: round(v, 3) for k, v in g["host_ms"].items()}
         g.update(kind="step", slots=self.engine.slots, layers=self.layers,
                  block_length=self.engine.block_length,
-                 denoising_steps=self.engine.denoising_steps)
+                 denoising_steps=self.engine.denoising_steps,
+                 expert_matmul=self.engine.expert_matmul)
         self.stats.record_steps(g)
         if self._trace is None:
             self._trace = tracing.default_tracer().start_trace(

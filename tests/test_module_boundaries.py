@@ -64,6 +64,10 @@ def reaches(imported: set[str], target: str) -> bool:
     # where the two borrowed helpers live now
     ("utils/transfer.py", "sonata_tpu.models", False),
     ("models/decode_opts.py", "sonata_tpu.models.piper", False),
+    # a kernel knows shapes, not who calls it (PR 34)
+    ("ops/grouped_matmul.py", "sonata_tpu.models", False),
+    ("ops/grouped_matmul.py", "sonata_tpu.synth", False),
+    ("ops/grouped_matmul.py", "sonata_tpu.serving", False),
 ])
 def test_module_does_not_import(module, forbidden, module_level_only):
     imported = imports_of(module, module_level_only=module_level_only)
@@ -96,6 +100,14 @@ def test_what_moved_out_of_the_voice_left_no_alias_behind(name):
     assert name not in defined | assigned
 
 
-def test_the_ops_package_is_gone():
-    assert not (PACKAGE / "ops").exists()
-    assert importlib.util.find_spec(f"{PACKAGE.name}.ops") is None
+def test_the_ops_package_holds_the_one_kernel():
+    """PR 30 took the package out with its last kernel; PR 34 brought it
+    back for the expert products' grouped matmul, and nothing else."""
+    assert sorted(p.name for p in (PACKAGE / "ops").glob("*.py")) == [
+        "__init__.py", "grouped_matmul.py"]
+    assert importlib.util.find_spec(f"{PACKAGE.name}.ops") is not None
+    # the package re-exports nothing: a function named as its module
+    # would hide the module
+    tree = ast.parse((PACKAGE / "ops/__init__.py").read_text())
+    assert not [n for n in ast.walk(tree)
+                if isinstance(n, (ast.Import, ast.ImportFrom))]
