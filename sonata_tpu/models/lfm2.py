@@ -10,10 +10,12 @@ tied to the embedding.
 
 The pieces (:func:`mm`, :func:`rms_norm`, :func:`apply_rope`, ``_qkv``,
 :func:`swiglu`, :func:`moe_ffn`, :func:`sample`) also serve the other
-backbones of a unit voice (:mod:`.sdar`).  What differs between them is a
-field of the configuration, read when a program is built: the router's
-scoring (``router_scoring``), ``head_dim`` where it is not ``hidden_size /
-heads``, and a head of its own (``tie_word_embeddings: false``).
+backbones of a unit voice (:mod:`.sdar`, :mod:`.nemotron_h`).  What differs
+between them is a field of the configuration, read when a program is built:
+the router's scoring (``router_scoring``), ``head_dim`` where it is not
+``hidden_size / heads``, a head of its own (``tie_word_embeddings: false``),
+an expert's form (``expert_act``) and, in the layer's weights, a shared
+expert (``p["shared"]``).
 
 Two programs over one set of weights: :func:`prefill` runs one row's prompt
 whole, writes its state into a slot of the cache and samples the row's
@@ -51,7 +53,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.grouped_matmul import grouped_matmul, implementation
+from ..ops.grouped_matmul import grouped_matmul, implementation, lanes
 
 Params = dict
 BF16 = jnp.bfloat16
@@ -84,6 +86,10 @@ class Lfm2Config:
     router_scoring: str = "sigmoid"
     #: false: the head is a matrix of its own (``params["head"]``)
     tie_word_embeddings: bool = True
+    #: an expert's form: ``swiglu`` (``(silu(u w1) * (u w3)) w2``, ``w13``
+    #: the two side by side) or ``relu2`` (``relu(u w_up)^2 w_down``, no
+    #: gate: ``w13`` is ``w_up`` alone)
+    expert_act: str = "swiglu"
 
     @classmethod
     def from_dict(cls, d: dict) -> "Lfm2Config":
@@ -230,15 +236,17 @@ def block_mask(positions, block: int = 1):
     return at[:, None] >= at[None, :]
 
 
-def attn_op_seq(u, p, cfg: Lfm2Config, block: int = 1):
+def attn_op_seq(u, p, cfg, block: int = 1, qkv=_qkv):
     """A row's prompt whole; also its keys and values.  Causal between
-    blocks of ``block`` positions and whole inside one (1: causal)."""
+    blocks of ``block`` positions and whole inside one (1: causal).
+    ``qkv`` makes queries, keys and values of ``u`` (a backbone without
+    head norms or rotary brings its own)."""
     with jax.named_scope("attn_op"):
         t = u.shape[0]
         kv, d = cfg.num_key_value_heads, cfg.head_dim
         g = cfg.num_attention_heads // kv
         pos = jnp.arange(t)
-        q, k, v = _qkv(u, p, cfg, pos)
+        q, k, v = qkv(u, p, cfg, pos)
         q = q.reshape(t, kv, g, d).astype(BF16)
         scores = jnp.einsum("qkgd,pkd->kgqp", q, k,
                             preferred_element_type=F32) / jnp.sqrt(F32(d))
@@ -249,14 +257,14 @@ def attn_op_seq(u, p, cfg: Lfm2Config, block: int = 1):
         return mm(out.reshape(t, -1), p["wo"]), k, v
 
 
-def attn_op_step(u, p, cfg: Lfm2Config, k_cache, v_cache, pos):
+def attn_op_step(u, p, cfg, k_cache, v_cache, pos, qkv=_qkv):
     """One token of every slot at its position ``pos`` ``[S]``, through
     the slots' keys and values ``[S, P, kv, d]``."""
     with jax.named_scope("attn_op"):
         s, span = k_cache.shape[0], k_cache.shape[1]
         kv, d = cfg.num_key_value_heads, cfg.head_dim
         g = cfg.num_attention_heads // kv
-        q, k, v = _qkv(u, p, cfg, pos)
+        q, k, v = qkv(u, p, cfg, pos)
         rows = jnp.arange(s)
         k_cache = k_cache.at[rows, pos].set(k)
         v_cache = v_cache.at[rows, pos].set(v)
@@ -305,16 +313,38 @@ def route(u, p, cfg):
         return chosen, weights * cfg.routed_scaling_factor
 
 
+def _expert_act(up, cfg):
+    """What stands between an expert's two products (``expert_act``)."""
+    if cfg.expert_act == "relu2":
+        return jnp.square(jax.nn.relu(up))
+    a, b = jnp.split(up, 2, axis=-1)
+    return jax.nn.silu(a) * b
+
+
+def pad_experts(w_up, w_down):
+    """Ungated experts ``[E, H, I]``, ``[E, I, H]`` laid out in whole lanes
+    of ``I``: zero columns of ``w_up`` give zeros (``relu(0)^2``) that meet
+    zero rows of ``w_down``, so the layer's result is the same to the last
+    bit, and the products are shapes :func:`grouped_matmul`'s kernel takes
+    where ``I`` itself is not (``ops/grouped_matmul.py`` says why)."""
+    pad = lanes(w_up.shape[-1]) - w_up.shape[-1]
+    return (jnp.pad(w_up, ((0, 0), (0, 0), (0, pad))),
+            jnp.pad(w_down, ((0, 0), (0, pad), (0, 0))))
+
+
 def moe_ffn(u, p, cfg, held: Optional[tuple] = None,
             valid=None):
     """The expert layer over tokens ``u`` ``[N, H]``.
 
     Returns the layer's output (the sum over each token's chosen experts
-    that this layer holds), the experts chosen ``[N, k]``, and the load
-    ``[3]`` over the valid tokens: distinct experts chosen, the most
-    assignments any one expert got, assignments in all.  ``valid`` ``[N]``
-    masks padding and empty slots: they cost no expert product and count
-    for nothing."""
+    that this layer holds and, where the layer has one (``p["shared"]``),
+    the shared expert every token takes), the experts chosen ``[N, k]``,
+    and the load over the valid tokens: distinct experts chosen, the most
+    assignments any one expert got, assignments in all; where ``held`` is
+    given also the distinct experts chosen *among the held* and the
+    assignments that fell on them.  ``valid`` ``[N]`` masks padding and
+    empty slots: they cost no expert product and count for nothing.  An
+    expert's form is the configuration's ``expert_act``."""
     n, top = u.shape[0], cfg.num_experts_per_tok
     first, count = held if held is not None else (0, cfg.num_experts)
     if p["w13"].shape[0] != count:
@@ -335,29 +365,36 @@ def moe_ffn(u, p, cfg, held: Optional[tuple] = None,
             jnp.int32)
         x = u.astype(BF16)[order // top]
         # rows behind the last group come back as anything: masked below
-        a, b = jnp.split(grouped_matmul(x, p["w13"], sizes,
-                                        preferred_element_type=F32), 2, -1)
-        y = grouped_matmul((jax.nn.silu(a) * b).astype(BF16), p["w2"], sizes,
+        up = grouped_matmul(x, p["w13"], sizes, preferred_element_type=F32)
+        y = grouped_matmul(_expert_act(up, cfg).astype(BF16), p["w2"], sizes,
                            preferred_element_type=F32)
         y = jnp.where(mine[order][:, None],
                       y * weights.reshape(-1)[order][:, None], 0.0)
         out = y[jnp.argsort(order)].reshape(n, top, -1).sum(1)
         loads = jnp.bincount(jnp.where(counted, expert, cfg.num_experts),
                              length=cfg.num_experts + 1)[:cfg.num_experts]
-        load = jnp.stack([jnp.sum(loads > 0), jnp.max(loads),
-                          jnp.sum(loads)]).astype(jnp.int32)
+        load = [jnp.sum(loads > 0), jnp.max(loads), jnp.sum(loads)]
+        if held is not None:
+            load += [jnp.sum(sizes > 0), jnp.sum(sizes)]
+        load = jnp.stack(load).astype(jnp.int32)
+    if "shared" in p:
+        with jax.named_scope("shared_expert"):
+            out = out + mm(_expert_act(mm(u, p["shared"]["w_up"]), cfg),
+                           p["shared"]["w_down"])
     return out, chosen, load
 
 
 def expert_matmul(cfg, tokens: int, held: Optional[tuple] = None) -> str:
     """What the expert products of a program over ``tokens`` tokens run on
     this backend: ``"grouped"`` (this repo's kernel, both products) or
-    ``"ragged_dot"``.  The shapes are :func:`moe_ffn`'s."""
+    ``"ragged_dot"``.  The shapes are :func:`moe_ffn`'s (ungated experts
+    lie in whole lanes: :func:`pad_experts`)."""
     rows = tokens * cfg.num_experts_per_tok
     count = held[1] if held is not None else cfg.num_experts
     h, i = cfg.hidden_size, cfg.moe_intermediate_size
+    i, up = (lanes(i),) * 2 if cfg.expert_act == "relu2" else (i, 2 * i)
     both = {implementation(rows, count, k, n, BF16)
-            for k, n in ((h, 2 * i), (i, h))}
+            for k, n in ((h, up), (i, h))}
     return "grouped" if both == {"grouped"} else "ragged_dot"
 
 

@@ -75,6 +75,7 @@ class SdarConfig:
     num_hidden_layers: int
     tie_word_embeddings: bool
     router_scoring: str = "softmax"
+    expert_act: str = "swiglu"
 
     @classmethod
     def from_dict(cls, d: dict) -> "SdarConfig":

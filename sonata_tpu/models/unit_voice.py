@@ -11,9 +11,12 @@ on-device int16 epilogue (:func:`.decode_opts.decode_quantize`).
 (:data:`BACKBONES`): ``lfm2_moe`` (:mod:`.lfm2`) decodes one unit a row a
 step through its key-value cache and convolution state; ``sdar_moe``
 (:mod:`.sdar`) gives a block of units by denoising passes and a commit pass
-over a block that sees itself whole.  Both stand behind the engine surface
-:class:`~sonata_tpu.synth.steploop.StepLoop` names; what differs is in the
-two classes here, and nothing else of the voice forks.
+over a block that sees itself whole; ``nemotron_h`` (:mod:`.nemotron_h`)
+decodes one unit a row a step through Mamba-2 states beside keys and values,
+with the share of each layer's routed experts the chip holds.  All stand
+behind the engine surface :class:`~sonata_tpu.synth.steploop.StepLoop`
+names; what differs is in the classes here, and nothing else of the voice
+forks.
 
 The voice JSON says so with ``"family": "unit_lm"``
 (:func:`sonata_tpu.models.from_config_path`); beside Piper's keys
@@ -61,7 +64,7 @@ from ..serving import tracing
 from ..text.phonemizer import text_to_phonemes
 from ..utils.buckets import FRAME_BUCKETS, TEXT_BUCKETS, bucket_for
 from ..utils.transfer import prefetch_to_host
-from . import decode_opts, lfm2, sdar
+from . import decode_opts, lfm2, nemotron_h, sdar
 from .config import ModelConfig, SynthesisConfig
 from .serialization import load_params, unflatten_params
 
@@ -162,6 +165,10 @@ class Lfm2Backbone:
     gives every live row one more."""
 
     block_length, denoising_steps = 1, 0
+    #: the share of each layer's routed experts held here (None: all), and
+    #: what a slot holds that does not grow with its row: layers with a
+    #: recurrent state, and its bytes a slot
+    held, ssm_layers, ssm_state_bytes = None, 0, 0
     pack_layer = staticmethod(lfm2.pack_layer)
 
     def __init__(self, backbone: dict, units: dict, seed: int):
@@ -232,6 +239,7 @@ class SdarBackbone:
     """``sdar_moe``: the prefill keeps the prompt's whole blocks, and every
     ``denoising_steps + 1`` passes give every live row a block of units."""
 
+    held, ssm_layers, ssm_state_bytes = None, 0, 0
     pack_layer = staticmethod(sdar.pack_layer)
     #: a flagged row's logits are ``[B, V]`` a pass: every sixteenth block
     DUMP_EVERY = 16
@@ -323,7 +331,69 @@ class SdarBackbone:
                 "denoising_steps": np.int32(self.denoising_steps)}
 
 
-BACKBONES = {"lfm2_moe": Lfm2Backbone, "sdar_moe": SdarBackbone}
+class NemotronBackbone(Lfm2Backbone):
+    """``nemotron_h``: a row's launches, units and dump are ``lfm2_moe``'s
+    (the prefill samples a row's first unit, every step gives every live
+    row one more); the programs and what a slot holds are its own."""
+
+    pack_layer = staticmethod(nemotron_h.pack_layer)
+
+    def __init__(self, backbone: dict, units: dict, seed: int):
+        self.cfg = nemotron_h.NemotronConfig.from_dict(backbone)
+        self.units = lfm2.UnitIds(int(units["first_id"]),
+                                  int(units["stop_id"]))
+        self.layers = len(self.cfg.pattern)
+        self.seed = seed
+        self.held = self.cfg.held
+        self.ssm_layers = len(self.cfg.layers_of("M"))
+        self.ssm_state_bytes = self.cfg.ssm_state_bytes
+
+    def new_cache(self, slots: int, positions: int) -> dict:
+        return nemotron_h.new_cache(self.cfg, slots, positions)
+
+    def record(self, cache, slot: int) -> tuple:
+        return (*super().record(cache, slot), cache["ssm"][-1][slot])
+
+    def dump(self, ids: list, budget: int, kept: list, record) -> dict:
+        """``lfm2_moe``'s dump and the recurrent state ``[heads, P, N]`` the
+        row left in the last Mamba layer: what no logit shows apart (the
+        products' bfloat16 inputs cover a state kept in less than
+        float32)."""
+        return dict(super().dump(ids, budget, kept, record[:2]),
+                    state=record[2])
+
+    def prefill_chunks(self, text_bucket: int) -> int:
+        """Chunks the Mamba layers' scans run over a prompt padded to
+        ``text_bucket``."""
+        return -(-text_bucket // self.cfg.chunk_size) * self.ssm_layers
+
+    def build_step(self):
+        cfg, units, seed = self.cfg, self.units, self.seed
+
+        def nemotron_step(params, cache, live, temperature, step_no):
+            cache, logits, load = nemotron_h.step(
+                params, cache, live, temperature, step_no, cfg=cfg,
+                units=units, seed=seed)
+            return cache, (logits,), load
+
+        return jax.jit(nemotron_step, donate_argnums=(1,))
+
+    def build_prefill(self):
+        cfg, units, seed = self.cfg, self.units, self.seed
+
+        def nemotron_prefill(params, cache, ids, n, slot, temperature,
+                             row_no):
+            key = jax.random.fold_in(jax.random.PRNGKey(seed + 1), row_no)
+            cache, logits, load = nemotron_h.prefill(
+                params, cache, ids, n, slot, temperature, key, cfg=cfg,
+                units=units)
+            return cache, (logits,), load
+
+        return jax.jit(nemotron_prefill, donate_argnums=(1,))
+
+
+BACKBONES = {"lfm2_moe": Lfm2Backbone, "sdar_moe": SdarBackbone,
+             "nemotron_h": NemotronBackbone}
 
 
 def make_backbone(backbone: dict, units: dict, seed: int = 0):
@@ -358,10 +428,13 @@ class UnitVoice(BaseModel):
         self.positions = int(os.environ.get(POSITIONS_ENV)
                              or DEFAULT_POSITIONS)
         self.expert_layers = self.cfg.expert_layers
+        self.ssm_layers = self.backbone.ssm_layers
+        #: bytes of recurrent state and convolution columns a slot holds
+        self.ssm_state_bytes = self.backbone.ssm_state_bytes
         #: what the step program's expert products run (a step group's
         #: span says it): known from the program's shape, before it is built
         self.expert_matmul = lfm2.expert_matmul(
-            self.cfg, self.slots * self.block_length)
+            self.cfg, self.slots * self.block_length, self.backbone.held)
         self.params = weights["backbone"]
         self.unit_table = weights["unit_table"]
         self.generator = {"dec": weights["generator"]["dec"]}
@@ -375,6 +448,7 @@ class UnitVoice(BaseModel):
         self._jit_lock = threading.Lock()
         self._programs: dict = {}
         self._used: set = set()
+        self._warm_caches: Optional[threading.Semaphore] = None
         self._prefill_no = 0
         self.scope_voice: Optional[str] = None
         self._loop_lock = threading.Lock()
@@ -511,10 +585,10 @@ class UnitVoice(BaseModel):
 
     # -- warm-up lattice (serving/warmup.py) ---------------------------------
     def lattice_shapes(self, mode: str = "full") -> list:
-        """Every program a request can need: the step, a prefill for each
-        text bucket a slot can hold with its frames, a vocoder for each
-        frame bucket those give (``minimal``: the step alone; the rest
-        compiles on first use).  The whole list, and not what some traffic
+        """Every program a request can need: the step (and the gather of
+        what a flagged row keeps of it), a prefill for each text bucket a
+        slot can hold with its frames, a vocoder for each frame bucket those
+        give (``minimal``: the step alone; the rest compiles on first use).  The whole list, and not what some traffic
         happens to reach, because a first use compiles on the loop's own
         thread: every live row stands still for as long as it takes.  What
         fits a slot bounds it: at 1024 positions the step, 7 prefills and
@@ -541,17 +615,53 @@ class UnitVoice(BaseModel):
 
     def warm_shape(self, shape: tuple) -> None:
         """Compile one program of :meth:`lattice_shapes`: a dummy dispatch
-        on a cache of its own through the jit cache real traffic uses."""
-        cache = self.new_cache()
-        if shape[0] == "step":
-            out = self.step(cache, np.zeros((self.slots,), bool),
-                            np.zeros((self.slots,), np.float32), 0)
-        elif shape[0] == "prefill":
-            out = self.prefill(cache, 0, [0] * self._fewest_ids(shape[1]),
-                               0.0)[:3]
-        else:
-            out = self.vocode(cache, 0, 1, shape[1])[0]
-        jax.block_until_ready(out)
+        through the jit cache real traffic uses.  A step or a prefill runs
+        on a cache of its own, and the warm-up compiles several shapes at
+        once: as many of them hold a cache as the device's free memory
+        takes (:meth:`_warm_cache_slots`).  The vocoder reads a row's units
+        and nothing else of a cache, so it is handed nothing else."""
+        if shape[0] == "vocode":
+            shapes = jax.eval_shape(self.new_cache)
+            units, _ = self.backbone.units_of(shapes, 1)
+            cache = {k: jnp.zeros(v.shape, v.dtype) if v is units else v
+                     for k, v in shapes.items()}
+            jax.block_until_ready(self.vocode(cache, 0, 1, shape[1])[0])
+            return
+        with self._warm_cache_slots():
+            cache = self.new_cache()
+            if shape[0] == "step":
+                from ..synth.steploop import DUMP_ROWS
+
+                out = self.step(cache, np.zeros((self.slots,), bool),
+                                np.zeros((self.slots,), np.float32), 0)
+                # the gather of what flagged rows keep of a launch is a
+                # program too: cold, it compiled on the loop's thread in
+                # front of the first flagged row's step (PERF.md §7)
+                out = (out, self.take_rows(out[1], [0] * DUMP_ROWS))
+            else:
+                out = self.prefill(cache, 0,
+                                   [0] * self._fewest_ids(shape[1]), 0.0)[:3]
+            jax.block_until_ready(out)
+            del cache, out
+
+    def _warm_cache_slots(self) -> threading.Semaphore:
+        """How many warm-up dispatches may hold a cache at once: what the
+        device says is free over a cache's bytes, less one for what a
+        program reserves beside it; at least one, and no bound where the
+        backend reports no memory (the CPU).  A cache of state that does not
+        grow with a row is gigabytes at a few hundred slots: four of them
+        beside the weights do not fit a chip."""
+        with self._jit_lock:
+            if self._warm_caches is None:
+                need = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                           for a in jax.tree_util.tree_leaves(
+                               jax.eval_shape(self.new_cache)))
+                stats = jax.local_devices()[0].memory_stats() or {}
+                free = stats.get("bytes_limit", 0) - stats.get(
+                    "bytes_in_use", 0)
+                fit = max(1, free // max(need, 1) - 1) if free > 0 else 1 << 30
+                self._warm_caches = threading.Semaphore(int(fit))
+            return self._warm_caches
 
     # -- the step loop's engine ------------------------------------------------
     def new_cache(self) -> dict:
@@ -608,8 +718,11 @@ class UnitVoice(BaseModel):
         # one jitted function: a text bucket is a shape of its argument
         fn = self._program(("prefill",), self.backbone.build_prefill)
         shape = {"text_bucket": t,
-                 "expert_matmul": lfm2.expert_matmul(self.cfg, t),
+                 "expert_matmul": lfm2.expert_matmul(self.cfg, t,
+                                                     self.backbone.held),
                  "compile": self._first_use(("prefill", t))}
+        if self.ssm_layers:
+            shape["ssm_chunks"] = self.backbone.prefill_chunks(t)
         self._prefill_no += 1
         cache, out, load = fn(
             self.params, cache, padded, np.int32(len(ids)), np.int32(slot),

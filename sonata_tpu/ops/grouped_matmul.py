@@ -18,6 +18,17 @@ the whole row tile and keeps the rows that are the group's.
 
 Which tiles, and whether the kernel runs at all, is ``tile_rule``: a pure
 function of the shape.  Off a TPU the function *is* ``lax.ragged_dot``.
+
+**Widths the lanes do not divide** (an expert width of 1856 = 14.5 x 128).
+A block that spans a whole dimension needs no multiple of 128, and the
+kernel compiles for a v5e at ``[2688, 1856]``; but XLA keeps an array
+``[G, 2688, 1856]`` with the 2688 minor-most, so as not to pad the lanes, and
+a kernel that wants it row-major is handed a copy of **all** ``G`` matrices
+every call (660 MB of temporaries at 64 groups, compiled for a v5e: PERF.md
+§5), which is the stream this kernel exists to avoid.  So the rule stays:
+such a width gets no tiles, and whoever lays the weights out pads the width
+to whole lanes once (:func:`lanes`; zero columns of an up matrix and zero
+rows of a down matrix add nothing where the form between them maps 0 to 0).
 """
 
 from __future__ import annotations
@@ -41,6 +52,11 @@ VMEM_BUDGET = 40 * 2 ** 20
 #: more than it streams; a shorter one makes more visits and won nothing
 #: at 4 or at 17 rows a group (PERF.md §5)
 ROW_TILE = 128
+
+
+def lanes(n: int) -> int:
+    """``n`` up to a whole number of the 128 lanes."""
+    return -(-n // 128) * 128
 
 
 class Tiles(NamedTuple):

@@ -678,6 +678,13 @@ AR_HOST_PHASES = ("launch", "admit", "retire")
 EXPERT_MATMULS = ("grouped", "ragged_dot")
 
 
+def held_load(load) -> tuple:
+    """Of one expert layer's load (``lfm2.moe_ffn``): the distinct experts
+    chosen among those the chip holds and the assignments that fell on
+    them; a layer that holds every expert reports neither apart."""
+    return (load[3], load[4]) if len(load) > 3 else (load[0], load[2])
+
+
 class StepStats:
     """Process-lifetime counters of step-wise generation (a voice's step
     loop: :mod:`sonata_tpu.synth.steploop`), fed by the loop whether or not
@@ -694,8 +701,11 @@ class StepStats:
         self.rows = {"admitted": 0, "retired": 0}
         self.host_s = dict.fromkeys(AR_HOST_PHASES, 0.0)
         #: per expert layer: assignments, distinct experts summed over
-        #: steps, the fullest expert's assignments summed over steps
+        #: steps, the fullest expert's assignments summed over steps, the
+        #: assignments that fell on experts the chip holds
         self.moe: dict = {}
+        #: bytes of recurrent state the loops' slots hold (all slots)
+        self.ssm_state_resident_bytes = 0
         #: launches by what their expert products ran and by program
         self.expert_matmul = {(impl, program): 0
                               for impl in EXPERT_MATMULS
@@ -719,7 +729,8 @@ class StepStats:
                 self.host_s[phase] += group["host_ms"][phase] / 1e3
             self._add_loads(group["layers"], group["assignments"],
                             group["experts_touched"],
-                            group["max_expert_assignments"])
+                            group["max_expert_assignments"],
+                            group["held_assignments"])
 
     def record_prefill(self, tokens: int, layers, loads, units: int = 0,
                        expert_matmul: str = "ragged_dot") -> None:
@@ -733,17 +744,23 @@ class StepStats:
             self.rows["admitted"] += 1
             self._add_loads(layers, [int(l[2]) for l in loads],
                             [int(l[0]) for l in loads],
-                            [int(l[1]) for l in loads])
+                            [int(l[1]) for l in loads],
+                            [int(held_load(l)[1]) for l in loads])
 
-    def _add_loads(self, layers, assignments, touched, fullest) -> None:
+    def _add_loads(self, layers, assignments, touched, fullest,
+                   held) -> None:
         new = [layer for layer in layers if layer not in self.moe]
-        for layer, a, t, m in zip(layers, assignments, touched, fullest):
-            sums = self.moe.setdefault(layer, [0, 0, 0])
-            sums[0] += a
-            sums[1] += t
-            sums[2] += m
+        for layer, *sums in zip(layers, assignments, touched, fullest, held):
+            totals = self.moe.setdefault(layer, [0, 0, 0, 0])
+            for k, value in enumerate(sums):
+                totals[k] += value
         if new and self._registry is not None:
             self._bind_layers(new)
+
+    def record_resident(self, state_bytes: int) -> None:
+        """A loop's slots were made (or, negative, let go)."""
+        with self._lock:
+            self.ssm_state_resident_bytes += state_bytes
 
     def record_retired(self) -> None:
         with self._lock:
@@ -816,6 +833,13 @@ class StepStats:
             "sonata_ar_slots_in_use",
             "Slots of step-wise generation loops that hold a row."
         ).set_function(lambda: float(self.slots_in_use))
+        registry.gauge(
+            "sonata_ssm_state_resident_bytes",
+            "Bytes of recurrent state and convolution columns the slots of "
+            "step-wise generation loops hold on the device (every slot, "
+            "live or not: such state does not grow with a row; 0 for a "
+            "backbone that has none)."
+        ).set_function(lambda: float(self.ssm_state_resident_bytes))
         self._registry = registry
         with self._lock:
             self._bind_layers(list(self.moe))
@@ -831,7 +855,11 @@ class StepStats:
              "programs (over sonata_ar_steps_total: experts a step reads)."),
             ("sonata_moe_max_expert_assignments_total",
              "Assignments of the fullest expert of an expert layer, summed "
-             "over its programs (over assignments: the load's skew)."))
+             "over its programs (over assignments: the load's skew)."),
+            ("sonata_moe_held_assignments_total",
+             "Assignments of an expert layer that fell on experts this chip "
+             "holds (over assignments: the share of the layer's routed work "
+             "done here; all of them where the chip holds every expert)."))
         for k, (name, text) in enumerate(series):
             metric = r.counter(name, text)
             for layer in layers:

@@ -39,12 +39,16 @@ closed every few seconds), one ``dispatch`` span per group of
 ``live_slot_steps``, ``slots``, ``units`` the live rows were left with,
 ``positions`` they ran, ``denoise_row_passes`` and ``commit_row_passes``
 (a row's launch that finishes a block commits), ``kv_positions`` the live
-rows attended over, per expert layer ``assignments``, ``experts_touched``
-and ``max_expert_assignments``, ``host_ms`` by phase) beside the engine's
-``block_length``, ``denoising_steps`` and ``expert_matmul`` (``grouped`` |
-``ragged_dot``: what the step program's expert products run).  Each
+rows attended over, ``ssm_state_bytes`` of recurrent state the live rows'
+steps read and wrote, per expert layer ``assignments``, ``experts_touched``,
+``max_expert_assignments`` and, of the experts the chip holds,
+``held_assignments`` and ``held_experts_touched``, ``host_ms`` by phase)
+beside the engine's ``block_length``, ``denoising_steps``, ``ssm_layers`` and
+``expert_matmul`` (``grouped`` | ``ragged_dot``: what the step program's
+expert products run).  Each
 prefill (``blocks`` of the prompt kept whole, ``tail_ids`` left to the
-first generated block, ``expert_matmul`` of its own program) and each vocoder launch is a ``dispatch`` span (``kind: prefill`` |
+first generated block, ``expert_matmul`` of its own program, ``ssm_chunks``
+its state-space layers' scans ran) and each vocoder launch is a ``dispatch`` span (``kind: prefill`` |
 ``vocode``) in the trace of the request the row belongs to; both end when
 what their program produced is on the host (a prefill's load, a row's
 samples), and a vocoder's says what the row needed and what it was padded
@@ -55,7 +59,8 @@ its own work after).  The always-on counters are
 
 The engine (a voice: :class:`~sonata_tpu.models.unit_voice.UnitVoice`)
 gives ``slots``, ``expert_layers``, ``block_length``, ``denoising_steps``,
-``expert_matmul``, ``new_cache()``, ``plan(n_ids, budget)``, ``prefill(cache, slot, ids,
+``expert_matmul``, ``ssm_layers``, ``ssm_state_bytes`` (a slot's),
+``new_cache()``, ``plan(n_ids, budget)``, ``prefill(cache, slot, ids,
 temperature)``, ``step(cache, live, temperature, step_no)``, ``vocode(cache,
 slot, n_ids, units)``, ``wait_audio(out)``, ``fetch_audio(out, units)`` and,
 for flagged rows, ``dumped(plan, done)`` (which launches a row keeps),
@@ -157,6 +162,8 @@ class StepLoop:
         self.name = name
         self.slots = SlotTable(engine.slots)
         self.stats = tracing.step_stats()
+        self._resident = engine.slots * engine.ssm_state_bytes
+        self.stats.record_resident(self._resident)
         self.layers = list(engine.expert_layers)
         dump_dir = os.environ.get(DUMP_DIR_ENV)
         self._dump_dir = Path(dump_dir) if dump_dir else None
@@ -210,6 +217,8 @@ class StepLoop:
         with self._cond:
             self._closed = True
             self._cond.notify_all()
+            resident, self._resident = self._resident, 0
+        self.stats.record_resident(-resident)
         self._thread.join(timeout=30.0)
         with self._finish_cond:
             self._finish_cond.notify_all()
@@ -366,6 +375,8 @@ class StepLoop:
                 "assignments": [0] * len(self.layers),
                 "experts_touched": [0] * len(self.layers),
                 "max_expert_assignments": [0] * len(self.layers),
+                "held_assignments": [0] * len(self.layers),
+                "held_experts_touched": [0] * len(self.layers),
                 "host_ms": dict.fromkeys(tracing.AR_HOST_PHASES, 0.0)}
         g["steps"] += 1
         for key, value in sums.items():
@@ -374,6 +385,9 @@ class StepLoop:
             g["experts_touched"][k] += int(loads[k][0])
             g["max_expert_assignments"][k] += int(loads[k][1])
             g["assignments"][k] += int(loads[k][2])
+            touched, assigned = tracing.held_load(loads[k])
+            g["held_experts_touched"][k] += int(touched)
+            g["held_assignments"][k] += int(assigned)
         for phase, seconds in host.items():
             g["host_ms"][phase] += seconds * 1e3
         if g["steps"] >= STEP_GROUP:
@@ -389,7 +403,10 @@ class StepLoop:
         g.update(kind="step", slots=self.engine.slots, layers=self.layers,
                  block_length=self.engine.block_length,
                  denoising_steps=self.engine.denoising_steps,
-                 expert_matmul=self.engine.expert_matmul)
+                 expert_matmul=self.engine.expert_matmul,
+                 ssm_layers=self.engine.ssm_layers,
+                 ssm_state_bytes=(2 * self.engine.ssm_state_bytes
+                                  * g["live_slot_steps"]))
         self.stats.record_steps(g)
         if self._trace is None:
             self._trace = tracing.default_tracer().start_trace(

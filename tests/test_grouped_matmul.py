@@ -134,12 +134,16 @@ STEP_SHAPES = {
     "lfm2_step.w2": (256, 64, 1536, 2048),
     "sdar_pass.w13": (2048, 128, 2048, 1536),
     "sdar_pass.w2": (2048, 128, 768, 2048),
+    # 256 rows x 6 on the 64 held experts; 1856 columns in 1920 lanes
+    "nemotron_step.w13": (1536, 64, 2688, 1920),
+    "nemotron_step.w2": (1536, 64, 1920, 2688),
 }
 PREFILL_SHAPES = {
     f"{name}_prefill{t}.{w}": (t * top, groups, k, n)
     for name, top, groups, shapes in (
         ("lfm2", 4, 64, {"w13": (2048, 3072), "w2": (1536, 2048)}),
-        ("sdar", 8, 128, {"w13": (2048, 1536), "w2": (768, 2048)}))
+        ("sdar", 8, 128, {"w13": (2048, 1536), "w2": (768, 2048)}),
+        ("nemotron", 6, 64, {"w13": (2688, 1920), "w2": (1920, 2688)}))
     for t in (96, 128, 192) for w, (k, n) in shapes.items()}
 SHAPES = {**STEP_SHAPES, **PREFILL_SHAPES}
 
@@ -155,7 +159,9 @@ def test_the_tile_rule_at_the_benchmarks_shapes(name):
         assert tiles is not None
     if tiles is None:
         return
-    assert rows % tiles.tm == 0 and n % tiles.tn == 0
+    # rows no multiple of the tile (a prefill of 96 ids x 6) are padded by
+    # the kernel: a few rows of activations, no weight
+    assert n % tiles.tn == 0 and (rows % tiles.tm == 0 or "nemotron" in name)
     assert tiles.tm % 16 == 0 and tiles.tn % 128 == 0
     assert gm.vmem_bytes(tiles, k, 2, 2) <= gm.VMEM_BUDGET
     assert gm.mxu_seconds(rows, groups, k, n, tiles.tm) <= \
@@ -168,12 +174,17 @@ def test_the_tile_rule_reads_the_shape_alone():
     assert {name: gm.tile_rule(*shape, jnp.bfloat16)
             for name, shape in STEP_SHAPES.items()} == {
         "lfm2_step.w13": Tiles(128, 3072), "lfm2_step.w2": Tiles(128, 2048),
-        "sdar_pass.w13": Tiles(128, 1536), "sdar_pass.w2": Tiles(128, 2048)}
+        "sdar_pass.w13": Tiles(128, 1536), "sdar_pass.w2": Tiles(128, 2048),
+        "nemotron_step.w13": Tiles(128, 1920),
+        "nemotron_step.w2": Tiles(128, 2688)}
     # fewer rows than a tile: all of them, in sublanes of 16
     assert gm.tile_rule(24, 64, 2048, 3072, jnp.bfloat16) == Tiles(32, 3072)
     # many rows a group: no weight stream, XLA's product stays
     assert gm.tile_rule(64 * 1024, 64, 2048, 3072, jnp.bfloat16) is None
-    # widths the lanes do not divide
+    # widths the lanes do not divide: XLA would hand the kernel a copy of
+    # every group's matrix (the module says why); the caller pads to lanes
+    assert gm.tile_rule(1536, 64, 2688, 1856, jnp.bfloat16) is None
+    assert gm.tile_rule(1536, 64, 1856, 2688, jnp.bfloat16) is None
     assert gm.tile_rule(256, 64, 2048, 3000, jnp.bfloat16) is None
     assert gm.tile_rule(256, 64, 100, 3072, jnp.bfloat16) is None
 

@@ -3,8 +3,8 @@
 megablox ``gmm`` that ships with jax, and this repo's kernel at a list of
 tiles, at the shapes the unit voices' programs run.
 
-    python tools/profile_grouped.py [--shapes step|prefill|all] [--probes]
-                                    [--out F]
+    python tools/profile_grouped.py [--shapes step|prefill|all]
+                                    [--only PREFIX] [--probes] [--out F]
 
 Routes are drawn as the cells' seeded routers draw them (PERF.md §5): a few
 experts no row chooses and log-normal weights on the rest, so 61 of 64
@@ -18,7 +18,12 @@ beside two halves of itself: its weights' DMA with the product taken out,
 and its products with every visit on one group (the weights fetched once).
 Such a reading holds about 0.1 ms a product that is not the kernel's (the
 visit list's arithmetic, the outputs' sums): the kernels' own time is in a
-cell's capture (PERF.md §5).  Needs a TPU.
+cell's capture (PERF.md §5).  A shape whose published width the lanes do
+not divide (``nemotron-3-nano-30b-a3b``: 1856 columns laid out in 1920)
+states it: ``ragged_dot@published`` and the kernel with whole-dimension
+blocks (``own@published``) run on arrays of the published width, the other
+candidates on the laid-out ones, all held to the first on the published
+columns, and ``GB/s`` counts the published bytes.  Needs a TPU.
 """
 
 from __future__ import annotations
@@ -40,12 +45,16 @@ from jax import lax
 gm = importlib.import_module("sonata_tpu.ops.grouped_matmul")
 
 REPS = 8
-#: (name, rows, groups, k, n, valid rows, experts no row chooses, sigma)
+#: (name, rows, groups, k, n, valid rows, experts no row chooses, sigma[,
+#: (published k, published n)])
 STEP_SHAPES = (
     ("lfm2_step.w13", 256, 64, 2048, 3072, 256, 1, 0.3),
     ("lfm2_step.w2", 256, 64, 1536, 2048, 256, 1, 0.3),
     ("sdar_pass.w13", 2048, 128, 2048, 1536, 2048, 10, 0.95),
     ("sdar_pass.w2", 2048, 128, 768, 2048, 2048, 10, 0.95),
+    # 256 rows x 6 of which half fall on the 64 held experts
+    ("nemotron_step.w13", 1536, 64, 2688, 1920, 768, 0, 0.3, (2688, 1856)),
+    ("nemotron_step.w2", 1536, 64, 1920, 2688, 768, 0, 0.3, (1856, 2688)),
 )
 #: a prompt of 68-182 ids at its text bucket (96, 128, 192), the mean
 #: prompt's rows valid
@@ -58,6 +67,14 @@ PREFILL_SHAPES = (
     ("sdar_prefill128.w13", 1024, 128, 2048, 1536, 896, 10, 0.95),
     ("sdar_prefill192.w13", 1536, 128, 2048, 1536, 1280, 10, 0.95),
     ("sdar_prefill192.w2", 1536, 128, 768, 2048, 1280, 10, 0.95),
+    ("nemotron_prefill96.w13", 576, 64, 2688, 1920, 246, 0, 0.3,
+     (2688, 1856)),
+    ("nemotron_prefill128.w13", 768, 64, 2688, 1920, 336, 0, 0.3,
+     (2688, 1856)),
+    ("nemotron_prefill192.w13", 1152, 64, 2688, 1920, 480, 0, 0.3,
+     (2688, 1856)),
+    ("nemotron_prefill192.w2", 1152, 64, 1920, 2688, 480, 0, 0.3,
+     (1856, 2688)),
 )
 
 
@@ -136,38 +153,53 @@ def build(name: str, tiles):
 
 def measure(shape: tuple, full: bool, seed: int,
             probes: bool = False) -> list:
-    name, rows, groups, k, n, valid, dead, sigma = shape
+    name, rows, groups, k, n, valid, dead, sigma = shape[:8]
+    k_pub, n_pub = shape[8] if len(shape) > 8 else (k, n)
     rng = np.random.default_rng(seed)
     keys = jax.random.split(jax.random.PRNGKey(seed), 2)
     xs = jax.random.normal(keys[0], (REPS, rows, k), jnp.bfloat16)
     w = jax.random.normal(keys[1], (groups, k, n), jnp.bfloat16) * 0.02
+    cands = candidates(k, n, full, probes)
+    published = {}
+    if (k_pub, n_pub) != (k, n):
+        # zero columns and rows outside the published width, and the same
+        # numbers as arrays of the published width
+        xs = xs * (jnp.arange(k) < k_pub)
+        w = w * (jnp.arange(k) < k_pub)[:, None] * (jnp.arange(n) < n_pub)
+        published = {"xs": jnp.array(xs[..., :k_pub]),
+                     "w": jnp.array(w[:, :k_pub, :n_pub])}
+        cands = [("ragged_dot@published", None),
+                 ("own@published", gm.Tiles(128, n_pub))] + cands
     draws = [jnp.asarray(draw_sizes(rng, valid, groups, dead, sigma))
              for _ in range(2)]
     touched = float(np.mean([int((np.asarray(s) > 0).sum()) for s in draws]))
     fullest = float(np.mean([int(np.asarray(s).max()) for s in draws])) / valid
     rows_out, ref = [], None
-    for cand, tiles in candidates(k, n, full, probes):
+    for cand, tiles in cands:
         line = {"shape": name, "rows": rows, "groups": groups, "k": k,
                 "n": n, "valid": valid, "touched": touched,
                 "fullest_share": fullest, "candidate": cand}
+        if published:
+            line["published"] = [k_pub, n_pub]
+        at = published if cand.endswith("@published") else {"xs": xs, "w": w}
         try:
-            fn = build(cand, tiles)
+            fn = build(cand.split("@")[0], tiles)
             t0 = time.perf_counter()
-            _, first = jax.block_until_ready(fn(xs, w, draws[0]))
+            _, first = jax.block_until_ready(fn(at["xs"], at["w"], draws[0]))
             line["compile_s"] = time.perf_counter() - t0
+            first = first[:valid, :n_pub]
             if ref is None:
                 ref = first
-                line["ref_abs_max"] = float(jnp.max(jnp.abs(ref[:valid])))
-            line["err_max"] = float(jnp.max(jnp.abs(
-                first[:valid] - ref[:valid])))
+                line["ref_abs_max"] = float(jnp.max(jnp.abs(ref)))
+            line["err_max"] = float(jnp.max(jnp.abs(first - ref)))
             times = []
             for i in range(6):
                 t0 = time.perf_counter()
-                jax.block_until_ready(fn(xs, w, draws[i % 2])[0])
+                jax.block_until_ready(fn(at["xs"], at["w"], draws[i % 2])[0])
                 times.append(time.perf_counter() - t0)
             ms = min(times[1:]) * 1e3 / REPS
             line["ms"] = ms
-            line["gb_per_s"] = touched * k * n * 2 / ms / 1e6
+            line["gb_per_s"] = touched * k_pub * n_pub * 2 / ms / 1e6
             line["share_of_819"] = line["gb_per_s"] / 819.0
         except Exception as e:  # a candidate the compiler refuses
             line["error"] = f"{type(e).__name__}: {str(e)[:200]}"
@@ -180,6 +212,8 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--shapes", default="all",
                     choices=("step", "prefill", "all"))
+    ap.add_argument("--only", default="",
+                    help="shapes whose name starts with this alone")
     ap.add_argument("--probes", action="store_true",
                     help="the kernel beside its two probes only: the "
                          "weights' DMA without the product, the products "
@@ -198,7 +232,8 @@ def main() -> int:
         shapes += tuple((s, False) for s in PREFILL_SHAPES)
     lines = []
     for shape, full in shapes:
-        lines += measure(shape, full, args.seed, args.probes)
+        if shape[0].startswith(args.only):
+            lines += measure(shape, full, args.seed, args.probes)
     out = Path(args.out)
     out.parent.mkdir(parents=True, exist_ok=True)
     out.write_text(json.dumps(
