@@ -54,6 +54,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops.grouped_matmul import grouped_matmul, implementation, lanes
+from ..ops.slot_attention import slot_attention, stored_shape, write_rows, \
+    write_slot
 
 Params = dict
 BF16 = jnp.bfloat16
@@ -257,26 +259,20 @@ def attn_op_seq(u, p, cfg, block: int = 1, qkv=_qkv):
         return mm(out.reshape(t, -1), p["wo"]), k, v
 
 
-def attn_op_step(u, p, cfg, k_cache, v_cache, pos, qkv=_qkv):
+def attn_op_step(u, p, cfg, k_buf, v_buf, pos, qkv=_qkv):
     """One token of every slot at its position ``pos`` ``[S]``, through
-    the slots' keys and values ``[S, P, kv, d]``."""
+    the slots' keys and values (``ops/slot_attention.py`` says where they
+    lie)."""
     with jax.named_scope("attn_op"):
-        s, span = k_cache.shape[0], k_cache.shape[1]
+        s = pos.shape[0]
         kv, d = cfg.num_key_value_heads, cfg.head_dim
         g = cfg.num_attention_heads // kv
         q, k, v = qkv(u, p, cfg, pos)
-        rows = jnp.arange(s)
-        k_cache = k_cache.at[rows, pos].set(k)
-        v_cache = v_cache.at[rows, pos].set(v)
-        q = q.reshape(s, kv, g, d).astype(BF16)
-        scores = jnp.einsum("skgd,spkd->skgp", q, k_cache,
-                            preferred_element_type=F32) / jnp.sqrt(F32(d))
-        seen = jnp.arange(span)[None, :] <= pos[:, None]
-        probs = jax.nn.softmax(
-            jnp.where(seen[:, None, None, :], scores, -jnp.inf), -1)
-        out = jnp.einsum("skgp,spkd->skgd", probs.astype(BF16), v_cache,
-                         preferred_element_type=F32)
-        return mm(out.reshape(s, -1), p["wo"]), k_cache, v_cache
+        k_buf = write_rows(k_buf, k[:, None], pos[:, None])
+        v_buf = write_rows(v_buf, v[:, None], pos[:, None])
+        out = slot_attention(q.reshape(s, 1, kv, g, d), k_buf, v_buf,
+                             pos + 1)
+        return mm(out.reshape(s, -1), p["wo"]), k_buf, v_buf
 
 
 def swiglu(u, w13, w2):
@@ -451,8 +447,12 @@ def new_cache(cfg: Lfm2Config, slots: int, positions: int) -> dict:
     """The state of ``slots`` rows of at most ``positions`` tokens: keys
     and values of the attention layers, the convolution layers' columns,
     and per slot the next token, its position, the units sampled so far
-    and the experts every token chose."""
-    kv_shape = (slots, positions, cfg.num_key_value_heads, cfg.head_dim)
+    and the experts every token chose.  What a step writes per slot and
+    position (keys, values, the experts chosen) lies as
+    :func:`~sonata_tpu.ops.slot_attention.stored_shape` says: a position
+    is one row of whole lanes."""
+    kv_shape = stored_shape(slots, positions, cfg.num_key_value_heads,
+                            cfg.head_dim)
     n_attn, n_conv = (len(cfg.layers_of("full_attention")),
                       len(cfg.layers_of("conv")))
     return {
@@ -464,8 +464,9 @@ def new_cache(cfg: Lfm2Config, slots: int, positions: int) -> dict:
         "pos": jnp.zeros((slots,), jnp.int32),
         "count": jnp.zeros((slots,), jnp.int32),
         "units": jnp.zeros((slots, positions), jnp.int32),
-        "routes": jnp.zeros((slots, positions, len(cfg.expert_layers),
-                             cfg.num_experts_per_tok), jnp.int8),
+        "routes": jnp.zeros(stored_shape(
+            slots, positions, len(cfg.expert_layers),
+            cfg.num_experts_per_tok), jnp.int8),
     }
 
 
@@ -491,10 +492,8 @@ def prefill(params: Params, cache: dict, ids, n, slot, temperature, key, *,
             i_conv += 1
         else:
             op, k, v = attn_op_seq(u, p["op"], cfg)
-            cache["k"][i_attn] = lax.dynamic_update_slice(
-                cache["k"][i_attn], k[None], (slot, 0, 0, 0))
-            cache["v"][i_attn] = lax.dynamic_update_slice(
-                cache["v"][i_attn], v[None], (slot, 0, 0, 0))
+            cache["k"][i_attn] = write_slot(cache["k"][i_attn], k, slot)
+            cache["v"][i_attn] = write_slot(cache["v"][i_attn], v, slot)
             i_attn += 1
         h = _ffn_half(h + op, p, i, cfg, held, valid, routes, loads)
     last = lax.dynamic_slice_in_dim(h, n - 1, 1, axis=0)
@@ -504,9 +503,7 @@ def prefill(params: Params, cache: dict, ids, n, slot, temperature, key, *,
     cache["pos"] = cache["pos"].at[slot].set(n)
     cache["count"] = cache["count"].at[slot].set(1)
     cache["units"] = cache["units"].at[slot, 0].set(unit)
-    cache["routes"] = lax.dynamic_update_slice(
-        cache["routes"], jnp.stack(routes, 1).astype(jnp.int8)[None],
-        (slot, 0, 0, 0))
+    cache["routes"] = write_slot(cache["routes"], jnp.stack(routes, 1), slot)
     return cache, logits[0], jnp.stack(loads)
 
 
@@ -541,8 +538,8 @@ def step(params: Params, cache: dict, live, temperature, step_no, *,
     unit = sample(logits, temperature, key, units)
     rows = jnp.arange(live.shape[0])
     span = cache["units"].shape[1]
-    cache["routes"] = cache["routes"].at[rows, pos].set(
-        jnp.stack(routes, 1).astype(jnp.int8))
+    cache["routes"] = write_rows(cache["routes"],
+                                 jnp.stack(routes, 1)[:, None], pos[:, None])
     cache["units"] = cache["units"].at[
         rows, jnp.minimum(cache["count"], span - 1)].set(
         jnp.where(live, unit, 0))

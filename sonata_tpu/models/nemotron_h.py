@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops.slot_attention import stored_shape, write_rows, write_slot
 from .lfm2 import BF16, F32, UnitIds, _head, attn_op_seq, attn_op_step, mm, \
     moe_ffn, pad_experts, rms_norm, sample
 
@@ -306,8 +307,11 @@ def new_cache(cfg: NemotronConfig, slots: int, positions: int) -> dict:
     """The state of ``slots`` rows of at most ``positions`` tokens: keys
     and values of the attention layers, the Mamba layers' recurrent states
     and convolution columns, and per slot the next token, its position, the
-    units sampled so far and the experts every token chose."""
-    kv_shape = (slots, positions, cfg.num_key_value_heads, cfg.head_dim)
+    units sampled so far and the experts every token chose.  Keys, values
+    and the experts chosen lie as
+    :func:`~sonata_tpu.ops.slot_attention.stored_shape` says."""
+    kv_shape = stored_shape(slots, positions, cfg.num_key_value_heads,
+                            cfg.head_dim)
     n_attn, n_ssm = len(cfg.layers_of("*")), len(cfg.layers_of("M"))
     return {
         "k": [jnp.zeros(kv_shape, BF16) for _ in range(n_attn)],
@@ -320,8 +324,9 @@ def new_cache(cfg: NemotronConfig, slots: int, positions: int) -> dict:
         "pos": jnp.zeros((slots,), jnp.int32),
         "count": jnp.zeros((slots,), jnp.int32),
         "units": jnp.zeros((slots, positions), jnp.int32),
-        "routes": jnp.zeros((slots, positions, len(cfg.expert_layers),
-                             cfg.num_experts_per_tok), jnp.int8),
+        "routes": jnp.zeros(stored_shape(
+            slots, positions, len(cfg.expert_layers),
+            cfg.num_experts_per_tok), jnp.int8),
     }
 
 
@@ -354,10 +359,8 @@ def prefill(params: Params, cache: dict, ids, n, slot, temperature, key, *,
             i_ssm += 1
         elif kind == "*":
             out, k, v = attn_op_seq(u, p["mixer"], cfg, qkv=_qkv)
-            cache["k"][i_attn] = lax.dynamic_update_slice(
-                cache["k"][i_attn], k[None], (slot, 0, 0, 0))
-            cache["v"][i_attn] = lax.dynamic_update_slice(
-                cache["v"][i_attn], v[None], (slot, 0, 0, 0))
+            cache["k"][i_attn] = write_slot(cache["k"][i_attn], k, slot)
+            cache["v"][i_attn] = write_slot(cache["v"][i_attn], v, slot)
             i_attn += 1
         else:
             out = _experts(u, p["mixer"], cfg, valid, routes, loads)
@@ -369,9 +372,7 @@ def prefill(params: Params, cache: dict, ids, n, slot, temperature, key, *,
     cache["pos"] = cache["pos"].at[slot].set(n)
     cache["count"] = cache["count"].at[slot].set(1)
     cache["units"] = cache["units"].at[slot, 0].set(unit)
-    cache["routes"] = lax.dynamic_update_slice(
-        cache["routes"], jnp.stack(routes, 1).astype(jnp.int8)[None],
-        (slot, 0, 0, 0))
+    cache["routes"] = write_slot(cache["routes"], jnp.stack(routes, 1), slot)
     return cache, logits[0], jnp.stack(loads)
 
 
@@ -410,8 +411,8 @@ def step(params: Params, cache: dict, live, temperature, step_no, *,
     unit = sample(logits, temperature, key, units)
     rows = jnp.arange(live.shape[0])
     span = cache["units"].shape[1]
-    cache["routes"] = cache["routes"].at[rows, pos].set(
-        jnp.stack(routes, 1).astype(jnp.int8))
+    cache["routes"] = write_rows(cache["routes"],
+                                 jnp.stack(routes, 1)[:, None], pos[:, None])
     cache["units"] = cache["units"].at[
         rows, jnp.minimum(cache["count"], span - 1)].set(
         jnp.where(live, unit, 0))

@@ -48,8 +48,9 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
+from ..ops.slot_attention import slot_attention, stored_shape, write_rows, \
+    write_slot
 from .lfm2 import BF16, F32, UnitIds, _head, _qkv, allowed_ids, \
     attn_op_seq, mm, moe_ffn, rms_norm, sample
 
@@ -147,29 +148,22 @@ def pack_layer(raw: dict) -> Params:
                     "w2": moe["w2"]}}
 
 
-def attn_op_block(u, p, cfg: SdarConfig, k_cache, v_cache, pos):
+def attn_op_block(u, p, cfg: SdarConfig, k_buf, v_buf, pos):
     """The block ``pos`` ``[S, B]`` of every slot (``u`` ``[S * B, H]``),
-    whole, over the slots' keys and values ``[S, P, kv, d]`` before it.
-    The block's own keys and values take their places in the slot: those
-    of the last pass over a block are the ones that stay."""
+    whole, over the slots' keys and values before it
+    (``ops/slot_attention.py`` says where they lie).  The block's own keys
+    and values take their places in the slot: those of the last pass over
+    a block are the ones that stay."""
     with jax.named_scope("attn_op"):
         s, b = pos.shape
-        span = k_cache.shape[1]
         kv, d = cfg.num_key_value_heads, cfg.head_dim
         g = cfg.num_attention_heads // kv
         q, k, v = _qkv(u, p, cfg, pos.reshape(-1))
-        rows = jnp.arange(s)[:, None]
-        k_cache = k_cache.at[rows, pos].set(k.reshape(s, b, kv, d))
-        v_cache = v_cache.at[rows, pos].set(v.reshape(s, b, kv, d))
-        q = q.reshape(s, b, kv, g, d).astype(BF16)
-        scores = jnp.einsum("sbkgd,spkd->skgbp", q, k_cache,
-                            preferred_element_type=F32) / jnp.sqrt(F32(d))
-        seen = jnp.arange(span)[None, :] < pos[:, :1] + b
-        probs = jax.nn.softmax(
-            jnp.where(seen[:, None, None, None, :], scores, -jnp.inf), -1)
-        out = jnp.einsum("skgbp,spkd->sbkgd", probs.astype(BF16), v_cache,
-                         preferred_element_type=F32)
-        return mm(out.reshape(s * b, -1), p["wo"]), k_cache, v_cache
+        k_buf = write_rows(k_buf, k.reshape(s, b, kv, d), pos)
+        v_buf = write_rows(v_buf, v.reshape(s, b, kv, d), pos)
+        out = slot_attention(q.reshape(s, b, kv, g, d), k_buf, v_buf,
+                             pos[:, 0] + b)
+        return mm(out.reshape(s * b, -1), p["wo"]), k_buf, v_buf
 
 
 def _moe_half(h, p, cfg: SdarConfig, held, valid, routes: list, loads: list):
@@ -214,8 +208,11 @@ def new_cache(cfg: SdarConfig, slots: int, positions: int) -> dict:
     and values of every layer; per slot the token row (a position still
     masked holds the mask id), the current block's first position and the
     pass number inside it; the pass at which every position was unmasked
-    (-1: given, or not yet), and the experts every token chose."""
-    kv_shape = (slots, positions, cfg.num_key_value_heads, cfg.head_dim)
+    (-1: given, or not yet), and the experts every token chose.  Keys,
+    values and the experts chosen lie as
+    :func:`~sonata_tpu.ops.slot_attention.stored_shape` says."""
+    kv_shape = stored_shape(slots, positions, cfg.num_key_value_heads,
+                            cfg.head_dim)
     n = cfg.num_hidden_layers
     return {
         "k": [jnp.zeros(kv_shape, BF16) for _ in range(n)],
@@ -224,8 +221,8 @@ def new_cache(cfg: SdarConfig, slots: int, positions: int) -> dict:
         "start": jnp.zeros((slots,), jnp.int32),
         "pass": jnp.zeros((slots,), jnp.int32),
         "unmasked_at": jnp.full((slots, positions), -1, jnp.int8),
-        "routes": jnp.zeros((slots, positions, n, cfg.num_experts_per_tok),
-                            jnp.int8),
+        "routes": jnp.zeros(stored_shape(
+            slots, positions, n, cfg.num_experts_per_tok), jnp.int8),
     }
 
 
@@ -249,10 +246,8 @@ def prefill(params: Params, cache: dict, ids, n, slot, *, cfg: SdarConfig,
         p = params["layers"][i]
         op, k, v = attn_op_seq(rms_norm(h, p["in_norm"], cfg.norm_eps),
                                p["attn"], cfg, b)
-        cache["k"][i] = lax.dynamic_update_slice(cache["k"][i], k[None],
-                                                 (slot, 0, 0, 0))
-        cache["v"][i] = lax.dynamic_update_slice(cache["v"][i], v[None],
-                                                 (slot, 0, 0, 0))
+        cache["k"][i] = write_slot(cache["k"][i], k, slot)
+        cache["v"][i] = write_slot(cache["v"][i], v, slot)
         h = _moe_half(h + op, p, cfg, held, valid, routes, loads)
     at = jnp.arange(span)
     row = jnp.zeros((span,), jnp.int32).at[:t].set(ids)
@@ -261,9 +256,7 @@ def prefill(params: Params, cache: dict, ids, n, slot, *, cfg: SdarConfig,
     cache["start"] = cache["start"].at[slot].set(whole)
     cache["pass"] = cache["pass"].at[slot].set(0)
     cache["unmasked_at"] = cache["unmasked_at"].at[slot].set(-1)
-    cache["routes"] = lax.dynamic_update_slice(
-        cache["routes"], jnp.stack(routes, 1).astype(jnp.int8)[None],
-        (slot, 0, 0, 0))
+    cache["routes"] = write_slot(cache["routes"], jnp.stack(routes, 1), slot)
     return cache, jnp.stack(loads)
 
 
@@ -307,7 +300,7 @@ def block_pass(params: Params, cache: dict, live, temperature, step_no, *,
     cache["unmasked_at"] = cache["unmasked_at"].at[rows, pos].set(jnp.where(
         denoise & taken, pass_no[:, None].astype(jnp.int8),
         cache["unmasked_at"][rows, pos]))
-    cache["routes"] = cache["routes"].at[rows, pos].set(chose)
+    cache["routes"] = write_rows(cache["routes"], chose, pos)
     # an empty slot stays where it is, and no slot leaves the cache
     cache["start"] = jnp.where(live & commit, jnp.minimum(
         cache["start"] + b, span - b), cache["start"])

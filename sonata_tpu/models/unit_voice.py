@@ -63,6 +63,7 @@ from ..core import AudioInfo, BaseModel, FailedToLoadResource, \
 from ..serving import tracing
 from ..text.phonemizer import text_to_phonemes
 from ..utils.buckets import FRAME_BUCKETS, TEXT_BUCKETS, bucket_for
+from ..ops import slot_attention
 from ..utils.transfer import prefetch_to_host
 from . import decode_opts, lfm2, nemotron_h, sdar
 from .config import ModelConfig, SynthesisConfig
@@ -160,6 +161,13 @@ class RowPlan:
         return done % self.passes == self.passes - 1
 
 
+def routes_of(cfg, rows):
+    """A slot's rows of the routes' record, fetched, as ``[positions,
+    expert layers, k]``: the shape the comparison reads."""
+    return slot_attention.read_slot(rows, len(cfg.expert_layers),
+                                    cfg.num_experts_per_tok)
+
+
 class Lfm2Backbone:
     """``lfm2_moe``: the prefill samples a row's first unit, and every step
     gives every live row one more."""
@@ -230,7 +238,7 @@ class Lfm2Backbone:
         ``d + 1``)."""
         units, routes = record
         return {"units": units[:budget],
-                "routes": routes[:len(ids) + budget - 1],
+                "routes": routes_of(self.cfg, routes)[:len(ids) + budget - 1],
                 "logit_units": np.asarray([d + 1 for d, _ in kept], np.int32),
                 "logits": np.stack([a[0] for _, a in kept])}
 
@@ -321,7 +329,8 @@ class SdarBackbone:
         and the experts it chose."""
         tokens, routes, unmasked_at = record
         t = self.positions_needed(len(ids), budget)
-        return {"tokens": tokens[:t], "routes": routes[:t],
+        return {"tokens": tokens[:t],
+                "routes": routes_of(self.cfg, routes)[:t],
                 "unmasked_at": unmasked_at[:t],
                 "passes": np.asarray([d for d, _ in kept], np.int32),
                 "seen": np.stack([a[0] for _, a in kept]),
@@ -435,6 +444,11 @@ class UnitVoice(BaseModel):
         #: span says it): known from the program's shape, before it is built
         self.expert_matmul = lfm2.expert_matmul(
             self.cfg, self.slots * self.block_length, self.backbone.held)
+        #: what reads the slots' keys and values in the step program
+        self.attention = slot_attention.implementation(
+            self.positions, self.cfg.num_key_value_heads,
+            self.cfg.num_attention_heads // self.cfg.num_key_value_heads,
+            self.cfg.head_dim, self.block_length)
         self.params = weights["backbone"]
         self.unit_table = weights["unit_table"]
         self.generator = {"dec": weights["generator"]["dec"]}
@@ -720,6 +734,8 @@ class UnitVoice(BaseModel):
         shape = {"text_bucket": t,
                  "expert_matmul": lfm2.expert_matmul(self.cfg, t,
                                                      self.backbone.held),
+                 # a prefill attends over its own prompt, whole
+                 "attention": "einsum",
                  "compile": self._first_use(("prefill", t))}
         if self.ssm_layers:
             shape["ssm_chunks"] = self.backbone.prefill_chunks(t)

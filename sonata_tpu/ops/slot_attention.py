@@ -1,0 +1,276 @@
+"""The slots' keys and values, and a step's attention over them.
+
+A unit voice's step programs keep, per attention layer, the keys and the
+values of every slot: ``slots`` rows of ``positions`` places, each ``kv``
+heads of ``d``.  A step writes one place a slot (a pass over a block,
+``b``), and then every slot's ``b`` queries read the slot's places
+``< upto[slot]``.  This module owns both sides: where the buffers lie
+(:func:`stored_shape`), how they are written (:func:`write_rows`,
+:func:`write_slot`) and how they are read (:func:`slot_attention`).
+
+**The layout** is ``[slots, positions, kv * d]``: a place is one row of
+whole lanes.  A step's write is then a scatter of ``slots * b`` rows into a
+buffer that stays where it is, a prefill's is one ``dynamic_update_slice``,
+and the reader takes ``(positions tile, kv * d)`` blocks and cuts the heads
+out of them in VMEM.  Stored ``[slots, positions, kv, d]`` and read by an
+einsum, XLA re-laid every buffer twice a step (it wants the positions on
+the lanes for its products and the heads there for its scatter: PERF.md
+§5), which cost more than the attention itself.
+
+**The reader** on a TPU is a kernel of this module where :func:`tile_rule`
+has tiles for the shape: a grid of (slot, positions tile), online softmax
+over the tiles, ``upto`` prefetched as scalars, so that a tile past a
+slot's length is neither fetched (its block index repeats the last one the
+slot needs) nor multiplied.  Heads narrower than the 128 lanes share a
+lane group: the queries of head ``h`` lie in their own rows with zeros in
+the other heads' lanes, so one product over the group's lanes gives every
+head's scores, and of the product with the values each row keeps its own
+head's lanes.  Off a TPU, and where the rule says no, the reader is the
+einsum it replaces, over the same buffers (on a TPU that einsum re-lays
+the buffers again: the rule says no only where the kernel cannot run).
+
+**Precision**: queries and probabilities enter the products in the type
+the buffers hold (bfloat16 in every program of a voice); scores, the
+softmax and every sum are float32.  The online softmax sums in another
+order than a softmax over the whole row, no more.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .grouped_matmul import lanes
+
+F32 = jnp.float32
+LANES = 128
+#: what stands for "not seen" in the kernel's scores: finite, so that a
+#: running maximum of nothing seen yet gives exp(0) and not a NaN
+MASKED = -1e30
+#: the elements of a positions tile of keys (and of one of values): 512
+#: places of 512 lanes, 1024 of 256 (half a megabyte of bfloat16).  A tile's
+#: cost is its lanes' passage through the MXU, a row of 128 a cycle; a grid
+#: step costs half a microsecond whatever it holds, so a smaller tile makes
+#: more steps than it spares places past a slot's length (PERF.md §5 has
+#: the table)
+TILE_ELEMENTS = 512 * 512
+#: the most query rows a lane group may hold: above it the products are no
+#: stream of keys and values any more and XLA's own stay
+MAX_ROWS = 256
+
+
+class Tiles(NamedTuple):
+    tp: int     #: positions of a tile
+
+
+def stored_shape(slots: int, positions: int, kv: int, d: int) -> tuple:
+    """The shape of a buffer that holds ``[kv, d]`` a slot and place (one
+    layer's keys or values; the experts a token chose, ``[layers, k]``):
+    a place is one row of whole lanes.  A row narrower than the lanes is
+    padded to them: left narrow, the compiler stores the buffer with the
+    places on the lanes and re-lays all of it around every write."""
+    return (slots, positions, lanes(kv * d))
+
+
+def _rows_of(buf, new, lead: int):
+    """``new`` ``[*lead dims, kv, d]`` as rows of ``buf``'s width."""
+    rows = new.reshape(*new.shape[:lead], -1).astype(buf.dtype)
+    pad = buf.shape[-1] - rows.shape[-1]
+    return jnp.pad(rows, ((0, 0),) * lead + ((0, pad),)) if pad else rows
+
+
+def write_rows(buf, new, pos):
+    """``new`` ``[S, b, kv, d]`` at the places ``pos`` ``[S, b]`` of every
+    slot."""
+    rows = jnp.arange(pos.shape[0])[:, None]
+    return buf.at[rows, pos].set(_rows_of(buf, new, 2))
+
+
+def write_slot(buf, seq, slot):
+    """A row's prompt ``seq`` ``[T, kv, d]`` at the places ``0 .. T`` of
+    ``slot``."""
+    return lax.dynamic_update_slice(buf, _rows_of(buf, seq, 1)[None],
+                                    (slot, 0, 0))
+
+
+def read_slot(buf, kv: int, d: int):
+    """A slot's rows ``[P, width]`` (on the host or the device) as ``[P,
+    kv, d]``."""
+    return buf[:, :kv * d].reshape(buf.shape[0], kv, d)
+
+
+@functools.lru_cache(maxsize=None)
+def tile_rule(positions: int, kv: int, g: int, d: int,
+              b: int) -> Optional[Tiles]:
+    """The kernel's tiles for ``b`` queries a slot of ``kv`` heads of ``d``
+    (``g`` query heads each) over ``positions`` places, or None where the
+    einsum stays: a pure function of the shape.
+
+    The kernel wants whole lanes: a head that fills lane groups (``d`` a
+    multiple of 128) or heads that share one (``d`` divides 128 and the
+    group's heads divide ``kv``).  The positions tile holds
+    ``TILE_ELEMENTS`` keys' elements (the power of two below), at most all
+    the positions, which it has to divide, and at least the 16 sublanes of
+    a bfloat16 tile."""
+    if d % LANES and (LANES % d or kv % (LANES // d)):
+        return None
+    if _sharing(d) * b * g > MAX_ROWS:
+        return None
+    tp = 1 << (max(TILE_ELEMENTS // (kv * d), 1).bit_length() - 1)
+    tp = min(tp, positions)
+    return Tiles(tp) if tp >= 16 and positions % tp == 0 else None
+
+
+def _sharing(d: int) -> int:
+    """Heads of keys and values that share a lane group."""
+    return max(1, LANES // d)
+
+
+def _kernel(upto, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+            tp: int, scale: float):
+    s, t = pl.program_id(0), pl.program_id(1)
+    groups, rows, lw = q_ref.shape
+    n = upto[s]
+
+    @pl.when(t == 0)
+    def _start():
+        m_ref[...] = jnp.full(m_ref.shape, MASKED, F32)
+        l_ref[...] = jnp.zeros(l_ref.shape, F32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, F32)
+
+    @pl.when(t * tp < n)
+    def _tile():
+        seen = t * tp + lax.broadcasted_iota(jnp.int32, (rows, tp), 1) < n
+        for j in range(groups):
+            lanes = slice(j * lw, (j + 1) * lw)
+            scores = lax.dot_general(
+                q_ref[j], k_ref[:, lanes], (((1,), (1,)), ((), ())),
+                preferred_element_type=F32) * scale
+            scores = jnp.where(seen, scores, MASKED)
+            m_prev = m_ref[j]
+            m_next = jnp.maximum(m_prev,
+                                 jnp.max(scores, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_next)
+            p = jnp.exp(scores - m_next)
+            l_ref[j] = alpha * l_ref[j] + jnp.sum(p, axis=1, keepdims=True)
+            m_ref[j] = m_next
+            acc_ref[j] = alpha * acc_ref[j] + jnp.dot(
+                p.astype(v_ref.dtype), v_ref[:, lanes],
+                preferred_element_type=F32)
+
+    @pl.when(t == pl.num_programs(1) - 1)
+    def _finish():
+        # a slot that sees nothing gives zeros
+        total = l_ref[...]
+        o_ref[...] = acc_ref[...] / jnp.where(total > 0.0, total, 1.0)
+
+
+def slot_attention_kernel(q, k_buf, v_buf, upto, tiles: Tiles, *,
+                          interpret: bool = False):
+    """The kernel itself, whatever the backend (``interpret`` for the
+    CPU): ``q`` ``[S, b, kv, g, d]``, the buffers ``[S, P, kv * d]``,
+    ``upto`` ``[S]``.  Returns ``[S, b, kv, g, d]`` float32."""
+    s, b, kv, g, d = q.shape
+    span, width = k_buf.shape[1:]
+    tp, heads = tiles.tp, _sharing(d)
+    lw = heads * d
+    groups = kv // heads
+    if width != kv * d or kv % heads or lw % LANES or span % tp:
+        raise ValueError(f"q {q.shape} and tiles {tiles} do not fit buffers "
+                         f"{k_buf.shape}")
+    # a lane group's rows: (head, query, query head), each head's queries
+    # in that head's lanes and zeros in the others'
+    rows = heads * b * g
+    qg = q.reshape(s, b, groups, heads, g, d).transpose(0, 2, 3, 1, 4, 5)
+    own = jnp.eye(heads, dtype=q.dtype)
+    qg = (qg[:, :, :, :, :, None, :] * own[:, None, None, :, None]).reshape(
+        s, groups, rows, lw).astype(k_buf.dtype)
+    pad = -rows % 16
+    if pad:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, pad), (0, 0)))
+    padded = rows + pad
+
+    def q_map(i, t, upto):
+        return (i, 0, 0, 0)
+
+    def kv_map(i, t, upto):
+        # past the slot's last tile the index stays: nothing is fetched
+        return (i, jnp.minimum(t, (jnp.maximum(upto[i], 1) - 1) // tp), 0)
+
+    out = pl.pallas_call(
+        functools.partial(_kernel, tp=tp, scale=float(d) ** -0.5),
+        out_shape=jax.ShapeDtypeStruct((s, groups, padded, lw), F32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(s, span // tp),
+            in_specs=[pl.BlockSpec((None, groups, padded, lw), q_map),
+                      pl.BlockSpec((None, tp, width), kv_map),
+                      pl.BlockSpec((None, tp, width), kv_map)],
+            out_specs=pl.BlockSpec((None, groups, padded, lw), q_map),
+            scratch_shapes=[pltpu.VMEM((groups, padded, 1), F32),
+                            pltpu.VMEM((groups, padded, 1), F32),
+                            pltpu.VMEM((groups, padded, lw), F32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * s * groups * padded * span * lw,
+            transcendentals=s * groups * padded * span,
+            bytes_accessed=(2 * s * span * width * k_buf.dtype.itemsize
+                            + 6 * s * groups * padded * lw)),
+        name="slot_attention",
+        interpret=interpret,
+    )(upto.astype(jnp.int32), qg, k_buf, v_buf)
+    out = out[:, :, :rows].reshape(s, groups, heads, b, g, heads, d)
+    out = jnp.stack([out[:, :, h, :, :, h] for h in range(heads)], 2)
+    return out.transpose(0, 3, 1, 2, 4, 5).reshape(s, b, kv, g, d)
+
+
+def slot_attention_einsum(q, k_buf, v_buf, upto):
+    """The products and the softmax as one expression over the whole
+    buffers: what the kernel is held to, and the reader wherever the kernel
+    is not."""
+    s, b, kv, g, d = q.shape
+    span = k_buf.shape[1]
+    k = k_buf[..., :kv * d].reshape(s, span, kv, d)
+    v = v_buf[..., :kv * d].reshape(s, span, kv, d)
+    scores = jnp.einsum("sbkgd,spkd->skgbp", q.astype(k.dtype), k,
+                        preferred_element_type=F32) / jnp.sqrt(F32(d))
+    seen = jnp.arange(span)[None, :] < upto[:, None]
+    probs = jax.nn.softmax(
+        jnp.where(seen[:, None, None, None, :], scores, -jnp.inf), -1)
+    out = jnp.einsum("skgbp,spkd->sbkgd", probs.astype(v.dtype), v,
+                     preferred_element_type=F32)
+    return jnp.where((upto > 0)[:, None, None, None, None], out, 0.0)
+
+
+def _tiles_here(positions: int, kv: int, g: int, d: int,
+                b: int) -> Optional[Tiles]:
+    """``tile_rule``'s tiles on a TPU, None on every other backend."""
+    if jax.default_backend() != "tpu":
+        return None
+    return tile_rule(positions, kv, g, d, b)
+
+
+def implementation(positions: int, kv: int, g: int, d: int, b: int) -> str:
+    """``"slot_kernel"`` where :func:`slot_attention` runs the kernel at
+    this shape on this backend, else ``"einsum"``: what the spans report."""
+    return ("einsum" if _tiles_here(positions, kv, g, d, b) is None
+            else "slot_kernel")
+
+
+def slot_attention(q, k_buf, v_buf, upto):
+    """Every slot's queries ``q`` ``[S, b, kv, g, d]`` over the slot's
+    keys and values at the places ``< upto[slot]`` (all ``b`` queries of a
+    slot see the same places): ``[S, b, kv, g, d]`` float32; zeros for a
+    slot that sees nothing."""
+    _, b, kv, g, d = q.shape
+    tiles = _tiles_here(k_buf.shape[1], kv, g, d, b)
+    if tiles is None:
+        return slot_attention_einsum(q, k_buf, v_buf, upto)
+    return slot_attention_kernel(q, k_buf, v_buf, upto, tiles)

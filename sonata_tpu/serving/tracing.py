@@ -676,6 +676,9 @@ AR_HOST_PHASES = ("launch", "admit", "retire")
 #: what a step or prefill program's expert products run (a span's
 #: ``expert_matmul``)
 EXPERT_MATMULS = ("grouped", "ragged_dot")
+#: what reads the slots' keys and values in a step program, and a prompt's
+#: own in a prefill program (a span's ``attention``)
+ATTENTION_IMPLS = ("slot_kernel", "einsum")
 
 
 def held_load(load) -> tuple:
@@ -710,6 +713,10 @@ class StepStats:
         self.expert_matmul = {(impl, program): 0
                               for impl in EXPERT_MATMULS
                               for program in ("step", "prefill")}
+        #: launches by what their attention ran and by program
+        self.attention = {(impl, program): 0
+                          for impl in ATTENTION_IMPLS
+                          for program in ("step", "prefill")}
         self.slots_in_use = 0
         self._registry = None
 
@@ -722,6 +729,7 @@ class StepStats:
             self.row_passes["commit"] += group["commit_row_passes"]
             self.expert_matmul[group["expert_matmul"], "step"] += group[
                 "steps"]
+            self.attention[group["attention"], "step"] += group["steps"]
             self.slot_steps["live"] += group["live_slot_steps"]
             self.slot_steps["empty"] += (group["steps"] * group["slots"]
                                          - group["live_slot_steps"])
@@ -733,12 +741,14 @@ class StepStats:
                             group["held_assignments"])
 
     def record_prefill(self, tokens: int, layers, loads, units: int = 0,
-                       expert_matmul: str = "ragged_dot") -> None:
+                       expert_matmul: str = "ragged_dot",
+                       attention: str = "einsum") -> None:
         """One row admitted: its prompt's tokens, what they chose, the
         units the prefill itself gave the row, and what its program's
-        expert products ran."""
+        expert products and its attention ran."""
         with self._lock:
             self.expert_matmul[expert_matmul, "prefill"] += 1
+            self.attention[attention, "prefill"] += 1
             self.prefill_tokens += tokens
             self.units += units
             self.rows["admitted"] += 1
@@ -829,6 +839,17 @@ class StepStats:
         for impl, program in self.expert_matmul:
             launches.labels(impl=impl, program=program).set_function(
                 lambda k=(impl, program): float(self.expert_matmul[k]))
+        attention = registry.counter(
+            "sonata_attention_impl_total",
+            "Launches of step and prefill programs, by what their "
+            "attention runs: slot_kernel (this repo's kernel over the "
+            "slots' keys and values: sonata_tpu/ops/slot_attention.py) or "
+            "einsum (XLA's products: every launch off a TPU, the shapes "
+            "the kernel's tile rule leaves to it, and every prefill, which "
+            "attends over its own prompt).")
+        for impl, program in self.attention:
+            attention.labels(impl=impl, program=program).set_function(
+                lambda k=(impl, program): float(self.attention[k]))
         registry.gauge(
             "sonata_ar_slots_in_use",
             "Slots of step-wise generation loops that hold a row."

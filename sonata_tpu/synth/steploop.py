@@ -45,9 +45,11 @@ steps read and wrote, per expert layer ``assignments``, ``experts_touched``,
 ``held_assignments`` and ``held_experts_touched``, ``host_ms`` by phase)
 beside the engine's ``block_length``, ``denoising_steps``, ``ssm_layers`` and
 ``expert_matmul`` (``grouped`` | ``ragged_dot``: what the step program's
-expert products run).  Each
+expert products run) and ``attention`` (``slot_kernel`` | ``einsum``: what
+reads the slots' keys and values).  Each
 prefill (``blocks`` of the prompt kept whole, ``tail_ids`` left to the
-first generated block, ``expert_matmul`` of its own program, ``ssm_chunks``
+first generated block, ``expert_matmul`` and ``attention`` of its own
+program, ``ssm_chunks``
 its state-space layers' scans ran) and each vocoder launch is a ``dispatch`` span (``kind: prefill`` |
 ``vocode``) in the trace of the request the row belongs to; both end when
 what their program produced is on the host (a prefill's load, a row's
@@ -59,7 +61,8 @@ its own work after).  The always-on counters are
 
 The engine (a voice: :class:`~sonata_tpu.models.unit_voice.UnitVoice`)
 gives ``slots``, ``expert_layers``, ``block_length``, ``denoising_steps``,
-``expert_matmul``, ``ssm_layers``, ``ssm_state_bytes`` (a slot's),
+``expert_matmul``, ``attention``, ``ssm_layers``, ``ssm_state_bytes`` (a
+slot's),
 ``new_cache()``, ``plan(n_ids, budget)``, ``prefill(cache, slot, ids,
 temperature)``, ``step(cache, live, temperature, step_no)``, ``vocode(cache,
 slot, n_ids, units)``, ``wait_audio(out)``, ``fetch_audio(out, units)`` and,
@@ -356,7 +359,8 @@ class StepLoop:
         for row, start, attrs, load in admitted:
             self.stats.record_prefill(attrs["tokens"], self.layers,
                                       np.asarray(load), row.plan.units(0),
-                                      attrs["expert_matmul"])
+                                      attrs["expert_matmul"],
+                                      attrs["attention"])
             row.span(start, time.monotonic(), **attrs)
         if pending is None:
             return
@@ -404,6 +408,7 @@ class StepLoop:
                  block_length=self.engine.block_length,
                  denoising_steps=self.engine.denoising_steps,
                  expert_matmul=self.engine.expert_matmul,
+                 attention=self.engine.attention,
                  ssm_layers=self.engine.ssm_layers,
                  ssm_state_bytes=(2 * self.engine.ssm_state_bytes
                                   * g["live_slot_steps"]))

@@ -1,0 +1,88 @@
+"""What a unit voice says of its attention (PR 37): every step-group and
+every prefill span states ``attention``, and
+``sonata_attention_impl_total{impl, program}`` counts the launches.  On the
+CPU a step reads the slots' keys and values by the einsum; a voice whose
+step runs this repo's kernel is made here by steering the one name both
+the decision and the reader ask (``slot_attention._tiles_here``, the kernel
+then in interpret mode), not by an option of the program.  A prefill
+attends over its own prompt and says ``einsum`` whatever the step runs."""
+
+import functools
+import importlib
+import json
+from pathlib import Path
+
+import pytest
+
+from perfbench.harness import lfm2gen, sdargen
+from sonata_tpu.models import from_config_path
+from sonata_tpu.models.config import SynthesisConfig
+from sonata_tpu.serving import tracing
+from sonata_tpu.serving.metrics import MetricsRegistry
+
+sa = importlib.import_module("sonata_tpu.ops.slot_attention")
+DATA = Path(__file__).resolve().parent / "perfbench/data"
+TEXTS = ["one short row.", "and another one."]
+
+
+def series(registry) -> dict:
+    out = {}
+    for line in registry.render().splitlines():
+        if line.startswith("sonata_attention_impl_total{"):
+            labels, value = line.split("{")[1].split("} ")
+            labels = dict(p.split("=") for p in labels.split(","))
+            out[labels["impl"].strip('"'),
+                labels["program"].strip('"')] = float(value)
+    return out
+
+
+@pytest.mark.parametrize("tiny, writer, impl", [
+    ("lfm2-tiny.json", lfm2gen, "einsum"),
+    ("sdar-tiny.json", sdargen, "einsum"),
+    ("sdar-tiny.json", sdargen, "slot_kernel")],
+    ids=["lfm2-einsum", "sdar-einsum", "sdar-slot_kernel"])
+def test_spans_and_series_say_what_the_attention_ran(
+        tiny, writer, impl, tmp_path, monkeypatch):
+    config = json.loads((DATA / tiny).read_text())
+    if impl == "slot_kernel":
+        # two heads of 64 fill one lane group: a shape the kernel takes
+        config["head_dim"] = 64
+        monkeypatch.setattr(sa, "_tiles_here", lambda *shape: sa.Tiles(64))
+        monkeypatch.setattr(sa, "slot_attention_kernel", functools.partial(
+            sa.slot_attention_kernel, interpret=True))
+    monkeypatch.setenv("SONATA_AR_SLOTS", "2")
+    monkeypatch.setenv("SONATA_AR_POSITIONS", "256")
+    voice = from_config_path(writer.write_tensors(tmp_path, config))
+    voice.set_fallback_synthesis_config(SynthesisConfig(noise_scale=0.0))
+    registry = MetricsRegistry()
+    stats = tracing.step_stats()
+    stats.bind_metrics(registry)
+    tracer = tracing.default_tracer()
+    tracer.clear()
+    before, steps_before = series(registry), stats.steps
+    assert set(before) == {(i, p) for i in tracing.ATTENTION_IMPLS
+                           for p in ("prefill", "step")}
+    try:
+        assert voice.attention == impl
+        for k, text in enumerate(TEXTS):
+            with tracer.trace_request("test", request_id=f"row-{k}"):
+                audio = voice.speak_batch(list(voice.phonemize_text(text)))
+            assert len(audio[0].samples) > 0
+    finally:
+        voice.close()       # the loop's last group is recorded as it ends
+    traces = {t.request_id: t for t in tracer.recent_traces()}
+    for k in range(len(TEXTS)):
+        (prefill,) = [s.attrs for s in traces[f"row-{k}"].spans_snapshot()
+                      if s.attrs.get("kind") == "prefill"]
+        assert prefill["attention"] == "einsum"
+    groups = [s.attrs for rid, t in traces.items()
+              if rid.startswith("ar-steps-") for s in t.spans_snapshot()
+              if s.name == "dispatch"]
+    assert groups and {g["attention"] for g in groups} == {impl}
+    after = series(registry)
+    moved = {k: after[k] - before[k] for k in after if after[k] != before[k]}
+    assert stats.steps > steps_before
+    want = {("einsum", "prefill"): float(len(TEXTS))}
+    want[impl, "step"] = want.get((impl, "step"), 0.0) + float(
+        stats.steps - steps_before)
+    assert moved == want

@@ -1,0 +1,178 @@
+"""What the TPU's compiler makes of this repo's kernels and of the unit
+voices' step programs, compiled here for the chip the cells run on (a v5e
+that is described, not attached: nothing runs).  All such tests live in
+this one file: a process that has described the topology holds the TPU's
+library, and a second file could land on another worker.
+
+- both kernels at the step programs' real widths: alignment and VMEM, which
+  interpret mode cannot show;
+- each backbone's step program at its cell's size: it may hold no ``copy``
+  of a whole key, value or routes buffer (until PR 37 XLA re-laid every
+  buffer twice a step), and both kernels are in it."""
+
+import functools
+import importlib
+import json
+import re
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from perfbench.harness import lfm2gen
+from sonata_tpu.models import unit_voice
+from test_grouped_matmul import STEP_SHAPES
+from test_slot_attention import GEOMETRIES
+
+sa = importlib.import_module("sonata_tpu.ops.slot_attention")
+gm = importlib.import_module("sonata_tpu.ops.grouped_matmul")
+ROOT = Path(__file__).resolve().parent.parent
+BF16, F32 = jnp.bfloat16, jnp.float32
+
+
+#: the cells' step programs: configuration, writer, slots
+CELLS = {
+    "lfm2_step": ("perfbench/configs/lfm2/lfm2-24b-a2b.json", "lfm2gen", 64),
+    "sdar_pass": ("perfbench/configs/sdar/sdar-30b-a3b.json", "sdargen", 64),
+    "nemotron_step": (
+        "perfbench/configs/nemotron/nemotron-3-nano-30b-a3b.json",
+        "nemotrongen", 256),
+}
+POSITIONS = 1024
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def spec_on(sharding):
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=sharding)
+
+
+@pytest.mark.parametrize("name", sorted(STEP_SHAPES))
+def test_the_grouped_matmul_compiles_for_a_v5e_at_the_step_shapes(
+        one_chip, no_compile_cache, name):
+    """The expert products' kernel with the rule's tiles (alignment, VMEM)
+    at the real widths."""
+    rows, groups, k, n = STEP_SHAPES[name]
+    tiles = gm.tile_rule(rows, groups, k, n, BF16)
+    spec = spec_on(one_chip)
+    compiled = jax.jit(functools.partial(
+        gm.grouped_matmul_kernel, tiles=tiles)).lower(
+        spec((rows, k), BF16), spec((groups, k, n), BF16),
+        spec((groups,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name", sorted(GEOMETRIES))
+def test_the_slot_attention_compiles_for_a_v5e_at_the_step_shapes(
+        one_chip, no_compile_cache, name):
+    """The attention kernel with the rule's tiles at the real widths."""
+    kv, g, d, b = GEOMETRIES[name]
+    slots = CELLS[name][2]
+    tiles = sa.tile_rule(POSITIONS, kv, g, d, b)
+    spec = spec_on(one_chip)
+    buf = spec(sa.stored_shape(slots, POSITIONS, kv, d), BF16)
+    compiled = jax.jit(functools.partial(
+        sa.slot_attention_kernel, tiles=tiles)).lower(
+        spec((slots, b, kv, g, d), F32), buf, buf,
+        spec((slots,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def step_shapes(name: str, sharding):
+    """The backbone of a cell and the shapes of its step program's
+    arguments at the cell's real size: no array is made."""
+    path, writer, slots = CELLS[name]
+    config = json.loads((ROOT / path).read_text())
+    writer = importlib.import_module(f"perfbench.harness.{writer}")
+    bb = writer.backbone(config)
+    backbone = unit_voice.make_backbone(bb, config["voice"]["units"])
+
+    def layer(i: int):
+        prefix = f"layers.{i}."
+        raw = lfm2gen.nest({
+            s[0][len(prefix):]: jax.ShapeDtypeStruct(s[1], getattr(jnp, s[2]))
+            for s in writer.layer_specs(bb, i)})
+        return jax.eval_shape(backbone.pack_layer, raw)
+
+    cfg = backbone.cfg
+    embed = jax.ShapeDtypeStruct((cfg.vocab_size, cfg.hidden_size), BF16)
+    params = {"embed": embed,
+              "norm_f": jax.ShapeDtypeStruct((cfg.hidden_size,), F32),
+              "layers": [layer(i) for i in range(backbone.layers)]}
+    if not cfg.tie_word_embeddings:
+        params["head"] = embed
+    cache = jax.eval_shape(lambda: backbone.new_cache(slots, POSITIONS))
+    args = (params, cache, jax.ShapeDtypeStruct((slots,), jnp.bool_),
+            jax.ShapeDtypeStruct((slots,), F32),
+            jax.ShapeDtypeStruct((), jnp.int32))
+    return backbone, cache, jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        args)
+
+
+def whole_buffer_copies(hlo: str, elements: int) -> list:
+    """The ``copy`` operations of an optimised module whose result holds
+    at least ``elements`` elements of bfloat16 or int8."""
+    found = []
+    for line in hlo.splitlines():
+        m = re.search(r"= (bf16|s8)\[([\d,]+)\]\S* copy\(", line)
+        if m and np.prod([int(n) for n in m.group(2).split(",")]) >= elements:
+            found.append(line.strip()[:160])
+    return found
+
+
+def test_the_copies_of_the_layout_before_are_found():
+    """Two lines of ``lfm2_step`` and one of ``sdar_pass`` as the parent
+    compiled them for a v5e: the cache re-laid for the scatter and for the
+    einsum, and the routes' record."""
+    parent = """
+  %copy.292 = bf16[64,1024,8,64]{3,2,1,0:T(8,128)(2,1)} copy(%custom-call.202), sharding={replicated}
+  %copy.370 = s8[64,1024,8,4]{1,3,2,0:T(4,128)(4,1)} copy(%fusion.84), backend_config={}
+  ROOT %copy.313 = bf16[64,1024,4,128,1]{3,1,4,2,0:T(8,128)(2,1)} copy(%param_0.636), metadata={}
+  %copy.162 = bf16[64,4,4,8,128]{4,3,2,1,0:T(8,128)(2,1)S(1)} copy(%fusion.301), metadata={}
+  %fusion.74 = bf16[64,1024,8,64]{3,2,1,0:T(8,128)(2,1)} fusion(%copy.292, %fusion.352)
+"""
+    assert len(whole_buffer_copies(parent, 64 * 1024 * 32)) == 3
+
+
+@pytest.mark.parametrize("name", sorted(CELLS))
+def test_a_step_compiled_for_a_v5e_copies_no_slot_buffer_whole(
+        one_chip, no_compile_cache, monkeypatch, name):
+    """The optimised ``lfm2_step``, ``sdar_pass`` and ``nemotron_step`` at
+    the cells' sizes hold no ``copy`` of as many elements as the smallest
+    of the buffers a step writes per slot and place (the routes' record);
+    both kernels of this repo are in them.  On the CPU the rules answer
+    None: the test steers them, not an option of the program."""
+    monkeypatch.setattr(gm, "_tiles_here", gm.tile_rule)
+    monkeypatch.setattr(sa, "_tiles_here", sa.tile_rule)
+    backbone, cache, args = step_shapes(name, one_chip)
+    per_place = [int(np.prod(a.shape)) for a in (
+        cache["routes"], *cache["k"], *cache["v"])]
+    assert min(per_place) == cache["routes"].size
+    hlo = backbone.build_step().lower(*args).compile().as_text()
+    assert "slot_attention" in hlo and "grouped_matmul" in hlo
+    assert whole_buffer_copies(hlo, min(per_place)) == []

@@ -1,7 +1,7 @@
 """The expert products' kernel (``sonata_tpu/ops/grouped_matmul.py``), held
 to ``jax.lax.ragged_dot`` in interpret mode at tiny widths, its tile rule
-as a pure function, and a compile of the kernel at the benchmark's shapes
-for the chip the cells run on."""
+as a pure function.  The kernel compiled at the benchmark's shapes for the
+chip the cells run on: ``test_compiled_for_v5e.py``."""
 
 import functools
 import importlib
@@ -187,42 +187,3 @@ def test_the_tile_rule_reads_the_shape_alone():
     assert gm.tile_rule(1536, 64, 1856, 2688, jnp.bfloat16) is None
     assert gm.tile_rule(256, 64, 2048, 3000, jnp.bfloat16) is None
     assert gm.tile_rule(256, 64, 100, 3072, jnp.bfloat16) is None
-
-
-# -- the kernel compiled at the real widths, for the chip the cells run on --
-
-@pytest.fixture(scope="module")
-def one_chip():
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
-
-
-@pytest.mark.parametrize("name", sorted(STEP_SHAPES))
-def test_the_kernel_compiles_for_a_v5e_at_the_step_shapes(one_chip, name):
-    """What interpret mode cannot show: the TPU's compiler takes the
-    kernel with the rule's tiles (alignment, VMEM) at the real widths."""
-    from jax.experimental.compilation_cache import compilation_cache
-    rows, groups, k, n = STEP_SHAPES[name]
-    tiles = gm.tile_rule(rows, groups, k, n, jnp.bfloat16)
-
-    def spec(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        compiled = jax.jit(functools.partial(
-            gm.grouped_matmul_kernel, tiles=tiles)).lower(
-            spec((rows, k), jnp.bfloat16), spec((groups, k, n), jnp.bfloat16),
-            spec((groups,), jnp.int32)).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
-    assert "tpu_custom_call" in compiled.as_text()

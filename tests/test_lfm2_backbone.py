@@ -14,6 +14,7 @@ import pytest
 
 from perfbench.harness import lfm2gen, parts
 from sonata_tpu.models import lfm2
+from sonata_tpu.models.unit_voice import routes_of
 
 ROOT = Path(__file__).resolve().parent.parent
 CONFIG = json.loads((ROOT / "tests/perfbench/data/lfm2-tiny.json").read_text())
@@ -144,7 +145,8 @@ def test_prefill_and_steps_through_slots_match_one_full_forward_pass(
             if row["got"] == row["units"]:
                 row["chosen"] = np.asarray(cache["units"][slot,
                                                           :row["units"]])
-                row["routes"] = np.asarray(cache["routes"][slot])
+                row["routes"] = routes_of(
+                    CFG, np.asarray(cache["routes"][slot]))
                 done.append(rows.pop(slot))
     assert len(done) == 4 and not rows
     assert not any((row["chosen"] == UNITS.stop_id).any() for row in done)

@@ -100,11 +100,12 @@ def test_what_moved_out_of_the_voice_left_no_alias_behind(name):
     assert name not in defined | assigned
 
 
-def test_the_ops_package_holds_the_one_kernel():
+def test_the_ops_package_holds_the_two_kernels():
     """PR 30 took the package out with its last kernel; PR 34 brought it
-    back for the expert products' grouped matmul, and nothing else."""
+    back for the expert products' grouped matmul, PR 37 put the slots'
+    keys and values and their reader beside it, and nothing else."""
     assert sorted(p.name for p in (PACKAGE / "ops").glob("*.py")) == [
-        "__init__.py", "grouped_matmul.py"]
+        "__init__.py", "grouped_matmul.py", "slot_attention.py"]
     assert importlib.util.find_spec(f"{PACKAGE.name}.ops") is not None
     # the package re-exports nothing: a function named as its module
     # would hide the module

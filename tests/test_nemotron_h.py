@@ -20,6 +20,7 @@ from jax import lax
 from perfbench.harness import lfm2gen, nemotrongen, parts, sdargen
 from sonata_tpu.models import from_config_path, lfm2, nemotron_h, sdar
 from sonata_tpu.models.config import SynthesisConfig
+from sonata_tpu.models.unit_voice import routes_of
 from sonata_tpu.serving import tracing
 from sonata_tpu.serving.metrics import MetricsRegistry
 
@@ -106,7 +107,7 @@ def test_prefill_then_steps_give_the_references_full_pass(name, raw, params):
         params["norm_f"], lambda i: raw[i], held=CFG.held)
     np.testing.assert_allclose(got, np.asarray(want)[n - 1:], rtol=0,
                                atol=2e-4)
-    served = np.asarray(cache["routes"][1])[:len(tokens)]
+    served = routes_of(CFG, np.asarray(cache["routes"][1]))[:len(tokens)]
     assert np.array_equal(np.sort(served, -1), np.sort(np.asarray(routes),
                                                        -1))
     assert served.shape[1:] == (3, 2) and served.max() < 8

@@ -16,6 +16,7 @@ import pytest
 
 from perfbench.harness import lfm2gen, parts, sdargen
 from sonata_tpu.models import lfm2, sdar
+from sonata_tpu.models.unit_voice import routes_of
 
 ROOT = Path(__file__).resolve().parent.parent
 CONFIG = json.loads((ROOT / "tests/perfbench/data/sdar-tiny.json").read_text())
@@ -245,8 +246,8 @@ def test_prefill_and_passes_through_slots_match_the_whole_forward_pass(
                 assert int(cache["start"][slot]) == start + B
                 assert int(cache["pass"][slot]) == 0
                 # the commit pass's experts are the ones the slot keeps
-                assert np.array_equal(np.asarray(
-                    cache["routes"][slot, start:start + B]), routes[start:])
+                assert np.array_equal(routes_of(CFG, np.asarray(
+                    cache["routes"][slot]))[start:start + B], routes[start:])
             row["passes"] += 1
             if row["passes"] == row["left"]:
                 n = len(row["ids"])

@@ -1,0 +1,187 @@
+"""The slots' keys and values and a step's attention over them
+(``sonata_tpu/ops/slot_attention.py``): the kernel in interpret mode and
+the fallback against the expressions ``lfm2.attn_op_step`` and
+``sdar.attn_op_block`` had until PR 37, at the three cells' geometries; the
+two ways a slot is written; and the tile rule as a pure function.  What the
+chip's compiler makes of it is in ``test_compiled_for_v5e.py``."""
+
+import functools
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sa = importlib.import_module("sonata_tpu.ops.slot_attention")
+Tiles = sa.Tiles
+BF16, F32 = jnp.bfloat16, jnp.float32
+
+#: name -> (kv, g, d, b) of the three cells' step programs, and in the
+#: tests 3 slots of 256 places in tiles of 64
+GEOMETRIES = {"lfm2_step": (8, 4, 64, 1), "sdar_pass": (4, 8, 128, 4),
+              "nemotron_step": (2, 16, 128, 1)}
+S, P, TP = 3, 256, 64
+#: name -> a slot's length in each of the 3 slots: one place, a tile's
+#: edge from both sides, every place, and lengths inside tiles
+UPTOS = {"one_place": [1, 1, 4], "a_tiles_edge": [64, 128, 192],
+         "past_a_tiles_edge": [65, 129, 193], "every_place": [256, 256, 255],
+         "inside_tiles": [37, 100, 211]}
+
+
+def until_pr37(q, k_buf, v_buf, upto):
+    """``attn_op_step`` (``b`` 1) and ``attn_op_block`` as they were, over
+    buffers ``[S, P, kv, d]``."""
+    d, span = q.shape[-1], k_buf.shape[1]
+    scores = jnp.einsum("sbkgd,spkd->skgbp", q.astype(BF16), k_buf,
+                        preferred_element_type=F32) / jnp.sqrt(F32(d))
+    seen = jnp.arange(span)[None, :] < upto[:, None]
+    probs = jax.nn.softmax(
+        jnp.where(seen[:, None, None, None, :], scores, -jnp.inf), -1)
+    return jnp.einsum("skgbp,spkd->sbkgd", probs.astype(BF16), v_buf,
+                      preferred_element_type=F32)
+
+
+def operands(name: str, seed: int = 0):
+    kv, g, d, b = GEOMETRIES[name]
+    rng = np.random.default_rng(seed)
+    q = jnp.asarray(rng.standard_normal((S, b, kv, g, d)), F32)
+    k = jnp.asarray(rng.standard_normal((S, P, kv, d)), BF16)
+    v = jnp.asarray(rng.standard_normal((S, P, kv, d)), BF16)
+    return q, k, v
+
+
+def stored(a):
+    return a.reshape(sa.stored_shape(*a.shape))
+
+
+@pytest.mark.parametrize("upto", sorted(UPTOS))
+@pytest.mark.parametrize("name", sorted(GEOMETRIES))
+def test_the_kernel_and_the_fallback_are_the_einsum_they_replace(name, upto):
+    """bfloat16 into the products, float32 out: the fallback to the
+    rounding of the same sums, the kernel to that of probabilities rounded
+    to bfloat16 before they are normalised and not after."""
+    q, k, v = operands(name)
+    upto = jnp.asarray(UPTOS[upto], jnp.int32)
+    want = until_pr37(q, k, v, upto)
+    fallback = sa.slot_attention_einsum(q, stored(k), stored(v), upto)
+    kernel = sa.slot_attention_kernel(q, stored(k), stored(v), upto,
+                                      Tiles(TP), interpret=True)
+    assert kernel.shape == want.shape and kernel.dtype == F32
+    np.testing.assert_allclose(np.asarray(fallback), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(kernel), np.asarray(want),
+                               rtol=0, atol=6e-3)
+
+
+@pytest.mark.parametrize("name", sorted(GEOMETRIES))
+def test_a_slot_that_sees_nothing_gives_zeros(name):
+    q, k, v = operands(name)
+    upto = jnp.asarray([0, 5, 0], jnp.int32)
+    for got in (sa.slot_attention_einsum(q, stored(k), stored(v), upto),
+                sa.slot_attention_kernel(q, stored(k), stored(v), upto,
+                                         Tiles(TP), interpret=True)):
+        got = np.asarray(got)
+        assert np.all(got[[0, 2]] == 0.0) and np.all(np.isfinite(got))
+        assert np.abs(got[1]).max() > 0.0
+
+
+@pytest.mark.parametrize("reader", ["kernel", "fallback"])
+def test_a_block_is_whole_inside_and_blind_past_it(reader):
+    """``b = 4``: every query of a slot's block sees the block's last
+    place, and none sees a place at or past ``upto``."""
+    q, k, v = operands("sdar_pass")
+    upto = jnp.asarray([8, 100, 132], jnp.int32)
+    read = (sa.slot_attention_einsum if reader == "fallback" else
+            functools.partial(sa.slot_attention_kernel, tiles=Tiles(TP),
+                              interpret=True))
+    base = np.asarray(read(q, stored(k), stored(v), upto))
+    rows = np.arange(S)
+    last, past = np.asarray(upto) - 1, np.asarray(upto)
+    inside = np.asarray(read(q, stored(k), stored(
+        v.at[rows, last].set(v[rows, last] + 4.0)), upto))
+    # every one of the block's four queries, every head
+    assert np.all(np.abs(inside - base).max(-1) > 1e-3)
+    outside = np.asarray(read(q, stored(k.at[rows, past].set(50.0)), stored(
+        v.at[rows, past].set(50.0)), upto))
+    assert np.array_equal(outside, base)
+
+
+@pytest.mark.parametrize("name", sorted(GEOMETRIES))
+def test_written_by_rows_or_by_slot_a_slot_reads_the_same(name):
+    """A prefill writes a slot's row whole, a step a place a slot (a pass
+    a block): the same keys and values either way."""
+    kv, g, d, b = GEOMETRIES[name]
+    q, k, v = operands(name, seed=1)
+    t = 32
+    empty = jnp.zeros(sa.stored_shape(S, P, kv, d), BF16)
+    by_slot, by_rows = [empty, empty], [empty, empty]
+    for i, a in enumerate((k, v)):
+        for slot in range(S):
+            by_slot[i] = sa.write_slot(by_slot[i], a[slot, :t], slot)
+        for start in range(0, t, b):
+            pos = jnp.broadcast_to(start + jnp.arange(b), (S, b))
+            by_rows[i] = sa.write_rows(by_rows[i], a[:, start:start + b],
+                                       pos)
+        assert np.array_equal(np.asarray(by_slot[i], F32),
+                              np.asarray(by_rows[i], F32))
+        assert np.array_equal(
+            np.asarray(sa.read_slot(by_slot[i][1], kv, d)[:t], F32),
+            np.asarray(a[1, :t], F32))
+    upto = jnp.asarray([t, t - b, b], jnp.int32)
+    assert np.array_equal(
+        np.asarray(sa.slot_attention(q, *by_slot, upto)),
+        np.asarray(sa.slot_attention(q, *by_rows, upto)))
+
+
+def test_a_narrow_record_lies_in_whole_lanes():
+    """The routes' record: 8 layers of 4 experts a token are 32 bytes a
+    place, stored as a row of 128; what was written comes back."""
+    assert sa.stored_shape(64, 1024, 8, 4) == (64, 1024, 128)
+    assert sa.stored_shape(64, 1024, 8, 64) == (64, 1024, 512)
+    assert sa.stored_shape(256, 1024, 2, 128) == (256, 1024, 256)
+    rng = np.random.default_rng(2)
+    chose = jnp.asarray(rng.integers(0, 64, (S, 1, 8, 4)), jnp.int32)
+    buf = jnp.zeros(sa.stored_shape(S, P, 8, 4), jnp.int8)
+    pos = jnp.asarray([[3], [200], [0]], jnp.int32)
+    buf = sa.write_rows(buf, chose, pos)
+    assert buf.shape == (S, P, 128) and buf.dtype == jnp.int8
+    for slot, at in enumerate(np.asarray(pos)[:, 0]):
+        got = sa.read_slot(np.asarray(buf[slot]), 8, 4)
+        assert got.shape == (P, 8, 4) and got.dtype == np.int8
+        assert np.array_equal(got[at], np.asarray(chose[slot, 0]))
+        assert not got[np.arange(P) != at].any()
+
+
+#: (positions, kv, g, d, b) -> tiles, or None where the einsum stays
+RULE = {
+    # half a megabyte of keys a tile: 512 places of 512 lanes ...
+    "lfm2_step": ((1024, 8, 4, 64, 1), Tiles(512)),
+    "sdar_pass": ((1024, 4, 8, 128, 4), Tiles(512)),
+    # ... 1024 of 256: every place, one tile a slot
+    "nemotron_step": ((1024, 2, 16, 128, 1), Tiles(1024)),
+    "1536_positions": ((1536, 8, 4, 64, 1), Tiles(512)),
+    "fewer_positions_than_a_tile": ((384, 8, 4, 64, 1), Tiles(384)),
+    "heads_of_256": ((1024, 2, 4, 256, 1), Tiles(512)),
+    "heads_of_32_four_a_lane_group": ((1024, 8, 4, 32, 1), Tiles(1024)),
+    "heads_the_lanes_do_not_divide": ((1024, 8, 4, 96, 1), None),
+    "fewer_heads_than_a_lane_group": ((1024, 1, 4, 64, 1), None),
+    "the_tiny_voices": ((256, 2, 2, 16, 1), None),
+    "positions_the_tile_does_not_divide": ((1000, 8, 4, 64, 1), None),
+    "many_query_rows": ((1024, 8, 4, 64, 64), None),
+}
+
+
+@pytest.mark.parametrize("name", sorted(RULE))
+def test_the_tile_rule_reads_the_shape_alone(name):
+    shape, tiles = RULE[name]
+    assert sa.tile_rule(*shape) == tiles
+
+
+def test_off_a_tpu_the_function_is_the_einsum():
+    q, k, v = operands("lfm2_step")
+    upto = jnp.asarray([5, 6, 7], jnp.int32)
+    graph = str(jax.make_jaxpr(sa.slot_attention)(q, stored(k), stored(v),
+                                                  upto))
+    assert "dot_general" in graph and "pallas_call" not in graph
+    assert sa.implementation(1024, 8, 4, 64, 1) == "einsum"

@@ -1,0 +1,200 @@
+#!/usr/bin/env python3
+"""A step's attention over the slots alone, on the chip: the write of a
+step's places and the read of every slot's keys and values, by each
+candidate layout and reader, at the shapes the unit voices' steps run.
+
+    python tools/profile_attention.py [--only PREFIX] [--out F] [--rehearse]
+
+Candidates (``sonata_tpu/ops/slot_attention.py`` says which the programs
+run): ``einsum@SPkd``, buffers ``[S, P, kv, d]`` written by a scatter and
+read by two einsums (every program's until PR 37); ``einsum@SkPd``, the
+same over ``[S, kv, P, d]`` (both products batched over slot and head);
+``einsum@SPw``, the einsum over the stored layout ``[S, P, kv * d]`` (what
+runs off a TPU); ``kernel(tp)``, the module's kernel at positions tiles of
+``tp``.  Every candidate writes ``b`` places a slot and then attends, on
+buffers it is given to keep (donated), ``REPS`` layers in one jitted
+program, as a step does; a reading is the host's clock around that program
+over ``REPS``, the least of five.  ``upto`` is drawn as the cells' rows
+stand: a prompt of 68-182 ids and a uniform share of its ``3.5 x ids``
+units behind it (68-819 places, 344 at the mean).  ``GB/s`` counts what
+``perfbench/harness/lfm2_costs.py`` charges a step: a key and a value of
+``kv * d`` for every place a slot attends over, bfloat16; percent is of 819
+GB/s.  Every candidate is held to the first.  Needs a TPU (``--rehearse``:
+the CPU, a few slots and places, the kernel interpreted: no times).
+"""
+
+from __future__ import annotations
+
+import argparse
+import functools
+import importlib
+import json
+import sys
+import time
+from pathlib import Path
+
+sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+sa = importlib.import_module("sonata_tpu.ops.slot_attention")
+
+REPS = 4
+BF16, F32 = jnp.bfloat16, jnp.float32
+#: name -> (S, P, kv, g, d, b): the three cells' step programs
+SHAPES = {
+    "lfm2_step": (64, 1024, 8, 4, 64, 1),
+    "sdar_pass": (64, 1024, 4, 8, 128, 4),
+    "nemotron_step": (256, 1024, 2, 16, 128, 1),
+}
+TILES = (128, 256, 512, 1024)
+
+
+def draw_upto(rng, slots: int, positions: int, b: int):
+    ids = rng.integers(68, 183, slots)
+    at = ids + (rng.random(slots) * 3.5 * ids).astype(np.int64)
+    return np.clip(at // b * b + b, b, positions).astype(np.int32)
+
+
+def einsums(buffers: str, q, k_buf, v_buf, upto):
+    """The two einsums ``lfm2.attn_op_step`` and ``sdar.attn_op_block`` had,
+    over buffers whose dimensions ``buffers`` names (``spkd``: as they had
+    them)."""
+    d, span = q.shape[-1], k_buf.shape[buffers.index("p")]
+    scores = jnp.einsum(f"sbkgd,{buffers}->skgbp", q.astype(BF16), k_buf,
+                        preferred_element_type=F32) / jnp.sqrt(F32(d))
+    seen = (jnp.arange(span)[None, :] < upto[:, None])[:, None, None, None]
+    probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), -1)
+    return jnp.einsum(f"skgbp,{buffers}->sbkgd", probs.astype(BF16), v_buf,
+                      preferred_element_type=F32)
+
+
+def candidates(shape: tuple, rehearse: bool) -> list:
+    """``(name, stored shape, write, attend)``."""
+    s, p, kv, g, d, b = shape
+    rows = jnp.arange(s)[:, None]
+
+    def write_spkd(buf, new, pos):
+        return buf.at[rows, pos].set(new)
+
+    def write_skpd(buf, new, pos):
+        return buf.at[rows, :, pos].set(new)
+
+    out = [("einsum@SPkd", (s, p, kv, d), write_spkd,
+            functools.partial(einsums, "spkd")),
+           ("einsum@SkPd", (s, kv, p, d), write_skpd,
+            functools.partial(einsums, "skpd")),
+           ("einsum@SPw", sa.stored_shape(s, p, kv, d), sa.write_rows,
+            sa.slot_attention_einsum)]
+    for tp in TILES:
+        if p % tp == 0:
+            out.append((f"kernel({tp})", sa.stored_shape(s, p, kv, d),
+                        sa.write_rows, functools.partial(
+                            sa.slot_attention_kernel, tiles=sa.Tiles(tp),
+                            interpret=rehearse)))
+    return out
+
+
+def build(write, attend):
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    def many(k_bufs, v_bufs, qs, ks, vs, pos, upto):
+        outs = []
+        k_bufs, v_bufs = list(k_bufs), list(v_bufs)
+        for i in range(REPS):
+            k_bufs[i] = write(k_bufs[i], ks[i], pos)
+            v_bufs[i] = write(v_bufs[i], vs[i], pos)
+            outs.append(attend(qs[i], k_bufs[i], v_bufs[i], upto))
+        return k_bufs, v_bufs, jnp.stack(outs)
+
+    return many
+
+
+def fill(stored: tuple, shape: tuple, key):
+    """A buffer of the stored shape holding the same keys whatever the
+    layout: drawn as ``[S, P, kv, d]``."""
+    s, p, kv, d, = shape[0], shape[1], shape[2], shape[4]
+    flat = jax.random.normal(key, (s, p, kv, d), BF16)
+    if stored == (s, kv, p, d):
+        return flat.transpose(0, 2, 1, 3)
+    return flat.reshape(stored)
+
+
+def measure(name: str, shape: tuple, seed: int, rehearse: bool) -> list:
+    s, p, kv, g, d, b = shape
+    rng = np.random.default_rng(seed)
+    uptos = [draw_upto(rng, s, p, b) for _ in range(2)]
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3 + 2 * REPS)
+    qs = jax.random.normal(keys[0], (REPS, s, b, kv, g, d), F32)
+    ks = jax.random.normal(keys[1], (REPS, s, b, kv, d), BF16)
+    vs = jax.random.normal(keys[2], (REPS, s, b, kv, d), BF16)
+    lines, ref = [], None
+    for cand, stored, write, attend in candidates(shape, rehearse):
+        line = {"shape": name, "S": s, "P": p, "kv": kv, "g": g, "d": d,
+                "b": b, "candidate": cand,
+                "mean_upto": float(np.mean(uptos))}
+        try:
+            fn = build(write, attend)
+            k_bufs = [fill(stored, shape, keys[3 + i]) for i in range(REPS)]
+            v_bufs = [fill(stored, shape, keys[3 + REPS + i])
+                      for i in range(REPS)]
+            times = []
+            for i in range(2 if rehearse else 6):
+                upto = jnp.asarray(uptos[i % 2])
+                pos = upto[:, None] - b + jnp.arange(b)[None, :]
+                t0 = time.perf_counter()
+                k_bufs, v_bufs, out = jax.block_until_ready(
+                    fn(k_bufs, v_bufs, qs, ks, vs, pos, upto))
+                times.append(time.perf_counter() - t0)
+                if i == 0:
+                    line["compile_s"] = times[0]
+                    if ref is None:
+                        ref = out
+                        line["ref_abs_max"] = float(jnp.max(jnp.abs(ref)))
+                    line["err_max"] = float(jnp.max(jnp.abs(out - ref)))
+            del k_bufs, v_bufs
+            if not rehearse:
+                ms = min(times[1:]) * 1e3 / REPS
+                charged = 2 * 2 * kv * d * float(np.mean(
+                    [u.sum() for u in uptos]))
+                line.update(ms=ms, gb_per_s=charged / ms / 1e6,
+                            share_of_819=charged / ms / 1e6 / 819.0,
+                            ms_of_bytes=charged / 819e6)
+        except Exception as e:  # a candidate the compiler refuses
+            line["error"] = f"{type(e).__name__}: {str(e)[:300]}"
+        print(json.dumps(line), flush=True)
+        lines.append(line)
+    return lines
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--only", default="",
+                    help="shapes whose name starts with this alone")
+    ap.add_argument("--seed", type=int, default=3700)
+    ap.add_argument("--rehearse", action="store_true",
+                    help="on the CPU at 4 slots of 256 places, the kernel "
+                         "interpreted: results only, no times")
+    ap.add_argument("--out", default="chiprun_out/profile_attention.json")
+    args = ap.parse_args()
+    device = jax.devices()[0]
+    if device.platform != "tpu" and not args.rehearse:
+        print(f"needs a TPU, found {device.platform}", file=sys.stderr)
+        return 1
+    lines = []
+    for name, shape in SHAPES.items():
+        if name.startswith(args.only):
+            if args.rehearse:
+                shape = (4, 256) + shape[2:]
+            lines += measure(name, shape, args.seed, args.rehearse)
+    out = Path(args.out)
+    out.parent.mkdir(parents=True, exist_ok=True)
+    out.write_text(json.dumps(
+        {"device": {"platform": device.platform, "kind": device.device_kind},
+         "reps": REPS, "lines": lines}, indent=1))
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())
