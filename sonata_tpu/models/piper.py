@@ -1424,7 +1424,7 @@ class PiperVoice(BaseModel):
         frame counts are prefetched to the host."""
         t_start = time.perf_counter()
         n_real = len(ids_list)
-        with tracing.annotation("front"):
+        with tracing.annotation("front"), tracing.compile_sink() as paid:
             ids, lens, b, t = self._pad_batch(ids_list)
             sid = self._sid_array(sc, b, speakers)
             nw, ls, ns, ls_host = self._scale_arrays(sc, b, scales)
@@ -1454,8 +1454,10 @@ class PiperVoice(BaseModel):
                 "scaled": any(abs(l - sc.length_scale) > 1e-9
                               for l in ls_host[:n_real]),
                 "t_enqueue": t_enqueue,
-                # the front's host work and what its launch took from the
-                # host, for the first of its groups' records to carry
+                # the front's host work, what its launch took from the
+                # host and what compiled under it, for the first of its
+                # groups' records to carry
+                "paid": paid,
                 "enqueue_ms": (t_enqueue - t_start) * 1e3,
                 "upload_bytes": self._weights_host_bytes + ns.nbytes + sum(
                     a.nbytes for a in args[1:])}
@@ -1495,7 +1497,10 @@ class PiperVoice(BaseModel):
         b, t, f = group.batch, front["t"], group.frame_bucket
         n_real = len(group.rows)
         needs = [front["frames_needed"][r] for r in group.rows]
-        with tracing.annotation("enqueue"):
+        # what compiles on this thread under the launch (the program on a
+        # cold shape, an eager operation's small one on its first use) is
+        # this record's: ``compile_ms`` and ``compiled`` beside ``compile``
+        with tracing.annotation("enqueue"), tracing.compile_sink() as paid:
             staged = front["staged"]
             if b == front["b"] and group.rows == list(range(n_real)):
                 rows = self._identity_rows(b)   # the batch whole: no gather
@@ -1555,7 +1560,8 @@ class PiperVoice(BaseModel):
             front_wait_ms=round(front.pop("front_wait_ms", 0.0), 3),
             enqueue_ms=round((t_enqueue - t_start) * 1e3
                              + front.pop("enqueue_ms", 0.0), 3),
-            launch_ms=round((t_enqueue - t_launch) * 1e3, 3))
+            launch_ms=round((t_enqueue - t_launch) * 1e3, 3),
+            **tracing.compile_attrs(front.pop("paid", []) + paid))
         return {"out": out, "n_real": n_real, "t_enqueue": t_enqueue,
                 "group": record}
 

@@ -461,7 +461,6 @@ class UnitVoice(BaseModel):
         self._synth_config = config.inference.copy()
         self._jit_lock = threading.Lock()
         self._programs: dict = {}
-        self._used: set = set()
         self._warm_caches: Optional[threading.Semaphore] = None
         self._prefill_no = 0
         self.scope_voice: Optional[str] = None
@@ -694,14 +693,6 @@ class UnitVoice(BaseModel):
                 fn = self._programs[key] = build()
         return fn
 
-    def _first_use(self, shape: tuple) -> str:
-        """``cold`` the first time a program runs at ``shape`` (it
-        compiles, or loads from the persistent cache), else ``cached``."""
-        with self._jit_lock:
-            seen = shape in self._used
-            self._used.add(shape)
-        return "cached" if seen else "cold"
-
     def _build_vocode(self, frames: int):
         hp = self.hp
 
@@ -735,14 +726,18 @@ class UnitVoice(BaseModel):
                  "expert_matmul": lfm2.expert_matmul(self.cfg, t,
                                                      self.backbone.held),
                  # a prefill attends over its own prompt, whole
-                 "attention": "einsum",
-                 "compile": self._first_use(("prefill", t))}
+                 "attention": "einsum"}
         if self.ssm_layers:
             shape["ssm_chunks"] = self.backbone.prefill_chunks(t)
         self._prefill_no += 1
-        cache, out, load = fn(
-            self.params, cache, padded, np.int32(len(ids)), np.int32(slot),
-            np.float32(temperature), np.int32(self._prefill_no))
+        # ``compile``: whether a backend compile or a load from the
+        # persistent cache ran under this launch, on this thread
+        with tracing.compile_sink() as paid:
+            cache, out, load = fn(
+                self.params, cache, padded, np.int32(len(ids)),
+                np.int32(slot), np.float32(temperature),
+                np.int32(self._prefill_no))
+        shape.update(tracing.launch_compile(paid))
         return cache, out, load, shape
 
     def vocode(self, cache, slot: int, n_ids: int, units: int):
@@ -752,11 +747,12 @@ class UnitVoice(BaseModel):
         f = min(bucket_for(units, FRAME_BUCKETS), self.positions)
         fn = self._program(("vocode", f), lambda: self._build_vocode(f))
         held, start = self.backbone.units_of(cache, n_ids)
-        out = fn(self.generator, self.unit_table, held, np.int32(slot),
-                 np.int32(start), np.int32(units))
+        with tracing.compile_sink() as paid:
+            out = fn(self.generator, self.unit_table, held, np.int32(slot),
+                     np.int32(start), np.int32(units))
         prefetch_to_host(out)
         return out, {"batch_bucket": 1, "frames_bucket": f,
-                     "compile": self._first_use(("vocode", f))}
+                     **tracing.launch_compile(paid)}
 
     def wait_audio(self, out) -> None:
         """Block until the vocoder program of ``out`` has run."""

@@ -227,6 +227,9 @@ class ServingRuntime:
         #: step-wise generation (a unit voice's step loop): steps, slots,
         #: prefill tokens, rows, host seconds and the expert layers' load
         tracing.step_stats().bind_metrics(r)
+        #: every XLA compile since the process enabled its compile cache:
+        #: by program, phase, persistent-cache hit or miss, and stage
+        tracing.compile_stats().bind_metrics(r)
         #: sonata-scope aggregation plane (ISSUE 7): rolling per-stage
         #: quantiles, SLO burn rates, dispatch padding-waste accounting,
         #: and the 1 Hz flight recorder.  SONATA_SCOPE=0 disables; the

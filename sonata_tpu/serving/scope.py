@@ -482,31 +482,15 @@ class Scope:
         key = (attrs.get("batch_bucket"), attrs.get("text_bucket"),
                attrs.get("frame_bucket"))
         waste = duration_s * float(ratio) if ratio is not None else 0.0
-        runtime_cold = False
         with self._bucket_lock:
             self.dispatches_total += 1
             if cold:
                 self.cold_compiles_total += 1
-                # `scaled` = a non-default length scale changed the
-                # frame estimate: that shape was never in the lattice's
-                # coverage promise, so its compile is expected work,
-                # not a regression
-                if (self._warmup_complete
-                        and not attrs.get("scaled")
-                        and (self._warmed_voices is None
-                             or voice in self._warmed_voices)):
-                    runtime_cold = True
-                    v = voice if voice is not None else ""
-                    self._runtime_cold[v] = self._runtime_cold.get(v, 0) + 1
-        if runtime_cold:
-            # a compile AFTER warmup completion means the lattice missed
-            # a shape real traffic hits: loud log + incident dump (the
-            # preceding minutes show which traffic found the hole)
-            log.error(
-                "runtime cold compile after warmup completion "
-                "(voice=%s bucket=%s): the warmup lattice does not "
-                "cover this shape", voice, key)
-            self.note_incident("cold-compile")
+        # `scaled` = a non-default length scale changed the frame
+        # estimate: that shape was never in the lattice's coverage
+        # promise, so its compile is expected work, not a regression
+        if cold and not attrs.get("scaled"):
+            self.note_runtime_compile(voice, f"bucket={key}")
         if ratio is None:
             return  # a model that never annotated (no bucket story)
         # per-tenant chargeback (sonata-tenancy): a dispatch batch can
@@ -568,11 +552,37 @@ class Scope:
             self._warmup_complete = True
             self._warmed_voices = (None if voices is None
                                    else frozenset(voices))
+        if _installed is self:
+            _set_compile_stage(True)
 
     @property
     def warmup_complete(self) -> bool:
         with self._bucket_lock:
             return self._warmup_complete
+
+    def note_runtime_compile(self, voice: Optional[str], what: str) -> None:
+        """A voice's work paid a compile (or a load from the persistent
+        cache: the thread stands as long): after warmup completion, and
+        for a voice the warmup covered, it counts per voice
+        (``sonata_runtime_cold_compiles_total``) and ships an incident.
+        What :meth:`note_dispatch` calls for a ``compile=cold`` dispatch,
+        and a step loop for what compiled under its launches (its spans
+        do not pass :meth:`note_dispatch`: the bucket and waste tables
+        are the stock path's)."""
+        with self._bucket_lock:
+            if not (self._warmup_complete
+                    and (self._warmed_voices is None
+                         or voice in self._warmed_voices)):
+                return
+            v = voice if voice is not None else ""
+            self._runtime_cold[v] = self._runtime_cold.get(v, 0) + 1
+        # a compile AFTER warmup completion means the lattice missed a
+        # shape real traffic hits: loud log + incident dump (the
+        # preceding minutes show which traffic found the hole)
+        log.error(
+            "runtime cold compile after warmup completion (voice=%s %s): "
+            "the warmup lattice does not cover this shape", voice, what)
+        self.note_incident("cold-compile")
 
     def runtime_cold_compiles(self, voice: str) -> float:
         """Cold compiles after warmup completion, per voice (the
@@ -965,6 +975,8 @@ def install(scope: Scope) -> None:
     tracing.set_trace_observer(_on_trace_finished)
     # dispatches a model records itself (no scheduler in front of it)
     tracing.set_dispatch_observer(note_dispatch)
+    # (a test's stand-in for a scope may know nothing of a warm-up)
+    _set_compile_stage(getattr(scope, "warmup_complete", False))
 
 
 def uninstall(scope: Scope) -> None:
@@ -983,6 +995,14 @@ def installed() -> Optional[Scope]:
     return _installed
 
 
+def _set_compile_stage(warm: bool) -> None:
+    """Compiles count under ``serving`` once the installed scope's warmup
+    is complete (``sonata_compile_total``'s ``stage``)."""
+    from . import tracing
+
+    tracing.compile_stats().stage = tracing.COMPILE_STAGES[bool(warm)]
+
+
 def _on_trace_finished(trace) -> None:
     scope = _installed
     if scope is not None:
@@ -996,6 +1016,14 @@ def note_dispatch(duration_s: float, attrs: dict) -> None:
     scope = _installed
     if scope is not None:
         scope.note_dispatch(duration_s, attrs)
+
+
+def note_runtime_compile(voice: Optional[str], what: str) -> None:
+    """A step loop's hook: a compile ran under one of a voice's launches
+    (no-op when no scope is installed)."""
+    scope = _installed
+    if scope is not None:
+        scope.note_runtime_compile(voice, what)
 
 
 def note_watchdog() -> None:

@@ -44,6 +44,15 @@ Design constraints, in order:
   the program's boundaries into it as ``sonata:<name>`` events carrying
   ``request_id`` / ``dispatch_id``, so host spans and device operations
   share one file and one clock.
+- **Every XLA compile is an event.**  One ``jax.monitoring`` listener a
+  process (:func:`install_compile_listener`) turns each top-level trace,
+  lowering and backend compile into a record (program, phase, persistent
+  cache hit or miss, seconds, thread): counted in :class:`CompileStats`
+  (``/metrics``, by warm-up or serving stage), handed to whoever pays for
+  it on that thread (:func:`compile_sink`: a device group's, a prefill's,
+  a vocoder's, a step group's record gains ``compile_ms`` and
+  ``compiled``) and, where a trace is current, recorded as a ``compile``
+  span of the request.
 
 Finished traces export three ways:
 
@@ -57,7 +66,9 @@ Finished traces export three ways:
 
 ``SONATA_TRACE=0`` disables tracing entirely (default: on; what the
 spans cost on a chip, and what a profiler capture costs on top, is
-measured in PERF.md, "Where the time goes").
+measured in PERF.md, "Where the time goes": the lines "What the spans
+cost" and "What a capture costs").  The counters (:class:`ProgramStats`,
+:class:`StepStats`, :class:`CompileStats`) stay on either way.
 """
 
 from __future__ import annotations
@@ -69,6 +80,7 @@ import itertools
 import json
 import logging
 import os
+import re
 import threading
 import time
 import uuid
@@ -673,6 +685,20 @@ def record_device_group(group: dict, voice: Optional[str] = None) -> None:
 #: device: ``launch`` (the step program's asynchronous call), ``admit``
 #: (prefills enqueued), ``retire`` (vocoder programs enqueued)
 AR_HOST_PHASES = ("launch", "admit", "retire")
+#: the rest of a turn of the loop (launch to launch), a step group's
+#: attributes of their own (``<phase>_ms``): ``device_wait`` (blocked until
+#: the step before has run: the one sign inside the program of whether host
+#: or device is the clock), ``record`` (the rest of settling that step:
+#: sums, closing a group, finishing the loop's trace with its exporters and
+#: observers) and ``other`` (the turn less all of these: the condition's
+#: lock, the garbage collector)
+AR_SETTLE_PHASES = ("device_wait", "record", "other")
+#: every phase of a turn; a turn's phases sum to its wall time
+AR_TURN_PHASES = AR_HOST_PHASES + AR_SETTLE_PHASES
+#: bounds of ``sonata_ar_turn_seconds``: a step is 5-20 ms; a stall is
+#: what lands at 0.1 s and above
+AR_TURN_BUCKETS_S = (0.005, 0.01, 0.02, 0.03, 0.05, 0.1, 0.25, 0.5, 1.0,
+                     2.5, 5.0, 10.0)
 #: what a step or prefill program's expert products run (a span's
 #: ``expert_matmul``)
 EXPERT_MATMULS = ("grouped", "ragged_dot")
@@ -702,7 +728,9 @@ class StepStats:
         self.slot_steps = {"live": 0, "empty": 0}
         self.prefill_tokens = 0
         self.rows = {"admitted": 0, "retired": 0}
-        self.host_s = dict.fromkeys(AR_HOST_PHASES, 0.0)
+        self.host_s = dict.fromkeys(AR_TURN_PHASES, 0.0)
+        #: wall seconds of each turn of a loop that launched a step
+        self.turns = profiling.Histogram(AR_TURN_BUCKETS_S)
         #: per expert layer: assignments, distinct experts summed over
         #: steps, the fullest expert's assignments summed over steps, the
         #: assignments that fell on experts the chip holds
@@ -735,6 +763,8 @@ class StepStats:
                                          - group["live_slot_steps"])
             for phase in AR_HOST_PHASES:
                 self.host_s[phase] += group["host_ms"][phase] / 1e3
+            for phase in AR_SETTLE_PHASES:
+                self.host_s[phase] += group[phase + "_ms"] / 1e3
             self._add_loads(group["layers"], group["assignments"],
                             group["experts_touched"],
                             group["max_expert_assignments"],
@@ -823,12 +853,22 @@ class StepStats:
                 lambda e=event: float(self.rows[e]))
         host = registry.counter(
             "sonata_ar_host_seconds_total",
-            "Host seconds of a step loop's iterations, none blocked on the "
-            "device, by phase: launch (the step's asynchronous call), admit "
-            "(prefills enqueued), retire (vocoder programs enqueued).")
-        for phase in AR_HOST_PHASES:
+            "Seconds of a step loop's turns by phase.  Not blocked on the "
+            "device: launch (the step's asynchronous call), admit (prefills "
+            "enqueued), retire (vocoder programs enqueued), record (sums, "
+            "spans, the loop's trace handed to its exporters), other (the "
+            "turn less its phases: locks, the garbage collector).  Blocked "
+            "on it: device_wait (until the step before has run); its share "
+            "of the sum falls as the loop turns host-bound.")
+        for phase in AR_TURN_PHASES:
             host.labels(phase=phase).set_function(
                 lambda p=phase: self.host_s[p])
+        registry.histogram(
+            "sonata_ar_turn_seconds",
+            "Wall seconds of one turn of a step loop, launch to launch "
+            "(admit, launch, retire, wait for the step before, record): a "
+            "stall of the loop's thread is a count at 0.1 s and above.",
+            buckets=AR_TURN_BUCKETS_S).attach(self.turns)
         launches = registry.counter(
             "sonata_moe_expert_matmul_total",
             "Launches of step and prefill programs, by what their expert "
@@ -894,6 +934,218 @@ _step_stats = StepStats()
 def step_stats() -> StepStats:
     """The process's one :class:`StepStats`."""
     return _step_stats
+
+
+# ---------------------------------------------------------------------------
+# compile events: which program compiled, when, for how long, on which thread
+# ---------------------------------------------------------------------------
+
+#: ``jax.monitoring``'s time spans of a program's way to an executable
+COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend"}
+#: events JAX fires inside a backend span, and what they say of the
+#: persistent cache: asked (and, unless a hit follows, compiled: an entry
+#: is written only above the cache's thresholds), loaded, written
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "miss",
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss"}
+COMPILE_STAGES = ("warmup", "serving")
+#: distinct program names counted apart (jitted programs are a few dozen;
+#: eager operations keep their own); the rest count as ``other``
+MAX_COMPILE_PROGRAMS = 256
+
+#: per thread: ``depth`` of open compile spans, ``cache`` (what the open
+#: backend span has heard of the persistent cache), ``sink`` (the list of
+#: whoever pays for what compiles here, or None)
+_compiling = threading.local()
+
+
+def _program_name(fun_name) -> str:
+    """``fun_name`` without its ``jit(...)``, safe as a label value."""
+    name = str(fun_name or "unknown")
+    wrapped = re.match(r"^\w+\((.*)\)$", name)
+    if wrapped:
+        name = wrapped.group(1)
+    return re.sub(r"[^\w.<>:-]", "_", name)[:64] or "unknown"
+
+
+class CompileStats:
+    """Process-lifetime counters of XLA compiles, fed by the listener of
+    :func:`install_compile_listener` and read by ``/metrics``: how many and
+    how many seconds, by program, phase (``trace`` | ``lower`` |
+    ``backend``), persistent cache (``hit`` | ``miss`` | ``off``: the backend
+    phase's; a trace or a lowering consults none and says ``off``) and
+    stage (``warmup`` until the scope's ``mark_warmup_complete``,
+    ``serving`` after)."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        #: set by the installed scope (``scope._set_compile_stage``)
+        self.stage = COMPILE_STAGES[0]
+        #: (program, phase, cache, stage) -> [count, seconds]
+        self._totals: dict = {}
+        self._programs: set = set()
+        self._registry = None
+
+    def record(self, record: dict) -> None:
+        program = record["program"]
+        with self._lock:
+            if program not in self._programs:
+                if len(self._programs) >= MAX_COMPILE_PROGRAMS:
+                    program = "other"
+                self._programs.add(program)
+            key = (program, record["phase"], record["cache"], self.stage)
+            totals = self._totals.get(key)
+            if totals is None:
+                totals = self._totals[key] = [0, 0.0]
+                if self._registry is not None:
+                    self._bind(key)
+            totals[0] += 1
+            totals[1] += record["seconds"]
+
+    def snapshot(self) -> dict:
+        """``{(program, phase, cache, stage): (count, seconds)}``."""
+        with self._lock:
+            return {k: tuple(v) for k, v in self._totals.items()}
+
+    def bind_metrics(self, registry) -> None:
+        """Scrape-time callbacks, as :meth:`ProgramStats.bind_metrics`; a
+        series appears with its first compile."""
+        with self._lock:
+            self._registry = registry
+            for key in self._totals:
+                self._bind(key)
+
+    def _bind(self, key: tuple) -> None:
+        labels = dict(zip(("program", "phase", "cache", "stage"), key))
+        r = self._registry
+        r.counter(
+            "sonata_compile_total",
+            "XLA compiles by program (the jitted function's name; an eager "
+            "operation's own), phase (trace, lower, backend), persistent "
+            "cache (hit: loaded; miss: compiled; off: not asked) and stage "
+            "(warmup until the boot warm-up completed, serving after: "
+            "whatever compiles then holds a serving thread)."
+        ).labels(**labels).set_function(
+            lambda k=key: float(self._totals[k][0]))
+        r.counter(
+            "sonata_compile_seconds_total",
+            "Seconds the compiles of sonata_compile_total took, on the "
+            "thread that asked (nested traces count in their parent's)."
+        ).labels(**labels).set_function(
+            lambda k=key: self._totals[k][1])
+
+
+_compile_stats = CompileStats()
+
+
+def compile_stats() -> CompileStats:
+    """The process's one :class:`CompileStats`."""
+    return _compile_stats
+
+
+def _on_compile_start(event: str, value=None, **kwargs) -> None:
+    # JAX records a span's start as a scalar: what tells a nested span
+    # (a jitted function traced inside another's trace) from its parent
+    if event in COMPILE_PHASES:
+        _compiling.depth = getattr(_compiling, "depth", 0) + 1
+        if COMPILE_PHASES[event] == "backend":
+            _compiling.cache = "off"
+
+
+def _on_cache_event(event: str, **kwargs) -> None:
+    state = _CACHE_EVENTS.get(event)
+    if state is not None:
+        _compiling.cache = state
+
+
+def _on_compile_span(event: str, start: float, end: float,
+                     **kwargs) -> None:
+    phase = COMPILE_PHASES.get(event)
+    if phase is None:
+        return
+    depth = _compiling.depth = max(getattr(_compiling, "depth", 1) - 1, 0)
+    if depth:
+        return      # nested: its time is its parent's
+    try:
+        # JAX reads time.time(); the spans' clock is the monotonic one
+        anchor = profiling.clock_anchor()
+        shift = anchor["monotonic"] - anchor["wall"]
+        record = {
+            "program": _program_name(kwargs.get("fun_name")), "phase": phase,
+            "cache": (getattr(_compiling, "cache", "off")
+                      if phase == "backend" else "off"),
+            "seconds": end - start, "start": start + shift,
+            "end": end + shift, "thread": threading.current_thread().name}
+        _compile_stats.record(record)
+        sink = getattr(_compiling, "sink", None)
+        if sink is not None:
+            sink.append(record)
+        ctx = _CTX.get()
+        if ctx is not None:
+            ctx[0].new_span(
+                "compile", parent=ctx[1], start=record["start"],
+                end=record["end"],
+                attrs={k: record[k] for k in ("program", "phase", "cache")})
+    except Exception:   # a listener must never break a compile
+        log.exception("compile listener failed")
+
+
+_compile_listener_installed = False
+
+
+def install_compile_listener() -> None:
+    """Register the listener with ``jax.monitoring``, once a process
+    (called where the persistent cache is enabled: every long-lived entry
+    point starts there, before its first compile)."""
+    global _compile_listener_installed
+    with _default_lock:
+        if _compile_listener_installed:
+            return
+        from jax import monitoring
+
+        monitoring.register_scalar_listener(_on_compile_start)
+        monitoring.register_event_listener(_on_cache_event)
+        monitoring.register_event_time_span_listener(_on_compile_span)
+        _compile_listener_installed = True
+
+
+@contextlib.contextmanager
+def compile_sink() -> Iterator[list]:
+    """Collect the records of what compiles on this thread inside the
+    block: whoever closes a record of the work that paid for a compile
+    opens one around it and reads the list (:func:`compile_attrs`).  An
+    inner block takes what compiles under it; the outer one never sees
+    that, and no other thread's."""
+    outer = getattr(_compiling, "sink", None)
+    sink = _compiling.sink = []
+    try:
+        yield sink
+    finally:
+        _compiling.sink = outer
+
+
+def compile_attrs(paid: list) -> dict:
+    """What a record gains from the compiles it paid for, which are taken
+    off ``paid``: ``compile_ms`` and ``compiled`` (the programs' names), or
+    nothing where nothing compiled."""
+    if not paid:
+        return {}
+    records, paid[:] = list(paid), []
+    return {"compile_ms": round(sum(r["seconds"] for r in records) * 1e3, 3),
+            "compiled": sorted({r["program"] for r in records})}
+
+
+def launch_compile(paid: list) -> dict:
+    """The ``compile`` of a launch from what ran under it: ``cold`` where
+    a backend compile or a cache load did, else ``cached``; with
+    ``compile_ms`` and ``compiled`` where anything compiled."""
+    # a load from the persistent cache holds the thread as a compile does
+    cold = any(r["phase"] == "backend" for r in paid)
+    return dict(compile_attrs(paid), compile="cold" if cold else "cached")
 
 
 # ---------------------------------------------------------------------------

@@ -42,7 +42,14 @@ closed every few seconds), one ``dispatch`` span per group of
 rows attended over, ``ssm_state_bytes`` of recurrent state the live rows'
 steps read and wrote, per expert layer ``assignments``, ``experts_touched``,
 ``max_expert_assignments`` and, of the experts the chip holds,
-``held_assignments`` and ``held_experts_touched``, ``host_ms`` by phase)
+``held_assignments`` and ``held_experts_touched``, ``host_ms`` by phase,
+and of the loop's turns: ``wall_ms`` and, beside ``host_ms``'s three,
+``device_wait_ms`` (blocked until the step before had run), ``record_ms``
+(the rest of settling it) and ``other_ms`` (the turn less its phases), which
+sum to ``wall_ms``; of the longest turn ``turn_ms_max``, ``turn_max_phase``
+(which phase held most of it) and ``turn_max_step``; ``arrivals`` (rows
+admitted); ``compile_ms`` and ``compiled`` where a program compiled on the
+loop's thread outside a prefill's or a vocoder's launch)
 beside the engine's ``block_length``, ``denoising_steps``, ``ssm_layers`` and
 ``expert_matmul`` (``grouped`` | ``ragged_dot``: what the step program's
 expert products run) and ``attention`` (``slot_kernel`` | ``einsum``: what
@@ -56,8 +63,13 @@ what their program produced is on the host (a prefill's load, a row's
 samples), and a vocoder's says what the row needed and what it was padded
 to (``frames_needed``, ``frames_bucket``) and what the finisher thread
 spent on it (``fetch_wait_ms`` until the program had run, ``finish_ms`` of
-its own work after).  The always-on counters are
-:class:`~sonata_tpu.serving.tracing.StepStats`.
+its own work after).  A prefill's and a vocoder's ``compile`` (``cold`` |
+``cached``, with ``compile_ms`` and ``compiled`` where anything compiled) is
+the engine's to say (``shape``); a compile after the warm-up counts against
+the voice (``scope.note_runtime_compile``).  The always-on counters are
+:class:`~sonata_tpu.serving.tracing.StepStats`.  While ``/debug/profile``
+holds a capture the loop's phases are mirrored into it as ``sonata:admit |
+launch | retire | settle`` with ``step_no``.
 
 The engine (a voice: :class:`~sonata_tpu.models.unit_voice.UnitVoice`)
 gives ``slots``, ``expert_layers``, ``block_length``, ``denoising_steps``,
@@ -85,7 +97,8 @@ from typing import Optional
 import numpy as np
 
 from ..core import OperationError
-from ..serving import tracing
+from ..serving import scope, tracing
+from ..utils import profiling
 
 log = logging.getLogger("sonata.steploop")
 
@@ -152,6 +165,12 @@ class Row:
         #: kept does not fill the device
         self.dump: Optional[list] = None
 
+    def traced(self):
+        """The row's request made current on the calling thread (the
+        loop's), so that what compiles under the row's launch is a
+        ``compile`` span of the request that waited for it."""
+        return tracing.use_trace(*(self.context or (None,)))
+
     def span(self, start: float, end: float, **attrs) -> None:
         if self.context is not None:
             trace, parent = self.context
@@ -182,6 +201,8 @@ class StepLoop:
         self._trace_began = 0.0
         self._trace_seq = 0
         self._group = None
+        #: what compiled on the loop's thread and no launch has claimed
+        self._paid: list = []
         #: prefills enqueued whose load has not been read yet (read with
         #: the next step's, so that an admit does not wait for the device):
         #: ``(row, when admitted, the span's attributes, load)``
@@ -231,7 +252,11 @@ class StepLoop:
     def _run(self) -> None:
         failure = "the voice's step loop was closed"
         try:
-            self._loop()
+            # what compiles on this thread outside a prefill's or a
+            # vocoder's launch (the step program, a gather, an eager
+            # operation) is the step group's to report
+            with tracing.compile_sink() as self._paid:
+                self._loop()
         except Exception as e:  # the loop must fail its rows, not hang them
             log.exception("step loop %s failed", self.name)
             failure = f"step loop failed: {type(e).__name__}: {e}"
@@ -247,6 +272,7 @@ class StepLoop:
         engine = self.engine
         cache = engine.new_cache()
         pending = None          # the step before: (its load, when launched)
+        turn_began = time.perf_counter()
         while True:
             with self._cond:
                 while not self._closed and not self._waiting \
@@ -254,15 +280,19 @@ class StepLoop:
                     if pending is not None or self._group is not None:
                         break
                     self._cond.wait()
+                    turn_began = time.perf_counter()    # idle is no turn
                 if self._closed:
                     return
                 arrivals = []
                 while self._waiting and self.slots.in_use + len(
                         arrivals) < engine.slots:
                     arrivals.append(self._waiting.popleft())
+            step_no = self._step_no
             t0 = time.perf_counter()
-            for row in arrivals:
-                cache = self._admit(cache, row)
+            if arrivals:
+                with profiling.annotation("sonata:admit", step_no=step_no):
+                    for row in arrivals:
+                        cache = self._admit(cache, row)
             t1 = time.perf_counter()
             rows = self.slots.live()
             if not rows:
@@ -272,6 +302,7 @@ class StepLoop:
                 pending = None
                 self._close_group(time.monotonic())
                 self._roll_trace(force=True)
+                turn_began = time.perf_counter()
                 continue
             live = np.zeros((engine.slots,), bool)
             temperature = np.zeros((engine.slots,), np.float32)
@@ -288,45 +319,58 @@ class StepLoop:
                 sums["units"] += row.plan.units(row.done + 1) \
                     - row.plan.units(row.done)
             launched = time.monotonic()
-            cache, kept, load = engine.step(cache, live, temperature,
-                                            self._step_no)
-            load.copy_to_host_async()
+            with profiling.annotation("sonata:launch", step_no=step_no):
+                cache, kept, load = engine.step(cache, live, temperature,
+                                                step_no)
+                load.copy_to_host_async()
             self._step_no += 1
             t2 = time.perf_counter()
-            due = [row for row in rows if row.dump is not None
-                   and engine.dumped(row.plan, row.done)]
-            gathers = []
-            for k in range(0, len(due), DUMP_ROWS):
-                part = due[k:k + DUMP_ROWS]
-                got = engine.take_rows(kept, [r.slot for r in part]
-                                       + [0] * (DUMP_ROWS - len(part)))
-                for a in got:
-                    a.copy_to_host_async()
-                for j, row in enumerate(part):
-                    row.dump.append([row.done, (got, j)])
-                    gathers.append(row.dump[-1])
-            for row in rows:
-                row.done += 1
-                if row.done >= row.plan.launches:
-                    self._retire(cache, row)
+            with profiling.annotation("sonata:retire", step_no=step_no):
+                due = [row for row in rows if row.dump is not None
+                       and engine.dumped(row.plan, row.done)]
+                gathers = []
+                for k in range(0, len(due), DUMP_ROWS):
+                    part = due[k:k + DUMP_ROWS]
+                    got = engine.take_rows(kept, [r.slot for r in part]
+                                           + [0] * (DUMP_ROWS - len(part)))
+                    for a in got:
+                        a.copy_to_host_async()
+                    for j, row in enumerate(part):
+                        row.dump.append([row.done, (got, j)])
+                        gathers.append(row.dump[-1])
+                for row in rows:
+                    row.done += 1
+                    if row.done >= row.plan.launches:
+                        self._retire(cache, row)
             t3 = time.perf_counter()
-            self._settle(pending)
+            with profiling.annotation("sonata:settle", step_no=step_no):
+                waited = self._settle(pending)
+            t4 = time.perf_counter()
+            # this turn, phase by phase; it joins a group's sums when its
+            # own step is settled, a turn from now
+            turn = {"admit": t1 - t0, "launch": t2 - t1, "retire": t3 - t2,
+                    "device_wait": waited, "record": t4 - t3 - waited}
+            wall = t4 - turn_began
+            turn["other"] = wall - sum(turn.values())
+            turn_began = t4
+            self.stats.turns.observe(wall)
             pending = (load, launched, sums, gathers,
-                       {"admit": t1 - t0, "launch": t2 - t1,
-                        "retire": t3 - t2})
+                       (turn, wall, step_no, len(arrivals)))
 
     def _admit(self, cache, row: Row):
         engine = self.engine
         start = time.monotonic()
         slot = self.slots.take(row)
         row.slot = slot
-        cache, kept, load, shape = engine.prefill(cache, slot, row.ids,
-                                                  row.temperature)
+        with row.traced():
+            cache, kept, load, shape = engine.prefill(cache, slot, row.ids,
+                                                      row.temperature)
         if row.dump is not None and kept is not None:
             for a in kept:
                 a.copy_to_host_async()
             row.dump.append([-1, (kept, None)])
         load.copy_to_host_async()
+        self._note_compile(shape, "prefill")
         block = engine.block_length
         self._admitted.append((row, start, dict(
             shape, kind="prefill", rows=1, tokens=len(row.ids), slot=slot,
@@ -340,7 +384,10 @@ class StepLoop:
     def _retire(self, cache, row: Row) -> None:
         engine = self.engine
         start = time.monotonic()
-        out, shape = engine.vocode(cache, row.slot, len(row.ids), row.budget)
+        with row.traced():
+            out, shape = engine.vocode(cache, row.slot, len(row.ids),
+                                       row.budget)
+        self._note_compile(shape, "vocode")
         record = None
         if row.dump is not None:
             record = engine.row_record(cache, row.slot)
@@ -351,21 +398,38 @@ class StepLoop:
             self._finish.append((row, out, shape, record, start))
             self._finish_cond.notify()
 
-    def _settle(self, pending) -> None:
+    def _note_compile(self, shape: dict, kind: str) -> None:
+        """A launch that compiled after the warm-up counts against the
+        voice (``sonata_runtime_cold_compiles_total``)."""
+        if shape.get("compile") == "cold":
+            scope.note_runtime_compile(
+                self.name or None,
+                f"{kind} {', '.join(shape.get('compiled', ()))}")
+
+    def _settle(self, pending) -> float:
         """The step before the one just launched has finished: fetch its
         load (this is where the loop waits for the device) and add it to
-        the group's sums."""
+        the group's sums.  Returns the seconds spent blocked on the device
+        (inside ``np.asarray(load)``, of the prefills enqueued this turn
+        and of the step, and nowhere else)."""
+        waited = 0.0
         admitted, self._admitted = self._admitted, []
         for row, start, attrs, load in admitted:
+            t = time.perf_counter()
+            loads = np.asarray(load)
+            waited += time.perf_counter() - t
             self.stats.record_prefill(attrs["tokens"], self.layers,
-                                      np.asarray(load), row.plan.units(0),
+                                      loads, row.plan.units(0),
                                       attrs["expert_matmul"],
                                       attrs["attention"])
             row.span(start, time.monotonic(), **attrs)
         if pending is None:
-            return
-        load, launched, sums, gathers, host = pending
+            return waited
+        load, launched, sums, gathers, (turn, wall, step_no, arrivals) = \
+            pending
+        t = time.perf_counter()
         loads = np.asarray(load)
+        waited += time.perf_counter() - t
         now = time.monotonic()
         for entry in gathers:
             # gathered behind a step that has finished: to the host, so that
@@ -381,7 +445,10 @@ class StepLoop:
                 "max_expert_assignments": [0] * len(self.layers),
                 "held_assignments": [0] * len(self.layers),
                 "held_experts_touched": [0] * len(self.layers),
-                "host_ms": dict.fromkeys(tracing.AR_HOST_PHASES, 0.0)}
+                "host_ms": dict.fromkeys(tracing.AR_HOST_PHASES, 0.0),
+                "wall_ms": 0.0, "turn_ms_max": 0.0, "turn_max_phase": None,
+                "turn_max_step": None, "arrivals": 0,
+                **{p + "_ms": 0.0 for p in tracing.AR_SETTLE_PHASES}}
         g["steps"] += 1
         for key, value in sums.items():
             g[key] += int(value)
@@ -392,11 +459,20 @@ class StepLoop:
             touched, assigned = tracing.held_load(loads[k])
             g["held_experts_touched"][k] += int(touched)
             g["held_assignments"][k] += int(assigned)
-        for phase, seconds in host.items():
-            g["host_ms"][phase] += seconds * 1e3
+        for phase, seconds in turn.items():
+            if phase in g["host_ms"]:
+                g["host_ms"][phase] += seconds * 1e3
+            else:
+                g[phase + "_ms"] += seconds * 1e3
+        g["wall_ms"] += wall * 1e3
+        g["arrivals"] += arrivals
+        if wall * 1e3 > g["turn_ms_max"]:
+            g.update(turn_ms_max=wall * 1e3, turn_max_step=step_no,
+                     turn_max_phase=max(turn, key=turn.get))
         if g["steps"] >= STEP_GROUP:
             self._close_group(now)
             self._roll_trace()
+        return waited
 
     def _close_group(self, end: float) -> None:
         g, self._group = self._group, None
@@ -404,6 +480,13 @@ class StepLoop:
             return
         start = g.pop("start")
         g["host_ms"] = {k: round(v, 3) for k, v in g["host_ms"].items()}
+        for key in ("wall_ms", "turn_ms_max", *(
+                p + "_ms" for p in tracing.AR_SETTLE_PHASES)):
+            g[key] = round(g[key], 3)
+        took = tracing.launch_compile(self._paid)
+        self._note_compile(took, "step group")
+        del took["compile"]     # a group says what compiled, if anything
+        g.update(took)
         g.update(kind="step", slots=self.engine.slots, layers=self.layers,
                  block_length=self.engine.block_length,
                  denoising_steps=self.engine.denoising_steps,

@@ -40,9 +40,18 @@ def enable_persistent_compile_cache(min_compile_secs: float = 1.0) -> str:
     could be pre-created and poisoned by another local user) and handed to
     ``jax.config``.  A directory that cannot be created raises: a process
     that silently runs uncached re-pays every compile on every boot.
+
+    Every long-lived entry point starts here, before its first compile:
+    this is also where the process's one compile listener is registered
+    (:func:`sonata_tpu.serving.tracing.install_compile_listener`), so that
+    what a start spends tracing, lowering, compiling and loading from this
+    cache is counted program by program.
     """
     import jax
 
+    from ..serving.tracing import install_compile_listener
+
+    install_compile_listener()
     cache_dir = compile_cache_dir()
     if not os.environ.get(CACHE_DIR_ENV):
         os.makedirs(cache_dir, mode=0o700, exist_ok=True)

@@ -153,7 +153,7 @@ def annotation(name: str, **ids):
     return jax.profiler.TraceAnnotation(name, **ids)
 
 
-def _clock_anchor() -> dict:
+def clock_anchor() -> dict:
     """The wall clock and the monotonic clock (the one the program's
     spans use) read together."""
     return {"wall": time.time(), "monotonic": time.monotonic()}
@@ -189,17 +189,17 @@ def capture_profile(seconds: float, log_dir: Optional[str] = None) -> dict:
         raise RuntimeError("a profiler capture is already running")
     anchors = {}
     try:
-        anchors["start_called"] = _clock_anchor()
+        anchors["start_called"] = clock_anchor()
         jax.profiler.start_trace(log_dir)
         try:
-            anchors["start_returned"] = _clock_anchor()
+            anchors["start_returned"] = clock_anchor()
             _capturing = True
             time.sleep(seconds)
         finally:
             _capturing = False
-            anchors["stop_called"] = _clock_anchor()
+            anchors["stop_called"] = clock_anchor()
             jax.profiler.stop_trace()
-            anchors["stop_returned"] = _clock_anchor()
+            anchors["stop_returned"] = clock_anchor()
     finally:
         _PROFILE_LOCK.release()
     return {"log_dir": log_dir, "anchors": anchors}

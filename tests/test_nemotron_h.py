@@ -430,6 +430,10 @@ def test_the_warm_up_holds_as_many_caches_as_the_device_has_room_for(
         for shape in shapes:
             voice.warm_shape(shape)
         assert voice._warm_caches._value == 1
-        assert voice._first_use(("vocode", shapes[-1][1])) == "cached"
+        # warm: the same launch again compiles nothing (the listener's word)
+        out, launch = voice.vocode(voice.new_cache(), 0, 1, shapes[-1][1])
+        jax.block_until_ready(out)
+        assert launch["frames_bucket"] == shapes[-1][1]
+        assert launch["compile"] == "cached" and "compile_ms" not in launch
     finally:
         voice.close()

@@ -1,0 +1,268 @@
+"""The step loop's account of its own turns, on an engine of this file's own
+that touches no jax (the protocol is in ``steploop.py``'s docstring): which
+phase held the longest turn, what the loop spent blocked on the device, that
+a group's phases sum to its wall time, what the always-on series gain, and
+what the loop mirrors into a profiler capture."""
+
+from __future__ import annotations
+
+import contextlib
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from sonata_tpu.serving import MetricsRegistry, tracing
+from sonata_tpu.serving import scope as scope_mod
+from sonata_tpu.serving.metrics import parse_prometheus_text
+from sonata_tpu.serving.scope import Scope
+from sonata_tpu.synth import steploop
+from sonata_tpu.utils import profiling
+
+TURN_PHASES = ("admit", "launch", "retire", "device_wait", "record", "other")
+
+
+class Load:
+    """What a program hands back: on the host at ``ready_at`` (the
+    ``perf_counter``'s time), and whoever reads it earlier waits."""
+
+    def __init__(self, ready_at: float = 0.0):
+        self.ready_at = ready_at
+
+    def copy_to_host_async(self) -> None:
+        pass
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(max(self.ready_at - time.perf_counter(), 0.0))
+        return np.zeros((0, 3), np.int64)
+
+
+class Plan:
+    block = 1
+
+    def __init__(self, launches: int):
+        self.launches = launches
+
+    def units(self, done: int) -> int:
+        return done
+
+    def attended(self, done: int) -> int:
+        return done + 1
+
+    def commits(self, done: int) -> bool:
+        return True
+
+
+class Engine:
+    """One unit a launch, ``launches`` of them a row; ``prefill_s`` is what
+    a prefill keeps the host, ``step_s`` what a step keeps the device."""
+
+    slots = 4
+    expert_layers = ()
+    block_length = 1
+    denoising_steps = 1
+    expert_matmul = "ragged_dot"
+    attention = "einsum"
+    ssm_layers = 0
+    ssm_state_bytes = 0
+
+    def __init__(self, launches=6, prefill_s=0.0, step_s=0.0,
+                 prefill_compile=None):
+        self.launches, self.prefill_s, self.step_s = (launches, prefill_s,
+                                                      step_s)
+        self.prefill_compile = prefill_compile or {"compile": "cached"}
+        self.steps = []
+        self.device_free_at = 0.0      # programs run one behind the other
+
+    def new_cache(self):
+        return {}
+
+    def plan(self, n_ids: int, budget: int) -> Plan:
+        return Plan(self.launches)
+
+    def prefill(self, cache, slot, ids, temperature):
+        time.sleep(self.prefill_s)
+        return cache, None, Load(), dict(self.prefill_compile,
+                                         expert_matmul="ragged_dot",
+                                         attention="einsum", text_bucket=32)
+
+    def step(self, cache, live, temperature, step_no):
+        self.steps.append(step_no)
+        self.device_free_at = max(self.device_free_at,
+                                  time.perf_counter()) + self.step_s
+        return cache, (), Load(self.device_free_at)
+
+    def vocode(self, cache, slot, n_ids, units):
+        return object(), {"batch_bucket": 1, "frames_bucket": 64,
+                          "compile": "cached"}
+
+    def wait_audio(self, out) -> None:
+        pass
+
+    def fetch_audio(self, out, units):
+        return np.zeros((units,), np.float32)
+
+
+def run_rows(engine: Engine, name: str, rows: int = 1) -> list:
+    """Rows through a loop of ``engine``; the attributes of the step groups
+    it recorded."""
+    loop = steploop.StepLoop(engine, name=name)
+    try:
+        futures = [loop.submit([1, 2, 3], 8, 0.0) for _ in range(rows)]
+        for f in futures:
+            assert f.result(timeout=60.0).shape == (8,)
+        deadline = time.monotonic() + 30.0
+        while time.monotonic() < deadline:
+            groups = [s.attrs for t in tracing.default_tracer().recent_traces()
+                      if t.request_id.startswith(f"ar-steps-{name}-")
+                      for s in t.spans_snapshot()
+                      if s.name == "dispatch" and s.attrs.get("kind") == "step"]
+            if sum(g["steps"] for g in groups) == len(engine.steps) > 0:
+                return groups
+            time.sleep(0.01)
+        raise AssertionError("the loop never handed its groups on")
+    finally:
+        loop.close()
+
+
+@pytest.mark.parametrize("engine,phase", [
+    (dict(prefill_s=0.08), "admit"),
+    (dict(step_s=0.04), "device_wait"),
+], ids=["a-slow-prefill", "a-load-slow-to-arrive"])
+def test_the_longest_turn_names_the_phase_that_held_it(engine, phase):
+    eng = Engine(**engine)
+    groups = run_rows(eng, f"turns-{phase}")
+    longest = max(groups, key=lambda g: g["turn_ms_max"])
+    assert longest["turn_max_phase"] == phase
+    assert longest["turn_ms_max"] >= 30.0
+    assert longest["turn_max_step"] in eng.steps
+    assert sum(g["arrivals"] for g in groups) == 1
+
+
+def test_a_slow_device_shows_as_the_loops_wait_and_nothing_else():
+    groups = run_rows(Engine(launches=8, step_s=0.03), "turns-wait")
+    wall = sum(g["wall_ms"] for g in groups)
+    waited = sum(g["device_wait_ms"] for g in groups)
+    # each turn but the first waits out the step before: 7 x 30 ms
+    assert waited >= 7 * 30.0 * 0.9
+    assert waited / wall > 0.8
+    assert sum(sum(g["host_ms"].values()) for g in groups) < 0.2 * wall
+
+
+def test_a_groups_phases_sum_to_its_wall_time_and_host_ms_keeps_its_keys():
+    groups = run_rows(Engine(launches=40, step_s=0.001), "turns-sum", rows=3)
+    assert len(groups) >= 2          # 40 steps: one full group and the rest
+    for g in groups:
+        assert set(g["host_ms"]) == set(tracing.AR_HOST_PHASES) == {
+            "launch", "admit", "retire"}
+        parts = sum(g["host_ms"].values()) + g["device_wait_ms"] \
+            + g["record_ms"] + g["other_ms"]
+        assert parts == pytest.approx(g["wall_ms"], abs=0.02)
+        assert 0 < g["turn_ms_max"] <= g["wall_ms"] + 1e-3
+        assert g["turn_max_phase"] in TURN_PHASES
+        # nothing compiled on the loop's thread: the attributes are absent
+        assert "compile_ms" not in g and "compiled" not in g
+
+
+def test_the_series_gain_the_three_phases_and_the_turns_histogram():
+    registry = MetricsRegistry()
+    stats = tracing.step_stats()
+    stats.bind_metrics(registry)
+    before = dict(stats.host_s), stats.turns.snapshot().total
+    eng = Engine(launches=5, step_s=0.02)
+    run_rows(eng, "turns-series")
+    assert stats.host_s["device_wait"] - before[0]["device_wait"] >= 0.06
+    assert stats.turns.snapshot().total - before[1] == len(eng.steps)
+    page = parse_prometheus_text(registry.render())
+    phases = {labels["phase"] for labels, _ in
+              page["sonata_ar_host_seconds_total"]}
+    assert phases == set(TURN_PHASES)
+    buckets = {labels["le"] for labels, _ in
+               page["sonata_ar_turn_seconds_bucket"]}
+    assert {"0.1", "+Inf"} <= buckets
+    assert page["sonata_ar_turn_seconds_count"][0][1] >= len(eng.steps)
+
+
+def test_outside_a_capture_the_loop_makes_no_annotation(monkeypatch):
+    made = []
+    real = profiling.annotation
+
+    def watched(name, **ids):
+        out = real(name, **ids)
+        made.append((name, out))
+        return out
+
+    monkeypatch.setattr(steploop.profiling, "annotation", watched)
+    run_rows(Engine(launches=3), "turns-quiet")
+    assert made and all(out is profiling.NO_ANNOTATION for _, out in made)
+
+
+def test_inside_a_capture_the_loop_mirrors_its_phases(monkeypatch):
+    import jax
+
+    seen, lock = [], threading.Lock()
+
+    @contextlib.contextmanager
+    def fake(name, **ids):
+        with lock:
+            seen.append((name, ids))
+        yield
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", fake)
+    monkeypatch.setattr(profiling, "_capturing", True)
+    eng = Engine(launches=3)
+    run_rows(eng, "turns-capture")
+    names = {name for name, _ in seen}
+    assert names == {"sonata:admit", "sonata:launch", "sonata:retire",
+                     "sonata:settle"}
+    assert {ids["step_no"] for name, ids in seen
+            if name == "sonata:launch"} == set(eng.steps)
+    # one admit for the one turn that had an arrival
+    assert sum(name == "sonata:admit" for name, _ in seen) == 1
+
+
+def test_a_launch_that_compiled_after_the_warm_up_counts_against_the_voice():
+    sc = Scope(slos="error_rate:0.01")
+    scope_mod.install(sc)
+    try:
+        cold = {"compile": "cold", "compile_ms": 12.5,
+                "compiled": ["lfm2_prefill"]}
+        run_rows(Engine(launches=2, prefill_compile=cold), "unit-before")
+        assert sc.runtime_cold_compiles("unit-before") == 0   # still warming
+        sc.mark_warmup_complete()
+        run_rows(Engine(launches=2, prefill_compile=cold), "unit-after")
+        assert sc.runtime_cold_compiles("unit-after") == 1
+        run_rows(Engine(launches=2), "unit-cached")
+        assert sc.runtime_cold_compiles("unit-cached") == 0
+        # the stock path's tables are as they were: no dispatch was noted
+        assert sc.dispatches_total == 0 and sc.cold_compiles_total == 0
+        assert sc.buckets_snapshot()["buckets"] == []
+    finally:
+        scope_mod.uninstall(sc)
+        tracing.compile_stats().stage = "warmup"
+
+
+def test_the_report_reads_the_four_names_of_a_capture():
+    from tools import profile_report
+
+    def note(name, start_ms, dur_ms, **ids):
+        return {"name": name, "start_ns": start_ms * 1e6,
+                "dur_ns": dur_ms * 1e6,
+                "ids": {k: str(v) for k, v in ids.items()}}
+
+    notes = [note("sonata:launch", 0.0, 1.0, step_no=7),
+             note("sonata:retire", 1.0, 0.5, step_no=7),
+             note("sonata:settle", 1.5, 13.0, step_no=7),
+             note("sonata:admit", 15.0, 40.0, step_no=8),
+             note("sonata:launch", 55.0, 1.5, step_no=8),
+             note("sonata:retire", 56.5, 0.5, step_no=8),
+             note("sonata:settle", 57.0, 60.0, step_no=8),
+             note("sonata:enqueue", 3.0, 2.0, dispatch_id="a1")]
+    got = profile_report.loop_turns(notes)
+    assert set(got["phases"]) == {"admit", "launch", "retire", "settle"}
+    assert got["phases"]["settle"] == {"count": 2, "total_ms": 73.0,
+                                       "max_ms": 60.0, "max_step_no": 8}
+    assert got["turns"] == 2
+    assert got["longest_turn"] == {"step_no": 8, "ms": 102.0}
+    assert profile_report.loop_turns(notes[-1:]) is None   # the stock path

@@ -10,6 +10,11 @@ names its stages (``jax.named_scope``), so a capture is read without
 guessing shapes or fitting clocks:
 
 - ``annotations``: the ``sonata:`` events by name;
+- ``loop_turns``: a step loop's ``sonata:admit | launch | retire | settle``
+  (a unit voice's; they carry ``step_no``): per phase the count, the sum and
+  the longest with its ``step_no``, and the longest turn (one ``step_no``'s
+  phases from the first's start to the last's end), so a stall of the
+  loop's thread lies beside the device's operations on one clock;
 - ``stages_s``: device seconds by stage, from the scope path that the
   device plane keeps in each operation's *metadata* (statistic ``tf_op``,
   beside ``hlo_category``, ``flops`` and ``bytes_accessed``;
@@ -40,6 +45,9 @@ ROOT = Path(__file__).resolve().parent.parent
 SCOPE_STAT = "tf_op"
 DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):\d+")
 #: stage of a device operation, by the first pattern its scope path holds
+#: the phases of a step loop's turn, as the loop mirrors them
+LOOP_PHASES = ("sonata:admit", "sonata:launch", "sonata:retire",
+               "sonata:settle")
 STAGES = (("decode/pre", "decode/pre"), ("decode/post", "decode/post"),
           (r"decode/ups\d+", None), ("decode", "decode (other)"),
           ("epilogue", "epilogue"), ("flow_reverse", "flow_reverse"),
@@ -85,8 +93,35 @@ def annotations(events: list) -> list:
     for e in events:
         if e["name"].startswith("sonata:"):
             out.append(dict(e, ids={k: str(v) for k, v in e["stats"].items()
-                                    if k in ("request_id", "dispatch_id")}))
+                                    if k in ("request_id", "dispatch_id",
+                                             "step_no")}))
     return sorted(out, key=lambda e: e["start_ns"])
+
+
+def loop_turns(notes: list):
+    """What the step loop's mirrored phases say (``notes``:
+    :func:`annotations`), or ``None`` where the capture holds none."""
+    phases = [e for e in notes
+              if e["name"] in LOOP_PHASES and "step_no" in e["ids"]]
+    if not phases:
+        return None
+    out, turns = {}, {}
+    for e in phases:
+        acc = out.setdefault(e["name"][len("sonata:"):],
+                             {"count": 0, "total_ms": 0.0, "max_ms": 0.0,
+                              "max_step_no": None})
+        acc["count"] += 1
+        acc["total_ms"] += e["dur_ns"] / 1e6
+        if e["dur_ns"] / 1e6 > acc["max_ms"]:
+            acc.update(max_ms=e["dur_ns"] / 1e6,
+                       max_step_no=int(e["ids"]["step_no"]))
+        turn = turns.setdefault(int(e["ids"]["step_no"]),
+                                [e["start_ns"], e["start_ns"]])
+        turn[0] = min(turn[0], e["start_ns"])
+        turn[1] = max(turn[1], e["start_ns"] + e["dur_ns"])
+    step_no, (a, b) = max(turns.items(), key=lambda t: t[1][1] - t[1][0])
+    return {"phases": out, "turns": len(turns),
+            "longest_turn": {"step_no": step_no, "ms": (b - a) / 1e6}}
 
 
 def device_ops(events: list) -> list:
@@ -252,7 +287,8 @@ def clock(events: list, traces: list, profile: dict) -> dict:
 def report(log_dir, traces=None, profile=None) -> dict:
     events = load(log_dir)
     by_name = {}
-    for e in annotations(events):
+    notes = annotations(events)
+    for e in notes:
         by_name.setdefault(e["name"], []).append(e)
     ops = device_ops(events)
     metadata = op_metadata(log_dir) if ops else {}
@@ -263,6 +299,7 @@ def report(log_dir, traces=None, profile=None) -> dict:
                             "median_ms": statistics.median(
                                 e["dur_ns"] for e in v) / 1e6}
                         for k, v in sorted(by_name.items())},
+        "loop_turns": loop_turns(notes),
         "device_ops": len(ops),
         "metadata_example": metadata.get(ops[len(ops) // 2]["name"])
         if ops else None,
