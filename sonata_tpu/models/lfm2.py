@@ -17,11 +17,15 @@ the router's scoring (``router_scoring``), ``head_dim`` where it is not
 an expert's form (``expert_act``) and, in the layer's weights, a shared
 expert (``p["shared"]``).
 
-Two programs over one set of weights: :func:`prefill` runs one row's prompt
-whole, writes its state into a slot of the cache and samples the row's
-first token; :func:`step` advances every slot by one token.  Both keep the
-generation state on the device (:func:`new_cache`): the host says which
-slots are live and reads nothing back to decide the next launch.
+Three programs over one set of weights: :func:`prefill` runs one row's
+prompt whole, writes its state into a slot of the cache and samples the
+row's first token; :func:`step` advances every slot by one token;
+:func:`step_admit` does both in one launch (the live rows step, an arriving
+row's prompt rides beside them: each kind of row through its own mixer,
+everything row-wise, the expert layer above all, once over both, so that a
+touched expert leaves HBM once).  All keep the generation state on the
+device (:func:`new_cache`): the host says which slots are live and reads
+nothing back to decide the next launch.
 
 **Precision** (what the comparison of a benchmark cell is held to): weights
 are bfloat16; every matrix product takes bfloat16 inputs and accumulates in
@@ -440,7 +444,7 @@ def sample(logits, temperature, key, units: UnitIds):
 
 
 # ---------------------------------------------------------------------------
-# the generation state and the two programs
+# the generation state and the programs
 # ---------------------------------------------------------------------------
 
 def new_cache(cfg: Lfm2Config, slots: int, positions: int) -> dict:
@@ -468,6 +472,64 @@ def new_cache(cfg: Lfm2Config, slots: int, positions: int) -> dict:
             slots, positions, len(cfg.expert_layers),
             cfg.num_experts_per_tok), jnp.int8),
     }
+
+
+def join(cache: dict, slot, n, logits, routes: list, temperature, key,
+         units: UnitIds) -> dict:
+    """What a prompt leaves a slot with beside its layers' state: the row's
+    first unit, sampled from the ``logits`` ``[1, V]`` at the prompt's last
+    position, its place, its count, and the experts its ``[T]`` tokens
+    chose."""
+    unit = sample(logits, temperature[None], key, units)[0]
+    cache["token"] = cache["token"].at[slot].set(unit)
+    cache["pos"] = cache["pos"].at[slot].set(n)
+    cache["count"] = cache["count"].at[slot].set(1)
+    cache["units"] = cache["units"].at[slot, 0].set(unit)
+    cache["routes"] = write_slot(cache["routes"], jnp.stack(routes, 1), slot)
+    return cache
+
+
+def advance(cache: dict, live, logits, routes: list, temperature, step_no,
+            units: UnitIds, seed: int) -> dict:
+    """What a step leaves every slot with beside its layers' state: the
+    live rows' next unit, sampled from ``logits`` ``[S, V]``, one place and
+    one unit more, and the experts their tokens chose."""
+    pos = cache["pos"]
+    key = jax.random.fold_in(jax.random.PRNGKey(seed), step_no)
+    unit = sample(logits, temperature, key, units)
+    rows = jnp.arange(live.shape[0])
+    span = cache["units"].shape[1]
+    cache["routes"] = write_rows(cache["routes"],
+                                 jnp.stack(routes, 1)[:, None], pos[:, None])
+    cache["units"] = cache["units"].at[
+        rows, jnp.minimum(cache["count"], span - 1)].set(
+        jnp.where(live, unit, 0))
+    cache["token"] = jnp.where(live, unit, cache["token"])
+    # an empty slot stays where it is, inside the cache
+    cache["pos"] = jnp.where(live, jnp.minimum(pos + 1, span - 1), pos)
+    cache["count"] = jnp.where(live, cache["count"] + 1, cache["count"])
+    return cache
+
+
+def advance_and_join(params: Params, cache: dict, h, routes: list, live,
+                     temperature, step_no, n, slot, row_temperature, row_key,
+                     cfg, units: UnitIds, seed: int) -> tuple:
+    """The end of a step that carried an arrival, from ``h`` ``[S + T, H]``
+    behind the last layer (the slots' rows, then the prompt's): the head
+    over the ``S`` rows and the prompt's last position together (its matrix
+    read once), then :func:`advance` of the live rows and, after it so
+    that the prompt's write is the last word on ``slot``, :func:`join`.
+    Returns the cache and the logits ``[S + 1, V]``: the slots' rows, then
+    the prompt's (left in one array: the ``S`` rows apart would be a copy
+    of all of them, 134 MB at 256 slots of 131 072 ids)."""
+    s = live.shape[0]
+    last = lax.dynamic_slice_in_dim(h, s + n - 1, 1, axis=0)
+    logits = _head(jnp.concatenate([h[:s], last]), params, cfg)
+    cache = advance(cache, live, logits[:s], [r[:s] for r in routes],
+                    temperature, step_no, units, seed)
+    cache = join(cache, slot, n, logits[s:], [r[s:] for r in routes],
+                 row_temperature, row_key, units)
+    return cache, logits
 
 
 def prefill(params: Params, cache: dict, ids, n, slot, temperature, key, *,
@@ -498,12 +560,7 @@ def prefill(params: Params, cache: dict, ids, n, slot, temperature, key, *,
         h = _ffn_half(h + op, p, i, cfg, held, valid, routes, loads)
     last = lax.dynamic_slice_in_dim(h, n - 1, 1, axis=0)
     logits = _head(last, params, cfg)
-    unit = sample(logits, temperature[None], key, units)[0]
-    cache["token"] = cache["token"].at[slot].set(unit)
-    cache["pos"] = cache["pos"].at[slot].set(n)
-    cache["count"] = cache["count"].at[slot].set(1)
-    cache["units"] = cache["units"].at[slot, 0].set(unit)
-    cache["routes"] = write_slot(cache["routes"], jnp.stack(routes, 1), slot)
+    cache = join(cache, slot, n, logits, routes, temperature, key, units)
     return cache, logits[0], jnp.stack(loads)
 
 
@@ -534,17 +591,55 @@ def step(params: Params, cache: dict, live, temperature, step_no, *,
             i_attn += 1
         h = _ffn_half(h + op, p, i, cfg, held, live, routes, loads)
     logits = _head(h, params, cfg)
-    key = jax.random.fold_in(jax.random.PRNGKey(seed), step_no)
-    unit = sample(logits, temperature, key, units)
-    rows = jnp.arange(live.shape[0])
-    span = cache["units"].shape[1]
-    cache["routes"] = write_rows(cache["routes"],
-                                 jnp.stack(routes, 1)[:, None], pos[:, None])
-    cache["units"] = cache["units"].at[
-        rows, jnp.minimum(cache["count"], span - 1)].set(
-        jnp.where(live, unit, 0))
-    cache["token"] = jnp.where(live, unit, cache["token"])
-    # an empty slot stays where it is, inside the cache
-    cache["pos"] = jnp.where(live, jnp.minimum(pos + 1, span - 1), pos)
-    cache["count"] = jnp.where(live, cache["count"] + 1, cache["count"])
+    cache = advance(cache, live, logits, routes, temperature, step_no, units,
+                    seed)
+    return cache, logits, jnp.stack(loads)
+
+
+def step_admit(params: Params, cache: dict, live, temperature, step_no, ids,
+               n, slot, row_temperature, row_key, *, cfg: Lfm2Config,
+               units: UnitIds, seed: int = 0, held=None):
+    """A step that carries an arrival: what :func:`step` over ``live`` and
+    then :func:`prefill` of ``ids`` ``[T]`` (``n`` real) into ``slot`` give,
+    in one launch.  Per layer the mixer runs once per kind of row (the
+    slots' tokens through the slots' state, the prompt whole), and what is
+    row-wise runs once over both, ``[S + T, H]``: the norms, the
+    feed-forward, the expert layer (one sort, each touched expert read
+    once), the head over the ``S`` rows and the prompt's last position.
+
+    ``slot`` is not live here (its row steps from the next launch on): its
+    stale place is computed like any empty slot's, and the prompt's write
+    comes after the step's on every buffer, so it is the last word on the
+    slot.  Returns the cache, the logits ``[S + 1, V]`` (the slots' rows as
+    :func:`step` gives them, then the prompt's last position as
+    :func:`prefill` does) and the load of both kinds of row together."""
+    s, t = live.shape[0], ids.shape[0]
+    valid = jnp.concatenate([live, jnp.arange(t) < n])
+    cache = dict(cache, k=list(cache["k"]), v=list(cache["v"]),
+                 conv=list(cache["conv"]))
+    pos = cache["pos"]
+    h = params["embed"][jnp.concatenate([cache["token"], ids])].astype(F32)
+    i_attn = i_conv = 0
+    routes, loads = [], []
+    for i, kind in enumerate(cfg.layer_types):
+        p = params["layers"][i]
+        u = rms_norm(h, p["op_norm"], cfg.norm_eps)
+        if kind == "conv":
+            op, state = conv_op_step(u[:s], p["op"], cache["conv"][i_conv])
+            joined, columns = conv_op_seq(u[s:], p["op"], n)
+            cache["conv"][i_conv] = state.at[slot].set(columns)
+            i_conv += 1
+        else:
+            op, k_buf, v_buf = attn_op_step(
+                u[:s], p["op"], cfg, cache["k"][i_attn], cache["v"][i_attn],
+                pos)
+            joined, k, v = attn_op_seq(u[s:], p["op"], cfg)
+            cache["k"][i_attn] = write_slot(k_buf, k, slot)
+            cache["v"][i_attn] = write_slot(v_buf, v, slot)
+            i_attn += 1
+        h = _ffn_half(h + jnp.concatenate([op, joined]), p, i, cfg, held,
+                      valid, routes, loads)
+    cache, logits = advance_and_join(
+        params, cache, h, routes, live, temperature, step_no, n, slot,
+        row_temperature, row_key, cfg, units, seed)
     return cache, logits, jnp.stack(loads)

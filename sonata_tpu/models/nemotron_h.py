@@ -51,9 +51,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.slot_attention import stored_shape, write_rows, write_slot
-from .lfm2 import BF16, F32, UnitIds, _head, attn_op_seq, attn_op_step, mm, \
-    moe_ffn, pad_experts, rms_norm, sample
+from ..ops.slot_attention import stored_shape, write_slot
+from .lfm2 import BF16, F32, UnitIds, _head, advance, advance_and_join, \
+    attn_op_seq, attn_op_step, join, mm, moe_ffn, pad_experts, rms_norm
 
 Params = dict
 #: a layer's mixer by its character of the pattern: Mamba-2, attention,
@@ -300,7 +300,7 @@ def _experts(u, p, cfg: NemotronConfig, valid, routes: list, loads: list):
 
 
 # ---------------------------------------------------------------------------
-# the generation state and the two programs
+# the generation state and the programs
 # ---------------------------------------------------------------------------
 
 def new_cache(cfg: NemotronConfig, slots: int, positions: int) -> dict:
@@ -367,12 +367,7 @@ def prefill(params: Params, cache: dict, ids, n, slot, temperature, key, *,
         h = h + out
     logits = _head(lax.dynamic_slice_in_dim(h, n - 1, 1, axis=0), params,
                    cfg)
-    unit = sample(logits, temperature[None], key, units)[0]
-    cache["token"] = cache["token"].at[slot].set(unit)
-    cache["pos"] = cache["pos"].at[slot].set(n)
-    cache["count"] = cache["count"].at[slot].set(1)
-    cache["units"] = cache["units"].at[slot, 0].set(unit)
-    cache["routes"] = write_slot(cache["routes"], jnp.stack(routes, 1), slot)
+    cache = join(cache, slot, n, logits, routes, temperature, key, units)
     return cache, logits[0], jnp.stack(loads)
 
 
@@ -407,17 +402,54 @@ def step(params: Params, cache: dict, live, temperature, step_no, *,
             out = _experts(u, p["mixer"], cfg, live, routes, loads)
         h = h + out
     logits = _head(h, params, cfg)
-    key = jax.random.fold_in(jax.random.PRNGKey(seed), step_no)
-    unit = sample(logits, temperature, key, units)
-    rows = jnp.arange(live.shape[0])
-    span = cache["units"].shape[1]
-    cache["routes"] = write_rows(cache["routes"],
-                                 jnp.stack(routes, 1)[:, None], pos[:, None])
-    cache["units"] = cache["units"].at[
-        rows, jnp.minimum(cache["count"], span - 1)].set(
-        jnp.where(live, unit, 0))
-    cache["token"] = jnp.where(live, unit, cache["token"])
-    # an empty slot stays where it is, inside the cache
-    cache["pos"] = jnp.where(live, jnp.minimum(pos + 1, span - 1), pos)
-    cache["count"] = jnp.where(live, cache["count"] + 1, cache["count"])
+    cache = advance(cache, live, logits, routes, temperature, step_no, units,
+                    seed)
+    return cache, logits, jnp.stack(loads)
+
+
+def step_admit(params: Params, cache: dict, live, temperature, step_no, ids,
+               n, slot, row_temperature, row_key, *, cfg: NemotronConfig,
+               units: UnitIds, seed: int = 0):
+    """A step that carries an arrival (:func:`~.lfm2.step_admit` says what
+    that is): :func:`step` over ``live`` and :func:`prefill` of ``ids``
+    ``[T]`` (``n`` real) into ``slot`` in one launch.  A Mamba or attention
+    layer runs once per kind of row, an expert layer once over both.
+    ``slot``'s stale state moves with the step like any empty slot's, and
+    the prompt's state, written after, replaces it whole.  Returns the
+    cache, the logits ``[S + 1, V]`` (the slots' rows, then the prompt's
+    last position) and the load of both kinds of row together."""
+    s, t = live.shape[0], ids.shape[0]
+    valid = jnp.concatenate([live, jnp.arange(t) < n])
+    cache = _open(cache)
+    pos = cache["pos"]
+    h = params["embed"][jnp.concatenate([cache["token"], ids])].astype(F32)
+    i_attn = i_ssm = 0
+    routes, loads = [], []
+    for i, kind in enumerate(cfg.pattern):
+        p = params["layers"][i]
+        u = rms_norm(h, p["norm"], cfg.norm_eps)
+        if kind == "M":
+            out, states, columns = mamba_step(
+                u[:s], p["mixer"], cfg, cache["ssm"][i_ssm],
+                cache["conv"][i_ssm])
+            joined, state, conv = mamba_seq(u[s:], p["mixer"], cfg, n)
+            cache["ssm"][i_ssm] = states.at[slot].set(state)
+            cache["conv"][i_ssm] = columns.at[slot].set(conv)
+            i_ssm += 1
+            out = jnp.concatenate([out, joined])
+        elif kind == "*":
+            out, k_buf, v_buf = attn_op_step(
+                u[:s], p["mixer"], cfg, cache["k"][i_attn],
+                cache["v"][i_attn], pos, qkv=_qkv)
+            joined, k, v = attn_op_seq(u[s:], p["mixer"], cfg, qkv=_qkv)
+            cache["k"][i_attn] = write_slot(k_buf, k, slot)
+            cache["v"][i_attn] = write_slot(v_buf, v, slot)
+            i_attn += 1
+            out = jnp.concatenate([out, joined])
+        else:
+            out = _experts(u, p["mixer"], cfg, valid, routes, loads)
+        h = h + out
+    cache, logits = advance_and_join(
+        params, cache, h, routes, live, temperature, step_no, n, slot,
+        row_temperature, row_key, cfg, units, seed)
     return cache, logits, jnp.stack(loads)

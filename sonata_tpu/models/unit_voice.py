@@ -224,6 +224,23 @@ class Lfm2Backbone:
 
         return jax.jit(lfm2_prefill, donate_argnums=(1,))
 
+    def build_step_admit(self):
+        """The step that carries an arrival: a step by name (every live
+        row gains a token), and what the prefill program gives beside."""
+        cfg, units, seed = self.cfg, self.units, self.seed
+
+        def lfm2_step_admit(params, cache, live, temperature, step_no, ids,
+                            n, slot, row_temperature, row_no):
+            key = jax.random.fold_in(jax.random.PRNGKey(seed + 1), row_no)
+            cache, logits, load = lfm2.step_admit(
+                params, cache, live, temperature, step_no, ids, n, slot,
+                row_temperature, key, cfg=cfg, units=units, seed=seed)
+            # a flagged row's slot finds its row in the whole array; the
+            # arrival's is the one behind the slots'
+            return cache, (logits,), (logits[-1],), load
+
+        return jax.jit(lfm2_step_admit, donate_argnums=(1,))
+
     def units_of(self, cache, n_ids: int) -> tuple:
         """The array a row's units lie in and where they start."""
         return cache["units"], 0
@@ -249,6 +266,9 @@ class SdarBackbone:
 
     held, ssm_layers, ssm_state_bytes = None, 0, 0
     pack_layer = staticmethod(sdar.pack_layer)
+    #: no step of this backbone carries an arrival (a pass keeps a phase a
+    #: slot): a row's prompt runs apart, in ``sdar_prefill``
+    build_step_admit = None
     #: a flagged row's logits are ``[B, V]`` a pass: every sixteenth block
     DUMP_EVERY = 16
 
@@ -399,6 +419,19 @@ class NemotronBackbone(Lfm2Backbone):
             return cache, (logits,), load
 
         return jax.jit(nemotron_prefill, donate_argnums=(1,))
+
+    def build_step_admit(self):
+        cfg, units, seed = self.cfg, self.units, self.seed
+
+        def nemotron_step_admit(params, cache, live, temperature, step_no,
+                                ids, n, slot, row_temperature, row_no):
+            key = jax.random.fold_in(jax.random.PRNGKey(seed + 1), row_no)
+            cache, logits, load = nemotron_h.step_admit(
+                params, cache, live, temperature, step_no, ids, n, slot,
+                row_temperature, key, cfg=cfg, units=units, seed=seed)
+            return cache, (logits,), (logits[-1],), load
+
+        return jax.jit(nemotron_step_admit, donate_argnums=(1,))
 
 
 BACKBONES = {"lfm2_moe": Lfm2Backbone, "sdar_moe": SdarBackbone,
@@ -599,13 +632,15 @@ class UnitVoice(BaseModel):
     # -- warm-up lattice (serving/warmup.py) ---------------------------------
     def lattice_shapes(self, mode: str = "full") -> list:
         """Every program a request can need: the step (and the gather of
-        what a flagged row keeps of it), a prefill for each text bucket a
-        slot can hold with its frames, a vocoder for each frame bucket those
-        give (``minimal``: the step alone; the rest compiles on first use).  The whole list, and not what some traffic
-        happens to reach, because a first use compiles on the loop's own
-        thread: every live row stands still for as long as it takes.  What
-        fits a slot bounds it: at 1024 positions the step, 7 prefills and
-        7 vocoders."""
+        what a flagged row keeps of it), for each text bucket a slot can
+        hold with its frames what admits a row of it (the step that carries
+        its prompt, ``step_admit``, or where :meth:`carries` says no a
+        ``prefill``), a vocoder for each frame bucket those give
+        (``minimal``: the step alone; the rest compiles on first use).  The
+        whole list, and not what some traffic happens to reach, because a
+        first use compiles on the loop's own thread: every live row stands
+        still for as long as it takes.  What fits a slot bounds it: at 1024
+        positions the step, 7 admitting programs and 7 vocoders."""
         shapes = [("step",)]
         if mode == "full":
             texts = [t for t in TEXT_BUCKETS
@@ -614,7 +649,8 @@ class UnitVoice(BaseModel):
                           if self._fits(n))
             frames = sorted({bucket_for(self.frame_budget(n), FRAME_BUCKETS)
                              for n in range(1, longest + 1)})
-            shapes += [("prefill", t) for t in texts]
+            shapes += [("step_admit" if self.carries(self._fewest_ids(t))
+                        else "prefill", t) for t in texts]
             shapes += [("vocode", f) for f in frames]
         return shapes
 
@@ -628,7 +664,8 @@ class UnitVoice(BaseModel):
 
     def warm_shape(self, shape: tuple) -> None:
         """Compile one program of :meth:`lattice_shapes`: a dummy dispatch
-        through the jit cache real traffic uses.  A step or a prefill runs
+        through the jit cache real traffic uses.  A step (with or without
+        an arrival) or a prefill runs
         on a cache of its own, and the warm-up compiles several shapes at
         once: as many of them hold a cache as the device's free memory
         takes (:meth:`_warm_cache_slots`).  The vocoder reads a row's units
@@ -640,20 +677,25 @@ class UnitVoice(BaseModel):
                      for k, v in shapes.items()}
             jax.block_until_ready(self.vocode(cache, 0, 1, shape[1])[0])
             return
+        from ..synth.steploop import DUMP_ROWS
+
         with self._warm_cache_slots():
             cache = self.new_cache()
-            if shape[0] == "step":
-                from ..synth.steploop import DUMP_ROWS
-
-                out = self.step(cache, np.zeros((self.slots,), bool),
-                                np.zeros((self.slots,), np.float32), 0)
-                # the gather of what flagged rows keep of a launch is a
-                # program too: cold, it compiled on the loop's thread in
-                # front of the first flagged row's step (PERF.md §7)
-                out = (out, self.take_rows(out[1], [0] * DUMP_ROWS))
-            else:
+            idle = (np.zeros((self.slots,), bool),
+                    np.zeros((self.slots,), np.float32), 0)
+            if shape[0] == "prefill":
                 out = self.prefill(cache, 0,
                                    [0] * self._fewest_ids(shape[1]), 0.0)[:3]
+            else:
+                out = self.step(cache, *idle) if shape[0] == "step" else \
+                    self.step_admit(cache, *idle, 0,
+                                    [0] * self._fewest_ids(shape[1]), 0.0)[:4]
+                # the gather of what flagged rows keep of a launch is a
+                # program too (one for a step's logits, one for a carrying
+                # step's, which hold a row more): cold, it compiled on the
+                # loop's thread in front of the first flagged row's step
+                # (PERF.md §7)
+                out = (out, self.take_rows(out[1], [0] * DUMP_ROWS))
             jax.block_until_ready(out)
             del cache, out
 
@@ -716,29 +758,66 @@ class UnitVoice(BaseModel):
         fn = self._program(("step",), self.backbone.build_step)
         return fn(self.params, cache, live, temperature, np.int32(step_no))
 
-    def prefill(self, cache, slot: int, ids: list, temperature: float):
+    def carries(self, n_ids: int) -> bool:
+        """Whether a row of ``n_ids`` prompt ids is admitted in a step
+        (:meth:`step_admit`) and not by a prefill apart: the backbone
+        builds such a step, and the prompt's rows beside the slots' leave
+        the expert products what a step alone runs them on (a shape the
+        kernel's tile rule hands to ``ragged_dot`` would cost every live
+        row its stream)."""
+        if self.backbone.build_step_admit is None:
+            return False
+        t = bucket_for(n_ids, TEXT_BUCKETS)
+        return lfm2.expert_matmul(self.cfg, self.slots + t,
+                                  self.backbone.held) == self.expert_matmul
+
+    def _arrival(self, ids: list, rows: int) -> tuple:
+        """A prompt as its program takes it (padded to its text bucket, its
+        length, the row's number for its key) and what the row's
+        ``prefill`` span says of the program: ``rows`` tokens go through
+        the expert products beside the prompt's."""
         t = bucket_for(len(ids), TEXT_BUCKETS)
         padded = np.zeros((t,), np.int32)
         padded[:len(ids)] = ids
-        # one jitted function: a text bucket is a shape of its argument
-        fn = self._program(("prefill",), self.backbone.build_prefill)
         shape = {"text_bucket": t,
-                 "expert_matmul": lfm2.expert_matmul(self.cfg, t,
+                 "expert_matmul": lfm2.expert_matmul(self.cfg, rows + t,
                                                      self.backbone.held),
-                 # a prefill attends over its own prompt, whole
+                 # a prompt attends over itself, whole
                  "attention": "einsum"}
         if self.ssm_layers:
             shape["ssm_chunks"] = self.backbone.prefill_chunks(t)
         self._prefill_no += 1
+        return padded, np.int32(len(ids)), np.int32(self._prefill_no), shape
+
+    def prefill(self, cache, slot: int, ids: list, temperature: float):
+        padded, n, row_no, shape = self._arrival(ids, 0)
+        # one jitted function: a text bucket is a shape of its argument
+        fn = self._program(("prefill",), self.backbone.build_prefill)
         # ``compile``: whether a backend compile or a load from the
         # persistent cache ran under this launch, on this thread
         with tracing.compile_sink() as paid:
             cache, out, load = fn(
-                self.params, cache, padded, np.int32(len(ids)),
-                np.int32(slot), np.float32(temperature),
-                np.int32(self._prefill_no))
+                self.params, cache, padded, n, np.int32(slot),
+                np.float32(temperature), row_no)
         shape.update(tracing.launch_compile(paid))
         return cache, out, load, shape
+
+    def step_admit(self, cache, live, temperature, step_no: int, slot: int,
+                   ids: list, row_temperature: float):
+        """One launch over every slot that carries the row arriving in
+        ``slot`` (not live in it): :meth:`step` and :meth:`prefill` in one
+        program.  Returns the cache, what a flagged row keeps of the step,
+        the load of both together, what a flagged arrival keeps of its
+        prompt, and what the row's ``prefill`` span says of the program."""
+        padded, n, row_no, shape = self._arrival(ids, self.slots)
+        fn = self._program(("step_admit",), self.backbone.build_step_admit)
+        with tracing.compile_sink() as paid:
+            cache, kept, first, load = fn(
+                self.params, cache, live, temperature, np.int32(step_no),
+                padded, n, np.int32(slot), np.float32(row_temperature),
+                row_no)
+        shape.update(tracing.launch_compile(paid))
+        return cache, kept, load, first, shape
 
     def vocode(self, cache, slot: int, n_ids: int, units: int):
         """The vocoder program of one retired row, enqueued: the slot's

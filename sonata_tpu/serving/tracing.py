@@ -728,6 +728,9 @@ class StepStats:
         self.slot_steps = {"live": 0, "empty": 0}
         self.prefill_tokens = 0
         self.rows = {"admitted": 0, "retired": 0}
+        #: rows admitted by ``(voice, how)``: ``step`` (the prompt rode a
+        #: step) or ``apart`` (a prefill program of its own)
+        self.admits: dict = {}
         self.host_s = dict.fromkeys(AR_TURN_PHASES, 0.0)
         #: wall seconds of each turn of a loop that launched a step
         self.turns = profiling.Histogram(AR_TURN_BUCKETS_S)
@@ -772,20 +775,38 @@ class StepStats:
 
     def record_prefill(self, tokens: int, layers, loads, units: int = 0,
                        expert_matmul: str = "ragged_dot",
-                       attention: str = "einsum") -> None:
-        """One row admitted: its prompt's tokens, what they chose, the
-        units the prefill itself gave the row, and what its program's
-        expert products and its attention ran."""
+                       attention: str = "einsum", voice: str = "") -> None:
+        """One row admitted by a prefill program of its own: its prompt's
+        tokens, what they chose, the units the prefill itself gave the row,
+        and what its program's expert products and its attention ran."""
         with self._lock:
             self.expert_matmul[expert_matmul, "prefill"] += 1
             self.attention[attention, "prefill"] += 1
-            self.prefill_tokens += tokens
-            self.units += units
-            self.rows["admitted"] += 1
             self._add_loads(layers, [int(l[2]) for l in loads],
                             [int(l[0]) for l in loads],
                             [int(l[1]) for l in loads],
                             [int(held_load(l)[1]) for l in loads])
+            self._admitted(tokens, units, voice, "apart")
+
+    def record_admit_step(self, tokens: int, units: int = 0,
+                          voice: str = "") -> None:
+        """One row admitted in a step: its prompt's tokens and the units
+        the launch gave the row.  What the prompt chose is in the step's
+        load, and no program of its own ran."""
+        with self._lock:
+            self._admitted(tokens, units, voice, "step")
+
+    def _admitted(self, tokens: int, units: int, voice: str,
+                  how: str) -> None:
+        self.prefill_tokens += tokens
+        self.units += units
+        self.rows["admitted"] += 1
+        key = (voice, how)
+        if key not in self.admits:
+            self.admits[key] = 0
+            if self._registry is not None:
+                self._bind_admits([key])
+        self.admits[key] += 1
 
     def _add_loads(self, layers, assignments, touched, fullest,
                    held) -> None:
@@ -841,12 +862,13 @@ class StepStats:
                 lambda s=state: float(self.slot_steps[s]))
         registry.counter(
             "sonata_ar_prefill_tokens_total",
-            "Prompt tokens run by prefill programs."
+            "Prompt tokens run by prefill programs and by steps that "
+            "carried an arrival."
         ).set_function(lambda: float(self.prefill_tokens))
         rows = registry.counter(
             "sonata_ar_rows_total",
             "Rows (sentences) of step-wise generation, by event: admitted "
-            "(prefilled into a slot) or retired (frame budget reached, "
+            "(its prompt run into a slot) or retired (frame budget reached, "
             "units handed to the vocoder).")
         for event in ("admitted", "retired"):
             rows.labels(event=event).set_function(
@@ -904,6 +926,19 @@ class StepStats:
         self._registry = registry
         with self._lock:
             self._bind_layers(list(self.moe))
+            self._bind_admits(list(self.admits))
+
+    def _bind_admits(self, keys) -> None:
+        metric = self._registry.counter(
+            "sonata_ar_admits_total",
+            "Rows admitted by step-wise generation loops, by voice and by "
+            "how the prompt ran: step (it rode the step it arrived beside: "
+            "one launch streamed the experts once for the live rows and "
+            "the prompt) or apart (a prefill program of its own, between "
+            "two steps).")
+        for voice, how in keys:
+            metric.labels(voice=voice, how=how).set_function(
+                lambda k=(voice, how): float(self.admits[k]))
 
     def _bind_layers(self, layers) -> None:
         r = self._registry

@@ -8,8 +8,14 @@ launch (keys and values, convolution columns, a block half denoised) stays
 on the device in the slot the row was given.  The loop, on a thread of its
 own:
 
-1. **admit**: waiting rows take free slots, one prefill program each (per
-   arrival: the prompt runs whole and writes the slot's state);
+1. **admit**: a waiting row takes a free slot.  Where the engine's step
+   can carry an arrival (``engine.carries(n_ids)``), the row's prompt rides
+   the launch it arrives beside (``engine.step_admit``): **one prompt a
+   launch**, the others wait for the launches that follow, which run back
+   to back while anything waits; the row is live from the next launch on.
+   Where it cannot (the engine offers no such step, or not for this
+   prompt), the row is prefilled apart, one program a row between two
+   steps, as many rows as have come;
 2. **step**: one program over all ``S`` slots, a static shape, empty slots
    masked and counted;
 3. **retire**: a row leaves after its last launch; its units go to the
@@ -36,10 +42,12 @@ in, belongs to the engine (``new_cache``).
 request: the loop records its steps on a trace it owns (``ar-steps``,
 closed every few seconds), one ``dispatch`` span per group of
 :data:`STEP_GROUP` steps with ``kind: step`` and the group's sums (``steps``,
-``live_slot_steps``, ``slots``, ``units`` the live rows were left with,
+``live_slot_steps`` (slots that held a row in a launch: the rows that
+stepped and the row whose prompt it carried), ``slots``, ``units`` the live rows were left with,
 ``positions`` they ran, ``denoise_row_passes`` and ``commit_row_passes``
 (a row's launch that finishes a block commits), ``kv_positions`` the live
-rows attended over, ``ssm_state_bytes`` of recurrent state the live rows'
+rows attended over, ``admit_steps`` launches that carried an arrival and
+``prompt_tokens`` they carried, ``ssm_state_bytes`` of recurrent state the live rows'
 steps read and wrote, per expert layer ``assignments``, ``experts_touched``,
 ``max_expert_assignments`` and, of the experts the chip holds,
 ``held_assignments`` and ``held_experts_touched``, ``host_ms`` by phase,
@@ -54,7 +62,8 @@ beside the engine's ``block_length``, ``denoising_steps``, ``ssm_layers`` and
 ``expert_matmul`` (``grouped`` | ``ragged_dot``: what the step program's
 expert products run) and ``attention`` (``slot_kernel`` | ``einsum``: what
 reads the slots' keys and values).  Each
-prefill (``blocks`` of the prompt kept whole, ``tail_ids`` left to the
+row admitted (``admit``: ``step``, with the ``step_no`` that carried it, or
+``apart``; ``blocks`` of the prompt kept whole, ``tail_ids`` left to the
 first generated block, ``expert_matmul`` and ``attention`` of its own
 program, ``ssm_chunks``
 its state-space layers' scans ran) and each vocoder launch is a ``dispatch`` span (``kind: prefill`` |
@@ -76,7 +85,9 @@ gives ``slots``, ``expert_layers``, ``block_length``, ``denoising_steps``,
 ``expert_matmul``, ``attention``, ``ssm_layers``, ``ssm_state_bytes`` (a
 slot's),
 ``new_cache()``, ``plan(n_ids, budget)``, ``prefill(cache, slot, ids,
-temperature)``, ``step(cache, live, temperature, step_no)``, ``vocode(cache,
+temperature)``, ``step(cache, live, temperature, step_no)``, where its step
+carries arrivals ``carries(n_ids)`` and ``step_admit(cache, live,
+temperature, step_no, slot, ids, row_temperature)``, ``vocode(cache,
 slot, n_ids, units)``, ``wait_audio(out)``, ``fetch_audio(out, units)`` and,
 for flagged rows, ``dumped(plan, done)`` (which launches a row keeps),
 ``row_record(cache, slot)``, ``take_rows(kept, rows)`` and ``dump(ids,
@@ -207,6 +218,8 @@ class StepLoop:
         #: the next step's, so that an admit does not wait for the device):
         #: ``(row, when admitted, the span's attributes, load)``
         self._admitted: list = []
+        #: whether a row's prompt rides a step (None: the engine offers none)
+        self._carries = getattr(engine, "carries", None)
         self._thread = threading.Thread(
             target=self._run, name=f"sonata_steploop_{name}", daemon=True)
         self._finisher = threading.Thread(
@@ -283,19 +296,26 @@ class StepLoop:
                     turn_began = time.perf_counter()    # idle is no turn
                 if self._closed:
                     return
-                arrivals = []
-                while self._waiting and self.slots.in_use + len(
-                        arrivals) < engine.slots:
-                    arrivals.append(self._waiting.popleft())
+                # rows prefilled apart, and the one this launch carries
+                arrivals, carried = [], None
+                while self._waiting and self.slots.in_use + len(arrivals) \
+                        + (carried is not None) < engine.slots:
+                    if self._carries is None or not self._carries(
+                            len(self._waiting[0].ids)):
+                        arrivals.append(self._waiting.popleft())
+                    elif carried is None:
+                        carried = self._waiting.popleft()
+                    else:
+                        break       # one prompt a launch
             step_no = self._step_no
             t0 = time.perf_counter()
             if arrivals:
                 with profiling.annotation("sonata:admit", step_no=step_no):
                     for row in arrivals:
-                        cache = self._admit(cache, row)
+                        cache, _ = self._admit(cache, row)
             t1 = time.perf_counter()
             rows = self.slots.live()
-            if not rows:
+            if not rows and carried is None:
                 # nothing to step: what is still in flight is waited for,
                 # and the steps recorded so far go out
                 self._settle(pending)
@@ -318,10 +338,18 @@ class StepLoop:
                 sums["denoise_row_passes"] += not commits
                 sums["units"] += row.plan.units(row.done + 1) \
                     - row.plan.units(row.done)
+            # the slot of the row this launch carries holds a row too
+            sums["live_slot_steps"] += carried is not None
             launched = time.monotonic()
+            joined = None
             with profiling.annotation("sonata:launch", step_no=step_no):
-                cache, kept, load = engine.step(cache, live, temperature,
-                                                step_no)
+                if carried is None:
+                    cache, kept, load = engine.step(cache, live, temperature,
+                                                    step_no)
+                else:
+                    # the row is live from the next launch on
+                    cache, (kept, load, joined) = self._admit(
+                        cache, carried, (live, temperature, step_no))
                 load.copy_to_host_async()
             self._step_no += 1
             t2 = time.perf_counter()
@@ -354,32 +382,50 @@ class StepLoop:
             turn["other"] = wall - sum(turn.values())
             turn_began = t4
             self.stats.turns.observe(wall)
-            pending = (load, launched, sums, gathers,
-                       (turn, wall, step_no, len(arrivals)))
+            pending = (load, launched, sums, gathers, joined,
+                       (turn, wall, step_no,
+                        len(arrivals) + (carried is not None)))
 
-    def _admit(self, cache, row: Row):
+    def _admit(self, cache, row: Row, step: Optional[tuple] = None):
+        """``row`` takes a slot and its prompt a launch: a prefill program
+        of its own or, with ``step`` (``live, temperature, step_no``), the
+        step itself.  Returns the cache and, of a step, what it gave
+        (``kept``, ``load``) and what the row's ``prefill`` span waits
+        with until that load is read (``row, when admitted, the span's
+        attributes``); a prefill apart queues the like itself, with a load
+        of its own."""
         engine = self.engine
         start = time.monotonic()
         slot = self.slots.take(row)
         row.slot = slot
+        out, how = None, {"admit": "apart"}     # of the step, if any
         with row.traced():
-            cache, kept, load, shape = engine.prefill(cache, slot, row.ids,
-                                                      row.temperature)
+            if step is None:
+                cache, kept, load, shape = engine.prefill(
+                    cache, slot, row.ids, row.temperature)
+                load.copy_to_host_async()
+            else:
+                cache, *out, kept, shape = engine.step_admit(
+                    cache, *step, slot, row.ids, row.temperature)
+                how = {"admit": "step", "step_no": step[2]}
         if row.dump is not None and kept is not None:
             for a in kept:
                 a.copy_to_host_async()
             row.dump.append([-1, (kept, None)])
-        load.copy_to_host_async()
         self._note_compile(shape, "prefill")
         block = engine.block_length
-        self._admitted.append((row, start, dict(
+        joined = (row, start, dict(
             shape, kind="prefill", rows=1, tokens=len(row.ids), slot=slot,
             blocks=len(row.ids) // block, tail_ids=len(row.ids) % block,
-            wait_ms=round((start - row.t_submit) * 1e3, 3)), load))
+            wait_ms=round((start - row.t_submit) * 1e3, 3), **how))
+        if step is None:
+            self._admitted.append((*joined, load))
+        else:
+            out.append(joined)
         self.stats.slots_in_use = self.slots.in_use
         if row.plan.launches <= 0:
             self._retire(cache, row)
-        return cache
+        return cache, out
 
     def _retire(self, cache, row: Row) -> None:
         engine = self.engine
@@ -421,16 +467,22 @@ class StepLoop:
             self.stats.record_prefill(attrs["tokens"], self.layers,
                                       loads, row.plan.units(0),
                                       attrs["expert_matmul"],
-                                      attrs["attention"])
+                                      attrs["attention"], voice=self.name)
             row.span(start, time.monotonic(), **attrs)
         if pending is None:
             return waited
-        load, launched, sums, gathers, (turn, wall, step_no, arrivals) = \
-            pending
+        load, launched, sums, gathers, joined, \
+            (turn, wall, step_no, arrivals) = pending
         t = time.perf_counter()
         loads = np.asarray(load)
         waited += time.perf_counter() - t
         now = time.monotonic()
+        if joined is not None:
+            # the step carried this row's prompt: its load is the step's
+            row, start, attrs = joined
+            self.stats.record_admit_step(attrs["tokens"], row.plan.units(0),
+                                         voice=self.name)
+            row.span(start, now, **attrs)
         for entry in gathers:
             # gathered behind a step that has finished: to the host, so that
             # a flagged row's logits do not pile up on the device
@@ -447,9 +499,13 @@ class StepLoop:
                 "held_experts_touched": [0] * len(self.layers),
                 "host_ms": dict.fromkeys(tracing.AR_HOST_PHASES, 0.0),
                 "wall_ms": 0.0, "turn_ms_max": 0.0, "turn_max_phase": None,
-                "turn_max_step": None, "arrivals": 0,
+                "turn_max_step": None, "arrivals": 0, "admit_steps": 0,
+                "prompt_tokens": 0,
                 **{p + "_ms": 0.0 for p in tracing.AR_SETTLE_PHASES}}
         g["steps"] += 1
+        if joined is not None:
+            g["admit_steps"] += 1
+            g["prompt_tokens"] += joined[2]["tokens"]
         for key, value in sums.items():
             g[key] += int(value)
         for k in range(len(self.layers)):

@@ -62,6 +62,8 @@ def test_spans_and_series_say_what_the_attention_ran(
     before, steps_before = series(registry), stats.steps
     assert set(before) == {(i, p) for i in tracing.ATTENTION_IMPLS
                            for p in ("prefill", "step")}
+    # a backbone whose step carries arrivals launches no prefill program
+    rides = voice.backbone.build_step_admit is not None
     try:
         assert voice.attention == impl
         for k, text in enumerate(TEXTS):
@@ -82,7 +84,7 @@ def test_spans_and_series_say_what_the_attention_ran(
     after = series(registry)
     moved = {k: after[k] - before[k] for k in after if after[k] != before[k]}
     assert stats.steps > steps_before
-    want = {("einsum", "prefill"): float(len(TEXTS))}
+    want = {} if rides else {("einsum", "prefill"): float(len(TEXTS))}
     want[impl, "step"] = want.get((impl, "step"), 0.0) + float(
         stats.steps - steps_before)
     assert moved == want

@@ -8,7 +8,12 @@ library, and a second file could land on another worker.
   interpret mode cannot show;
 - each backbone's step program at its cell's size: it may hold no ``copy``
   of a whole key, value or routes buffer (until PR 37 XLA re-laid every
-  buffer twice a step), and both kernels are in it."""
+  buffer twice a step), and both kernels are in it;
+- the step that carries an arrival (``lfm2_step_admit``,
+  ``nemotron_step_admit``) likewise: a step's scatter and a prompt's slice
+  land on one donated buffer in one program, which is where a copy could
+  come back, and its expert products over both kinds of row are the
+  kernel's."""
 
 import functools
 import importlib
@@ -134,12 +139,14 @@ def step_shapes(name: str, sharding):
         args)
 
 
-def whole_buffer_copies(hlo: str, elements: int) -> list:
+def whole_buffer_copies(hlo: str, elements: int,
+                        types: str = "bf16|s8") -> list:
     """The ``copy`` operations of an optimised module whose result holds
-    at least ``elements`` elements of bfloat16 or int8."""
+    at least ``elements`` elements of ``types`` (bfloat16 or int8: what a
+    step writes per slot and place)."""
     found = []
     for line in hlo.splitlines():
-        m = re.search(r"= (bf16|s8)\[([\d,]+)\]\S* copy\(", line)
+        m = re.search(rf"= ({types})\[([\d,]+)\]\S* copy\(", line)
         if m and np.prod([int(n) for n in m.group(2).split(",")]) >= elements:
             found.append(line.strip()[:160])
     return found
@@ -176,3 +183,30 @@ def test_a_step_compiled_for_a_v5e_copies_no_slot_buffer_whole(
     hlo = backbone.build_step().lower(*args).compile().as_text()
     assert "slot_attention" in hlo and "grouped_matmul" in hlo
     assert whole_buffer_copies(hlo, min(per_place)) == []
+
+
+@pytest.mark.parametrize("name", ["lfm2_step", "nemotron_step"])
+def test_a_carrying_step_compiled_for_a_v5e_copies_no_slot_buffer_whole(
+        one_chip, no_compile_cache, monkeypatch, name):
+    """The step that carries an arrival, at the cells' sizes and their
+    longest text bucket (192): the prompt's ``write_slot`` follows the
+    step's ``write_rows`` on each donated buffer, and none is copied whole
+    for it (keys, values, the routes' record; nor a layer's recurrent
+    states, float32, half a gigabyte each at 256 slots); the expert
+    products over ``(S + T) x k`` rows are the kernel's and the live rows'
+    attention too."""
+    monkeypatch.setattr(gm, "_tiles_here", gm.tile_rule)
+    monkeypatch.setattr(sa, "_tiles_here", sa.tile_rule)
+    backbone, cache, args = step_shapes(name, one_chip)
+    arrival = (jax.ShapeDtypeStruct((192,), jnp.int32),
+               *(jax.ShapeDtypeStruct((), t) for t in (
+                   jnp.int32, jnp.int32, F32, jnp.int32)))
+    args += tuple(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+                  for a in arrival)
+    hlo = backbone.build_step_admit().lower(*args).compile().as_text()
+    assert f"{name}_admit" in hlo
+    assert "slot_attention" in hlo and "grouped_matmul" in hlo
+    assert "ragged-dot" not in hlo and "ragged_dot" not in hlo
+    assert whole_buffer_copies(hlo, cache["routes"].size) == []
+    for state in cache.get("ssm", ())[:1]:
+        assert whole_buffer_copies(hlo, state.size, "f32") == []

@@ -65,7 +65,10 @@ def test_the_loader_reads_a_voice_directory_of_real_tensors(voice, voice_dir):
                           want["dec"]["conv_pre"]["w"])
     assert voice.audio_output_info().sample_rate == 16000
     assert voice.lattice_shapes("minimal") == [("step",)]
-    assert ("prefill", 32) in voice.lattice_shapes("full")
+    # a row's prompt rides a step: no prefill program is in the lattice
+    full = voice.lattice_shapes("full")
+    assert ("step_admit", 32) in full
+    assert not any(shape[0] == "prefill" for shape in full)
 
 
 def test_placed_weights_are_taken_once_and_a_bare_directory_fails(
@@ -175,12 +178,13 @@ def test_rows_join_a_running_loop_and_the_loop_records_what_it_ran(voice):
     assert stats.rows["admitted"] - before[1]["admitted"] == 8
     assert stats.rows["retired"] - before[1]["retired"] == 8
     units = sum(len(a.samples) // 16 for a in list(out.values()) + alone)
-    # a row of N units takes N - 1 steps (its first unit is the prefill's)
+    # a row of N units holds a slot for N launches: the one that carries
+    # its prompt (its first unit is that launch's) and N - 1 steps
     deadline = 50
-    while stats.slot_steps["live"] - before[2] < units - 8 and deadline:
+    while stats.slot_steps["live"] - before[2] < units and deadline:
         threading.Event().wait(0.1)
         deadline -= 1
-    assert stats.slot_steps["live"] - before[2] == units - 8
+    assert stats.slot_steps["live"] - before[2] == units
     assert stats.slots_in_use == 0
     assert {layer for layer, sums in stats.moe.items()
             if list(sums) != moe_before.get(layer)} == {2, 3, 4, 5}
@@ -204,8 +208,12 @@ def test_rows_join_a_running_loop_and_the_loop_records_what_it_ran(voice):
                           and g["layers"] == [2, 3, 4, 5] for g in groups)
     assert sum(g["steps"] for g in groups) <= stats.steps - before[0]
     assert all(0 < g["live_slot_steps"] <= 3 * g["steps"] for g in groups)
-    assert all(sum(g["assignments"]) == 4 * 2 * g["live_slot_steps"]
-               for g in groups)
+    # a launch's load is its live rows' and the prompt's it carried
+    assert all(sum(g["assignments"]) == 4 * 2 * (
+        g["live_slot_steps"] - g["admit_steps"] + g["prompt_tokens"])
+        for g in groups)
+    assert sum(g["admit_steps"] for g in groups) == sum(
+        g["arrivals"] for g in groups) > 0
 
 
 def test_a_closed_voice_refuses_and_fails_what_waits(voice_dir):

@@ -362,7 +362,7 @@ def test_the_voice_runs_and_its_loop_says_what_the_state_and_the_share_cost(
         assert (voice.ssm_layers, voice.ssm_state_bytes,
                 voice.expert_layers, voice.expert_matmul) == (
             3, per_slot, [1, 4, 6], "ragged_dot")
-        assert ("prefill", 32) in voice.lattice_shapes("full")
+        assert ("step_admit", 32) in voice.lattice_shapes("full")
         with tracer.trace_request("test", request_id="row-0"):
             audio = voice.speak_batch(
                 list(voice.phonemize_text("one short row.")))
@@ -379,6 +379,8 @@ def test_the_voice_runs_and_its_loop_says_what_the_state_and_the_share_cost(
     (prefill,) = [s.attrs for s in traces["row-0"].spans_snapshot()
                   if s.attrs.get("kind") == "prefill"]
     assert prefill["ssm_chunks"] == 3 * -(-prefill["text_bucket"] // 8)
+    # the row's prompt rode the loop's first launch, which had no live row
+    assert (prefill["admit"], prefill["step_no"]) == ("step", 0)
     groups = [s.attrs for rid, t in traces.items()
               if rid.startswith("ar-steps-") for s in t.spans_snapshot()
               if s.name == "dispatch"]
@@ -386,7 +388,9 @@ def test_the_voice_runs_and_its_loop_says_what_the_state_and_the_share_cost(
     for g in groups:
         assert g["ssm_layers"] == 3 and g["layers"] == [1, 4, 6]
         assert g["ssm_state_bytes"] == 2 * per_slot * g["live_slot_steps"]
-        assert g["assignments"] == [2 * g["live_slot_steps"]] * 3
+        assert g["assignments"] == [2 * (
+            g["live_slot_steps"] - g["admit_steps"]
+            + g["prompt_tokens"])] * 3
         assert all(0 <= h <= a for h, a in zip(g["held_assignments"],
                                                g["assignments"]))
         assert all(t <= 4 * g["steps"] for t in g["held_experts_touched"])
@@ -426,7 +430,7 @@ def test_the_warm_up_holds_as_many_caches_as_the_device_has_room_for(
                                 lambda stats=stats: [Device(stats)])
             assert voice._warm_cache_slots()._value == want
         shapes = voice.lattice_shapes("full")
-        assert {s[0] for s in shapes} == {"step", "prefill", "vocode"}
+        assert {s[0] for s in shapes} == {"step", "step_admit", "vocode"}
         for shape in shapes:
             voice.warm_shape(shape)
         assert voice._warm_caches._value == 1

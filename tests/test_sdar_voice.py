@@ -274,11 +274,14 @@ def test_an_lfm2_voice_still_reads_a_unit_a_live_slot_step(tmp_path):
         v.close()
     groups = step_groups(tracer)
     assert groups and all(
-        g["units"] == g["live_slot_steps"] == g["positions"]
+        g["units"] == g["live_slot_steps"] - g["admit_steps"]
+        == g["positions"]
         == g["commit_row_passes"] and g["denoise_row_passes"] == 0
         and g["block_length"] == 1 and g["denoising_steps"] == 0
         for g in groups)
-    # the prefill gave each row its first unit; the steps gave the rest
+    # the launch that carried a row's prompt gave it its first unit; the
+    # launches it stepped in gave the rest
+    assert sum(g["admit_steps"] for g in groups) == 2
     assert sum(g["units"] for g in groups) == units - 2
     assert stats.row_passes["denoise"] == before[1]["denoise"]
     spans = [s.attrs for t in tracer.recent_traces()

@@ -2,7 +2,9 @@
 that touches no jax (the protocol is in ``steploop.py``'s docstring): which
 phase held the longest turn, what the loop spent blocked on the device, that
 a group's phases sum to its wall time, what the always-on series gain, and
-what the loop mirrors into a profiler capture."""
+what the loop mirrors into a profiler capture; and how rows are admitted:
+one prompt a launch where the engine's step carries arrivals, a prefill
+apart a row where it does not."""
 
 from __future__ import annotations
 
@@ -266,3 +268,172 @@ def test_the_report_reads_the_four_names_of_a_capture():
     assert got["turns"] == 2
     assert got["longest_turn"] == {"step_no": 8, "ms": 102.0}
     assert profile_report.loop_turns(notes[-1:]) is None   # the stock path
+
+
+# -- admitting rows: in a step, or apart ---------------------------------------
+
+class Held(Engine):
+    """An engine that offers no step that carries an arrival, and records
+    what it is asked: ``seen`` every launch (its step number, the slots
+    live in it, the slot whose prompt it carried), ``prefilled`` every
+    prefill (the launches before it, its slot).  Its first program (a
+    prefill) waits for ``gate`` and sets ``entered`` first, so that a test
+    can queue rows behind it."""
+
+    def __init__(self, launches=6):
+        super().__init__(launches=launches)
+        self.seen, self.prefilled = [], []
+        self.entered, self.gate = threading.Event(), threading.Event()
+
+    def _hold(self):
+        if not self.entered.is_set():
+            self.entered.set()
+            assert self.gate.wait(30.0)
+
+    def prefill(self, cache, slot, ids, temperature):
+        self._hold()
+        self.prefilled.append((len(self.seen), slot))
+        return super().prefill(cache, slot, ids, temperature)
+
+    def step(self, cache, live, temperature, step_no, carried=None):
+        self._hold()
+        self.seen.append((step_no, set(np.flatnonzero(live)), carried))
+        return super().step(cache, live, temperature, step_no)
+
+
+class Carrying(Held):
+    """An engine whose step carries an arrival, but for the prompt lengths
+    of ``apart``."""
+
+    def __init__(self, launches=6, apart=()):
+        super().__init__(launches=launches)
+        self.apart = set(apart)
+
+    def carries(self, n_ids: int) -> bool:
+        return n_ids not in self.apart
+
+    def step_admit(self, cache, live, temperature, step_no, slot, ids,
+                   row_temperature):
+        cache, kept, load = self.step(cache, live, temperature, step_no,
+                                      carried=slot)
+        return cache, kept, load, None, {
+            "compile": "cached", "expert_matmul": "ragged_dot",
+            "attention": "einsum", "text_bucket": 32}
+
+
+def rows_behind_the_first(engine, name: str, prompts: list):
+    """One row starts the loop, the others queue while the engine holds
+    its first program, then all run out.  Returns the step groups and each
+    row's ``prefill`` span, in the order submitted."""
+    tracer = tracing.default_tracer()
+    loop = steploop.StepLoop(engine, name=name)
+    futures = []
+    try:
+        for k, ids in enumerate(prompts):
+            with tracer.trace_request("test", request_id=f"{name}-{k}"):
+                futures.append(loop.submit(ids, 8, 0.0))
+            if k == 0:
+                assert engine.entered.wait(30.0)
+        engine.gate.set()
+        for f in futures:
+            assert f.result(timeout=60.0).shape == (8,)
+        deadline = time.monotonic() + 30.0
+        while True:     # the loop hands its last group on a turn later
+            traces = {t.request_id: t for t in tracer.recent_traces()}
+            groups = [s.attrs for rid, t in traces.items()
+                      if rid.startswith(f"ar-steps-{name}-")
+                      for s in t.spans_snapshot()
+                      if s.attrs.get("kind") == "step"]
+            if sum(g["steps"] for g in groups) == len(engine.seen):
+                break
+            assert time.monotonic() < deadline
+            time.sleep(0.01)
+    finally:
+        loop.close()
+    spans = []
+    for k in range(len(prompts)):
+        (span,) = [s.attrs for s in traces[f"{name}-{k}"].spans_snapshot()
+                   if s.attrs.get("kind") == "prefill"]
+        spans.append(span)
+    return groups, spans
+
+
+def admits(page: dict, voice: str) -> dict:
+    return {labels["how"]: value
+            for labels, value in page.get("sonata_ar_admits_total", [])
+            if labels["voice"] == voice}
+
+
+@pytest.mark.parametrize("rows", [1, 4])
+def test_rows_waiting_together_take_a_launch_each_one_prompt_a_launch(rows):
+    registry = MetricsRegistry()
+    stats = tracing.step_stats()
+    stats.bind_metrics(registry)
+    eng = Carrying(launches=5)
+    name = f"carry-{rows}"
+    prompts = [[1] * (3 + k) for k in range(rows)]
+    groups, spans = rows_behind_the_first(eng, name, prompts)
+    assert not eng.prefilled                    # no prefill program ran
+    carrying = [(step_no, slot) for step_no, _, slot in eng.seen
+                if slot is not None]
+    # k rows: k launches back to back from the loop's first, a prompt each
+    assert [step_no for step_no, _ in carrying] == list(range(rows))
+    assert sorted(slot for _, slot in carrying) == list(range(rows))
+    for step_no, slot in carrying:
+        lived = [n for n, live, _ in eng.seen if slot in live]
+        # not live in the launch that carried it, live from the next one on,
+        # for as many launches as its plan said
+        assert lived == list(range(step_no + 1, step_no + 1 + 5))
+    # the live rows advanced while the later arrivals' prompts ran
+    assert eng.seen[rows - 1][1] == set(range(rows - 1))
+    assert [(s["admit"], s["step_no"], s["tokens"]) for s in spans] == [
+        ("step", k, 3 + k) for k in range(rows)]
+    assert all(s["wait_ms"] >= 0.0 and s["text_bucket"] == 32 for s in spans)
+    assert sum(g["admit_steps"] for g in groups) == rows == sum(
+        g["arrivals"] for g in groups)
+    assert sum(g["prompt_tokens"] for g in groups) == sum(
+        len(p) for p in prompts)
+    # a slot holds a row in the launch that carries it and in its steps
+    assert sum(g["live_slot_steps"] for g in groups) == rows * (1 + 5)
+    assert sum(g["units"] for g in groups) == rows * 5
+    assert admits(parse_prometheus_text(registry.render()), name) == {
+        "step": float(rows)}
+
+
+def test_an_engine_without_such_a_step_admits_as_before():
+    registry = MetricsRegistry()
+    stats = tracing.step_stats()
+    stats.bind_metrics(registry)
+    eng = Held(launches=5)
+    groups, spans = rows_behind_the_first(eng, "apart", [[1, 2, 3]] * 4)
+    # the row that woke the loop is prefilled and steps alone once; the
+    # three that came meanwhile are prefilled in one turn, in front of one
+    # step, and are live in it
+    assert eng.prefilled == [(0, 0), (1, 1), (1, 2), (1, 3)]
+    assert [live for _, live, _ in eng.seen[:2]] == [{0}, {0, 1, 2, 3}]
+    assert all(carried is None for _, _, carried in eng.seen)
+    assert [s["admit"] for s in spans] == ["apart"] * 4
+    assert not any("step_no" in s for s in spans)
+    assert sum(g["admit_steps"] + g["prompt_tokens"] for g in groups) == 0
+    assert sum(g["arrivals"] for g in groups) == 4
+    assert sum(g["live_slot_steps"] for g in groups) == 4 * 5
+    assert admits(parse_prometheus_text(registry.render()), "apart") == {
+        "apart": 4.0}
+
+
+def test_a_prompt_the_step_does_not_take_is_prefilled_apart_beside_it():
+    """Whether a row rides is asked per row: one whose prompt the engine's
+    step does not take is prefilled apart in the same turn that carries
+    the next one."""
+    eng = Carrying(launches=5, apart={7})
+    groups, spans = rows_behind_the_first(
+        eng, "mixed", [[1] * 3, [1] * 7, [1] * 4, [1] * 5])
+    assert [s["admit"] for s in spans] == ["step", "apart", "step", "step"]
+    # the second turn prefills the row of 7 and carries the row of 4; the
+    # row of 5 waits for the launch after
+    assert eng.prefilled == [(1, 1)]
+    assert [(n, carried) for n, _, carried in eng.seen[:3]] == [
+        (0, 0), (1, 2), (2, 3)]
+    assert eng.seen[1][1] == {0, 1} and eng.seen[2][1] == {0, 1, 2}
+    assert sum(g["admit_steps"] for g in groups) == 3
+    assert sum(g["arrivals"] for g in groups) == 4
