@@ -332,6 +332,35 @@ def pad_experts(w_up, w_down):
             jnp.pad(w_down, ((0, 0), (0, pad), (0, 0))))
 
 
+def held_rows(cfg, tokens: int, held: Optional[tuple]) -> int:
+    """Rows the expert products of a program over ``tokens`` tokens run on:
+    all ``tokens x k`` assignments, or, where the layer holds a thin share
+    of the router's experts, a static bound on what the held experts get:
+    twice the ``tokens x k x count / num_experts`` an even router gives
+    them and half a row tile more, in row tiles of 128.  A share of a half
+    is not thin by this (the bound is no shorter than all the rows): its
+    programs are what they were."""
+    rows = tokens * cfg.num_experts_per_tok
+    if held is None:
+        return rows
+    even = rows * held[1] / cfg.num_experts
+    return min(rows, lanes(int(2 * even) + 64))
+
+
+def _held_experts(x, p, cfg, take, top: int, sizes, weights, mine):
+    """The assignments ``take`` (indices into the ``[N x k]`` assignments,
+    sorted by held expert: ``sizes``) through the experts: each one's
+    token of ``x`` ``[N, H]``, its result times its weight, zero where not
+    ``mine``.  Rows behind the last group come back from the products as
+    anything: masked here."""
+    up = grouped_matmul(x[take // top], p["w13"], sizes,
+                        preferred_element_type=F32)
+    y = grouped_matmul(_expert_act(up, cfg).astype(BF16), p["w2"], sizes,
+                       preferred_element_type=F32)
+    return jnp.where(mine[take][:, None],
+                     y * weights.reshape(-1)[take][:, None], 0.0)
+
+
 def moe_ffn(u, p, cfg, held: Optional[tuple] = None,
             valid=None):
     """The expert layer over tokens ``u`` ``[N, H]``.
@@ -344,7 +373,15 @@ def moe_ffn(u, p, cfg, held: Optional[tuple] = None,
     given also the distinct experts chosen *among the held* and the
     assignments that fell on them.  ``valid`` ``[N]`` masks padding and
     empty slots: they cost no expert product and count for nothing.  An
-    expert's form is the configuration's ``expert_act``."""
+    expert's form is the configuration's ``expert_act``.
+
+    **A thin share** (:func:`held_rows` below all the assignments: 8 of
+    256 experts held) gathers, multiplies and adds back only the sorted
+    assignments' first :func:`held_rows`, which are the held experts'
+    whenever those got no more; a launch in which they got more takes the
+    full-length path inside the same program (no held assignment is ever
+    left out), and the load says so with a sixth number, 1 for such a
+    launch."""
     n, top = u.shape[0], cfg.num_experts_per_tok
     first, count = held if held is not None else (0, cfg.num_experts)
     if p["w13"].shape[0] != count:
@@ -363,19 +400,35 @@ def moe_ffn(u, p, cfg, held: Optional[tuple] = None,
         order = jnp.argsort(group, stable=True)
         sizes = jnp.bincount(group, length=count + 1)[:count].astype(
             jnp.int32)
-        x = u.astype(BF16)[order // top]
-        # rows behind the last group come back as anything: masked below
-        up = grouped_matmul(x, p["w13"], sizes, preferred_element_type=F32)
-        y = grouped_matmul(_expert_act(up, cfg).astype(BF16), p["w2"], sizes,
-                           preferred_element_type=F32)
-        y = jnp.where(mine[order][:, None],
-                      y * weights.reshape(-1)[order][:, None], 0.0)
-        out = y[jnp.argsort(order)].reshape(n, top, -1).sum(1)
+        x = u.astype(BF16)
+
+        def whole():
+            y = _held_experts(x, p, cfg, order, top, sizes, weights, mine)
+            return y[jnp.argsort(order)].reshape(n, top, -1).sum(1)
+
+        bound = held_rows(cfg, n, held)
+        fits = None     # not thin: all the rows, nothing to overflow
+        if bound == n * top:
+            out = whole()
+        else:
+            def thin():
+                take = order[:bound]
+                y = _held_experts(x, p, cfg, take, top, sizes, weights, mine)
+                # each row back to its token: a 0/1 matrix, exact at
+                # ``highest`` (a token may hold two rows; a scatter would
+                # walk them one by one)
+                back = (jnp.arange(n)[:, None] == (take // top)[None, :])
+                return jnp.dot(back.astype(F32), y, precision="highest")
+
+            fits = jnp.sum(sizes) <= bound
+            out = lax.cond(fits, thin, whole)
         loads = jnp.bincount(jnp.where(counted, expert, cfg.num_experts),
                              length=cfg.num_experts + 1)[:cfg.num_experts]
         load = [jnp.sum(loads > 0), jnp.max(loads), jnp.sum(loads)]
         if held is not None:
             load += [jnp.sum(sizes > 0), jnp.sum(sizes)]
+        if fits is not None:
+            load.append(jnp.logical_not(fits))
         load = jnp.stack(load).astype(jnp.int32)
     if "shared" in p:
         with jax.named_scope("shared_expert"):
@@ -388,8 +441,9 @@ def expert_matmul(cfg, tokens: int, held: Optional[tuple] = None) -> str:
     """What the expert products of a program over ``tokens`` tokens run on
     this backend: ``"grouped"`` (this repo's kernel, both products) or
     ``"ragged_dot"``.  The shapes are :func:`moe_ffn`'s (ungated experts
-    lie in whole lanes: :func:`pad_experts`)."""
-    rows = tokens * cfg.num_experts_per_tok
+    lie in whole lanes: :func:`pad_experts`; a thin share's rows are
+    :func:`held_rows`: what all but an overflowing launch runs)."""
+    rows = held_rows(cfg, tokens, held)
     count = held[1] if held is not None else cfg.num_experts
     h, i = cfg.hidden_size, cfg.moe_intermediate_size
     i, up = (lanes(i),) * 2 if cfg.expert_act == "relu2" else (i, 2 * i)

@@ -13,7 +13,10 @@ step through its key-value cache and convolution state; ``sdar_moe``
 (:mod:`.sdar`) gives a block of units by denoising passes and a commit pass
 over a block that sees itself whole; ``nemotron_h`` (:mod:`.nemotron_h`)
 decodes one unit a row a step through Mamba-2 states beside keys and values,
-with the share of each layer's routed experts the chip holds.  All stand
+with the share of each layer's routed experts the chip holds;
+``pangu_ultra_moe`` (:mod:`.pangu_moe`) one unit a row a step through latent
+attention's one cached row a position, with a thin share of the experts and
+of the vocabulary.  All stand
 behind the engine surface :class:`~sonata_tpu.synth.steploop.StepLoop`
 names; what differs is in the classes here, and nothing else of the voice
 forks.
@@ -46,6 +49,7 @@ Every sentence of every request of a voice goes through the voice's one
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import os
 import threading
@@ -65,7 +69,7 @@ from ..text.phonemizer import text_to_phonemes
 from ..utils.buckets import FRAME_BUCKETS, TEXT_BUCKETS, bucket_for
 from ..ops import slot_attention
 from ..utils.transfer import prefetch_to_host
-from . import decode_opts, lfm2, nemotron_h, sdar
+from . import decode_opts, lfm2, nemotron_h, pangu_moe, sdar
 from .config import ModelConfig, SynthesisConfig
 from .serialization import load_params, unflatten_params
 
@@ -168,16 +172,93 @@ def routes_of(cfg, rows):
                                     cfg.num_experts_per_tok)
 
 
-class Lfm2Backbone:
-    """``lfm2_moe``: the prefill samples a row's first unit, and every step
-    gives every live row one more."""
+class Backbone:
+    """What every backbone says of itself beside its programs; a backbone
+    that has the thing overrides the default."""
 
-    block_length, denoising_steps = 1, 0
     #: the share of each layer's routed experts held here (None: all), and
     #: what a slot holds that does not grow with its row: layers with a
     #: recurrent state, and its bytes a slot
     held, ssm_layers, ssm_state_bytes = None, 0, 0
+    #: layers whose cache is one latent row a position (keys and values at
+    #: once), and which form of latent attention a step runs
+    latent_layers, mla_form = 0, None
+
+    def attention(self, positions: int) -> str:
+        """What reads the slots' cache in the step program."""
+        cfg = self.cfg
+        return slot_attention.implementation(
+            positions, cfg.num_key_value_heads,
+            cfg.num_attention_heads // cfg.num_key_value_heads, cfg.head_dim,
+            self.block_length)
+
+    def latent_cache_bytes(self, positions: int) -> int:
+        """Bytes of ``positions`` cached latent rows, over all layers."""
+        return 0
+
+
+def token_step_programs(module, name: str) -> tuple:
+    """``build_step``, ``build_prefill`` and ``build_step_admit`` of a
+    backbone whose ``module`` has ``step``, ``prefill`` and ``step_admit``
+    over ``cfg``, ``units`` and ``seed`` alone; the jitted programs are
+    named ``<name>_step``, ``<name>_prefill`` and ``<name>_step_admit`` (the
+    device trace's readers find them by those names)."""
+
+    def named(fn, kind: str):
+        fn.__name__ = fn.__qualname__ = f"{name}_{kind}"
+        return jax.jit(fn, donate_argnums=(1,))
+
+    def build_step(self):
+        cfg, units, seed = self.cfg, self.units, self.seed
+
+        def step(params, cache, live, temperature, step_no):
+            cache, logits, load = module.step(
+                params, cache, live, temperature, step_no, cfg=cfg,
+                units=units, seed=seed)
+            return cache, (logits,), load
+
+        return named(step, "step")
+
+    def build_prefill(self):
+        cfg, units, seed = self.cfg, self.units, self.seed
+
+        def prefill(params, cache, ids, n, slot, temperature, row_no):
+            key = jax.random.fold_in(jax.random.PRNGKey(seed + 1), row_no)
+            cache, logits, load = module.prefill(
+                params, cache, ids, n, slot, temperature, key, cfg=cfg,
+                units=units)
+            return cache, (logits,), load
+
+        return named(prefill, "prefill")
+
+    def build_step_admit(self):
+        """The step that carries an arrival: a step by name (every live
+        row gains a token), and what the prefill program gives beside."""
+        cfg, units, seed = self.cfg, self.units, self.seed
+
+        def step_admit(params, cache, live, temperature, step_no, ids, n,
+                       slot, row_temperature, row_no):
+            key = jax.random.fold_in(jax.random.PRNGKey(seed + 1), row_no)
+            cache, logits, load = module.step_admit(
+                params, cache, live, temperature, step_no, ids, n, slot,
+                row_temperature, key, cfg=cfg, units=units, seed=seed)
+            # a flagged row's slot finds its row in the whole array; the
+            # arrival's is the one behind the slots'
+            return cache, (logits,), (logits[-1],), load
+
+        return named(step_admit, "step_admit")
+
+    return build_step, build_prefill, build_step_admit
+
+
+class Lfm2Backbone(Backbone):
+    """``lfm2_moe``: the prefill samples a row's first unit, and every step
+    gives every live row one more."""
+
+    block_length, denoising_steps = 1, 0
     pack_layer = staticmethod(lfm2.pack_layer)
+    build_step, build_prefill, build_step_admit = token_step_programs(
+        lfm2, "lfm2")
 
     def __init__(self, backbone: dict, units: dict, seed: int):
         self.cfg = lfm2.Lfm2Config.from_dict(backbone)
@@ -201,46 +282,6 @@ class Lfm2Backbone:
         every 32nd unit and the last (launch ``d`` gives unit ``d + 1``)."""
         return (done + 1) % 32 == 0 or done == plan.launches - 1
 
-    def build_step(self):
-        cfg, units, seed = self.cfg, self.units, self.seed
-
-        def lfm2_step(params, cache, live, temperature, step_no):
-            cache, logits, load = lfm2.step(
-                params, cache, live, temperature, step_no, cfg=cfg,
-                units=units, seed=seed)
-            return cache, (logits,), load
-
-        return jax.jit(lfm2_step, donate_argnums=(1,))
-
-    def build_prefill(self):
-        cfg, units, seed = self.cfg, self.units, self.seed
-
-        def lfm2_prefill(params, cache, ids, n, slot, temperature, row_no):
-            key = jax.random.fold_in(jax.random.PRNGKey(seed + 1), row_no)
-            cache, logits, load = lfm2.prefill(
-                params, cache, ids, n, slot, temperature, key, cfg=cfg,
-                units=units)
-            return cache, (logits,), load
-
-        return jax.jit(lfm2_prefill, donate_argnums=(1,))
-
-    def build_step_admit(self):
-        """The step that carries an arrival: a step by name (every live
-        row gains a token), and what the prefill program gives beside."""
-        cfg, units, seed = self.cfg, self.units, self.seed
-
-        def lfm2_step_admit(params, cache, live, temperature, step_no, ids,
-                            n, slot, row_temperature, row_no):
-            key = jax.random.fold_in(jax.random.PRNGKey(seed + 1), row_no)
-            cache, logits, load = lfm2.step_admit(
-                params, cache, live, temperature, step_no, ids, n, slot,
-                row_temperature, key, cfg=cfg, units=units, seed=seed)
-            # a flagged row's slot finds its row in the whole array; the
-            # arrival's is the one behind the slots'
-            return cache, (logits,), (logits[-1],), load
-
-        return jax.jit(lfm2_step_admit, donate_argnums=(1,))
-
     def units_of(self, cache, n_ids: int) -> tuple:
         """The array a row's units lie in and where they start."""
         return cache["units"], 0
@@ -260,11 +301,10 @@ class Lfm2Backbone:
                 "logits": np.stack([a[0] for _, a in kept])}
 
 
-class SdarBackbone:
+class SdarBackbone(Backbone):
     """``sdar_moe``: the prefill keeps the prompt's whole blocks, and every
     ``denoising_steps + 1`` passes give every live row a block of units."""
 
-    held, ssm_layers, ssm_state_bytes = None, 0, 0
     pack_layer = staticmethod(sdar.pack_layer)
     #: no step of this backbone carries an arrival (a pass keeps a phase a
     #: slot): a row's prompt runs apart, in ``sdar_prefill``
@@ -396,46 +436,47 @@ class NemotronBackbone(Lfm2Backbone):
         ``text_bucket``."""
         return -(-text_bucket // self.cfg.chunk_size) * self.ssm_layers
 
-    def build_step(self):
-        cfg, units, seed = self.cfg, self.units, self.seed
+    build_step, build_prefill, build_step_admit = token_step_programs(
+        nemotron_h, "nemotron")
 
-        def nemotron_step(params, cache, live, temperature, step_no):
-            cache, logits, load = nemotron_h.step(
-                params, cache, live, temperature, step_no, cfg=cfg,
-                units=units, seed=seed)
-            return cache, (logits,), load
 
-        return jax.jit(nemotron_step, donate_argnums=(1,))
+class PanguBackbone(Lfm2Backbone):
+    """``pangu_ultra_moe``: a row's launches, units and dump are
+    ``lfm2_moe``'s; the programs and what a slot holds (one latent row a
+    position and layer) are its own.  A step runs latent attention's
+    absorbed form, a prompt (apart or carried) the expanded one."""
 
-    def build_prefill(self):
-        cfg, units, seed = self.cfg, self.units, self.seed
+    mla_form = "absorbed"
 
-        def nemotron_prefill(params, cache, ids, n, slot, temperature,
-                             row_no):
-            key = jax.random.fold_in(jax.random.PRNGKey(seed + 1), row_no)
-            cache, logits, load = nemotron_h.prefill(
-                params, cache, ids, n, slot, temperature, key, cfg=cfg,
-                units=units)
-            return cache, (logits,), load
+    def __init__(self, backbone: dict, units: dict, seed: int):
+        self.cfg = pangu_moe.PanguConfig.from_dict(backbone)
+        self.units = lfm2.UnitIds(int(units["first_id"]),
+                                  int(units["stop_id"]))
+        self.layers = self.latent_layers = self.cfg.num_hidden_layers
+        self.seed = seed
+        self.held = self.cfg.held
+        self.pack_layer = functools.partial(pangu_moe.pack_layer,
+                                            cfg=self.cfg)
 
-        return jax.jit(nemotron_prefill, donate_argnums=(1,))
+    def new_cache(self, slots: int, positions: int) -> dict:
+        return pangu_moe.new_cache(self.cfg, slots, positions)
 
-    def build_step_admit(self):
-        cfg, units, seed = self.cfg, self.units, self.seed
+    def attention(self, positions: int) -> str:
+        cfg = self.cfg
+        return slot_attention.latent_implementation(
+            positions, cfg.num_attention_heads, cfg.latent_width,
+            cfg.kv_lora_rank, self.block_length)
 
-        def nemotron_step_admit(params, cache, live, temperature, step_no,
-                                ids, n, slot, row_temperature, row_no):
-            key = jax.random.fold_in(jax.random.PRNGKey(seed + 1), row_no)
-            cache, logits, load = nemotron_h.step_admit(
-                params, cache, live, temperature, step_no, ids, n, slot,
-                row_temperature, key, cfg=cfg, units=units, seed=seed)
-            return cache, (logits,), (logits[-1],), load
+    def latent_cache_bytes(self, positions: int) -> int:
+        return self.cfg.latent_cache_bytes(positions)
 
-        return jax.jit(nemotron_step_admit, donate_argnums=(1,))
+    build_step, build_prefill, build_step_admit = token_step_programs(
+        pangu_moe, "pangu")
 
 
 BACKBONES = {"lfm2_moe": Lfm2Backbone, "sdar_moe": SdarBackbone,
-             "nemotron_h": NemotronBackbone}
+             "nemotron_h": NemotronBackbone,
+             "pangu_ultra_moe": PanguBackbone}
 
 
 def make_backbone(backbone: dict, units: dict, seed: int = 0):
@@ -477,11 +518,12 @@ class UnitVoice(BaseModel):
         #: span says it): known from the program's shape, before it is built
         self.expert_matmul = lfm2.expert_matmul(
             self.cfg, self.slots * self.block_length, self.backbone.held)
-        #: what reads the slots' keys and values in the step program
-        self.attention = slot_attention.implementation(
-            self.positions, self.cfg.num_key_value_heads,
-            self.cfg.num_attention_heads // self.cfg.num_key_value_heads,
-            self.cfg.head_dim, self.block_length)
+        #: what reads the slots' keys and values (or latent rows) in the
+        #: step program
+        self.attention = self.backbone.attention(self.positions)
+        self.latent_layers = self.backbone.latent_layers
+        #: which form of latent attention a step runs (None: it has none)
+        self.mla_form = self.backbone.mla_form
         self.params = weights["backbone"]
         self.unit_table = weights["unit_table"]
         self.generator = {"dec": weights["generator"]["dec"]}
@@ -786,6 +828,9 @@ class UnitVoice(BaseModel):
                  "attention": "einsum"}
         if self.ssm_layers:
             shape["ssm_chunks"] = self.backbone.prefill_chunks(t)
+        if self.latent_layers:
+            # a prompt makes every head's keys and values of its own rows
+            shape["mla_form"] = "expanded"
         self._prefill_no += 1
         return padded, np.int32(len(ids)), np.int32(self._prefill_no), shape
 
@@ -844,6 +889,11 @@ class UnitVoice(BaseModel):
         peak = max(float(peak[0]), 0.01)
         return wav_i16[0, :int(wav_lengths[0])].astype(np.float32) * (
             peak / 32767.0)
+
+    def latent_cache_bytes(self, positions: int) -> int:
+        """Bytes of ``positions`` latent rows over the backbone's layers
+        (0: it has no latent attention)."""
+        return self.backbone.latent_cache_bytes(positions)
 
     def dumped(self, plan: RowPlan, done: int) -> bool:
         """Whether a flagged row keeps what its launch number ``done``

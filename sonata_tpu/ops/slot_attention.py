@@ -29,6 +29,18 @@ head's lanes.  Off a TPU, and where the rule says no, the reader is the
 einsum it replaces, over the same buffers (on a TPU that einsum re-lays
 the buffers again: the rule says no only where the kernel cannot run).
 
+**A latent row** (latent attention, MLA) is keys and values at once: a
+layer keeps one buffer, a place is ``[c_kv | k_rope]`` in whole lanes (576
+values in 640), every query head of a slot reads the same row, and the
+values are the row's first ``values`` lanes.  :func:`stored_shape`,
+:func:`write_rows` and :func:`write_slot` take such a row as they take any
+other (``kv = 1``); :func:`latent_attention` reads it: the same grid, the
+same online softmax, one block of the one buffer a tile, fetched once, its
+leading lanes cut out in VMEM for the second product.  With a hundred and
+more query heads on one row the products are no plain stream any more: at
+128 heads of 576 a tile does 242 operations a byte, the chip's ridge
+(:func:`latent_tile_rule`; PERF.md §5).
+
 **Precision**: queries and probabilities enter the products in the type
 the buffers hold (bfloat16 in every program of a voice); scores, the
 softmax and every sum are float32.  The online softmax sums in another
@@ -63,6 +75,12 @@ TILE_ELEMENTS = 512 * 512
 #: the most query rows a lane group may hold: above it the products are no
 #: stream of keys and values any more and XLA's own stay
 MAX_ROWS = 256
+#: the elements of a positions tile of latent rows: 1024 places of 640
+#: lanes (1.3 MB of bfloat16).  Beside 128 query rows a tile's products
+#: take as long as its fetch, and what is left to win is the grid's own
+#: cost: a tile of all 1024 places a slot, nothing skipped, beat tiles of
+#: 512, 256 and 128 at the mean row's 350 places (PERF.md §5 has the table)
+LATENT_TILE_ELEMENTS = 1024 * 640
 
 
 class Tiles(NamedTuple):
@@ -247,6 +265,161 @@ def slot_attention_einsum(q, k_buf, v_buf, upto):
     out = jnp.einsum("skgbp,spkd->sbkgd", probs.astype(v.dtype), v,
                      preferred_element_type=F32)
     return jnp.where((upto > 0)[:, None, None, None, None], out, 0.0)
+
+
+# ---------------------------------------------------------------------------
+# a latent row: keys and values at once
+# ---------------------------------------------------------------------------
+
+@functools.lru_cache(maxsize=None)
+def latent_tile_rule(positions: int, g: int, width: int, values: int,
+                     b: int) -> Optional[Tiles]:
+    """The latent reader's tiles for ``b`` queries a slot of ``g`` heads
+    over ``positions`` rows of ``width`` (stored in whole lanes) whose
+    first ``values`` lanes are the values, or None where the einsum stays:
+    a pure function of the shape, as :func:`tile_rule`.
+
+    The values have to be whole lanes of the row; the query rows of a slot
+    (``b * g``) at most ``MAX_ROWS``; the positions tile holds
+    ``LATENT_TILE_ELEMENTS`` of the stored row (the power of two below:
+    1024 places of 640 lanes), at most all the positions, which it has to
+    divide, in whole sublanes of a bfloat16 tile (16)."""
+    if values % LANES or not 0 < values <= width or b * g > MAX_ROWS:
+        return None
+    tp = 1 << (max(LATENT_TILE_ELEMENTS // lanes(width), 1).bit_length() - 1)
+    tp = min(tp, positions)
+    return Tiles(tp) if tp % 16 == 0 and positions % tp == 0 else None
+
+
+def _latent_kernel(upto, q_ref, c_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                   tp: int, scale: float):
+    s, t = pl.program_id(0), pl.program_id(1)
+    rows, values = o_ref.shape
+    n = upto[s]
+
+    @pl.when(t == 0)
+    def _start():
+        m_ref[...] = jnp.full(m_ref.shape, MASKED, F32)
+        l_ref[...] = jnp.zeros(l_ref.shape, F32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, F32)
+
+    @pl.when(t * tp < n)
+    def _tile():
+        seen = t * tp + lax.broadcasted_iota(jnp.int32, (rows, tp), 1) < n
+        scores = lax.dot_general(
+            q_ref[...], c_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=F32) * scale
+        scores = jnp.where(seen, scores, MASKED)
+        m_prev = m_ref[...]
+        m_next = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_next)
+        p = jnp.exp(scores - m_next)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[...] = m_next
+        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+            p.astype(c_ref.dtype), c_ref[:, :values],
+            preferred_element_type=F32)
+
+    @pl.when(t == pl.num_programs(1) - 1)
+    def _finish():
+        total = l_ref[...]
+        o_ref[...] = acc_ref[...] / jnp.where(total > 0.0, total, 1.0)
+
+
+def latent_attention_kernel(q, buf, upto, values: int, scale: float,
+                            tiles: Tiles, *, interpret: bool = False):
+    """The latent reader's kernel, whatever the backend (``interpret`` for
+    the CPU): ``q`` ``[S, b, g, width]``, the buffer ``[S, P, lanes(width)]``,
+    ``upto`` ``[S]``.  Returns ``[S, b, g, values]`` float32."""
+    s, b, g, width = q.shape
+    span, stored = buf.shape[1:]
+    tp = tiles.tp
+    if stored != lanes(width) or values % LANES or values > width \
+            or span % tp:
+        raise ValueError(f"q {q.shape}, values {values} and tiles {tiles} "
+                         f"do not fit the buffer {buf.shape}")
+    rows = b * g
+    padded = rows + -rows % 16
+    qg = jnp.pad(q.reshape(s, rows, width).astype(buf.dtype),
+                 ((0, 0), (0, padded - rows), (0, stored - width)))
+
+    def q_map(i, t, upto):
+        return (i, 0, 0)
+
+    def row_map(i, t, upto):
+        # past the slot's last tile the index stays: nothing is fetched
+        return (i, jnp.minimum(t, (jnp.maximum(upto[i], 1) - 1) // tp), 0)
+
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, tp=tp, scale=scale),
+        out_shape=jax.ShapeDtypeStruct((s, padded, values), F32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(s, span // tp),
+            in_specs=[pl.BlockSpec((None, padded, stored), q_map),
+                      pl.BlockSpec((None, tp, stored), row_map)],
+            out_specs=pl.BlockSpec((None, padded, values), q_map),
+            scratch_shapes=[pltpu.VMEM((padded, 1), F32),
+                            pltpu.VMEM((padded, 1), F32),
+                            pltpu.VMEM((padded, values), F32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * s * padded * span * (stored + values),
+            transcendentals=s * padded * span,
+            bytes_accessed=(s * span * stored * buf.dtype.itemsize
+                            + s * padded * (2 * stored + 4 * values))),
+        name="latent_attention",
+        interpret=interpret,
+    )(upto.astype(jnp.int32), qg, buf)
+    return out[:, :rows].reshape(s, b, g, values)
+
+
+def latent_attention_einsum(q, buf, upto, values: int, scale: float):
+    """The products and the softmax as one expression over the whole
+    buffer: what the kernel is held to, and the reader wherever the kernel
+    is not."""
+    width = q.shape[-1]
+    span = buf.shape[1]
+    scores = jnp.einsum("sbgw,spw->sgbp", q.astype(buf.dtype),
+                        buf[..., :width],
+                        preferred_element_type=F32) * F32(scale)
+    seen = jnp.arange(span)[None, :] < upto[:, None]
+    probs = jax.nn.softmax(
+        jnp.where(seen[:, None, None, :], scores, -jnp.inf), -1)
+    out = jnp.einsum("sgbp,spv->sgbv", probs.astype(buf.dtype),
+                     buf[..., :values], preferred_element_type=F32)
+    return jnp.where((upto > 0)[:, None, None, None],
+                     out.transpose(0, 2, 1, 3), 0.0)
+
+
+def _latent_tiles_here(positions: int, g: int, width: int, values: int,
+                       b: int) -> Optional[Tiles]:
+    """``latent_tile_rule``'s tiles on a TPU, None on every other
+    backend."""
+    if jax.default_backend() != "tpu":
+        return None
+    return latent_tile_rule(positions, g, width, values, b)
+
+
+def latent_implementation(positions: int, g: int, width: int, values: int,
+                          b: int) -> str:
+    """:func:`implementation` for :func:`latent_attention`."""
+    return ("einsum" if _latent_tiles_here(positions, g, width, values, b)
+            is None else "slot_kernel")
+
+
+def latent_attention(q, buf, upto, values: int, scale: float):
+    """Every slot's queries ``q`` ``[S, b, g, width]`` over the slot's
+    latent rows at the places ``< upto[slot]``, the scores times ``scale``
+    (the model's, not the row's width): ``[S, b, g, values]`` float32, the
+    probabilities' sums of the rows' first ``values`` lanes; zeros for a
+    slot that sees nothing."""
+    _, b, g, width = q.shape
+    tiles = _latent_tiles_here(buf.shape[1], g, width, values, b)
+    if tiles is None:
+        return latent_attention_einsum(q, buf, upto, values, scale)
+    return latent_attention_kernel(q, buf, upto, values, scale, tiles)
 
 
 def _tiles_here(positions: int, kv: int, g: int, d: int,

@@ -714,6 +714,14 @@ def held_load(load) -> tuple:
     return (load[3], load[4]) if len(load) > 3 else (load[0], load[2])
 
 
+def held_overflow(loads) -> bool:
+    """Whether, in a launch of these loads (one an expert layer), a thin
+    share's held experts got more rows than the program's short path
+    takes in any layer (``lfm2.moe_ffn``'s sixth number; a layer that is
+    not thin states none)."""
+    return any(len(load) > 5 and int(load[5]) for load in loads)
+
+
 class StepStats:
     """Process-lifetime counters of step-wise generation (a voice's step
     loop: :mod:`sonata_tpu.synth.steploop`), fed by the loop whether or not
@@ -740,6 +748,13 @@ class StepStats:
         self.moe: dict = {}
         #: bytes of recurrent state the loops' slots hold (all slots)
         self.ssm_state_resident_bytes = 0
+        #: bytes of latent rows (latent attention's keys and values at
+        #: once) the loops' slots hold (all slots, all positions)
+        self.mla_cache_resident_bytes = 0
+        #: step launches that took the expert layer's full-length path
+        #: because a thin share's held experts got more than the short one
+        #: takes
+        self.held_overflow_steps = 0
         #: launches by what their expert products ran and by program
         self.expert_matmul = {(impl, program): 0
                               for impl in EXPERT_MATMULS
@@ -761,6 +776,7 @@ class StepStats:
             self.expert_matmul[group["expert_matmul"], "step"] += group[
                 "steps"]
             self.attention[group["attention"], "step"] += group["steps"]
+            self.held_overflow_steps += group.get("held_overflow_steps", 0)
             self.slot_steps["live"] += group["live_slot_steps"]
             self.slot_steps["empty"] += (group["steps"] * group["slots"]
                                          - group["live_slot_steps"])
@@ -818,10 +834,13 @@ class StepStats:
         if new and self._registry is not None:
             self._bind_layers(new)
 
-    def record_resident(self, state_bytes: int) -> None:
-        """A loop's slots were made (or, negative, let go)."""
+    def record_resident(self, state_bytes: int,
+                        latent_bytes: int = 0) -> None:
+        """A loop's slots were made (or, negative, let go): their recurrent
+        state and their latent rows."""
         with self._lock:
             self.ssm_state_resident_bytes += state_bytes
+            self.mla_cache_resident_bytes += latent_bytes
 
     def record_retired(self) -> None:
         with self._lock:
@@ -923,6 +942,20 @@ class StepStats:
             "live or not: such state does not grow with a row; 0 for a "
             "backbone that has none)."
         ).set_function(lambda: float(self.ssm_state_resident_bytes))
+        registry.gauge(
+            "sonata_mla_cache_resident_bytes",
+            "Bytes of latent rows the slots of step-wise generation loops "
+            "hold on the device (latent attention: one row a position and "
+            "layer, keys and values at once, in whole lanes; every slot and "
+            "position, live or not; 0 for a backbone that has none)."
+        ).set_function(lambda: float(self.mla_cache_resident_bytes))
+        registry.counter(
+            "sonata_moe_held_overflow_steps_total",
+            "Step launches whose expert layer took its full-length path "
+            "because the experts this chip holds (a thin share of the "
+            "router's) got more assignment rows than the program's short "
+            "path takes; no held assignment is ever left out."
+        ).set_function(lambda: float(self.held_overflow_steps))
         self._registry = registry
         with self._lock:
             self._bind_layers(list(self.moe))

@@ -50,7 +50,11 @@ rows attended over, ``admit_steps`` launches that carried an arrival and
 ``prompt_tokens`` they carried, ``ssm_state_bytes`` of recurrent state the live rows'
 steps read and wrote, per expert layer ``assignments``, ``experts_touched``,
 ``max_expert_assignments`` and, of the experts the chip holds,
-``held_assignments`` and ``held_experts_touched``, ``host_ms`` by phase,
+``held_assignments`` and ``held_experts_touched``, ``held_overflow_steps``
+(launches in which a thin share's held experts got more rows than the
+program's short path takes, so that it took the full-length one: none is
+ever left out), ``latent_cache_bytes`` of latent rows the live rows'
+attention read (``kv_positions`` rows a layer, as stored), ``host_ms`` by phase,
 and of the loop's turns: ``wall_ms`` and, beside ``host_ms``'s three,
 ``device_wait_ms`` (blocked until the step before had run), ``record_ms``
 (the rest of settling it) and ``other_ms`` (the turn less its phases), which
@@ -58,7 +62,10 @@ sum to ``wall_ms``; of the longest turn ``turn_ms_max``, ``turn_max_phase``
 (which phase held most of it) and ``turn_max_step``; ``arrivals`` (rows
 admitted); ``compile_ms`` and ``compiled`` where a program compiled on the
 loop's thread outside a prefill's or a vocoder's launch)
-beside the engine's ``block_length``, ``denoising_steps``, ``ssm_layers`` and
+beside the engine's ``block_length``, ``denoising_steps``, ``ssm_layers``,
+``latent_layers`` (layers whose cache is one latent row a position) with
+``mla_form`` (``absorbed``: what a step's latent attention runs; a prefill
+span says ``expanded``), and
 ``expert_matmul`` (``grouped`` | ``ragged_dot``: what the step program's
 expert products run) and ``attention`` (``slot_kernel`` | ``einsum``: what
 reads the slots' keys and values).  Each
@@ -83,7 +90,8 @@ launch | retire | settle`` with ``step_no``.
 The engine (a voice: :class:`~sonata_tpu.models.unit_voice.UnitVoice`)
 gives ``slots``, ``expert_layers``, ``block_length``, ``denoising_steps``,
 ``expert_matmul``, ``attention``, ``ssm_layers``, ``ssm_state_bytes`` (a
-slot's),
+slot's), where it has latent attention ``latent_layers``, ``mla_form`` and
+``latent_cache_bytes(positions)``,
 ``new_cache()``, ``plan(n_ids, budget)``, ``prefill(cache, slot, ids,
 temperature)``, ``step(cache, live, temperature, step_no)``, where its step
 carries arrivals ``carries(n_ids)`` and ``step_admit(cache, live,
@@ -195,8 +203,15 @@ class StepLoop:
         self.name = name
         self.slots = SlotTable(engine.slots)
         self.stats = tracing.step_stats()
-        self._resident = engine.slots * engine.ssm_state_bytes
-        self.stats.record_resident(self._resident)
+        #: bytes of ``positions`` latent rows over the engine's layers (an
+        #: engine without latent attention: none)
+        self._latent_bytes = getattr(engine, "latent_cache_bytes",
+                                     lambda positions: 0)
+        self._resident = (
+            engine.slots * engine.ssm_state_bytes,
+            self._latent_bytes(engine.slots * getattr(engine, "positions",
+                                                      0)))
+        self.stats.record_resident(*self._resident)
         self.layers = list(engine.expert_layers)
         dump_dir = os.environ.get(DUMP_DIR_ENV)
         self._dump_dir = Path(dump_dir) if dump_dir else None
@@ -254,8 +269,8 @@ class StepLoop:
         with self._cond:
             self._closed = True
             self._cond.notify_all()
-            resident, self._resident = self._resident, 0
-        self.stats.record_resident(-resident)
+            resident, self._resident = self._resident, (0, 0)
+        self.stats.record_resident(*(-b for b in resident))
         self._thread.join(timeout=30.0)
         with self._finish_cond:
             self._finish_cond.notify_all()
@@ -464,6 +479,8 @@ class StepLoop:
             t = time.perf_counter()
             loads = np.asarray(load)
             waited += time.perf_counter() - t
+            if tracing.held_overflow(loads):
+                attrs["held_overflow"] = True
             self.stats.record_prefill(attrs["tokens"], self.layers,
                                       loads, row.plan.units(0),
                                       attrs["expert_matmul"],
@@ -500,9 +517,10 @@ class StepLoop:
                 "host_ms": dict.fromkeys(tracing.AR_HOST_PHASES, 0.0),
                 "wall_ms": 0.0, "turn_ms_max": 0.0, "turn_max_phase": None,
                 "turn_max_step": None, "arrivals": 0, "admit_steps": 0,
-                "prompt_tokens": 0,
+                "prompt_tokens": 0, "held_overflow_steps": 0,
                 **{p + "_ms": 0.0 for p in tracing.AR_SETTLE_PHASES}}
         g["steps"] += 1
+        g["held_overflow_steps"] += tracing.held_overflow(loads)
         if joined is not None:
             g["admit_steps"] += 1
             g["prompt_tokens"] += joined[2]["tokens"]
@@ -550,7 +568,11 @@ class StepLoop:
                  attention=self.engine.attention,
                  ssm_layers=self.engine.ssm_layers,
                  ssm_state_bytes=(2 * self.engine.ssm_state_bytes
-                                  * g["live_slot_steps"]))
+                                  * g["live_slot_steps"]),
+                 latent_layers=getattr(self.engine, "latent_layers", 0),
+                 latent_cache_bytes=self._latent_bytes(g["kv_positions"]))
+        if getattr(self.engine, "mla_form", None):
+            g["mla_form"] = self.engine.mla_form
         self.stats.record_steps(g)
         if self._trace is None:
             self._trace = tracing.default_tracer().start_trace(

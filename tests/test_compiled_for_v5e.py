@@ -10,7 +10,7 @@ library, and a second file could land on another worker.
   of a whole key, value or routes buffer (until PR 37 XLA re-laid every
   buffer twice a step), and both kernels are in it;
 - the step that carries an arrival (``lfm2_step_admit``,
-  ``nemotron_step_admit``) likewise: a step's scatter and a prompt's slice
+  ``nemotron_step_admit``, ``pangu_step_admit``) likewise: a step's scatter and a prompt's slice
   land on one donated buffer in one program, which is where a copy could
   come back, and its expert products over both kinds of row are the
   kernel's."""
@@ -44,6 +44,8 @@ CELLS = {
     "nemotron_step": (
         "perfbench/configs/nemotron/nemotron-3-nano-30b-a3b.json",
         "nemotrongen", 256),
+    "pangu_step": ("perfbench/configs/pangu/openpangu-ultra-moe-718b.json",
+                   "pangugen", 256),
 }
 POSITIONS = 1024
 
@@ -107,6 +109,23 @@ def test_the_slot_attention_compiles_for_a_v5e_at_the_step_shapes(
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_the_latent_reader_compiles_for_a_v5e_at_the_step_shape(
+        one_chip, no_compile_cache):
+    """The latent reader's kernel with the rule's tiles at the real widths:
+    128 query heads on one row of 576 values in 640 lanes, the values its
+    first 512, cut out of the block in VMEM."""
+    slots, heads, width, values = 256, 128, 576, 512
+    tiles = sa.latent_tile_rule(POSITIONS, heads, width, values, 1)
+    spec = spec_on(one_chip)
+    compiled = jax.jit(functools.partial(
+        sa.latent_attention_kernel, values=values, scale=192 ** -0.5,
+        tiles=tiles)).lower(
+        spec((slots, 1, heads, width), F32),
+        spec(sa.stored_shape(slots, POSITIONS, 1, width), BF16),
+        spec((slots,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def step_shapes(name: str, sharding):
     """The backbone of a cell and the shapes of its step program's
     arguments at the cell's real size: no array is made."""
@@ -140,9 +159,9 @@ def step_shapes(name: str, sharding):
 
 
 def whole_buffer_copies(hlo: str, elements: int,
-                        types: str = "bf16|s8") -> list:
+                        types: str = "bf16|s8|u8") -> list:
     """The ``copy`` operations of an optimised module whose result holds
-    at least ``elements`` elements of ``types`` (bfloat16 or int8: what a
+    at least ``elements`` elements of ``types`` (bfloat16 or a byte: what a
     step writes per slot and place)."""
     found = []
     for line in hlo.splitlines():
@@ -166,6 +185,11 @@ def test_the_copies_of_the_layout_before_are_found():
     assert len(whole_buffer_copies(parent, 64 * 1024 * 32)) == 3
 
 
+#: what reads the slots' cache in each step program
+READERS = {name: "latent_attention" if name == "pangu_step"
+           else "slot_attention" for name in CELLS}
+
+
 @pytest.mark.parametrize("name", sorted(CELLS))
 def test_a_step_compiled_for_a_v5e_copies_no_slot_buffer_whole(
         one_chip, no_compile_cache, monkeypatch, name):
@@ -176,16 +200,22 @@ def test_a_step_compiled_for_a_v5e_copies_no_slot_buffer_whole(
     None: the test steers them, not an option of the program."""
     monkeypatch.setattr(gm, "_tiles_here", gm.tile_rule)
     monkeypatch.setattr(sa, "_tiles_here", sa.tile_rule)
+    monkeypatch.setattr(sa, "_latent_tiles_here", sa.latent_tile_rule)
     backbone, cache, args = step_shapes(name, one_chip)
     per_place = [int(np.prod(a.shape)) for a in (
-        cache["routes"], *cache["k"], *cache["v"])]
+        cache["routes"], *cache.get("k", ()), *cache.get("v", ()),
+        *cache.get("latent", ()))]
     assert min(per_place) == cache["routes"].size
     hlo = backbone.build_step().lower(*args).compile().as_text()
-    assert "slot_attention" in hlo and "grouped_matmul" in hlo
+    assert READERS[name] in hlo and "grouped_matmul" in hlo
     assert whole_buffer_copies(hlo, min(per_place)) == []
+    # a thin share's program holds both paths: the short one on the kernel
+    # and, for a launch that overflows it, the full-length one
+    assert ("conditional" in hlo) == (name == "pangu_step")
 
 
-@pytest.mark.parametrize("name", ["lfm2_step", "nemotron_step"])
+@pytest.mark.parametrize("name", ["lfm2_step", "nemotron_step",
+                                  "pangu_step"])
 def test_a_carrying_step_compiled_for_a_v5e_copies_no_slot_buffer_whole(
         one_chip, no_compile_cache, monkeypatch, name):
     """The step that carries an arrival, at the cells' sizes and their
@@ -197,6 +227,7 @@ def test_a_carrying_step_compiled_for_a_v5e_copies_no_slot_buffer_whole(
     attention too."""
     monkeypatch.setattr(gm, "_tiles_here", gm.tile_rule)
     monkeypatch.setattr(sa, "_tiles_here", sa.tile_rule)
+    monkeypatch.setattr(sa, "_latent_tiles_here", sa.latent_tile_rule)
     backbone, cache, args = step_shapes(name, one_chip)
     arrival = (jax.ShapeDtypeStruct((192,), jnp.int32),
                *(jax.ShapeDtypeStruct((), t) for t in (
@@ -205,8 +236,12 @@ def test_a_carrying_step_compiled_for_a_v5e_copies_no_slot_buffer_whole(
                   for a in arrival)
     hlo = backbone.build_step_admit().lower(*args).compile().as_text()
     assert f"{name}_admit" in hlo
-    assert "slot_attention" in hlo and "grouped_matmul" in hlo
-    assert "ragged-dot" not in hlo and "ragged_dot" not in hlo
+    assert READERS[name] in hlo and "grouped_matmul" in hlo
+    # the operation's name, not the word: the module's stack frames name
+    # whatever function first traced a cached helper, a test's among them
+    # (a thin share keeps its full-length path, XLA's product, for a launch
+    # that overflows the short one)
+    assert ("%ragged-dot" in hlo) == (name == "pangu_step")
     assert whole_buffer_copies(hlo, cache["routes"].size) == []
     for state in cache.get("ssm", ())[:1]:
         assert whole_buffer_copies(hlo, state.size, "f32") == []

@@ -12,7 +12,7 @@ from pathlib import Path
 
 import pytest
 
-from perfbench.harness import lfm2gen, sdargen
+from perfbench.harness import lfm2gen, pangugen, sdargen
 from sonata_tpu.models import from_config_path, lfm2
 from sonata_tpu.models.config import SynthesisConfig
 from sonata_tpu.serving import tracing
@@ -36,8 +36,9 @@ def series(registry) -> dict:
 
 @pytest.mark.parametrize("impl", ["ragged_dot", "grouped"])
 @pytest.mark.parametrize("tiny, writer", [("lfm2-tiny.json", lfm2gen),
-                                          ("sdar-tiny.json", sdargen)],
-                         ids=["lfm2", "sdar"])
+                                          ("sdar-tiny.json", sdargen),
+                                          ("pangu-tiny.json", pangugen)],
+                         ids=["lfm2", "sdar", "pangu"])
 def test_spans_and_series_say_what_the_expert_products_ran(
         tiny, writer, impl, tmp_path, monkeypatch):
     if impl == "grouped":
@@ -87,3 +88,42 @@ def test_spans_and_series_say_what_the_expert_products_ran(
         want[impl, "prefill"] = float(len(TEXTS))
     assert moved == want
     assert stats.steps > steps_before
+
+
+@pytest.mark.parametrize("config, slots, held_of, step, siblings", [
+    ("lfm2/lfm2-24b-a2b.json", 64, None, 256, True),
+    ("sdar/sdar-30b-a3b.json", 64, None, 2048, True),
+    ("nemotron/nemotron-3-nano-30b-a3b.json", 256, (0, 64), 1536, True),
+    ("pangu/openpangu-ultra-moe-718b.json", 256, (0, 8), 256, False),
+], ids=["lfm2", "sdar", "nemotron", "pangu"])
+def test_what_a_program_says_of_a_thin_share_at_the_cells_shapes(
+        config, slots, held_of, step, siblings, monkeypatch):
+    """``expert_matmul`` at the cells' real widths, as on a TPU: the three
+    siblings' rows are all their assignments, as before a layer could be
+    told of a thin share; 8 of 256 experts held hand on 256 rows where an
+    untold layer handed on 2048, which the kernel's rule leaves to
+    ``ragged_dot``; every carrying step of the lattice says ``grouped``
+    too."""
+    from sonata_tpu.models import unit_voice
+    from sonata_tpu.utils.buckets import TEXT_BUCKETS
+
+    from perfbench.harness import nemotrongen
+    data = json.loads((DATA.parents[2] / "perfbench/configs"
+                       / config).read_text())
+    writer = {"lfm2": lfm2gen, "sdar": sdargen, "nemotron": nemotrongen,
+              "pangu": pangugen}[config.split("/")[0]]
+    built = unit_voice.make_backbone(
+        writer.backbone(data), {"first_id": 256, "stop_id": 511,
+                                "mask_id": 300, "block_length": 4})
+    cfg, tokens = built.cfg, slots * built.block_length
+    assert built.held == held_of
+    assert lfm2.held_rows(cfg, tokens, built.held) == step
+    assert (step == tokens * cfg.num_experts_per_tok) == siblings
+    monkeypatch.setattr(gm, "_tiles_here", gm.tile_rule)
+    assert lfm2.expert_matmul(cfg, tokens, built.held) == "grouped"
+    if not siblings:
+        # told nothing, the layer would hand all 2048 rows to ragged_dot
+        assert gm.tile_rule(tokens * cfg.num_experts_per_tok, 8, 7680, 4096,
+                            lfm2.BF16) is None
+        assert {lfm2.expert_matmul(cfg, slots + t, built.held)
+                for t in TEXT_BUCKETS} == {"grouped"}

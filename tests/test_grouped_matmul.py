@@ -137,6 +137,10 @@ STEP_SHAPES = {
     # 256 rows x 6 on the 64 held experts; 1856 columns in 1920 lanes
     "nemotron_step.w13": (1536, 64, 2688, 1920),
     "nemotron_step.w2": (1536, 64, 1920, 2688),
+    # 256 rows x 8 of which the short path of a thin share (8 of 256 experts
+    # held) takes 256; a weight block is a quarter, a half of a matrix
+    "pangu_step.w13": (256, 8, 7680, 4096),
+    "pangu_step.w2": (256, 8, 2048, 7680),
 }
 PREFILL_SHAPES = {
     f"{name}_prefill{t}.{w}": (t * top, groups, k, n)
@@ -145,6 +149,11 @@ PREFILL_SHAPES = {
         ("sdar", 8, 128, {"w13": (2048, 1536), "w2": (768, 2048)}),
         ("nemotron", 6, 64, {"w13": (2688, 1920), "w2": (1920, 2688)}))
     for t in (96, 128, 192) for w, (k, n) in shapes.items()}
+#: a carrying step of the thin share: the short path's rows at 256 + t tokens
+PREFILL_SHAPES.update({
+    f"pangu_step_admit{t}.{w}": (rows, 8, k, n)
+    for t, rows in ((128, 256), (192, 384), (256, 384))
+    for w, (k, n) in {"w13": (7680, 4096), "w2": (2048, 7680)}.items()})
 SHAPES = {**STEP_SHAPES, **PREFILL_SHAPES}
 
 
@@ -176,7 +185,15 @@ def test_the_tile_rule_reads_the_shape_alone():
         "lfm2_step.w13": Tiles(128, 3072), "lfm2_step.w2": Tiles(128, 2048),
         "sdar_pass.w13": Tiles(128, 1536), "sdar_pass.w2": Tiles(128, 2048),
         "nemotron_step.w13": Tiles(128, 1920),
-        "nemotron_step.w2": Tiles(128, 2688)}
+        "nemotron_step.w2": Tiles(128, 2688),
+        # an expert's matrix is 63 and 31 MB: a block is a quarter, a half
+        "pangu_step.w13": Tiles(128, 1024),
+        "pangu_step.w2": Tiles(128, 3840)}
+    # all 2048 assignments of a layer not told of its thin share: the
+    # worst-case masked multiply outlasts the 8 experts' stream
+    assert gm.tile_rule(2048, 8, 7680, 4096, jnp.bfloat16) is None
+    assert gm.tile_rule(2048, 8, 2048, 7680, jnp.bfloat16) is None
+    assert gm.tile_rule(64, 8, 7680, 4096, jnp.bfloat16) == Tiles(64, 1024)
     # fewer rows than a tile: all of them, in sublanes of 16
     assert gm.tile_rule(24, 64, 2048, 3072, jnp.bfloat16) == Tiles(32, 3072)
     # many rows a group: no weight stream, XLA's product stays

@@ -178,6 +178,95 @@ def test_the_tile_rule_reads_the_shape_alone(name):
     assert sa.tile_rule(*shape) == tiles
 
 
+# -- a latent row: keys and values at once ---------------------------------
+
+#: name -> (query heads, a row's values, of which the first are the values)
+LATENT = {"pangu_step": (128, 576, 512), "the_tiny_voices": (4, 40, 32),
+          "one_lane_group_of_values": (16, 160, 128)}
+
+
+def latent_operands(name: str, seed: int = 0):
+    g, width, _ = LATENT[name]
+    rng = np.random.default_rng(seed)
+    q = jnp.asarray(rng.standard_normal((S, 1, g, width)), F32)
+    rows = jnp.asarray(rng.standard_normal((S, P, 1, width)), F32)
+    buf = sa.write_slot(jnp.zeros(sa.stored_shape(S, P, 1, width), F32),
+                        rows[0], 0)
+    for slot in range(1, S):
+        buf = sa.write_slot(buf, rows[slot], slot)
+    return q, rows[:, :, 0], buf
+
+
+@pytest.mark.parametrize("upto", sorted(UPTOS))
+@pytest.mark.parametrize("name", ["pangu_step", "one_lane_group_of_values"])
+def test_the_latent_kernel_is_the_einsum_over_one_row_a_place(name, upto):
+    """One buffer, read once: the scores over a row's whole width, the
+    values its first lanes; the kernel (interpreted) against the einsum
+    over the same buffer and against a softmax written out."""
+    g, width, values = LATENT[name]
+    q, rows, buf = latent_operands(name)
+    assert buf.shape == (S, P, -(-width // 128) * 128)
+    upto = jnp.asarray(UPTOS[upto], jnp.int32)
+    scale = 0.07
+    with jax.default_matmul_precision("highest"):
+        scores = jnp.einsum("sgw,spw->sgp", q[:, 0], rows) * scale
+        seen = jnp.arange(P)[None, None, :] < upto[:, None, None]
+        want = jnp.einsum("sgp,spv->sgv", jax.nn.softmax(
+            jnp.where(seen, scores, -jnp.inf), -1), rows[..., :values])
+        fallback = sa.latent_attention_einsum(q, buf, upto, values, scale)
+        kernel = sa.latent_attention_kernel(q, buf, upto, values, scale,
+                                            Tiles(TP), interpret=True)
+    assert fallback.shape == kernel.shape == (S, 1, g, values)
+    np.testing.assert_allclose(np.asarray(fallback[:, 0]), np.asarray(want),
+                               rtol=0, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(kernel), np.asarray(fallback),
+                               rtol=0, atol=2e-5)
+
+
+def test_a_latent_slot_that_sees_nothing_gives_zeros_and_no_later_place():
+    g, width, values = LATENT["one_lane_group_of_values"]
+    q, rows, buf = latent_operands("one_lane_group_of_values", seed=3)
+    upto = jnp.asarray([0, 70, 256], jnp.int32)
+    for read in (sa.latent_attention_einsum, functools.partial(
+            sa.latent_attention_kernel, tiles=Tiles(TP), interpret=True)):
+        base = np.asarray(read(q, buf, upto, values, 0.1))
+        assert not base[0].any() and base[1].any()
+        later = buf.at[1, 70:].set(50.0)
+        assert np.array_equal(np.asarray(read(q, later, upto, values, 0.1)),
+                              base)
+
+
+#: (positions, g, width, values, b) -> tiles, or None where the einsum stays
+LATENT_RULE = {
+    # every place of a slot in one tile: 1024 places of 640 lanes
+    "pangu_step": ((1024, 128, 576, 512, 1), Tiles(1024)),
+    "twice_the_positions": ((2048, 128, 576, 512, 1), Tiles(1024)),
+    "fewer_positions_than_a_tile": ((128, 128, 576, 512, 1), Tiles(128)),
+    "the_tiny_voices": ((256, 4, 40, 32, 1), None),
+    "values_wider_than_the_row": ((1024, 128, 576, 640, 1), None),
+    "many_query_rows": ((1024, 128, 576, 512, 4), None),
+    "positions_the_tile_does_not_divide": ((1000, 128, 576, 512, 1), None),
+}
+
+
+@pytest.mark.parametrize("name", sorted(LATENT_RULE))
+def test_the_latent_tile_rule_reads_the_shape_alone(name):
+    shape, tiles = LATENT_RULE[name]
+    assert sa.latent_tile_rule(*shape) == tiles
+    # the per-head rule has no tiles for such a row (576 is not whole lanes)
+    assert sa.tile_rule(1024, 1, 128, 576, 1) is None
+    assert sa.stored_shape(256, 1024, 1, 576) == (256, 1024, 640)
+
+
+def test_off_a_tpu_the_latent_reader_is_the_einsum():
+    q, _, buf = latent_operands("the_tiny_voices")
+    graph = str(jax.make_jaxpr(functools.partial(
+        sa.latent_attention, values=32, scale=0.1))(
+        q, buf, jnp.asarray([5, 6, 7], jnp.int32)))
+    assert "dot_general" in graph and "pallas_call" not in graph
+    assert sa.latent_implementation(1024, 128, 576, 512, 1) == "einsum"
+
+
 def test_off_a_tpu_the_function_is_the_einsum():
     q, k, v = operands("lfm2_step")
     upto = jnp.asarray([5, 6, 7], jnp.int32)

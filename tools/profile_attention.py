@@ -21,6 +21,14 @@ units behind it (68-819 places, 344 at the mean).  ``GB/s`` counts what
 ``kv * d`` for every place a slot attends over, bfloat16; percent is of 819
 GB/s.  Every candidate is held to the first.  Needs a TPU (``--rehearse``:
 the CPU, a few slots and places, the kernel interpreted: no times).
+
+``--latent`` measures the latent reader instead (``pangu_step``: 256 slots,
+128 query heads on one row of 576 values in 640 lanes, the values its first
+512): ``einsum@SPw`` and ``kernel(tp)`` of ``latent_attention``, each a
+write of the step's row and the read, ``REPS`` layers a program.  ``GB/s``
+counts one row of 576 values a place attended over; ``ms_of_ops`` is the
+two products at the MXU's 197 TFLOP/s beside ``ms_of_bytes``: this reader
+sits at the ridge.
 """
 
 from __future__ import annotations
@@ -168,6 +176,74 @@ def measure(name: str, shape: tuple, seed: int, rehearse: bool) -> list:
     return lines
 
 
+#: the latent reader's shape: S, P, query heads, a row's values, of which
+#: the first are the values
+LATENT = (256, 1024, 128, 576, 512)
+
+
+def measure_latent(seed: int, rehearse: bool) -> list:
+    s, p, g, width, values = (4, 256, 8, 576, 512) if rehearse else LATENT
+    scale = 192.0 ** -0.5
+    rng = np.random.default_rng(seed)
+    uptos = [draw_upto(rng, s, p, 1) for _ in range(2)]
+    keys = jax.random.split(jax.random.PRNGKey(seed), 2 + REPS)
+    dtype = F32 if rehearse else BF16       # the CPU has no bfloat16 dot
+    qs = jax.random.normal(keys[0], (REPS, s, 1, g, width), F32)
+    rows = jax.random.normal(keys[1], (REPS, s, 1, 1, width), dtype)
+    cands = [("einsum@SPw", sa.latent_attention_einsum)] + [
+        (f"kernel({tp})", functools.partial(
+            sa.latent_attention_kernel, tiles=sa.Tiles(tp),
+            interpret=rehearse))
+        for tp in TILES if tp <= p]
+    lines, ref = [], None
+    for cand, attend in cands:
+        line = {"shape": "pangu_step", "S": s, "P": p, "g": g,
+                "width": width, "values": values, "candidate": cand,
+                "mean_upto": float(np.mean(uptos))}
+
+        @functools.partial(jax.jit, donate_argnums=(0,))
+        def many(bufs, qs, rows, pos, upto, attend=attend):
+            bufs, outs = list(bufs), []
+            for i in range(REPS):
+                bufs[i] = sa.write_rows(bufs[i], rows[i], pos)
+                outs.append(attend(qs[i], bufs[i], upto, values, scale))
+            return bufs, jnp.stack(outs)
+
+        try:
+            bufs = [jnp.pad(jax.random.normal(keys[2 + i], (s, p, width),
+                                              dtype),
+                            ((0, 0), (0, 0), (0, -width % 128)))
+                    for i in range(REPS)]
+            times = []
+            for i in range(2 if rehearse else 6):
+                upto = jnp.asarray(uptos[i % 2])
+                t0 = time.perf_counter()
+                bufs, out = jax.block_until_ready(
+                    many(bufs, qs, rows, upto[:, None] - 1, upto))
+                times.append(time.perf_counter() - t0)
+                if i == 0:
+                    line["compile_s"] = times[0]
+                    if ref is None:
+                        ref = out
+                        line["ref_abs_max"] = float(jnp.max(jnp.abs(ref)))
+                    line["err_max"] = float(jnp.max(jnp.abs(out - ref)))
+            del bufs
+            if not rehearse:
+                ms = min(times[1:]) * 1e3 / REPS
+                places = float(np.mean([u.sum() for u in uptos]))
+                charged = 2 * width * places
+                line.update(ms=ms, gb_per_s=charged / ms / 1e6,
+                            share_of_819=charged / ms / 1e6 / 819.0,
+                            ms_of_bytes=charged / 819e6,
+                            ms_of_ops=2 * g * (width + values) * places
+                            / 197e9)
+        except Exception as e:  # a candidate the compiler refuses
+            line["error"] = f"{type(e).__name__}: {str(e)[:300]}"
+        print(json.dumps(line), flush=True)
+        lines.append(line)
+    return lines
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="",
@@ -176,15 +252,18 @@ def main() -> int:
     ap.add_argument("--rehearse", action="store_true",
                     help="on the CPU at 4 slots of 256 places, the kernel "
                          "interpreted: results only, no times")
+    ap.add_argument("--latent", action="store_true",
+                    help="the latent reader (one row a place, keys and "
+                         "values at once) in the other shapes' place")
     ap.add_argument("--out", default="chiprun_out/profile_attention.json")
     args = ap.parse_args()
     device = jax.devices()[0]
     if device.platform != "tpu" and not args.rehearse:
         print(f"needs a TPU, found {device.platform}", file=sys.stderr)
         return 1
-    lines = []
+    lines = measure_latent(args.seed, args.rehearse) if args.latent else []
     for name, shape in SHAPES.items():
-        if name.startswith(args.only):
+        if name.startswith(args.only) and not args.latent:
             if args.rehearse:
                 shape = (4, 256) + shape[2:]
             lines += measure(name, shape, args.seed, args.rehearse)

@@ -55,6 +55,12 @@ STEP_SHAPES = (
     # 256 rows x 6 of which half fall on the 64 held experts
     ("nemotron_step.w13", 1536, 64, 2688, 1920, 768, 0, 0.3, (2688, 1856)),
     ("nemotron_step.w2", 1536, 64, 1920, 2688, 768, 0, 0.3, (1856, 2688)),
+    # 256 rows x 8 of which a thirty-second falls on the 8 held experts: the
+    # short path's 256 rows, and the 2048 an expert layer not told of its
+    # thin share would hand on
+    ("pangu_step.w13", 256, 8, 7680, 4096, 64, 0, 0.3),
+    ("pangu_step.w2", 256, 8, 2048, 7680, 64, 0, 0.3),
+    ("pangu_untold.w13", 2048, 8, 7680, 4096, 64, 0, 0.3),
 )
 #: a prompt of 68-182 ids at its text bucket (96, 128, 192), the mean
 #: prompt's rows valid
@@ -160,6 +166,10 @@ def measure(shape: tuple, full: bool, seed: int,
     xs = jax.random.normal(keys[0], (REPS, rows, k), jnp.bfloat16)
     w = jax.random.normal(keys[1], (groups, k, n), jnp.bfloat16) * 0.02
     cands = candidates(k, n, full, probes)
+    rule = gm.tile_rule(rows, groups, k, n, jnp.dtype(jnp.bfloat16))
+    if rule is not None and not probes and f"own{tuple(rule)}" not in dict(
+            cands):
+        cands.append((f"own{tuple(rule)}", rule))
     published = {}
     if (k_pub, n_pub) != (k, n):
         # zero columns and rows outside the published width, and the same
