@@ -196,6 +196,11 @@ class Backbone:
         """Bytes of ``positions`` cached latent rows, over all layers."""
         return 0
 
+    def latent_chunk(self, positions: int) -> int:
+        """What the step's latent reader rounds a row's places up to
+        (``slot_attention.latent_places``' chunk; 0: no latent rows)."""
+        return 0
+
 
 def token_step_programs(module, name: str) -> tuple:
     """``build_step``, ``build_prefill`` and ``build_step_admit`` of a
@@ -470,6 +475,12 @@ class PanguBackbone(Lfm2Backbone):
     def latent_cache_bytes(self, positions: int) -> int:
         return self.cfg.latent_cache_bytes(positions)
 
+    def latent_chunk(self, positions: int) -> int:
+        cfg = self.cfg
+        return slot_attention.latent_reach(
+            positions, cfg.num_attention_heads, cfg.latent_width,
+            cfg.kv_lora_rank, self.block_length)
+
     build_step, build_prefill, build_step_admit = token_step_programs(
         pangu_moe, "pangu")
 
@@ -522,6 +533,7 @@ class UnitVoice(BaseModel):
         #: step program
         self.attention = self.backbone.attention(self.positions)
         self.latent_layers = self.backbone.latent_layers
+        self._latent_chunk = self.backbone.latent_chunk(self.positions)
         #: which form of latent attention a step runs (None: it has none)
         self.mla_form = self.backbone.mla_form
         self.params = weights["backbone"]
@@ -894,6 +906,13 @@ class UnitVoice(BaseModel):
         """Bytes of ``positions`` latent rows over the backbone's layers
         (0: it has no latent attention)."""
         return self.backbone.latent_cache_bytes(positions)
+
+    def latent_places(self, attended: int) -> int:
+        """The places the step's latent reader moves for a row that attends
+        over ``attended``: whole chunks of the kernel's, every position
+        where the einsum reads (0: it has no latent attention)."""
+        return (slot_attention.latent_places(attended, self._latent_chunk)
+                if self._latent_chunk else 0)
 
     def dumped(self, plan: RowPlan, done: int) -> bool:
         """Whether a flagged row keeps what its launch number ``done``

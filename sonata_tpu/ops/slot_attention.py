@@ -34,12 +34,16 @@ layer keeps one buffer, a place is ``[c_kv | k_rope]`` in whole lanes (576
 values in 640), every query head of a slot reads the same row, and the
 values are the row's first ``values`` lanes.  :func:`stored_shape`,
 :func:`write_rows` and :func:`write_slot` take such a row as they take any
-other (``kv = 1``); :func:`latent_attention` reads it: the same grid, the
-same online softmax, one block of the one buffer a tile, fetched once, its
-leading lanes cut out in VMEM for the second product.  With a hundred and
-more query heads on one row the products are no plain stream any more: at
-128 heads of 576 a tile does 242 operations a byte, the chip's ridge
-(:func:`latent_tile_rule`; PERF.md §5).
+other (``kv = 1``); :func:`latent_attention` reads it.  With a hundred and
+more query heads on one row the products are no plain stream any more (at
+128 heads of 576 a place does 242 operations a byte, the chip's ridge), and
+a grid step costs half a microsecond fetched or skipped, so this reader
+has one grid step a slot and walks the slot's places inside it: the
+buffer stays in HBM, the kernel copies a slot's places in chunks up to its
+length (:func:`latent_places`), a few chunks a copy, the copies running
+ahead of the products from one slot into the next, and no place past a
+row's last chunk is fetched or multiplied (:func:`latent_tile_rule`;
+PERF.md §5).
 
 **Precision**: queries and probabilities enter the products in the type
 the buffers hold (bfloat16 in every program of a voice); scores, the
@@ -75,12 +79,19 @@ TILE_ELEMENTS = 512 * 512
 #: the most query rows a lane group may hold: above it the products are no
 #: stream of keys and values any more and XLA's own stay
 MAX_ROWS = 256
-#: the elements of a positions tile of latent rows: 1024 places of 640
-#: lanes (1.3 MB of bfloat16).  Beside 128 query rows a tile's products
-#: take as long as its fetch, and what is left to win is the grid's own
-#: cost: a tile of all 1024 places a slot, nothing skipped, beat tiles of
-#: 512, 256 and 128 at the mean row's 350 places (PERF.md §5 has the table)
-LATENT_TILE_ELEMENTS = 1024 * 640
+#: the elements of a chunk of latent rows, what a slot's length is rounded
+#: up to: 128 places of 640 lanes (160 KB of bfloat16), a lane group of
+#: scores.  Of the places the reader moves so, the cell's rows hold 0.84 at
+#: the mean, 0.73 at chunks of 256 (PERF.md §5 has the table)
+LATENT_CHUNK_ELEMENTS = 128 * 640
+#: the most chunks one copy brings in and one pass of the online softmax
+#: takes: a pass costs about half a microsecond whatever it holds, so 4
+#: chunks a trip beat 2 (and 8 won nothing more)
+LATENT_TRIP_CHUNKS = 4
+#: the trips the latent reader holds in VMEM: one under the products and two
+#: copies ahead of it (with one ahead the copies stall at every slot's end;
+#: three ahead won nothing more)
+LATENT_BUFFERS = 3
 
 
 class Tiles(NamedTuple):
@@ -271,104 +282,189 @@ def slot_attention_einsum(q, k_buf, v_buf, upto):
 # a latent row: keys and values at once
 # ---------------------------------------------------------------------------
 
+def latent_places(upto, chunk: int):
+    """The places the latent reader moves for a slot that holds ``upto``:
+    whole chunks of ``chunk`` up to the one its last place lies in (an int,
+    or an array a slot).  The kernel's copies and trip counts are made of
+    this, and a step group's ``latent_places_fetched`` sums it."""
+    return (upto + chunk - 1) // chunk * chunk
+
+
 @functools.lru_cache(maxsize=None)
 def latent_tile_rule(positions: int, g: int, width: int, values: int,
                      b: int) -> Optional[Tiles]:
-    """The latent reader's tiles for ``b`` queries a slot of ``g`` heads
-    over ``positions`` rows of ``width`` (stored in whole lanes) whose
-    first ``values`` lanes are the values, or None where the einsum stays:
-    a pure function of the shape, as :func:`tile_rule`.
+    """The latent reader's chunk (``Tiles.tp``: the places a slot's length
+    is rounded up to) for ``b`` queries a slot of ``g`` heads over
+    ``positions`` rows of ``width`` (stored in whole lanes) whose first
+    ``values`` lanes are the values, or None where the einsum stays: a pure
+    function of the shape, as :func:`tile_rule`.
 
     The values have to be whole lanes of the row; the query rows of a slot
-    (``b * g``) at most ``MAX_ROWS``; the positions tile holds
-    ``LATENT_TILE_ELEMENTS`` of the stored row (the power of two below:
-    1024 places of 640 lanes), at most all the positions, which it has to
-    divide, in whole sublanes of a bfloat16 tile (16)."""
+    (``b * g``) at most ``MAX_ROWS``; the chunk holds
+    ``LATENT_CHUNK_ELEMENTS`` of the stored row (the power of two below:
+    128 places of 640 lanes), at most all the positions, which it has to
+    divide, in whole lane groups (a chunk's scores are whole lanes)."""
     if values % LANES or not 0 < values <= width or b * g > MAX_ROWS:
         return None
-    tp = 1 << (max(LATENT_TILE_ELEMENTS // lanes(width), 1).bit_length() - 1)
+    tp = 1 << (max(LATENT_CHUNK_ELEMENTS // lanes(width), 1).bit_length() - 1)
     tp = min(tp, positions)
-    return Tiles(tp) if tp % 16 == 0 and positions % tp == 0 else None
+    return Tiles(tp) if tp % LANES == 0 and positions % tp == 0 else None
 
 
-def _latent_kernel(upto, q_ref, c_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                   tp: int, scale: float):
-    s, t = pl.program_id(0), pl.program_id(1)
+def _latent_kernel(upto, q_ref, rows_ref, o_ref, trip_ref, sem, cursor,
+                   m_ref, l_ref, acc_ref, *, tp: int, scale: float):
+    """One slot a grid step, and in it a loop over the slot's places as far
+    as ``upto`` says: a trip takes up to ``LATENT_TRIP_CHUNKS`` chunks of
+    ``tp`` places in one copy and one pass of the online softmax (a body
+    of static size for each count).  ``rows_ref`` is the whole buffer
+    where it lies (HBM); ``trip_ref`` ``[LATENT_BUFFERS, span, stored]``
+    (``span``: the places of a whole trip) takes the trips in turn.  The copies run ``LATENT_BUFFERS - 1`` trips
+    ahead of the products, in the order the products take them and from
+    one slot into the next: ``cursor`` (SMEM, kept from grid step to grid
+    step) holds the next trip to copy (its slot, its number there) and how
+    many trips have been multiplied.  ``m_ref`` and ``l_ref`` hold a row's
+    number in every lane (a column would be spread again for every use)."""
+    s, slots = pl.program_id(0), pl.num_programs(0)
     rows, values = o_ref.shape
+    buffers, span, _ = trip_ref.shape
+    ahead, most = buffers - 1, span // tp
     n = upto[s]
 
-    @pl.when(t == 0)
-    def _start():
-        m_ref[...] = jnp.full(m_ref.shape, MASKED, F32)
-        l_ref[...] = jnp.zeros(l_ref.shape, F32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, F32)
+    def chunks(holds, t):
+        """The chunks trip ``t`` of a slot that holds ``holds`` takes."""
+        return jnp.minimum(latent_places(holds - t * span, tp) // tp, most)
 
-    @pl.when(t * tp < n)
-    def _tile():
-        seen = t * tp + lax.broadcasted_iota(jnp.int32, (rows, tp), 1) < n
-        scores = lax.dot_general(
-            q_ref[...], c_ref[...], (((1,), (1,)), ((), ())),
-            preferred_element_type=F32) * scale
-        scores = jnp.where(seen, scores, MASKED)
-        m_prev = m_ref[...]
-        m_next = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_next)
-        p = jnp.exp(scores - m_next)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = m_next
-        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
-            p.astype(c_ref.dtype), c_ref[:, :values],
-            preferred_element_type=F32)
+    def sized(count, body):
+        """``body(places)`` at the static size ``count`` chunks have."""
+        for j in range(1, most + 1):
+            pl.when(count == j)(functools.partial(body, j * tp))
 
-    @pl.when(t == pl.num_programs(1) - 1)
-    def _finish():
-        total = l_ref[...]
-        o_ref[...] = acc_ref[...] / jnp.where(total > 0.0, total, 1.0)
+    def copy(slot, t, k, places):
+        return pltpu.make_async_copy(
+            rows_ref.at[slot, pl.ds(pl.multiple_of(t * span, span), places)],
+            trip_ref.at[k, pl.ds(0, places)], sem.at[k])
+
+    def held(slot):
+        """The first slot from ``slot`` on that holds a place."""
+        return lax.while_loop(
+            lambda j: jnp.logical_and(
+                j < slots, upto[jnp.minimum(j, slots - 1)] == 0),
+            lambda j: j + 1, slot)
+
+    def copy_next(k):
+        """Start the copy of the trip the cursor stands at, into buffer
+        ``k``, and move the cursor on."""
+        slot, t = cursor[0], cursor[1]
+
+        @pl.when(slot < slots)
+        def _start():
+            holds = upto[slot]
+            sized(chunks(holds, t),
+                  lambda places: copy(slot, t, k, places).start())
+            last = (t + 1) * span >= holds
+            cursor[0] = jnp.where(last, held(slot + 1), slot)
+            cursor[1] = jnp.where(last, 0, t + 1)
+
+    @pl.when(s == 0)
+    def _first():
+        cursor[0], cursor[1], cursor[2] = held(0), 0, 0
+        for k in range(ahead):
+            copy_next(k)
+
+    m_ref[...] = jnp.full(m_ref.shape, MASKED, F32)
+    l_ref[...] = jnp.zeros(l_ref.shape, F32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, F32)
+
+    def trip(t, done):
+        k = done % buffers
+        copy_next((done + ahead) % buffers)
+
+        def attend(places):
+            copy(s, t, k, places).wait()
+            c = trip_ref[k, :places]
+            seen = t * span + lax.broadcasted_iota(
+                jnp.int32, (rows, places), 1) < n
+            scores = lax.dot_general(
+                q_ref[...], c, (((1,), (1,)), ((), ())),
+                preferred_element_type=F32) * scale
+            scores = jnp.where(seen, scores, MASKED)
+            m_prev = m_ref[...]
+            m_next = jnp.maximum(m_prev,
+                                 jnp.max(scores, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_next)
+            p = jnp.exp(scores - jnp.tile(m_next, (1, places // LANES)))
+            l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1,
+                                                      keepdims=True)
+            m_ref[...] = m_next
+            acc_ref[...] = jnp.tile(alpha, (1, values // LANES)) \
+                * acc_ref[...] + jnp.dot(p.astype(c.dtype), c[:, :values],
+                                         preferred_element_type=F32)
+
+        sized(chunks(n, t), attend)
+        return done + 1
+
+    cursor[2] = lax.fori_loop(0, latent_places(n, span) // span, trip,
+                              cursor[2])
+    # a slot that sees nothing gives zeros
+    total = l_ref[...]
+    o_ref[...] = (acc_ref[...] / jnp.tile(
+        jnp.where(total > 0.0, total, 1.0),
+        (1, values // LANES))).astype(o_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=("values", "scale", "tiles",
+                                             "interpret"))
 def latent_attention_kernel(q, buf, upto, values: int, scale: float,
                             tiles: Tiles, *, interpret: bool = False):
     """The latent reader's kernel, whatever the backend (``interpret`` for
     the CPU): ``q`` ``[S, b, g, width]``, the buffer ``[S, P, lanes(width)]``,
-    ``upto`` ``[S]``.  Returns ``[S, b, g, values]`` float32."""
+    ``upto`` ``[S]``.  Returns ``[S, b, g, values]`` in the buffer's type
+    (the float32 quotient rounded once, as its caller would).  A jitted
+    function of its own: a program's layers share one trace and one
+    lowering of the kernel, whose four bodies a layer would else add a
+    fifth to a warm start (PERF.md §6 PR 42)."""
     s, b, g, width = q.shape
     span, stored = buf.shape[1:]
     tp = tiles.tp
     if stored != lanes(width) or values % LANES or values > width \
-            or span % tp:
+            or tp % LANES or span % tp:
         raise ValueError(f"q {q.shape}, values {values} and tiles {tiles} "
                          f"do not fit the buffer {buf.shape}")
     rows = b * g
     padded = rows + -rows % 16
     qg = jnp.pad(q.reshape(s, rows, width).astype(buf.dtype),
                  ((0, 0), (0, padded - rows), (0, stored - width)))
+    # the places of a trip: whole chunks that divide the positions
+    trip_places = tp * max(j for j in range(1, LATENT_TRIP_CHUNKS + 1)
+                           if span % (j * tp) == 0)
 
-    def q_map(i, t, upto):
+    def q_map(i, upto):
         return (i, 0, 0)
-
-    def row_map(i, t, upto):
-        # past the slot's last tile the index stays: nothing is fetched
-        return (i, jnp.minimum(t, (jnp.maximum(upto[i], 1) - 1) // tp), 0)
 
     out = pl.pallas_call(
         functools.partial(_latent_kernel, tp=tp, scale=scale),
-        out_shape=jax.ShapeDtypeStruct((s, padded, values), F32),
+        out_shape=jax.ShapeDtypeStruct((s, padded, values), buf.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(s, span // tp),
+            grid=(s,),
             in_specs=[pl.BlockSpec((None, padded, stored), q_map),
-                      pl.BlockSpec((None, tp, stored), row_map)],
+                      pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((None, padded, values), q_map),
-            scratch_shapes=[pltpu.VMEM((padded, 1), F32),
-                            pltpu.VMEM((padded, 1), F32),
+            scratch_shapes=[pltpu.VMEM((LATENT_BUFFERS, trip_places,
+                                        stored), buf.dtype),
+                            pltpu.SemaphoreType.DMA((LATENT_BUFFERS,)),
+                            pltpu.SMEM((3,), jnp.int32),
+                            pltpu.VMEM((padded, LANES), F32),
+                            pltpu.VMEM((padded, LANES), F32),
                             pltpu.VMEM((padded, values), F32)]),
+        # the copies run ahead from one slot into the next: in order
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         cost_estimate=pl.CostEstimate(
             flops=2 * s * padded * span * (stored + values),
             transcendentals=s * padded * span,
-            bytes_accessed=(s * span * stored * buf.dtype.itemsize
-                            + s * padded * (2 * stored + 4 * values))),
+            bytes_accessed=buf.dtype.itemsize * s * (
+                span * stored + padded * (stored + values))),
         name="latent_attention",
         interpret=interpret,
     )(upto.astype(jnp.int32), qg, buf)
@@ -412,14 +508,24 @@ def latent_implementation(positions: int, g: int, width: int, values: int,
 def latent_attention(q, buf, upto, values: int, scale: float):
     """Every slot's queries ``q`` ``[S, b, g, width]`` over the slot's
     latent rows at the places ``< upto[slot]``, the scores times ``scale``
-    (the model's, not the row's width): ``[S, b, g, values]`` float32, the
-    probabilities' sums of the rows' first ``values`` lanes; zeros for a
-    slot that sees nothing."""
+    (the model's, not the row's width): ``[S, b, g, values]`` in the rows'
+    type, the probabilities' sums of the rows' first ``values`` lanes
+    (float32 sums, rounded once); zeros for a slot that sees nothing."""
     _, b, g, width = q.shape
     tiles = _latent_tiles_here(buf.shape[1], g, width, values, b)
     if tiles is None:
-        return latent_attention_einsum(q, buf, upto, values, scale)
+        return latent_attention_einsum(q, buf, upto, values,
+                                       scale).astype(buf.dtype)
     return latent_attention_kernel(q, buf, upto, values, scale, tiles)
+
+
+def latent_reach(positions: int, g: int, width: int, values: int,
+                 b: int) -> int:
+    """What :func:`latent_attention` rounds a slot's length up to on this
+    backend (:func:`latent_places`' chunk): the kernel's chunk, or every
+    position where the einsum reads."""
+    tiles = _latent_tiles_here(positions, g, width, values, b)
+    return positions if tiles is None else tiles.tp
 
 
 def _tiles_here(positions: int, kv: int, g: int, d: int,

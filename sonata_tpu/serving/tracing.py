@@ -751,6 +751,9 @@ class StepStats:
         #: bytes of latent rows (latent attention's keys and values at
         #: once) the loops' slots hold (all slots, all positions)
         self.mla_cache_resident_bytes = 0
+        #: places a layer's latent reader moved for the live rows (whole
+        #: chunks up to a row's length; every position under the einsum)
+        self.mla_places_fetched = 0
         #: step launches that took the expert layer's full-length path
         #: because a thin share's held experts got more than the short one
         #: takes
@@ -777,6 +780,7 @@ class StepStats:
                 "steps"]
             self.attention[group["attention"], "step"] += group["steps"]
             self.held_overflow_steps += group.get("held_overflow_steps", 0)
+            self.mla_places_fetched += group.get("latent_places_fetched", 0)
             self.slot_steps["live"] += group["live_slot_steps"]
             self.slot_steps["empty"] += (group["steps"] * group["slots"]
                                          - group["live_slot_steps"])
@@ -949,6 +953,15 @@ class StepStats:
             "layer, keys and values at once, in whole lanes; every slot and "
             "position, live or not; 0 for a backbone that has none)."
         ).set_function(lambda: float(self.mla_cache_resident_bytes))
+        registry.counter(
+            "sonata_mla_places_fetched_total",
+            "Places a layer's latent reader moved for the live rows of "
+            "step launches: a row's places in whole chunks of the kernel's "
+            "up to its length, every position where the einsum reads (a "
+            "step group's kv_positions over its latent_places_fetched is "
+            "the share of what was moved that a row held; 0 for a backbone "
+            "without latent attention)."
+        ).set_function(lambda: float(self.mla_places_fetched))
         registry.counter(
             "sonata_moe_held_overflow_steps_total",
             "Step launches whose expert layer took its full-length path "

@@ -54,7 +54,11 @@ steps read and wrote, per expert layer ``assignments``, ``experts_touched``,
 (launches in which a thin share's held experts got more rows than the
 program's short path takes, so that it took the full-length one: none is
 ever left out), ``latent_cache_bytes`` of latent rows the live rows'
-attention read (``kv_positions`` rows a layer, as stored), ``host_ms`` by phase,
+attention read (``kv_positions`` rows a layer, as stored),
+``latent_places_fetched`` the places a layer's latent reader moved for them
+(a row's ``kv_positions`` in whole chunks of the kernel's, every position
+where the einsum reads: ``kv_positions`` over it is the share of what was
+moved that a row held), ``host_ms`` by phase,
 and of the loop's turns: ``wall_ms`` and, beside ``host_ms``'s three,
 ``device_wait_ms`` (blocked until the step before had run), ``record_ms``
 (the rest of settling it) and ``other_ms`` (the turn less its phases), which
@@ -90,8 +94,8 @@ launch | retire | settle`` with ``step_no``.
 The engine (a voice: :class:`~sonata_tpu.models.unit_voice.UnitVoice`)
 gives ``slots``, ``expert_layers``, ``block_length``, ``denoising_steps``,
 ``expert_matmul``, ``attention``, ``ssm_layers``, ``ssm_state_bytes`` (a
-slot's), where it has latent attention ``latent_layers``, ``mla_form`` and
-``latent_cache_bytes(positions)``,
+slot's), where it has latent attention ``latent_layers``, ``mla_form``,
+``latent_cache_bytes(positions)`` and ``latent_places(attended)``,
 ``new_cache()``, ``plan(n_ids, budget)``, ``prefill(cache, slot, ids,
 temperature)``, ``step(cache, live, temperature, step_no)``, where its step
 carries arrivals ``carries(n_ids)`` and ``step_admit(cache, live,
@@ -130,7 +134,7 @@ DUMP_ROWS = 8
 
 #: what a launch adds to its group's sums, row by row
 ROW_SUMS = ("live_slot_steps", "units", "positions", "denoise_row_passes",
-            "commit_row_passes", "kv_positions")
+            "commit_row_passes", "kv_positions", "latent_places_fetched")
 
 DUMP_DIR_ENV = "SONATA_AR_DUMP_DIR"
 DUMP_PREFIX_ENV = "SONATA_AR_DUMP_RID_PREFIX"
@@ -207,6 +211,10 @@ class StepLoop:
         #: engine without latent attention: none)
         self._latent_bytes = getattr(engine, "latent_cache_bytes",
                                      lambda positions: 0)
+        #: the places its latent reader moves for a row that attends over
+        #: so many
+        self._latent_places = getattr(engine, "latent_places",
+                                      lambda attended: 0)
         self._resident = (
             engine.slots * engine.ssm_state_bytes,
             self._latent_bytes(engine.slots * getattr(engine, "positions",
@@ -348,7 +356,9 @@ class StepLoop:
                 commits = row.plan.commits(row.done)
                 sums["live_slot_steps"] += 1
                 sums["positions"] += row.plan.block
-                sums["kv_positions"] += row.plan.attended(row.done)
+                attended = row.plan.attended(row.done)
+                sums["kv_positions"] += attended
+                sums["latent_places_fetched"] += self._latent_places(attended)
                 sums["commit_row_passes"] += commits
                 sums["denoise_row_passes"] += not commits
                 sums["units"] += row.plan.units(row.done + 1) \

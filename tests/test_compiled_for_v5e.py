@@ -111,11 +111,14 @@ def test_the_slot_attention_compiles_for_a_v5e_at_the_step_shapes(
 
 def test_the_latent_reader_compiles_for_a_v5e_at_the_step_shape(
         one_chip, no_compile_cache):
-    """The latent reader's kernel with the rule's tiles at the real widths:
+    """The latent reader's kernel with the rule's chunk at the real widths:
     128 query heads on one row of 576 values in 640 lanes, the values its
-    first 512, cut out of the block in VMEM."""
+    first 512; the buffer handed over where it lies, a slot's places copied
+    by the kernel itself up to its length (the copies, their semaphores
+    and the trip count from ``upto``: what interpret mode cannot refuse)."""
     slots, heads, width, values = 256, 128, 576, 512
     tiles = sa.latent_tile_rule(POSITIONS, heads, width, values, 1)
+    assert tiles == sa.Tiles(128)
     spec = spec_on(one_chip)
     compiled = jax.jit(functools.partial(
         sa.latent_attention_kernel, values=values, scale=192 ** -0.5,

@@ -400,6 +400,7 @@ def test_the_voice_runs_and_its_loop_says_what_the_cache_and_the_share_cost(
                 voice.expert_layers, voice.expert_matmul) == (
             4, "absorbed", "einsum", [1, 2, 3], "ragged_dot")
         assert voice.latent_cache_bytes(10) == 10 * row_bytes
+        assert (voice.latent_places(0), voice.latent_places(10)) == (0, 256)
         assert ("step_admit", 32) in voice.lattice_shapes("full")
         with tracer.trace_request("test", request_id="row-0"):
             audio = voice.speak_batch(
@@ -427,6 +428,10 @@ def test_the_voice_runs_and_its_loop_says_what_the_cache_and_the_share_cost(
         assert (g["latent_layers"], g["mla_form"], g["ssm_layers"]) == (
             4, "absorbed", 0)
         assert g["latent_cache_bytes"] == row_bytes * g["kv_positions"]
+        # off a TPU the einsum moves every position of each live row's slot
+        # (the row a launch carries is live from the next one on)
+        assert g["latent_places_fetched"] == 256 * (
+            g["live_slot_steps"] - g["admit_steps"]) >= g["kv_positions"]
         assert 0 <= g["held_overflow_steps"] <= g["steps"]
         assert all(0 <= h <= a for h, a in zip(g["held_assignments"],
                                                g["assignments"]))
@@ -436,3 +441,6 @@ def test_the_voice_runs_and_its_loop_says_what_the_cache_and_the_share_cost(
     text = registry.render()
     assert f"sonata_moe_held_overflow_steps_total {stats.held_overflow_steps}"\
         in text and stats.held_overflow_steps >= overflowed
+    fetched = sum(g["latent_places_fetched"] for g in groups)
+    assert f"sonata_mla_places_fetched_total {stats.mla_places_fetched}\n" \
+        in text and stats.mla_places_fetched >= fetched > 0

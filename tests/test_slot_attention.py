@@ -183,40 +183,57 @@ def test_the_tile_rule_reads_the_shape_alone(name):
 #: name -> (query heads, a row's values, of which the first are the values)
 LATENT = {"pangu_step": (128, 576, 512), "the_tiny_voices": (4, 40, 32),
           "one_lane_group_of_values": (16, 160, 128)}
+#: the latent reader's chunk in the tests (the rule's at the cell's shape):
+#: 256 places are one trip of two chunks, 1024 two trips of four
+CHUNK = 128
+#: name -> (places a slot, a slot's length in each of the 3 slots): the
+#: per-head reader's cases, and a chunk's edge, one place past it (at 512
+#: a trip's), nothing beside a full slot
+LATENT_UPTOS = {
+    **{name: (P, upto) for name, upto in UPTOS.items()},
+    "a_chunks_edge": (1024, [128, 512, 640]),
+    "past_a_chunks_edge": (1024, [129, 513, 641]),
+    "nothing_beside_a_full_slot": (1024, [0, 1024, 0]),
+    "full_slots_beside_nothing": (1024, [1024, 0, 1023]),
+}
 
 
-def latent_operands(name: str, seed: int = 0):
+def latent_operands(name: str, seed: int = 0, positions: int = P):
     g, width, _ = LATENT[name]
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.standard_normal((S, 1, g, width)), F32)
-    rows = jnp.asarray(rng.standard_normal((S, P, 1, width)), F32)
-    buf = sa.write_slot(jnp.zeros(sa.stored_shape(S, P, 1, width), F32),
-                        rows[0], 0)
+    rows = jnp.asarray(rng.standard_normal((S, positions, 1, width)), F32)
+    buf = sa.write_slot(
+        jnp.zeros(sa.stored_shape(S, positions, 1, width), F32), rows[0], 0)
     for slot in range(1, S):
         buf = sa.write_slot(buf, rows[slot], slot)
     return q, rows[:, :, 0], buf
 
 
-@pytest.mark.parametrize("upto", sorted(UPTOS))
+@pytest.mark.parametrize("upto", sorted(LATENT_UPTOS))
 @pytest.mark.parametrize("name", ["pangu_step", "one_lane_group_of_values"])
 def test_the_latent_kernel_is_the_einsum_over_one_row_a_place(name, upto):
     """One buffer, read once: the scores over a row's whole width, the
-    values its first lanes; the kernel (interpreted) against the einsum
-    over the same buffer and against a softmax written out."""
+    values its first lanes; the kernel (interpreted: its copies, its trip
+    counts from ``upto``) against the einsum over the same buffer and
+    against a softmax written out."""
     g, width, values = LATENT[name]
-    q, rows, buf = latent_operands(name)
-    assert buf.shape == (S, P, -(-width // 128) * 128)
-    upto = jnp.asarray(UPTOS[upto], jnp.int32)
+    positions, upto = LATENT_UPTOS[upto]
+    q, rows, buf = latent_operands(name, positions=positions)
+    assert buf.shape == (S, positions, -(-width // 128) * 128)
+    upto = jnp.asarray(upto, jnp.int32)
     scale = 0.07
     with jax.default_matmul_precision("highest"):
         scores = jnp.einsum("sgw,spw->sgp", q[:, 0], rows) * scale
-        seen = jnp.arange(P)[None, None, :] < upto[:, None, None]
+        seen = jnp.arange(positions)[None, None, :] < upto[:, None, None]
         want = jnp.einsum("sgp,spv->sgv", jax.nn.softmax(
             jnp.where(seen, scores, -jnp.inf), -1), rows[..., :values])
+        want = jnp.where((upto > 0)[:, None, None], want, 0.0)
         fallback = sa.latent_attention_einsum(q, buf, upto, values, scale)
         kernel = sa.latent_attention_kernel(q, buf, upto, values, scale,
-                                            Tiles(TP), interpret=True)
+                                            Tiles(CHUNK), interpret=True)
     assert fallback.shape == kernel.shape == (S, 1, g, values)
+    assert kernel.dtype == buf.dtype
     np.testing.assert_allclose(np.asarray(fallback[:, 0]), np.asarray(want),
                                rtol=0, atol=2e-5)
     np.testing.assert_allclose(np.asarray(kernel), np.asarray(fallback),
@@ -228,7 +245,7 @@ def test_a_latent_slot_that_sees_nothing_gives_zeros_and_no_later_place():
     q, rows, buf = latent_operands("one_lane_group_of_values", seed=3)
     upto = jnp.asarray([0, 70, 256], jnp.int32)
     for read in (sa.latent_attention_einsum, functools.partial(
-            sa.latent_attention_kernel, tiles=Tiles(TP), interpret=True)):
+            sa.latent_attention_kernel, tiles=Tiles(CHUNK), interpret=True)):
         base = np.asarray(read(q, buf, upto, values, 0.1))
         assert not base[0].any() and base[1].any()
         later = buf.at[1, 70:].set(50.0)
@@ -236,16 +253,56 @@ def test_a_latent_slot_that_sees_nothing_gives_zeros_and_no_later_place():
                               base)
 
 
-#: (positions, g, width, values, b) -> tiles, or None where the einsum stays
+def test_latent_places_are_whole_chunks_up_to_a_slots_length():
+    assert [sa.latent_places(n, 128) for n in (0, 1, 128, 129, 1024)] == [
+        0, 128, 128, 256, 1024]
+    assert np.array_equal(
+        sa.latent_places(np.asarray([0, 347, 512, 819]), 256),
+        [0, 512, 512, 1024])
+    # every position where the einsum reads: the chunk is the buffer's span
+    assert sa.latent_places(347, 1024) == 1024
+
+
+@pytest.mark.parametrize("chunk", [128, 256])
+def test_the_latent_kernel_moves_latent_places_and_no_more(chunk):
+    """The counter's function against the kernel's copies and trip counts:
+    a NaN in any place from ``latent_places`` on never reaches a result (a
+    place that was fetched would, through ``0 x NaN`` in the second
+    product), and one in the last place before it does, masked or not."""
+    g, width, values = LATENT["one_lane_group_of_values"]
+    q, _, buf = latent_operands("one_lane_group_of_values", seed=4,
+                                positions=1024)
+    read = functools.partial(sa.latent_attention_kernel, values=values,
+                             scale=0.1, tiles=Tiles(chunk), interpret=True)
+    for upto in ([1, 513, 0], [128, 700, 1024], [257, 0, 512]):
+        moved = sa.latent_places(np.asarray(upto), chunk)
+        base = np.asarray(read(q, buf, jnp.asarray(upto, jnp.int32)))
+        assert np.all(np.isfinite(base))
+        beyond, inside = buf, buf
+        for slot, places in enumerate(moved):
+            beyond = beyond.at[slot, int(places):].set(jnp.nan)
+            if places:
+                inside = inside.at[slot, int(places) - 1].set(jnp.nan)
+        assert np.array_equal(
+            np.asarray(read(q, beyond, jnp.asarray(upto, jnp.int32))), base)
+        reached = np.asarray(read(q, inside, jnp.asarray(upto, jnp.int32)))
+        for slot, places in enumerate(moved):
+            assert np.all(np.isnan(reached[slot])) == bool(places)
+
+
+#: (positions, g, width, values, b) -> the chunk, or None where the einsum
+#: stays
 LATENT_RULE = {
-    # every place of a slot in one tile: 1024 places of 640 lanes
-    "pangu_step": ((1024, 128, 576, 512, 1), Tiles(1024)),
-    "twice_the_positions": ((2048, 128, 576, 512, 1), Tiles(1024)),
-    "fewer_positions_than_a_tile": ((128, 128, 576, 512, 1), Tiles(128)),
+    # a lane group of scores a chunk: 128 places of 640 lanes
+    "pangu_step": ((1024, 128, 576, 512, 1), Tiles(128)),
+    "twice_the_positions": ((2048, 128, 576, 512, 1), Tiles(128)),
+    "a_chunk_of_positions": ((128, 128, 576, 512, 1), Tiles(128)),
+    "a_narrower_row": ((1024, 128, 200, 128, 1), Tiles(256)),
+    "fewer_positions_than_a_lane_group": ((64, 128, 576, 512, 1), None),
     "the_tiny_voices": ((256, 4, 40, 32, 1), None),
     "values_wider_than_the_row": ((1024, 128, 576, 640, 1), None),
     "many_query_rows": ((1024, 128, 576, 512, 4), None),
-    "positions_the_tile_does_not_divide": ((1000, 128, 576, 512, 1), None),
+    "positions_the_chunk_does_not_divide": ((1000, 128, 576, 512, 1), None),
 }
 
 
@@ -260,11 +317,18 @@ def test_the_latent_tile_rule_reads_the_shape_alone(name):
 
 def test_off_a_tpu_the_latent_reader_is_the_einsum():
     q, _, buf = latent_operands("the_tiny_voices")
-    graph = str(jax.make_jaxpr(functools.partial(
-        sa.latent_attention, values=32, scale=0.1))(
-        q, buf, jnp.asarray([5, 6, 7], jnp.int32)))
+    upto = jnp.asarray([5, 6, 7], jnp.int32)
+    read = functools.partial(sa.latent_attention, values=32, scale=0.1)
+    graph = str(jax.make_jaxpr(read)(q, buf, upto))
     assert "dot_general" in graph and "pallas_call" not in graph
     assert sa.latent_implementation(1024, 128, 576, 512, 1) == "einsum"
+    # which moves every position of a slot, in the rows' type
+    assert sa.latent_reach(1024, 128, 576, 512, 1) == 1024
+    half = read(q, buf.astype(BF16), upto)
+    assert half.dtype == BF16 and read(q, buf, upto).dtype == F32
+    assert np.array_equal(
+        np.asarray(half, F32), np.asarray(sa.latent_attention_einsum(
+            q, buf.astype(BF16), upto, 32, 0.1).astype(BF16), F32))
 
 
 def test_off_a_tpu_the_function_is_the_einsum():

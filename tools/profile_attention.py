@@ -24,11 +24,18 @@ the CPU, a few slots and places, the kernel interpreted: no times).
 
 ``--latent`` measures the latent reader instead (``pangu_step``: 256 slots,
 128 query heads on one row of 576 values in 640 lanes, the values its first
-512): ``einsum@SPw`` and ``kernel(tp)`` of ``latent_attention``, each a
-write of the step's row and the read, ``REPS`` layers a program.  ``GB/s``
-counts one row of 576 values a place attended over; ``ms_of_ops`` is the
-two products at the MXU's 197 TFLOP/s beside ``ms_of_bytes``: this reader
-sits at the ridge.
+512): ``einsum@SPw``; ``grid(tp)``, the reader until PR 42 (a grid of (slot,
+tile of ``tp`` places), kept here to be measured against: at 1024 what PR 41
+ran); ``walk(chunk, most, buffers)``, ``latent_attention_kernel`` (one grid
+step a slot, the slot's places copied in chunks up to its length, at most
+``most`` chunks a trip, ``buffers`` trips in VMEM; the first is the rule's),
+each a write of the step's row and the read, ``REPS`` layers a program.  ``GB/s`` counts one row of 576 values a place attended over;
+``ms_of_ops`` is the two products at the MXU's 197 TFLOP/s beside
+``ms_of_bytes``: this reader sits at the ridge.  ``places_fetched`` is what
+the candidate moves (``latent_places`` of ``upto`` at its chunk or tile;
+every place for the einsum), ``kernel_ms`` the device time of the kernel's
+own operations a layer, from a capture of two more programs (a reading by
+the host's clock holds the queries' padding and the scatter as well).
 """
 
 from __future__ import annotations
@@ -46,6 +53,8 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 sa = importlib.import_module("sonata_tpu.ops.slot_attention")
 
@@ -179,6 +188,123 @@ def measure(name: str, shape: tuple, seed: int, rehearse: bool) -> list:
 #: the latent reader's shape: S, P, query heads, a row's values, of which
 #: the first are the values
 LATENT = (256, 1024, 128, 576, 512)
+#: the walking kernel's candidates: (places a chunk, chunks a trip at
+#: most, trips in VMEM); the first is the rule's
+WALKS = ((128, 4, 3), (256, 4, 3), (256, 2, 3), (512, 1, 3), (128, 2, 3),
+         (128, 8, 3), (128, 4, 2), (128, 4, 4))
+
+
+def _grid_kernel(upto, q_ref, c_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                 tp: int, scale: float):
+    s, t = pl.program_id(0), pl.program_id(1)
+    rows, values = o_ref.shape
+    n = upto[s]
+
+    @pl.when(t == 0)
+    def _start():
+        m_ref[...] = jnp.full(m_ref.shape, sa.MASKED, F32)
+        l_ref[...] = jnp.zeros(l_ref.shape, F32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, F32)
+
+    @pl.when(t * tp < n)
+    def _tile():
+        seen = t * tp + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, tp), 1) < n
+        scores = jax.lax.dot_general(
+            q_ref[...], c_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=F32) * scale
+        scores = jnp.where(seen, scores, sa.MASKED)
+        m_prev = m_ref[...]
+        m_next = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_next)
+        p = jnp.exp(scores - m_next)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[...] = m_next
+        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+            p.astype(c_ref.dtype), c_ref[:, :values],
+            preferred_element_type=F32)
+
+    @pl.when(t == pl.num_programs(1) - 1)
+    def _finish():
+        total = l_ref[...]
+        o_ref[...] = acc_ref[...] / jnp.where(total > 0.0, total, 1.0)
+
+
+def latent_grid(q, buf, upto, values: int, scale: float, tp: int, *,
+                interpret: bool = False):
+    """The latent reader until PR 42: a grid of (slot, tile of ``tp``
+    places), one block of the buffer a tile, a tile past a slot's length
+    neither fetched (its block index repeats) nor multiplied, its grid
+    step paid."""
+    s, b, g, width = q.shape
+    span, stored = buf.shape[1:]
+    rows = b * g
+    padded = rows + -rows % 16
+    qg = jnp.pad(q.reshape(s, rows, width).astype(buf.dtype),
+                 ((0, 0), (0, padded - rows), (0, stored - width)))
+
+    def q_map(i, t, upto):
+        return (i, 0, 0)
+
+    def row_map(i, t, upto):
+        return (i, jnp.minimum(t, (jnp.maximum(upto[i], 1) - 1) // tp), 0)
+
+    out = pl.pallas_call(
+        functools.partial(_grid_kernel, tp=tp, scale=scale),
+        out_shape=jax.ShapeDtypeStruct((s, padded, values), F32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(s, span // tp),
+            in_specs=[pl.BlockSpec((None, padded, stored), q_map),
+                      pl.BlockSpec((None, tp, stored), row_map)],
+            out_specs=pl.BlockSpec((None, padded, values), q_map),
+            scratch_shapes=[pltpu.VMEM((padded, 1), F32),
+                            pltpu.VMEM((padded, 1), F32),
+                            pltpu.VMEM((padded, values), F32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        name="latent_grid",
+        interpret=interpret,
+    )(upto.astype(jnp.int32), qg, buf)
+    return out[:, :rows].reshape(s, b, g, values)
+
+
+def walk(trip_chunks: int, buffers: int, *args, **kwargs):
+    """``latent_attention_kernel`` with at most ``trip_chunks`` chunks a
+    trip and ``buffers`` trips in VMEM (the module's constants, read as the
+    kernel is traced)."""
+    was = sa.LATENT_TRIP_CHUNKS, sa.LATENT_BUFFERS
+    sa.LATENT_TRIP_CHUNKS, sa.LATENT_BUFFERS = trip_chunks, buffers
+    try:
+        # the function under its jit: the constants are read as it is traced
+        return sa.latent_attention_kernel.__wrapped__(*args, **kwargs)
+    finally:
+        sa.LATENT_TRIP_CHUNKS, sa.LATENT_BUFFERS = was
+
+
+def kernel_ms(run, name: str, layers: int):
+    """Device milliseconds a layer of the operations named ``name`` while
+    ``run()`` runs under a capture of its own, or None (no such operation:
+    an einsum has no name of its own)."""
+    import shutil
+    import tempfile
+
+    from perfbench.harness import trace
+
+    log_dir = tempfile.mkdtemp(prefix="profile_attention_")
+    try:
+        jax.profiler.start_trace(log_dir)
+        try:
+            run()
+        finally:
+            jax.profiler.stop_trace()
+        events = trace.load_events(
+            log_dir, planes=lambda p: bool(trace.DEVICE_PLANE.match(p)))
+    finally:
+        shutil.rmtree(log_dir, ignore_errors=True)
+    mine = [e["dur_ns"] for e in events
+            if e["line"] == trace.OPS_LINE and name in e["name"]]
+    return sum(mine) / 1e6 / layers if mine else None
 
 
 def measure_latent(seed: int, rehearse: bool) -> list:
@@ -190,16 +316,23 @@ def measure_latent(seed: int, rehearse: bool) -> list:
     dtype = F32 if rehearse else BF16       # the CPU has no bfloat16 dot
     qs = jax.random.normal(keys[0], (REPS, s, 1, g, width), F32)
     rows = jax.random.normal(keys[1], (REPS, s, 1, 1, width), dtype)
-    cands = [("einsum@SPw", sa.latent_attention_einsum)] + [
-        (f"kernel({tp})", functools.partial(
-            sa.latent_attention_kernel, tiles=sa.Tiles(tp),
-            interpret=rehearse))
-        for tp in TILES if tp <= p]
+    #: (name, attend, places a copy or a block brings in, the kernel's name)
+    cands = [("einsum@SPw", sa.latent_attention_einsum, p, None)] + [
+        (f"grid({tp})", functools.partial(latent_grid, tp=tp,
+                                          interpret=rehearse), tp,
+         "latent_grid")
+        for tp in TILES if tp <= p] + [
+        (f"walk({tp}, {most}, {buffers})", functools.partial(
+            walk, most, buffers, tiles=sa.Tiles(tp), interpret=rehearse),
+         tp, "latent_attention")
+        for tp, most, buffers in WALKS if tp <= p]
     lines, ref = [], None
-    for cand, attend in cands:
+    for cand, attend, chunk, kernel in cands:
         line = {"shape": "pangu_step", "S": s, "P": p, "g": g,
                 "width": width, "values": values, "candidate": cand,
-                "mean_upto": float(np.mean(uptos))}
+                "mean_upto": float(np.mean(uptos)),
+                "places_fetched": float(np.mean(
+                    [sa.latent_places(u, chunk).sum() for u in uptos]))}
 
         @functools.partial(jax.jit, donate_argnums=(0,))
         def many(bufs, qs, rows, pos, upto, attend=attend):
@@ -227,7 +360,6 @@ def measure_latent(seed: int, rehearse: bool) -> list:
                         ref = out
                         line["ref_abs_max"] = float(jnp.max(jnp.abs(ref)))
                     line["err_max"] = float(jnp.max(jnp.abs(out - ref)))
-            del bufs
             if not rehearse:
                 ms = min(times[1:]) * 1e3 / REPS
                 places = float(np.mean([u.sum() for u in uptos]))
@@ -237,6 +369,16 @@ def measure_latent(seed: int, rehearse: bool) -> list:
                             ms_of_bytes=charged / 819e6,
                             ms_of_ops=2 * g * (width + values) * places
                             / 197e9)
+                if kernel:
+                    def twice():
+                        nonlocal bufs
+                        for u in uptos:
+                            u = jnp.asarray(u)
+                            bufs, _ = jax.block_until_ready(
+                                many(bufs, qs, rows, u[:, None] - 1, u))
+
+                    line["kernel_ms"] = kernel_ms(twice, kernel, 2 * REPS)
+            del bufs
         except Exception as e:  # a candidate the compiler refuses
             line["error"] = f"{type(e).__name__}: {str(e)[:300]}"
         print(json.dumps(line), flush=True)
