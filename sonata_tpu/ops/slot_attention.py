@@ -29,6 +29,16 @@ head's lanes.  Off a TPU, and where the rule says no, the reader is the
 einsum it replaces, over the same buffers (on a TPU that einsum re-lays
 the buffers again: the rule says no only where the kernel cannot run).
 
+**A ring** is the buffer of a layer that attends over a window: it has
+``window`` places a slot, not ``positions``, and position ``p`` lies at
+place ``p mod window`` (:func:`write_rows` and :func:`write_slot` with
+``ring``), so a place holds the latest position congruent to it.  Keys are
+cached after their rotary, and a softmax does not care in which order the
+places lie: the reader is the one a whole buffer has, told to read
+:func:`ring_upto` places.  A row that joins a slot another row wrapped
+reads no stale place: below ``window`` positions it reads the places it
+wrote itself, and by the time it reads them all it has written them all.
+
 **A latent row** (latent attention, MLA) is keys and values at once: a
 layer keeps one buffer, a place is ``[c_kv | k_rope]`` in whole lanes (576
 values in 640), every query head of a slot reads the same row, and the
@@ -114,18 +124,39 @@ def _rows_of(buf, new, lead: int):
     return jnp.pad(rows, ((0, 0),) * lead + ((0, pad),)) if pad else rows
 
 
-def write_rows(buf, new, pos):
+def write_rows(buf, new, pos, *, ring: bool = False):
     """``new`` ``[S, b, kv, d]`` at the places ``pos`` ``[S, b]`` of every
-    slot."""
+    slot; in a ``ring`` (the module's docstring) position ``pos`` lies at
+    place ``pos`` modulo the buffer's places."""
     rows = jnp.arange(pos.shape[0])[:, None]
+    if ring:
+        pos = pos % buf.shape[1]
     return buf.at[rows, pos].set(_rows_of(buf, new, 2))
 
 
-def write_slot(buf, seq, slot):
+def write_slot(buf, seq, slot, *, ring_n=None):
     """A row's prompt ``seq`` ``[T, kv, d]`` at the places ``0 .. T`` of
-    ``slot``."""
-    return lax.dynamic_update_slice(buf, _rows_of(buf, seq, 1)[None],
-                                    (slot, 0, 0))
+    ``slot``.  ``ring_n`` (a ring takes it: the prompt's real length, ``T``
+    its padded one): a prompt longer than the ring leaves, at every place,
+    the last of its ``ring_n`` positions that falls there."""
+    rows = _rows_of(buf, seq, 1)
+    window = buf.shape[1]
+    if ring_n is not None and rows.shape[0] > window:
+        place = jnp.arange(window)
+        # a place no real position falls on takes any row: it is not read
+        # before a step writes it (``ring_upto``)
+        rows = rows[jnp.clip(
+            place + (ring_n - 1 - place) // window * window, 0,
+            rows.shape[0] - 1)]
+    return lax.dynamic_update_slice(buf, rows[None], (slot, 0, 0))
+
+
+def ring_upto(upto, window: int):
+    """How far a reader reads in a ring of ``window`` places for a slot
+    whose row holds ``upto`` positions: all of them until the ring is full,
+    then every place (the ``window`` latest positions, in the order they
+    lie: a softmax does not care)."""
+    return jnp.minimum(upto, window)
 
 
 def read_slot(buf, kv: int, d: int):

@@ -754,6 +754,12 @@ class StepStats:
         #: places a layer's latent reader moved for the live rows (whole
         #: chunks up to a row's length; every position under the einsum)
         self.mla_places_fetched = 0
+        #: bytes of keys and values the loops' slots hold in a backbone
+        #: with window layers: in layers that keep every position, and in
+        #: rings of ``window`` places
+        self.attn_cache_resident_bytes = {"full": 0, "ring": 0}
+        #: live rows' steps whose position was at or past the window
+        self.window_bound_row_steps = 0
         #: step launches that took the expert layer's full-length path
         #: because a thin share's held experts got more than the short one
         #: takes
@@ -781,6 +787,8 @@ class StepStats:
             self.attention[group["attention"], "step"] += group["steps"]
             self.held_overflow_steps += group.get("held_overflow_steps", 0)
             self.mla_places_fetched += group.get("latent_places_fetched", 0)
+            self.window_bound_row_steps += group.get(
+                "window_bound_row_steps", 0)
             self.slot_steps["live"] += group["live_slot_steps"]
             self.slot_steps["empty"] += (group["steps"] * group["slots"]
                                          - group["live_slot_steps"])
@@ -838,13 +846,16 @@ class StepStats:
         if new and self._registry is not None:
             self._bind_layers(new)
 
-    def record_resident(self, state_bytes: int,
-                        latent_bytes: int = 0) -> None:
+    def record_resident(self, state_bytes: int, latent_bytes: int = 0,
+                        full_bytes: int = 0, ring_bytes: int = 0) -> None:
         """A loop's slots were made (or, negative, let go): their recurrent
-        state and their latent rows."""
+        state, their latent rows and, of a backbone with window layers,
+        their keys and values in full layers and in rings."""
         with self._lock:
             self.ssm_state_resident_bytes += state_bytes
             self.mla_cache_resident_bytes += latent_bytes
+            self.attn_cache_resident_bytes["full"] += full_bytes
+            self.attn_cache_resident_bytes["ring"] += ring_bytes
 
     def record_retired(self) -> None:
         with self._lock:
@@ -962,6 +973,25 @@ class StepStats:
             "the share of what was moved that a row held; 0 for a backbone "
             "without latent attention)."
         ).set_function(lambda: float(self.mla_places_fetched))
+        resident = registry.gauge(
+            "sonata_attn_cache_resident_bytes",
+            "Bytes of keys and values the slots of step-wise generation "
+            "loops hold on the device in a backbone that mixes full and "
+            "window attention, by kind of layer: full (every position of "
+            "every slot) or ring (window places a slot: position p lies at "
+            "place p mod window); 0 for a backbone without window layers.")
+        for kind in ("full", "ring"):
+            resident.labels(kind=kind).set_function(
+                lambda k=kind: float(self.attn_cache_resident_bytes[k]))
+        registry.counter(
+            "sonata_attn_window_bound_row_steps_total",
+            "Live rows' steps whose position was at or past the window of "
+            "the backbone's window layers: the ring had wrapped and the "
+            "band, not the row's length, bounded what those layers read "
+            "(over sonata_ar_slot_steps_total{state=\"live\"}: the share "
+            "of row-steps the window binds; 0 for a backbone without "
+            "window layers)."
+        ).set_function(lambda: float(self.window_bound_row_steps))
         registry.counter(
             "sonata_moe_held_overflow_steps_total",
             "Step launches whose expert layer took its full-length path "

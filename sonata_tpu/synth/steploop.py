@@ -58,7 +58,11 @@ attention read (``kv_positions`` rows a layer, as stored),
 ``latent_places_fetched`` the places a layer's latent reader moved for them
 (a row's ``kv_positions`` in whole chunks of the kernel's, every position
 where the einsum reads: ``kv_positions`` over it is the share of what was
-moved that a row held), ``host_ms`` by phase,
+moved that a row held), for an engine with window layers
+``kv_cache_bytes`` (keys and values the live rows' steps read as held: a
+row's ``kv_positions`` a full layer, capped at the window a ring layer) and
+``window_bound_row_steps`` (row-steps whose position is at or past the
+window: the ring has wrapped and the band binds), ``host_ms`` by phase,
 and of the loop's turns: ``wall_ms`` and, beside ``host_ms``'s three,
 ``device_wait_ms`` (blocked until the step before had run), ``record_ms``
 (the rest of settling it) and ``other_ms`` (the turn less its phases), which
@@ -69,7 +73,9 @@ loop's thread outside a prefill's or a vocoder's launch)
 beside the engine's ``block_length``, ``denoising_steps``, ``ssm_layers``,
 ``latent_layers`` (layers whose cache is one latent row a position) with
 ``mla_form`` (``absorbed``: what a step's latent attention runs; a prefill
-span says ``expanded``), and
+span says ``expanded``), ``full_layers``, ``window_layers`` and ``window``
+(layers that keep every position of a slot beside layers that keep a ring
+of ``window`` places; a prefill span says ``window_layers``), and
 ``expert_matmul`` (``grouped`` | ``ragged_dot``: what the step program's
 expert products run) and ``attention`` (``slot_kernel`` | ``einsum``: what
 reads the slots' keys and values).  Each
@@ -95,7 +101,9 @@ The engine (a voice: :class:`~sonata_tpu.models.unit_voice.UnitVoice`)
 gives ``slots``, ``expert_layers``, ``block_length``, ``denoising_steps``,
 ``expert_matmul``, ``attention``, ``ssm_layers``, ``ssm_state_bytes`` (a
 slot's), where it has latent attention ``latent_layers``, ``mla_form``,
-``latent_cache_bytes(positions)`` and ``latent_places(attended)``,
+``latent_cache_bytes(positions)`` and ``latent_places(attended)``, where it
+has window layers ``full_layers``, ``window_layers``, ``window``,
+``kv_cache_bytes(attended)`` and ``cache_resident_bytes()``,
 ``new_cache()``, ``plan(n_ids, budget)``, ``prefill(cache, slot, ids,
 temperature)``, ``step(cache, live, temperature, step_no)``, where its step
 carries arrivals ``carries(n_ids)`` and ``step_admit(cache, live,
@@ -135,6 +143,8 @@ DUMP_ROWS = 8
 #: what a launch adds to its group's sums, row by row
 ROW_SUMS = ("live_slot_steps", "units", "positions", "denoise_row_passes",
             "commit_row_passes", "kv_positions", "latent_places_fetched")
+#: and row by row too, where the engine has window layers
+WINDOW_SUMS = ("kv_cache_bytes", "window_bound_row_steps")
 
 DUMP_DIR_ENV = "SONATA_AR_DUMP_DIR"
 DUMP_PREFIX_ENV = "SONATA_AR_DUMP_RID_PREFIX"
@@ -215,10 +225,15 @@ class StepLoop:
         #: so many
         self._latent_places = getattr(engine, "latent_places",
                                       lambda attended: 0)
+        #: the ring's places where the engine has window layers, else 0
+        self._window = (getattr(engine, "window", 0)
+                        if getattr(engine, "window_layers", 0) else 0)
+        self._row_sums = ROW_SUMS + (WINDOW_SUMS if self._window else ())
         self._resident = (
             engine.slots * engine.ssm_state_bytes,
             self._latent_bytes(engine.slots * getattr(engine, "positions",
-                                                      0)))
+                                                      0)),
+            *(engine.cache_resident_bytes() if self._window else (0, 0)))
         self.stats.record_resident(*self._resident)
         self.layers = list(engine.expert_layers)
         dump_dir = os.environ.get(DUMP_DIR_ENV)
@@ -277,7 +292,7 @@ class StepLoop:
         with self._cond:
             self._closed = True
             self._cond.notify_all()
-            resident, self._resident = self._resident, (0, 0)
+            resident, self._resident = self._resident, (0, 0, 0, 0)
         self.stats.record_resident(*(-b for b in resident))
         self._thread.join(timeout=30.0)
         with self._finish_cond:
@@ -349,7 +364,7 @@ class StepLoop:
                 continue
             live = np.zeros((engine.slots,), bool)
             temperature = np.zeros((engine.slots,), np.float32)
-            sums = dict.fromkeys(ROW_SUMS, 0)
+            sums = dict.fromkeys(self._row_sums, 0)
             for row in rows:
                 live[row.slot] = True
                 temperature[row.slot] = row.temperature
@@ -359,6 +374,11 @@ class StepLoop:
                 attended = row.plan.attended(row.done)
                 sums["kv_positions"] += attended
                 sums["latent_places_fetched"] += self._latent_places(attended)
+                if self._window:
+                    sums["kv_cache_bytes"] += engine.kv_cache_bytes(attended)
+                    # its position is the last it attends over
+                    sums["window_bound_row_steps"] += \
+                        attended > self._window
                 sums["commit_row_passes"] += commits
                 sums["denoise_row_passes"] += not commits
                 sums["units"] += row.plan.units(row.done + 1) \
@@ -518,7 +538,8 @@ class StepLoop:
         g = self._group
         if g is None:
             g = self._group = {
-                "start": launched, "steps": 0, **dict.fromkeys(ROW_SUMS, 0),
+                "start": launched, "steps": 0,
+                **dict.fromkeys(self._row_sums, 0),
                 "assignments": [0] * len(self.layers),
                 "experts_touched": [0] * len(self.layers),
                 "max_expert_assignments": [0] * len(self.layers),
@@ -583,6 +604,10 @@ class StepLoop:
                  latent_cache_bytes=self._latent_bytes(g["kv_positions"]))
         if getattr(self.engine, "mla_form", None):
             g["mla_form"] = self.engine.mla_form
+        if self._window:
+            g.update(full_layers=self.engine.full_layers,
+                     window_layers=self.engine.window_layers,
+                     window=self._window)
         self.stats.record_steps(g)
         if self._trace is None:
             self._trace = tracing.default_tracer().start_trace(

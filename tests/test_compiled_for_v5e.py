@@ -10,7 +10,7 @@ library, and a second file could land on another worker.
   of a whole key, value or routes buffer (until PR 37 XLA re-laid every
   buffer twice a step), and both kernels are in it;
 - the step that carries an arrival (``lfm2_step_admit``,
-  ``nemotron_step_admit``, ``pangu_step_admit``) likewise: a step's scatter and a prompt's slice
+  ``nemotron_step_admit``, ``pangu_step_admit``, ``laguna_step_admit``) likewise: a step's scatter and a prompt's slice
   land on one donated buffer in one program, which is where a copy could
   come back, and its expert products over both kinds of row are the
   kernel's."""
@@ -46,8 +46,13 @@ CELLS = {
         "nemotrongen", 256),
     "pangu_step": ("perfbench/configs/pangu/openpangu-ultra-moe-718b.json",
                    "pangugen", 256),
+    "laguna_step": ("perfbench/configs/laguna/laguna-xs.2.json", "lagunagen",
+                    256),
 }
 POSITIONS = 1024
+#: the two readers of ``laguna_step``: slots, and the places of a whole
+#: cache and of a ring
+READER_SHAPES = {"laguna_full": (256, 1024), "laguna_ring": (256, 512)}
 
 
 @pytest.fixture(scope="module")
@@ -98,10 +103,10 @@ def test_the_slot_attention_compiles_for_a_v5e_at_the_step_shapes(
         one_chip, no_compile_cache, name):
     """The attention kernel with the rule's tiles at the real widths."""
     kv, g, d, b = GEOMETRIES[name]
-    slots = CELLS[name][2]
-    tiles = sa.tile_rule(POSITIONS, kv, g, d, b)
+    slots, places = READER_SHAPES.get(name) or (CELLS[name][2], POSITIONS)
+    tiles = sa.tile_rule(places, kv, g, d, b)
     spec = spec_on(one_chip)
-    buf = spec(sa.stored_shape(slots, POSITIONS, kv, d), BF16)
+    buf = spec(sa.stored_shape(slots, places, kv, d), BF16)
     compiled = jax.jit(functools.partial(
         sa.slot_attention_kernel, tiles=tiles)).lower(
         spec((slots, b, kv, g, d), F32), buf, buf,
@@ -188,9 +193,13 @@ def test_the_copies_of_the_layout_before_are_found():
     assert len(whole_buffer_copies(parent, 64 * 1024 * 32)) == 3
 
 
+#: what a program's code may weigh (``generated_code_size_in_bytes``)
+CODE_MB = {"step": 24, "step_admit": 36}
 #: what reads the slots' cache in each step program
 READERS = {name: "latent_attention" if name == "pangu_step"
            else "slot_attention" for name in CELLS}
+#: the cells whose chip holds a thin share of each layer's experts
+THIN = ("pangu_step", "laguna_step")
 
 
 @pytest.mark.parametrize("name", sorted(CELLS))
@@ -204,21 +213,29 @@ def test_a_step_compiled_for_a_v5e_copies_no_slot_buffer_whole(
     monkeypatch.setattr(gm, "_tiles_here", gm.tile_rule)
     monkeypatch.setattr(sa, "_tiles_here", sa.tile_rule)
     monkeypatch.setattr(sa, "_latent_tiles_here", sa.latent_tile_rule)
+    monkeypatch.setattr(unit_voice, "_layers_once_here",
+                        lambda: unit_voice.LAYERS_ONCE)
     backbone, cache, args = step_shapes(name, one_chip)
     per_place = [int(np.prod(a.shape)) for a in (
         cache["routes"], *cache.get("k", ()), *cache.get("v", ()),
         *cache.get("latent", ()))]
     assert min(per_place) == cache["routes"].size
-    hlo = backbone.build_step().lower(*args).compile().as_text()
+    compiled = backbone.build_step().lower(*args).compile()
+    # a program of the lattice is tens of megabytes of the machine's
+    # compile cache, 192 MiB for 15 of them: 10-18 MB each here, and
+    # ``laguna_step`` 70 where its eight layers' code is emitted eight times
+    assert compiled.memory_analysis().generated_code_size_in_bytes < CODE_MB[
+        "step"] * 1e6
+    hlo = compiled.as_text()
     assert READERS[name] in hlo and "grouped_matmul" in hlo
     assert whole_buffer_copies(hlo, min(per_place)) == []
     # a thin share's program holds both paths: the short one on the kernel
     # and, for a launch that overflows it, the full-length one
-    assert ("conditional" in hlo) == (name == "pangu_step")
+    assert ("conditional" in hlo) == (name in THIN)
 
 
 @pytest.mark.parametrize("name", ["lfm2_step", "nemotron_step",
-                                  "pangu_step"])
+                                  "pangu_step", "laguna_step"])
 def test_a_carrying_step_compiled_for_a_v5e_copies_no_slot_buffer_whole(
         one_chip, no_compile_cache, monkeypatch, name):
     """The step that carries an arrival, at the cells' sizes and their
@@ -231,20 +248,29 @@ def test_a_carrying_step_compiled_for_a_v5e_copies_no_slot_buffer_whole(
     monkeypatch.setattr(gm, "_tiles_here", gm.tile_rule)
     monkeypatch.setattr(sa, "_tiles_here", sa.tile_rule)
     monkeypatch.setattr(sa, "_latent_tiles_here", sa.latent_tile_rule)
+    monkeypatch.setattr(unit_voice, "_layers_once_here",
+                        lambda: unit_voice.LAYERS_ONCE)
     backbone, cache, args = step_shapes(name, one_chip)
     arrival = (jax.ShapeDtypeStruct((192,), jnp.int32),
                *(jax.ShapeDtypeStruct((), t) for t in (
                    jnp.int32, jnp.int32, F32, jnp.int32)))
     args += tuple(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
                   for a in arrival)
-    hlo = backbone.build_step_admit().lower(*args).compile().as_text()
+    compiled = backbone.build_step_admit().lower(*args).compile()
+    # 18-28 MB at the longest text bucket (``laguna_step_admit`` 100 with
+    # its layers' code emitted eight times)
+    assert compiled.memory_analysis().generated_code_size_in_bytes < CODE_MB[
+        "step_admit"] * 1e6
+    hlo = compiled.as_text()
     assert f"{name}_admit" in hlo
     assert READERS[name] in hlo and "grouped_matmul" in hlo
     # the operation's name, not the word: the module's stack frames name
     # whatever function first traced a cached helper, a test's among them
-    # (a thin share keeps its full-length path, XLA's product, for a launch
-    # that overflows the short one)
+    # (a share of 8 experts keeps its full-length path, XLA's product, for
+    # a launch that overflows the short one; with 32 held that path's 3584
+    # rows are the kernel's too)
     assert ("%ragged-dot" in hlo) == (name == "pangu_step")
+    assert ("conditional" in hlo) == (name in THIN)
     assert whole_buffer_copies(hlo, cache["routes"].size) == []
     for state in cache.get("ssm", ())[:1]:
         assert whole_buffer_copies(hlo, state.size, "f32") == []

@@ -1,5 +1,5 @@
 """A step that carries an arrival (``lfm2.step_admit``,
-``nemotron_h.step_admit``, ``pangu_moe.step_admit``) against the two programs it stands for, at a tiny
+``nemotron_h.step_admit``, ``pangu_moe.step_admit``, ``laguna.step_admit``) against the two programs it stands for, at a tiny
 size on the CPU, float32: from one cache with some slots live, the carrying
 form with a prompt for slot ``s`` gives the cache, the live rows' logits and
 the prompt's logits that ``step`` (with ``s`` not live) followed by
@@ -14,8 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from perfbench.harness import lfm2gen, nemotrongen, pangugen
-from sonata_tpu.models import lfm2, nemotron_h, pangu_moe, unit_voice
+from perfbench.harness import lagunagen, lfm2gen, nemotrongen, pangugen
+from sonata_tpu.models import laguna, lfm2, nemotron_h, pangu_moe, unit_voice
 
 ROOT = Path(__file__).resolve().parent.parent
 DATA = ROOT / "tests/perfbench/data"
@@ -36,6 +36,7 @@ def float32_products(monkeypatch):
     monkeypatch.setattr(lfm2, "BF16", jnp.float32)
     monkeypatch.setattr(nemotron_h, "BF16", jnp.float32)
     monkeypatch.setattr(pangu_moe, "BF16", jnp.float32)
+    monkeypatch.setattr(laguna, "BF16", jnp.float32)
     with jax.default_matmul_precision("highest"):
         yield
 
@@ -74,8 +75,22 @@ def pangu_backbone():
     return pangu_moe, cfg, params
 
 
+def laguna_backbone():
+    """A window of 8 places: the rows in use have wrapped their rings, and
+    the longer prompts are longer than the window."""
+    config = json.loads((DATA / "laguna-tiny.json").read_text())
+    cfg = laguna.LagunaConfig.from_dict(lagunagen.backbone(config))
+    params = {"embed": wide(lagunagen.draw(config, "embed")),
+              "head": wide(lagunagen.draw(config, "head")),
+              "norm_f": wide(lagunagen.draw(config, "norm_f")),
+              "layers": [laguna.pack_layer(wide(
+                  lagunagen.draw_layer(config, i)))
+                  for i in range(len(cfg.layer_types))]}
+    return laguna, cfg, params
+
+
 BACKBONES = {"lfm2_moe": lfm2_backbone, "nemotron_h": nemotron_backbone,
-             "pangu_ultra_moe": pangu_backbone}
+             "pangu_ultra_moe": pangu_backbone, "laguna": laguna_backbone}
 
 
 @pytest.fixture(scope="module", params=sorted(BACKBONES))
@@ -179,15 +194,16 @@ class Sized:
     ("nemotron/nemotron-3-nano-30b-a3b.json", 256),
     ("sdar/sdar-30b-a3b.json", 64),
     ("pangu/openpangu-ultra-moe-718b.json", 256),
-], ids=["lfm2_moe", "nemotron_h", "sdar_moe", "pangu_ultra_moe"])
+    ("laguna/laguna-xs.2.json", 256),
+], ids=["lfm2_moe", "nemotron_h", "sdar_moe", "pangu_ultra_moe", "laguna"])
 def test_which_rows_ride_a_step_is_the_backbones_and_the_shapes_to_say(
         config, slots, monkeypatch):
     from sonata_tpu.ops import grouped_matmul
     from sonata_tpu.utils.buckets import TEXT_BUCKETS
 
     data = json.loads((ROOT / "perfbench/configs" / config).read_text())
-    gen = {"lfm2": lfm2gen, "nemotron": nemotrongen,
-           "pangu": pangugen}.get(config.split("/")[0])
+    gen = {"lfm2": lfm2gen, "nemotron": nemotrongen, "pangu": pangugen,
+           "laguna": lagunagen}.get(config.split("/")[0])
     if gen is None:
         from perfbench.harness import sdargen as gen
     units = {"first_id": 256, "stop_id": 511, "mask_id": 300,
