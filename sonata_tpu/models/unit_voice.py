@@ -52,6 +52,7 @@ Every sentence of every request of a voice goes through the voice's one
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import json
@@ -176,6 +177,14 @@ def routes_of(cfg, rows):
                                     cfg.num_experts_per_tok)
 
 
+def places_fetched(reaches: tuple, attended: int) -> int:
+    """The places the readers of ``reaches`` (``Backbone.kv_reaches``) move
+    for a row that attends over ``attended``: whole chunks up to the row's
+    length, no further than the places a layer keeps, times the layers."""
+    return sum(n * slot_attention.places_moved(min(attended, places), chunk)
+               for places, chunk, n in reaches)
+
+
 class Backbone:
     """What every backbone says of itself beside its programs; a backbone
     that has the thing overrides the default."""
@@ -190,14 +199,33 @@ class Backbone:
     #: attention layers that keep every position of a slot beside layers
     #: that keep a ring of ``window`` places (0: every cache is whole)
     full_layers, window_layers, window = 0, 0, 0
+    #: layers whose cache is keys and values a head at every position
+    attention_layers = 0
+
+    def readers(self, positions: int) -> dict:
+        """The geometries ``slot_attention`` reads in the step program,
+        ``(places a slot, kv, g, d, b)``, and the layers of each (none for
+        a backbone whose cache is no keys and values a head)."""
+        if not self.attention_layers:
+            return {}
+        cfg = self.cfg
+        return {(positions, cfg.num_key_value_heads,
+                 cfg.num_attention_heads // cfg.num_key_value_heads,
+                 cfg.head_dim, self.block_length): self.attention_layers}
 
     def attention(self, positions: int) -> str:
-        """What reads the slots' cache in the step program."""
-        cfg = self.cfg
-        return slot_attention.implementation(
-            positions, cfg.num_key_value_heads,
-            cfg.num_attention_heads // cfg.num_key_value_heads, cfg.head_dim,
-            self.block_length)
+        """What reads the slots' cache in the step program: ``slot_kernel``
+        where the reader of every geometry is the kernel."""
+        found = {slot_attention.implementation(*shape)
+                 for shape in self.readers(positions)}
+        return "slot_kernel" if found == {"slot_kernel"} else "einsum"
+
+    def kv_reaches(self, positions: int) -> tuple:
+        """Of the layers that keep keys and values a head, by geometry:
+        ``(places a layer keeps a slot, what the step's reader rounds a
+        row's places up to there (``slot_attention.reach``), layers)``."""
+        return tuple((shape[0], slot_attention.reach(*shape), n)
+                     for shape, n in self.readers(positions).items())
 
     def latent_cache_bytes(self, positions: int) -> int:
         """Bytes of ``positions`` cached latent rows, over all layers."""
@@ -307,6 +335,7 @@ class Lfm2Backbone(Backbone):
         self.units = lfm2.UnitIds(int(units["first_id"]),
                                   int(units["stop_id"]))
         self.layers = len(self.cfg.layer_types)
+        self.attention_layers = len(self.cfg.layers_of("full_attention"))
         self.seed = seed
 
     def new_cache(self, slots: int, positions: int) -> dict:
@@ -364,7 +393,7 @@ class SdarBackbone(Backbone):
             int(units.get("denoising_steps", 4)), self.units.mask_id)
         self.block_length = self.schedule.block_length
         self.denoising_steps = self.schedule.denoising_steps
-        self.layers = self.cfg.num_hidden_layers
+        self.layers = self.attention_layers = self.cfg.num_hidden_layers
         self.seed = seed
 
     def new_cache(self, slots: int, positions: int) -> dict:
@@ -454,6 +483,7 @@ class NemotronBackbone(Lfm2Backbone):
         self.units = lfm2.UnitIds(int(units["first_id"]),
                                   int(units["stop_id"]))
         self.layers = len(self.cfg.pattern)
+        self.attention_layers = len(self.cfg.layers_of("*"))
         self.seed = seed
         self.held = self.cfg.held
         self.ssm_layers = len(self.cfg.layers_of("M"))
@@ -548,16 +578,15 @@ class LagunaBackbone(Lfm2Backbone):
     def new_cache(self, slots: int, positions: int) -> dict:
         return laguna.new_cache(self.cfg, slots, positions)
 
-    def attention(self, positions: int) -> str:
-        """``slot_kernel`` where the reader of every layer's cache is the
-        kernel: both geometries (a layer's places, its query heads)."""
+    def readers(self, positions: int) -> dict:
+        """Both geometries: a kind's places (a ring's: the window) and its
+        query heads."""
         cfg = self.cfg
         kv = cfg.num_key_value_heads
-        readers = {slot_attention.implementation(
-            cfg.places(kind, positions), kv, heads // kv, cfg.head_dim,
-            self.block_length)
-            for kind, heads in zip(cfg.layer_types, cfg.heads_per_layer)}
-        return "slot_kernel" if readers == {"slot_kernel"} else "einsum"
+        return dict(collections.Counter(
+            (cfg.places(kind, positions), kv, heads // kv, cfg.head_dim,
+             self.block_length)
+            for kind, heads in zip(cfg.layer_types, cfg.heads_per_layer)))
 
     def kv_cache_bytes(self, attended: int) -> int:
         """A layer's places read as held: ``attended`` in a full layer,
@@ -625,6 +654,12 @@ class UnitVoice(BaseModel):
         self.attention = self.backbone.attention(self.positions)
         self.latent_layers = self.backbone.latent_layers
         self._latent_chunk = self.backbone.latent_chunk(self.positions)
+        #: the places the step's reader of keys and values moves for a row
+        #: of each length a slot can hold (the step loop asks a row a
+        #: launch: a look-up, not the sum over the layers' geometries)
+        self._kv_places = [
+            places_fetched(self.backbone.kv_reaches(self.positions), n)
+            for n in range(self.positions + 1)]
         #: which form of latent attention a step runs (None: it has none)
         self.mla_form = self.backbone.mla_form
         #: layers that keep every position beside layers that keep a ring
@@ -1012,6 +1047,14 @@ class UnitVoice(BaseModel):
         where the einsum reads (0: it has no latent attention)."""
         return (slot_attention.latent_places(attended, self._latent_chunk)
                 if self._latent_chunk else 0)
+
+    def kv_places_fetched(self, attended: int) -> int:
+        """The places the step's reader of keys and values moves for a row
+        that attends over ``attended``, summed over the layers that keep
+        them: whole chunks of the kernel's (a ring read no further than its
+        window), every place of a layer's buffer where the einsum reads (0:
+        no layer keeps keys and values a head)."""
+        return self._kv_places[min(attended, self.positions)]
 
     def kv_cache_bytes(self, attended: int) -> int:
         """Bytes of keys and values a step reads, as held, for a row that

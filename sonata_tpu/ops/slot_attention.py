@@ -11,22 +11,32 @@ heads of ``d``.  A step writes one place a slot (a pass over a block,
 **The layout** is ``[slots, positions, kv * d]``: a place is one row of
 whole lanes.  A step's write is then a scatter of ``slots * b`` rows into a
 buffer that stays where it is, a prefill's is one ``dynamic_update_slice``,
-and the reader takes ``(positions tile, kv * d)`` blocks and cuts the heads
-out of them in VMEM.  Stored ``[slots, positions, kv, d]`` and read by an
-einsum, XLA re-laid every buffer twice a step (it wants the positions on
-the lanes for its products and the heads there for its scatter: PERF.md
-§5), which cost more than the attention itself.
+and the reader copies ``(places, kv * d)`` runs of a slot's rows and cuts
+the heads out of them in VMEM.  Stored ``[slots, positions, kv, d]`` and
+read by an einsum, XLA re-laid every buffer twice a step (it wants the
+positions on the lanes for its products and the heads there for its
+scatter: PERF.md §5), which cost more than the attention itself.
 
 **The reader** on a TPU is a kernel of this module where :func:`tile_rule`
-has tiles for the shape: a grid of (slot, positions tile), online softmax
-over the tiles, ``upto`` prefetched as scalars, so that a tile past a
-slot's length is neither fetched (its block index repeats the last one the
-slot needs) nor multiplied.  Heads narrower than the 128 lanes share a
-lane group: the queries of head ``h`` lie in their own rows with zeros in
-the other heads' lanes, so one product over the group's lanes gives every
-head's scores, and of the product with the values each row keeps its own
-head's lanes.  Off a TPU, and where the rule says no, the reader is the
-einsum it replaces, over the same buffers (on a TPU that einsum re-lays
+has a chunk for the shape: one grid step a slot, ``upto`` prefetched as
+scalars, the buffers left in HBM.  Inside a grid step the kernel walks the
+slot's places as far as ``upto`` says (:func:`places_moved`: whole chunks of
+128 places), up to four chunks a trip: a copy of keys and one of values a
+chunk, one pass of the online softmax a trip for each lane group of heads,
+the copies running ahead of the products from one slot into the next.  No
+place past a row's last chunk is fetched or multiplied, and no grid step is
+paid for one (PERF.md §5 has it beside the grid over tiles it took the place
+of).  Heads narrower than the 128 lanes share a lane group:
+the queries of head ``h`` lie in their own rows with zeros in the other
+heads' lanes, so one product over the group's lanes gives every head's
+scores, and of the product with the values each row keeps its own head's
+lanes.  The kernel is a jitted function of its own and its jaxpr is kept
+small (the lane groups in one batched product, a trip's copies in a loop,
+a body of static size only for a pass's places): tracing and lowering are cached by
+nothing, so a start pays them again for every program, and a kernel laid
+out flat once a layer made a warm start of ``lfm2-24b-a2b`` 8 s longer
+(PERF.md §6, PR 45).  Off a TPU, and where the rule says no, the reader is
+the einsum it replaces, over the same buffers (on a TPU that einsum re-lays
 the buffers again: the rule says no only where the kernel cannot run).
 
 **A ring** is the buffer of a layer that attends over a window: it has
@@ -48,12 +58,11 @@ other (``kv = 1``); :func:`latent_attention` reads it.  With a hundred and
 more query heads on one row the products are no plain stream any more (at
 128 heads of 576 a place does 242 operations a byte, the chip's ridge), and
 a grid step costs half a microsecond fetched or skipped, so this reader
-has one grid step a slot and walks the slot's places inside it: the
-buffer stays in HBM, the kernel copies a slot's places in chunks up to its
-length (:func:`latent_places`), a few chunks a copy, the copies running
-ahead of the products from one slot into the next, and no place past a
-row's last chunk is fetched or multiplied (:func:`latent_tile_rule`;
-PERF.md §5).
+walks a slot's places as the per-head reader does, and did so first (PR
+42): one grid step a slot, the buffer left in HBM, a slot's places copied
+in chunks up to its length (:func:`latent_places`), a few chunks a copy,
+and no place past a row's last chunk fetched or multiplied
+(:func:`latent_tile_rule`; PERF.md §5).
 
 **Precision**: queries and probabilities enter the products in the type
 the buffers hold (bfloat16 in every program of a voice); scores, the
@@ -79,13 +88,20 @@ LANES = 128
 #: what stands for "not seen" in the kernel's scores: finite, so that a
 #: running maximum of nothing seen yet gives exp(0) and not a NaN
 MASKED = -1e30
-#: the elements of a positions tile of keys (and of one of values): 512
-#: places of 512 lanes, 1024 of 256 (half a megabyte of bfloat16).  A tile's
-#: cost is its lanes' passage through the MXU, a row of 128 a cycle; a grid
-#: step costs half a microsecond whatever it holds, so a smaller tile makes
-#: more steps than it spares places past a slot's length (PERF.md §5 has
+#: the places of a chunk of keys (and of one of values), what a slot's length
+#: is rounded up to: a lane group of scores.  Of the places the reader moves
+#: so, the cells' rows hold 0.85 at the mean, 0.74-0.77 at 256, which reads
+#: a tenth slower at 1024 lanes a place and no faster at 512 (PERF.md §5 has
 #: the table)
-TILE_ELEMENTS = 512 * 512
+CHUNK = 128
+#: the most chunks one pass of the online softmax takes: a pass costs about
+#: half a microsecond a lane group whatever it holds, so 4 chunks a trip
+#: beat 2 by a fifth to a quarter
+TRIP_CHUNKS = 4
+#: the trips the reader holds in VMEM, of keys and of values: one under the
+#: products and two copies ahead of it (with one ahead the copies stall at
+#: a slot's end: a tenth to a sixth slower)
+BUFFERS = 3
 #: the most query rows a lane group may hold: above it the products are no
 #: stream of keys and values any more and XLA's own stay
 MAX_ROWS = 256
@@ -105,7 +121,7 @@ LATENT_BUFFERS = 3
 
 
 class Tiles(NamedTuple):
-    tp: int     #: positions of a tile
+    tp: int     #: places of a chunk
 
 
 def stored_shape(slots: int, positions: int, kv: int, d: int) -> tuple:
@@ -165,26 +181,34 @@ def read_slot(buf, kv: int, d: int):
     return buf[:, :kv * d].reshape(buf.shape[0], kv, d)
 
 
+def places_moved(upto, chunk: int):
+    """The places a walking reader moves for a slot that holds ``upto``:
+    whole chunks of ``chunk`` up to the one its last place lies in (an int,
+    or an array a slot).  The kernels' copies and trip counts are made of
+    this, and a step group's ``kv_places_fetched`` and
+    ``latent_places_fetched`` sum it."""
+    return (upto + chunk - 1) // chunk * chunk
+
+
 @functools.lru_cache(maxsize=None)
 def tile_rule(positions: int, kv: int, g: int, d: int,
               b: int) -> Optional[Tiles]:
-    """The kernel's tiles for ``b`` queries a slot of ``kv`` heads of ``d``
-    (``g`` query heads each) over ``positions`` places, or None where the
-    einsum stays: a pure function of the shape.
+    """The kernel's chunk (``Tiles.tp``: the places a slot's length is
+    rounded up to) for ``b`` queries a slot of ``kv`` heads of ``d`` (``g``
+    query heads each) over ``positions`` places, or None where the einsum
+    stays: a pure function of the shape.
 
     The kernel wants whole lanes: a head that fills lane groups (``d`` a
     multiple of 128) or heads that share one (``d`` divides 128 and the
-    group's heads divide ``kv``).  The positions tile holds
-    ``TILE_ELEMENTS`` keys' elements (the power of two below), at most all
-    the positions, which it has to divide, and at least the 16 sublanes of
-    a bfloat16 tile."""
+    group's heads divide ``kv``).  The chunk is ``CHUNK`` places, at most
+    all the positions, which it has to divide, in whole lane groups (a
+    chunk's scores are whole lanes)."""
     if d % LANES and (LANES % d or kv % (LANES // d)):
         return None
     if _sharing(d) * b * g > MAX_ROWS:
         return None
-    tp = 1 << (max(TILE_ELEMENTS // (kv * d), 1).bit_length() - 1)
-    tp = min(tp, positions)
-    return Tiles(tp) if tp >= 16 and positions % tp == 0 else None
+    tp = min(CHUNK, positions)
+    return Tiles(tp) if tp % LANES == 0 and positions % tp == 0 else None
 
 
 def _sharing(d: int) -> int:
@@ -192,58 +216,157 @@ def _sharing(d: int) -> int:
     return max(1, LANES // d)
 
 
-def _kernel(upto, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-            tp: int, scale: float):
-    s, t = pl.program_id(0), pl.program_id(1)
+def _kernel(upto, q_ref, k_rows, v_rows, o_ref, k_trip, v_trip, sem, cursor,
+            m_ref, l_ref, acc_ref, *, tp: int, scale: float):
+    """One slot a grid step, and in it a loop over the slot's places as far
+    as ``upto`` says: a trip takes up to ``TRIP_CHUNKS`` chunks of ``tp``
+    places, a copy of keys and one of values a chunk, and one pass of the
+    online softmax for each lane group of heads.  ``k_rows`` and ``v_rows``
+    are the whole buffers where they lie (HBM); ``k_trip`` and ``v_trip``
+    ``[BUFFERS, span, width]`` (``span``: the places of a whole trip) take
+    the trips in turn.  The copies run ``BUFFERS - 1`` trips ahead of the
+    products, in the order the products take them and from one slot into
+    the next: ``cursor`` (SMEM, kept from grid step to grid step) holds the
+    next trip to copy (its slot, its number there) and how many trips have
+    been multiplied.  ``m_ref`` and ``l_ref`` hold a row's number in every
+    lane (a column would be spread again for every use).
+
+    What a start pays for is this function's equations, traced once a
+    process and lowered once a program (PERF.md section 6, PR 45): the one
+    thing of static size is a pass's places (a body for each count of
+    chunks); the chunks' copies and waits are loops, the lane groups one
+    batched product, and the scalars are ``lax`` operations on numbers
+    that are never negative, not ``jnp``'s with their sign rules."""
+    s, slots = pl.program_id(0), pl.num_programs(0)
     groups, rows, lw = q_ref.shape
+    buffers, span, _ = k_trip.shape
+    ahead, most = buffers - 1, span // tp
     n = upto[s]
 
-    @pl.when(t == 0)
-    def _start():
-        m_ref[...] = jnp.full(m_ref.shape, MASKED, F32)
-        l_ref[...] = jnp.zeros(l_ref.shape, F32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, F32)
+    def chunks(holds, t):
+        """The chunks trip ``t`` of a slot that holds ``holds`` takes."""
+        return jnp.minimum(lax.div(holds - t * span + (tp - 1), tp), most)
 
-    @pl.when(t * tp < n)
-    def _tile():
-        seen = t * tp + lax.broadcasted_iota(jnp.int32, (rows, tp), 1) < n
-        for j in range(groups):
-            lanes = slice(j * lw, (j + 1) * lw)
+    def copies(slot, t, k, c):
+        """Chunk ``c`` of trip ``t`` of ``slot`` into buffer ``k``: its
+        keys, its values."""
+        at = pl.ds(pl.multiple_of(t * span + c * tp, tp), tp)
+        to = pl.ds(pl.multiple_of(c * tp, tp), tp)
+        return [pltpu.make_async_copy(rows_ref.at[slot, at],
+                                      trip.at[k, to], sem.at[i, k])
+                for i, (rows_ref, trip) in enumerate(((k_rows, k_trip),
+                                                      (v_rows, v_trip)))]
+
+    def held(slot):
+        """The first slot from ``slot`` on that holds a place."""
+        return lax.while_loop(
+            lambda j: jnp.logical_and(
+                j < slots, upto[jnp.minimum(j, slots - 1)] == 0),
+            lambda j: j + 1, slot)
+
+    def copy_next(k):
+        """Start the copies of the trip the cursor stands at, into buffer
+        ``k``, and move the cursor on."""
+        slot, t = cursor[0], cursor[1]
+
+        @pl.when(slot < slots)
+        def _start():
+            holds = upto[slot]
+
+            def start(c, carry):
+                for copy in copies(slot, t, k, c):
+                    copy.start()
+                return carry
+
+            lax.fori_loop(0, chunks(holds, t), start, 0)
+            last = (t + 1) * span >= holds
+            cursor[0] = lax.select(last, held(slot + 1), slot)
+            cursor[1] = lax.select(last, 0, t + 1)
+
+    @pl.when(s == 0)
+    def _first():
+        cursor[0], cursor[1], cursor[2] = held(0), 0, 0
+
+        def first(k, carry):
+            copy_next(k)
+            return carry
+
+        lax.fori_loop(0, ahead, first, 0)
+
+    m_ref[...] = jnp.full(m_ref.shape, MASKED, F32)
+    l_ref[...] = jnp.zeros(l_ref.shape, F32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, F32)
+
+    def trip(t, done):
+        k = lax.rem(done, buffers)
+        copy_next(lax.rem(done + ahead, buffers))
+        count = chunks(n, t)
+
+        def arrived(c, carry):
+            for copy in copies(s, t, k, c):
+                copy.wait()
+            return carry
+
+        lax.fori_loop(0, count, arrived, 0)
+
+        def attend(places):
+            """One pass of the online softmax over the trip's ``places``,
+            every lane group in one batched product: the equations of one
+            group whatever the groups, and all the groups' chains side by
+            side for the scheduler as if they were written out."""
+            keys, values = (jnp.stack([
+                trip[k, :places, j * lw:(j + 1) * lw] for j in range(groups)])
+                for trip in (k_trip, v_trip))
+            seen = t * span + lax.broadcasted_iota(
+                jnp.int32, (1, rows, places), 2) < n
             scores = lax.dot_general(
-                q_ref[j], k_ref[:, lanes], (((1,), (1,)), ((), ())),
+                q_ref[...], keys, (((2,), (2,)), ((0,), (0,))),
                 preferred_element_type=F32) * scale
             scores = jnp.where(seen, scores, MASKED)
-            m_prev = m_ref[j]
+            m_prev = m_ref[...]
             m_next = jnp.maximum(m_prev,
-                                 jnp.max(scores, axis=1, keepdims=True))
+                                 jnp.max(scores, axis=2, keepdims=True))
             alpha = jnp.exp(m_prev - m_next)
-            p = jnp.exp(scores - m_next)
-            l_ref[j] = alpha * l_ref[j] + jnp.sum(p, axis=1, keepdims=True)
-            m_ref[j] = m_next
-            acc_ref[j] = alpha * acc_ref[j] + jnp.dot(
-                p.astype(v_ref.dtype), v_ref[:, lanes],
-                preferred_element_type=F32)
+            p = jnp.exp(scores - jnp.tile(m_next, (1, 1, places // LANES)))
+            l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=2,
+                                                      keepdims=True)
+            m_ref[...] = m_next
+            acc_ref[...] = jnp.tile(alpha, (1, 1, lw // LANES)) \
+                * acc_ref[...] + lax.dot_general(
+                    p.astype(values.dtype), values,
+                    (((2,), (1,)), ((0,), (0,))),
+                    preferred_element_type=F32)
 
-    @pl.when(t == pl.num_programs(1) - 1)
-    def _finish():
-        # a slot that sees nothing gives zeros
-        total = l_ref[...]
-        o_ref[...] = acc_ref[...] / jnp.where(total > 0.0, total, 1.0)
+        # a body of static size for each count of chunks a trip can hold
+        for j in range(1, most + 1):
+            pl.when(count == j)(functools.partial(attend, j * tp))
+        return done + 1
+
+    cursor[2] = lax.fori_loop(0, lax.div(n + (span - 1), span), trip,
+                              cursor[2])
+    # a slot that sees nothing gives zeros
+    total = l_ref[...]
+    o_ref[...] = acc_ref[...] / jnp.tile(
+        jnp.where(total > 0.0, total, 1.0), (1, 1, lw // LANES))
 
 
+@functools.partial(jax.jit, static_argnames=("tiles", "interpret"))
 def slot_attention_kernel(q, k_buf, v_buf, upto, tiles: Tiles, *,
                           interpret: bool = False):
     """The kernel itself, whatever the backend (``interpret`` for the
     CPU): ``q`` ``[S, b, kv, g, d]``, the buffers ``[S, P, kv * d]``,
-    ``upto`` ``[S]``.  Returns ``[S, b, kv, g, d]`` float32."""
+    ``upto`` ``[S]``.  Returns ``[S, b, kv, g, d]`` float32.  A jitted
+    function of its own: a program's layers of one geometry share one trace
+    and one lowering of the kernel and its bodies."""
     s, b, kv, g, d = q.shape
     span, width = k_buf.shape[1:]
     tp, heads = tiles.tp, _sharing(d)
     lw = heads * d
     groups = kv // heads
-    if width != kv * d or kv % heads or lw % LANES or span % tp:
+    if width != kv * d or kv % heads or lw % LANES or tp % LANES \
+            or span % tp or v_buf.shape != k_buf.shape:
         raise ValueError(f"q {q.shape} and tiles {tiles} do not fit buffers "
-                         f"{k_buf.shape}")
+                         f"{k_buf.shape}, {v_buf.shape}")
     # a lane group's rows: (head, query, query head), each head's queries
     # in that head's lanes and zeros in the others'
     rows = heads * b * g
@@ -255,29 +378,34 @@ def slot_attention_kernel(q, k_buf, v_buf, upto, tiles: Tiles, *,
     if pad:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, pad), (0, 0)))
     padded = rows + pad
+    # the places of a trip: whole chunks that divide the positions
+    trip_places = tp * max(j for j in range(1, TRIP_CHUNKS + 1)
+                           if span % (j * tp) == 0)
 
-    def q_map(i, t, upto):
+    def q_map(i, upto):
         return (i, 0, 0, 0)
-
-    def kv_map(i, t, upto):
-        # past the slot's last tile the index stays: nothing is fetched
-        return (i, jnp.minimum(t, (jnp.maximum(upto[i], 1) - 1) // tp), 0)
 
     out = pl.pallas_call(
         functools.partial(_kernel, tp=tp, scale=float(d) ** -0.5),
         out_shape=jax.ShapeDtypeStruct((s, groups, padded, lw), F32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(s, span // tp),
+            grid=(s,),
             in_specs=[pl.BlockSpec((None, groups, padded, lw), q_map),
-                      pl.BlockSpec((None, tp, width), kv_map),
-                      pl.BlockSpec((None, tp, width), kv_map)],
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((None, groups, padded, lw), q_map),
-            scratch_shapes=[pltpu.VMEM((groups, padded, 1), F32),
-                            pltpu.VMEM((groups, padded, 1), F32),
-                            pltpu.VMEM((groups, padded, lw), F32)]),
+            scratch_shapes=[
+                pltpu.VMEM((BUFFERS, trip_places, width), k_buf.dtype),
+                pltpu.VMEM((BUFFERS, trip_places, width), v_buf.dtype),
+                pltpu.SemaphoreType.DMA((2, BUFFERS)),
+                pltpu.SMEM((3,), jnp.int32),
+                pltpu.VMEM((groups, padded, LANES), F32),
+                pltpu.VMEM((groups, padded, LANES), F32),
+                pltpu.VMEM((groups, padded, lw), F32)]),
+        # the copies run ahead from one slot into the next: in order
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         cost_estimate=pl.CostEstimate(
             flops=4 * s * groups * padded * span * lw,
             transcendentals=s * groups * padded * span,
@@ -313,12 +441,8 @@ def slot_attention_einsum(q, k_buf, v_buf, upto):
 # a latent row: keys and values at once
 # ---------------------------------------------------------------------------
 
-def latent_places(upto, chunk: int):
-    """The places the latent reader moves for a slot that holds ``upto``:
-    whole chunks of ``chunk`` up to the one its last place lies in (an int,
-    or an array a slot).  The kernel's copies and trip counts are made of
-    this, and a step group's ``latent_places_fetched`` sums it."""
-    return (upto + chunk - 1) // chunk * chunk
+#: the latent reader's name for the places it moves
+latent_places = places_moved
 
 
 @functools.lru_cache(maxsize=None)
@@ -572,6 +696,14 @@ def implementation(positions: int, kv: int, g: int, d: int, b: int) -> str:
     this shape on this backend, else ``"einsum"``: what the spans report."""
     return ("einsum" if _tiles_here(positions, kv, g, d, b) is None
             else "slot_kernel")
+
+
+def reach(positions: int, kv: int, g: int, d: int, b: int) -> int:
+    """What :func:`slot_attention` rounds a slot's length up to on this
+    backend (:func:`places_moved`' chunk): the kernel's chunk, or every
+    place of the buffer where the einsum reads."""
+    tiles = _tiles_here(positions, kv, g, d, b)
+    return positions if tiles is None else tiles.tp
 
 
 def slot_attention(q, k_buf, v_buf, upto):

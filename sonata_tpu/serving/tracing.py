@@ -754,6 +754,10 @@ class StepStats:
         #: places a layer's latent reader moved for the live rows (whole
         #: chunks up to a row's length; every position under the einsum)
         self.mla_places_fetched = 0
+        #: places the reader of keys and values a head moved for the live
+        #: rows, over the layers that keep such (whole chunks up to a row's
+        #: length, a ring's window at most; every place under the einsum)
+        self.kv_places_fetched = 0
         #: bytes of keys and values the loops' slots hold in a backbone
         #: with window layers: in layers that keep every position, and in
         #: rings of ``window`` places
@@ -787,6 +791,7 @@ class StepStats:
             self.attention[group["attention"], "step"] += group["steps"]
             self.held_overflow_steps += group.get("held_overflow_steps", 0)
             self.mla_places_fetched += group.get("latent_places_fetched", 0)
+            self.kv_places_fetched += group.get("kv_places_fetched", 0)
             self.window_bound_row_steps += group.get(
                 "window_bound_row_steps", 0)
             self.slot_steps["live"] += group["live_slot_steps"]
@@ -973,6 +978,18 @@ class StepStats:
             "the share of what was moved that a row held; 0 for a backbone "
             "without latent attention)."
         ).set_function(lambda: float(self.mla_places_fetched))
+        registry.counter(
+            "sonata_kv_places_fetched_total",
+            "Places the reader of the slots' keys and values moved for the "
+            "live rows of step launches, summed over the layers that keep "
+            "keys and values a head: a row's places in whole chunks of the "
+            "kernel's up to its length, a ring read no further than its "
+            "window, every place of a layer's buffer where the einsum "
+            "reads (a step group's kv_positions a layer, or its "
+            "kv_cache_bytes over a place's bytes, over its "
+            "kv_places_fetched is the share of what was moved that a row "
+            "held; 0 for a backbone whose cache is latent rows)."
+        ).set_function(lambda: float(self.kv_places_fetched))
         resident = registry.gauge(
             "sonata_attn_cache_resident_bytes",
             "Bytes of keys and values the slots of step-wise generation "

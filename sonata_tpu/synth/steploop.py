@@ -58,7 +58,12 @@ attention read (``kv_positions`` rows a layer, as stored),
 ``latent_places_fetched`` the places a layer's latent reader moved for them
 (a row's ``kv_positions`` in whole chunks of the kernel's, every position
 where the einsum reads: ``kv_positions`` over it is the share of what was
-moved that a row held), for an engine with window layers
+moved that a row held), ``kv_places_fetched`` the places the reader of
+keys and values a head moved for them, summed over the engine's layers that
+keep such (whole chunks of the kernel's a row, a ring read no further than
+its window; every place of a layer's buffer where the einsum reads: a row's
+``kv_positions`` in those layers over it is the share of what was moved
+that a row held), for an engine with window layers
 ``kv_cache_bytes`` (keys and values the live rows' steps read as held: a
 row's ``kv_positions`` a full layer, capped at the window a ring layer) and
 ``window_bound_row_steps`` (row-steps whose position is at or past the
@@ -102,6 +107,7 @@ gives ``slots``, ``expert_layers``, ``block_length``, ``denoising_steps``,
 ``expert_matmul``, ``attention``, ``ssm_layers``, ``ssm_state_bytes`` (a
 slot's), where it has latent attention ``latent_layers``, ``mla_form``,
 ``latent_cache_bytes(positions)`` and ``latent_places(attended)``, where it
+keeps keys and values a head ``kv_places_fetched(attended)``, where it
 has window layers ``full_layers``, ``window_layers``, ``window``,
 ``kv_cache_bytes(attended)`` and ``cache_resident_bytes()``,
 ``new_cache()``, ``plan(n_ids, budget)``, ``prefill(cache, slot, ids,
@@ -142,7 +148,8 @@ DUMP_ROWS = 8
 
 #: what a launch adds to its group's sums, row by row
 ROW_SUMS = ("live_slot_steps", "units", "positions", "denoise_row_passes",
-            "commit_row_passes", "kv_positions", "latent_places_fetched")
+            "commit_row_passes", "kv_positions", "latent_places_fetched",
+            "kv_places_fetched")
 #: and row by row too, where the engine has window layers
 WINDOW_SUMS = ("kv_cache_bytes", "window_bound_row_steps")
 
@@ -225,6 +232,10 @@ class StepLoop:
         #: so many
         self._latent_places = getattr(engine, "latent_places",
                                       lambda attended: 0)
+        #: the places its reader of keys and values a head moves for such
+        #: a row, over its layers
+        self._kv_places = getattr(engine, "kv_places_fetched",
+                                  lambda attended: 0)
         #: the ring's places where the engine has window layers, else 0
         self._window = (getattr(engine, "window", 0)
                         if getattr(engine, "window_layers", 0) else 0)
@@ -374,6 +385,7 @@ class StepLoop:
                 attended = row.plan.attended(row.done)
                 sums["kv_positions"] += attended
                 sums["latent_places_fetched"] += self._latent_places(attended)
+                sums["kv_places_fetched"] += self._kv_places(attended)
                 if self._window:
                     sums["kv_cache_bytes"] += engine.kv_cache_bytes(attended)
                     # its position is the last it attends over

@@ -5,7 +5,10 @@ CPU a step reads the slots' keys and values by the einsum; a voice whose
 step runs this repo's kernel is made here by steering the one name both
 the decision and the reader ask (``slot_attention._tiles_here``, the kernel
 then in interpret mode), not by an option of the program.  A prefill
-attends over its own prompt and says ``einsum`` whatever the step runs."""
+attends over its own prompt and says ``einsum`` whatever the step runs.
+Since PR 45 a step group also states ``kv_places_fetched``, the places that
+reader moved for the live rows over the layers that keep keys and values,
+and ``sonata_kv_places_fetched_total`` sums it."""
 
 import functools
 import importlib
@@ -15,7 +18,7 @@ from pathlib import Path
 import pytest
 
 from perfbench.harness import lfm2gen, sdargen
-from sonata_tpu.models import from_config_path
+from sonata_tpu.models import from_config_path, unit_voice
 from sonata_tpu.models.config import SynthesisConfig
 from sonata_tpu.serving import tracing
 from sonata_tpu.serving.metrics import MetricsRegistry
@@ -47,11 +50,17 @@ def test_spans_and_series_say_what_the_attention_ran(
     if impl == "slot_kernel":
         # two heads of 64 fill one lane group: a shape the kernel takes
         config["head_dim"] = 64
-        monkeypatch.setattr(sa, "_tiles_here", lambda *shape: sa.Tiles(64))
+        monkeypatch.setattr(sa, "_tiles_here", lambda *shape: sa.Tiles(128))
         monkeypatch.setattr(sa, "slot_attention_kernel", functools.partial(
             sa.slot_attention_kernel, interpret=True))
     monkeypatch.setenv("SONATA_AR_SLOTS", "2")
     monkeypatch.setenv("SONATA_AR_POSITIONS", "256")
+    # the lengths the loop asks the voice's counting function about
+    asked, counting = [], unit_voice.UnitVoice.kv_places_fetched
+    monkeypatch.setattr(
+        unit_voice.UnitVoice, "kv_places_fetched",
+        lambda self, attended: asked.append(attended) or counting(
+            self, attended))
     voice = from_config_path(writer.write_tensors(tmp_path, config))
     voice.set_fallback_synthesis_config(SynthesisConfig(noise_scale=0.0))
     registry = MetricsRegistry()
@@ -60,6 +69,7 @@ def test_spans_and_series_say_what_the_attention_ran(
     tracer = tracing.default_tracer()
     tracer.clear()
     before, steps_before = series(registry), stats.steps
+    fetched_before = stats.kv_places_fetched
     assert set(before) == {(i, p) for i in tracing.ATTENTION_IMPLS
                            for p in ("prefill", "step")}
     # a backbone whose step carries arrivals launches no prefill program
@@ -88,3 +98,17 @@ def test_spans_and_series_say_what_the_attention_ran(
     want[impl, "step"] = want.get((impl, "step"), 0.0) + float(
         stats.steps - steps_before)
     assert moved == want
+    # the places the reader moved: every attention layer's, whole chunks of
+    # 128 a row under the kernel, all 256 of a slot under the einsum
+    layers = voice.backbone.attention_layers
+    chunk = 128 if impl == "slot_kernel" else 256
+    assert layers > 0 and [counting(voice, n) for n in (0, 1, 128, 129)] == [
+        0, layers * chunk, layers * chunk, layers * 256]
+    fetched = sum(g["kv_places_fetched"] for g in groups)
+    assert sum(asked) == sum(g["kv_positions"] for g in groups)
+    assert fetched == sum(counting(voice, n) for n in asked) \
+        >= layers * sum(asked) > 0
+    assert stats.kv_places_fetched == fetched_before + fetched
+    assert f"sonata_kv_places_fetched_total {stats.kv_places_fetched}\n" \
+        in registry.render()
+    assert all(g["latent_places_fetched"] == 0 for g in groups)

@@ -8,7 +8,8 @@ library, and a second file could land on another worker.
   interpret mode cannot show;
 - each backbone's step program at its cell's size: it may hold no ``copy``
   of a whole key, value or routes buffer (until PR 37 XLA re-laid every
-  buffer twice a step), and both kernels are in it;
+  buffer twice a step), and both kernels are in it, the reader lowered
+  once a geometry and not once a layer;
 - the step that carries an arrival (``lfm2_step_admit``,
   ``nemotron_step_admit``, ``pangu_step_admit``, ``laguna_step_admit``) likewise: a step's scatter and a prompt's slice
   land on one donated buffer in one program, which is where a copy could
@@ -30,6 +31,7 @@ from perfbench.harness import lfm2gen
 from sonata_tpu.models import unit_voice
 from test_grouped_matmul import STEP_SHAPES
 from test_slot_attention import GEOMETRIES
+from tools.profile_start import inner_jaxprs
 
 sa = importlib.import_module("sonata_tpu.ops.slot_attention")
 gm = importlib.import_module("sonata_tpu.ops.grouped_matmul")
@@ -101,7 +103,10 @@ def test_the_grouped_matmul_compiles_for_a_v5e_at_the_step_shapes(
 @pytest.mark.parametrize("name", sorted(GEOMETRIES))
 def test_the_slot_attention_compiles_for_a_v5e_at_the_step_shapes(
         one_chip, no_compile_cache, name):
-    """The attention kernel with the rule's tiles at the real widths."""
+    """The attention kernel with the rule's chunk at the real widths: the
+    buffers handed over where they lie, a slot's keys and values copied by
+    the kernel itself up to its length (the copies, their semaphores and
+    the trip count from ``upto``: what interpret mode cannot refuse)."""
     kv, g, d, b = GEOMETRIES[name]
     slots, places = READER_SHAPES.get(name) or (CELLS[name][2], POSITIONS)
     tiles = sa.tile_rule(places, kv, g, d, b)
@@ -198,6 +203,41 @@ CODE_MB = {"step": 24, "step_admit": 36}
 #: what reads the slots' cache in each step program
 READERS = {name: "latent_attention" if name == "pangu_step"
            else "slot_attention" for name in CELLS}
+#: the traces and lowerings of its reader a step program holds: one a
+#: geometry (a whole cache under 6 query heads a key head, a ring of 512
+#: places under 8)
+LOWERINGS = {"laguna_step": 2}
+#: the layers of each that read the slots' cache
+READS = {"lfm2_step": 2, "sdar_pass": 6, "nemotron_step": 1, "pangu_step": 7,
+         "laguna_step": 8}
+
+
+def reader_calls(jaxpr, name: str) -> list:
+    """The jaxprs under the equations that call the jitted function
+    ``name``, anywhere in ``jaxpr``: one for each call."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.params.get("name") == name:
+            found.append(eqn.params["jaxpr"])
+        else:
+            for inner in inner_jaxprs(eqn):
+                found += reader_calls(inner, name)
+    return found
+
+
+def lowered_once_a_geometry(program, args, name: str):
+    """``program`` traced and lowered for ``args``: every layer's reader is
+    a call of the kernel's jitted function, the layers of one geometry
+    share one trace of it (one jaxpr object) and the module holds one
+    lowering of the kernel for each."""
+    traced = program.trace(*args)
+    calls = reader_calls(traced.jaxpr.jaxpr, f"{READERS[name]}_kernel")
+    assert len(calls) == READS[name]
+    assert len({id(j) for j in calls}) == LOWERINGS.get(name, 1)
+    lowered = traced.lower()
+    assert lowered.as_text().count(
+        f'kernel_name = "{READERS[name]}"') == LOWERINGS.get(name, 1)
+    return lowered
 #: the cells whose chip holds a thin share of each layer's experts
 THIN = ("pangu_step", "laguna_step")
 
@@ -220,7 +260,8 @@ def test_a_step_compiled_for_a_v5e_copies_no_slot_buffer_whole(
         cache["routes"], *cache.get("k", ()), *cache.get("v", ()),
         *cache.get("latent", ()))]
     assert min(per_place) == cache["routes"].size
-    compiled = backbone.build_step().lower(*args).compile()
+    compiled = lowered_once_a_geometry(backbone.build_step(), args,
+                                       name).compile()
     # a program of the lattice is tens of megabytes of the machine's
     # compile cache, 192 MiB for 15 of them: 10-18 MB each here, and
     # ``laguna_step`` 70 where its eight layers' code is emitted eight times
@@ -256,7 +297,8 @@ def test_a_carrying_step_compiled_for_a_v5e_copies_no_slot_buffer_whole(
                    jnp.int32, jnp.int32, F32, jnp.int32)))
     args += tuple(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
                   for a in arrival)
-    compiled = backbone.build_step_admit().lower(*args).compile()
+    compiled = lowered_once_a_geometry(backbone.build_step_admit(), args,
+                                       name).compile()
     # 18-28 MB at the longest text bucket (``laguna_step_admit`` 100 with
     # its layers' code emitted eight times)
     assert compiled.memory_analysis().generated_code_size_in_bytes < CODE_MB[

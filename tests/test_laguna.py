@@ -20,7 +20,7 @@ import numpy as np
 import pytest
 
 from perfbench.harness import lagunagen, parts
-from sonata_tpu.models import from_config_path, laguna, lfm2
+from sonata_tpu.models import from_config_path, laguna, lfm2, unit_voice
 from sonata_tpu.models.config import SynthesisConfig
 from sonata_tpu.models.unit_voice import routes_of
 from sonata_tpu.ops import slot_attention
@@ -368,10 +368,10 @@ def test_the_configuration_is_read_as_the_module_says():
             cfg.places(laguna.SLIDING, 300)) == (1024, 512, 300)
     # the thin path at an eighth: 2048 assignments bounded to 640 rows
     assert lfm2.held_rows(cfg, 256, cfg.held) == 640
-    # both geometries have tiles of 256 places: both run the kernel
+    # both geometries have chunks of 128 places: both run the kernel
     for places, g in ((1024, 6), (512, 8)):
         assert slot_attention.tile_rule(places, 8, g, 128, 1) == \
-            slot_attention.Tiles(256)
+            slot_attention.Tiles(128)
     cache = jax.eval_shape(lambda: laguna.new_cache(cfg, 256, 1024))
     assert [a.shape for a in cache["k"]] == [
         (256, 1024 if kind == laguna.FULL else 512, 1024)
@@ -393,6 +393,31 @@ def test_the_configuration_is_read_as_the_module_says():
     whole = laguna.LagunaConfig.from_dict(
         {k: v for k, v in BB.items() if k != "expert_parallel"})
     assert (whole.num_experts, whole.held) == (2, (0, 2))
+
+
+def test_the_cells_reader_is_counted_in_chunks_and_a_ring_at_its_window(
+        monkeypatch):
+    """``kv_places_fetched`` at the cell's size where the kernel reads (the
+    rule steered as on a TPU): two whole caches and six rings of 512
+    places in chunks of 128, a ring read no further than its window; of
+    what is moved the rows hold ``kv_cache_bytes`` over a place's bytes."""
+    monkeypatch.setattr(slot_attention, "_tiles_here",
+                        slot_attention.tile_rule)
+    backbone = unit_voice.make_backbone(lagunagen.backbone(REAL),
+                                        REAL["voice"]["units"])
+    reaches = backbone.kv_reaches(1024)
+    assert sorted(reaches) == [(512, 128, 6), (1024, 128, 2)]
+    counted = functools.partial(unit_voice.places_fetched, reaches)
+    assert [counted(n) for n in (0, 1, 128, 129, 512, 513, 819, 1024)] == [
+        0, 8 * 128, 8 * 128, 8 * 256, 8 * 512, 2 * 640 + 6 * 512,
+        2 * 896 + 6 * 512, 2 * 1024 + 6 * 512]
+    for n in (1, 347, 513, 1024):
+        held = backbone.kv_cache_bytes(n) // backbone.cfg.place_bytes
+        assert held == 2 * n + 6 * min(n, 512) and 0 < held <= counted(n)
+    # the einsum, off a TPU, reads every place of every buffer
+    monkeypatch.undo()
+    assert sorted(backbone.kv_reaches(1024)) == [(512, 512, 6),
+                                                 (1024, 1024, 2)]
 
 
 # -- the voice and what its loop records ------------------------------------
@@ -420,6 +445,10 @@ def test_the_voice_runs_and_its_loop_says_what_the_two_caches_cost(
                                          "ragged_dot")
         assert voice.kv_cache_bytes(5) == place * 5 * 5
         assert voice.kv_cache_bytes(30) == place * (2 * 30 + 3 * 8)
+        # off a TPU the einsum moves every place of a layer's buffer: a
+        # whole cache's 256, a ring's 8
+        assert [voice.kv_places_fetched(n) for n in (0, 5, 30)] == [
+            0, 2 * 256 + 3 * 8, 2 * 256 + 3 * 8]
         assert voice.cache_resident_bytes() == (3 * place * 2 * 256,
                                                 3 * place * 3 * 8)
         assert ("step_admit", 32) in voice.lattice_shapes("full")
@@ -457,6 +486,8 @@ def test_the_voice_runs_and_its_loop_says_what_the_two_caches_cost(
         # a full layer's places and at most the window's in a ring
         assert place * 2 * g["kv_positions"] < g["kv_cache_bytes"] <= \
             place * (2 * g["kv_positions"] + 3 * 8 * stepped)
+        assert g["kv_places_fetched"] == (2 * 256 + 3 * 8) * stepped \
+            >= g["kv_cache_bytes"] // place
     # one row of len(ids) + budget - 1 positions: every step from the one
     # at position 8 on is bound by the window
     budget = round(3.5 * len(ids))
@@ -465,3 +496,6 @@ def test_the_voice_runs_and_its_loop_says_what_the_two_caches_cost(
     assert stats.window_bound_row_steps == bound_before + bound
     assert f"sonata_attn_window_bound_row_steps_total " \
         f"{stats.window_bound_row_steps}\n" in registry.render()
+    assert f"sonata_kv_places_fetched_total {stats.kv_places_fetched}\n" \
+        in registry.render() and stats.kv_places_fetched >= sum(
+            g["kv_places_fetched"] for g in groups) > 0
