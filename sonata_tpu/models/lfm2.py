@@ -485,16 +485,72 @@ def allowed_ids(vocab: int, units: UnitIds):
     return allowed
 
 
+def _scaled_and_noisy(logits, temperature, key, units: UnitIds) -> tuple:
+    """``logits`` ``[N, V]`` over the row's temperature with every id that
+    is no unit at ``-inf``, and the same plus Gumbel noise: the arg-max of
+    the second is a draw from the softmax of the first.  A row whose
+    ``temperature`` is 0 divides by 1 and gains no noise: its arg-max is
+    the largest allowed logit's, to the bit."""
+    drawn = temperature > 0
+    scale = lax.select(drawn, temperature, jnp.ones_like(temperature))
+    allowed = jnp.broadcast_to(allowed_ids(logits.shape[-1], units),
+                               logits.shape)
+    scaled = lax.select(allowed, logits,
+                        jnp.full_like(logits, -jnp.inf)) / scale[:, None]
+    noise = jax.random.gumbel(key, logits.shape, F32)
+    return scaled, scaled + lax.select(
+        jnp.broadcast_to(drawn[:, None], logits.shape), noise,
+        jnp.zeros_like(noise))
+
+
 def sample(logits, temperature, key, units: UnitIds):
     """A unit id per row of ``logits`` ``[N, V]``: the largest logit over
     the unit ids where ``temperature`` is 0, else a draw from
-    ``softmax(logits / temperature)`` over them.  The stop unit is
-    suppressed: a row ends at its frame budget, which the host counts."""
-    masked = jnp.where(allowed_ids(logits.shape[-1], units), logits, -jnp.inf)
-    greedy = jnp.argmax(masked, -1)
-    safe = jnp.maximum(temperature, 1e-6)[:, None]
-    drawn = jax.random.categorical(key, masked / safe, axis=-1)
-    return jnp.where(temperature > 0, drawn, greedy).astype(jnp.int32)
+    ``softmax(logits / temperature)`` over them, both as one arg-max
+    (:func:`_scaled_and_noisy`) that reads the logits where the head left
+    them: :func:`choose`'s id without what a step does not read (every
+    step program traces this, and a start pays for each equation).  The
+    stop unit is suppressed: a row ends at its frame budget, which the
+    host counts."""
+    noisy = _scaled_and_noisy(logits, temperature, key, units)[1]
+    return jnp.argmax(noisy, -1).astype(jnp.int32)
+
+
+def _pick(a, b):
+    """Of two candidates ``(noisy, place, plain there, largest plain)``
+    the one :func:`jnp.argmax` keeps: the larger, a NaN before a number,
+    the first of equals; and the larger of their largest."""
+    (best_a, at_a, plain_a, top_a), (best_b, at_b, plain_b, top_b) = a, b
+    b_wins = lax.bitwise_or(lax.gt(best_b, best_a), lax.ne(best_b, best_b))
+    b_first = lax.bitwise_or(b_wins, lax.bitwise_and(
+        lax.eq(best_b, best_a), lax.lt(at_b, at_a)))
+    return (lax.select(b_wins, best_b, best_a),
+            lax.select(b_first, at_b, at_a),
+            lax.select(b_first, plain_b, plain_a), lax.max(top_a, top_b))
+
+
+def choose(logits, temperature, key, units: UnitIds) -> tuple:
+    """:func:`sample`'s id per row of ``logits`` ``[N, V]`` and the
+    log-probability of that id under ``softmax(logits / temperature)`` over
+    the unit ids (``[N]`` int32, ``[N]`` float32): the reduction that takes
+    the arg-max also carries the scaled logit there and the row's largest,
+    one more reading of the logits sums the exponentials; no
+    ``log_softmax`` is written out."""
+    scaled, noisy = _scaled_and_noisy(logits, temperature, key, units)
+    _, ids, at, top = lax.reduce(
+        (noisy, lax.broadcasted_iota(jnp.int32, noisy.shape, 1), scaled,
+         scaled), (-jnp.inf, jnp.int32(0), -jnp.inf, -jnp.inf), _pick, (1,))
+    total = jnp.sum(jnp.exp(scaled - top[:, None]), -1)
+    return ids, at - top - jnp.log(total)
+
+
+def step_key(seed: int, step_no):
+    """The key of launch ``step_no``'s draws, of the ``rbg`` implementation:
+    its ``[N, V]`` words are the platform's own generator's, one operation
+    (on a v5e 0.26 ms for 256 rows of 151 936 ids), where threefry's
+    arithmetic inside the choice's fusion took 0.78 of that fusion's 0.99
+    (PERF.md section 6, PR 46).  The distribution is the same."""
+    return jax.random.fold_in(jax.random.key(seed, impl="rbg"), step_no)
 
 
 # ---------------------------------------------------------------------------
@@ -549,8 +605,7 @@ def advance(cache: dict, live, logits, routes: list, temperature, step_no,
     live rows' next unit, sampled from ``logits`` ``[S, V]``, one place and
     one unit more, and the experts their tokens chose."""
     pos = cache["pos"]
-    key = jax.random.fold_in(jax.random.PRNGKey(seed), step_no)
-    unit = sample(logits, temperature, key, units)
+    unit = sample(logits, temperature, step_key(seed, step_no), units)
     rows = jnp.arange(live.shape[0])
     span = cache["units"].shape[1]
     cache["routes"] = write_rows(cache["routes"],

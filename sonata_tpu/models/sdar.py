@@ -19,7 +19,7 @@ prompt, then its units; the logits at a position predict the token *at*
 that position.  The block being generated starts as mask tokens (the first
 one opens with the prompt's last ``n mod B`` ids as known tokens).  A
 *denoising* pass runs the block whole over the committed blocks, chooses an
-id for every masked position (:func:`~.lfm2.sample`) and unmasks the
+id for every masked position (:func:`~.lfm2.choose`) and unmasks the
 ``B / denoising_steps`` of them it is surest of (the chosen id's
 probability under the distribution it was chosen from); after
 ``denoising_steps`` of them the block holds no mask token and a *commit*
@@ -51,8 +51,8 @@ import jax.numpy as jnp
 
 from ..ops.slot_attention import slot_attention, stored_shape, write_rows, \
     write_slot
-from .lfm2 import BF16, F32, UnitIds, _head, _qkv, allowed_ids, \
-    attn_op_seq, mm, moe_ffn, rms_norm, sample
+from .lfm2 import BF16, F32, UnitIds, _head, _qkv, attn_op_seq, choose, \
+    mm, moe_ffn, rms_norm, step_key
 
 Params = dict
 
@@ -177,18 +177,15 @@ def _moe_half(h, p, cfg: SdarConfig, held, valid, routes: list, loads: list):
 def unmask(logits, x, temperature, key, pass_no, units: UnitIds,
            schedule: Schedule):
     """One denoising pass's choice for blocks ``x`` ``[S, B]`` with logits
-    ``[S, B, V]``: an id for every masked position, and the
-    ``schedule.transfers[pass_no]`` of them with the highest confidence
-    unmasked.  Returns the blocks and which positions were unmasked."""
+    ``[S * B, V]``, as the head left them: an id for every masked position
+    and, out of the same reading of the logits, its log-probability
+    (:func:`~.lfm2.choose`); the ``schedule.transfers[pass_no]`` surest of
+    them are unmasked.  Returns the blocks and which positions were
+    unmasked."""
     with jax.named_scope("unmask"):
         s, b = x.shape
-        flat = logits.reshape(s * b, -1)
-        chosen = sample(flat, jnp.repeat(temperature, b), key, units)
-        allowed = allowed_ids(flat.shape[-1], units)
-        scale = jnp.repeat(jnp.where(temperature > 0, temperature, 1.0), b)
-        log_p = jax.nn.log_softmax(
-            jnp.where(allowed, flat, -jnp.inf) / scale[:, None], -1)
-        confidence = jnp.take_along_axis(log_p, chosen[:, None], -1)[:, 0]
+        chosen, confidence = choose(logits, jnp.repeat(temperature, b), key,
+                                    units)
         masked = x == schedule.mask_id
         confidence = jnp.where(masked, confidence.reshape(s, b), -jnp.inf)
         # a position's rank among its block's, the surest first
@@ -269,8 +266,9 @@ def block_pass(params: Params, cache: dict, live, temperature, step_no, *,
     which slots hold a row: the others are computed (the shape is static)
     but cost no expert product, count for nothing and do not move.
     Returns the cache, what the pass saw and gave (the blocks as they went
-    in ``[S, B]``, the logits ``[S, B, V]``, the experts chosen ``[S, B,
-    layers, k]``) and the expert layers' load ``[layers, 3]``."""
+    in ``[S, B]``, the logits ``[S * B, V]``, a slot's ``B`` rows after
+    each other as the head left them, the experts chosen ``[S, B, layers,
+    k]``) and the expert layers' load ``[layers, 3]``."""
     b = schedule.block_length
     cache = dict(cache, k=list(cache["k"]), v=list(cache["v"]))
     s, span = cache["tokens"].shape
@@ -286,14 +284,13 @@ def block_pass(params: Params, cache: dict, live, temperature, step_no, *,
             rms_norm(h, p["in_norm"], cfg.norm_eps), p["attn"], cfg,
             cache["k"][i], cache["v"][i], pos)
         h = _moe_half(h + op, p, cfg, held, valid, routes, loads)
-    logits = _head(h, params, cfg).reshape(s, b, -1)
+    logits = _head(h, params, cfg)
     chose = jnp.stack(routes, 1).astype(jnp.int8).reshape(s, b, len(routes),
                                                           -1)
-    key = jax.random.fold_in(jax.random.PRNGKey(seed), step_no)
     pass_no = cache["pass"]
     commit = pass_no >= schedule.denoising_steps
-    after, taken = unmask(logits, x, temperature, key, pass_no, units,
-                          schedule)
+    after, taken = unmask(logits, x, temperature, step_key(seed, step_no),
+                          pass_no, units, schedule)
     denoise = (live & ~commit)[:, None]
     cache["tokens"] = cache["tokens"].at[rows, pos].set(
         jnp.where(denoise, after, x))

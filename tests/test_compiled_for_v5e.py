@@ -198,6 +198,60 @@ def test_the_copies_of_the_layout_before_are_found():
     assert len(whole_buffer_copies(parent, 64 * 1024 * 32)) == 3
 
 
+def logits_sized(hlo: str, elements: int, types: str = "f32") -> tuple:
+    """Of an optimised module's entry computation: the arrays of
+    ``elements`` elements of ``types`` (float32: the logits') its
+    operations write, as ``(operation, kind)`` (a fusion that writes two
+    stands twice; parameters and what only names an array again aside),
+    and the operations that read one."""
+    entry = hlo[hlo.index("\nENTRY "):]
+    arrays, made, read = set(), [], []
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\((.*)", line)
+        if not m:
+            continue
+        name, result, kind, rest = m.groups()
+        operands = re.findall(r"%([\w.-]+)", re.split(
+            r"\), |, calls=|, metadata=", rest)[0])
+        n = sum(int(np.prod([int(d) for d in dims.split(",")])) == elements
+                for dims in re.findall(rf"(?:{types})\[([\d,]+)\]", result))
+        if n:
+            arrays.add(name)
+        if kind in ("parameter", "get-tuple-element", "tuple", "bitcast"):
+            continue
+        made += [(name, kind)] * n
+        if arrays.intersection(operands):
+            read.append(name)
+    return made, read
+
+
+def test_the_logits_the_parent_wrote_twice_are_found():
+    """Lines of ``sdar_pass`` as the parent compiled it for a v5e: the
+    head's product (with ``log_softmax``'s maximum beside it), the two
+    arg-max fusions and the sum that read it, ``log_softmax`` written out
+    whole for a gather, and the copy to ``[64, 4, V]`` handed out."""
+    parent = """
+ENTRY %main.146 (params__embed__.1: bf16[151936,2048]) -> (f32[64,4,151936]) {
+  %fusion.138 = (f32[256]{0:T(256)S(1)}, f32[256,151936]{1,0:T(8,128)}) fusion(%copy-done.118, %params__head__.1), kind=kOutput, calls=%fused_computation.163, metadata={op_name="jit(sdar_pass)/head/dot_general"}
+  %get-tuple-element.585 = f32[256,151936]{1,0:T(8,128)} get-tuple-element(%fusion.138), index=1
+  %iota_reduce_fusion.1 = (bf16[256]{0:T(256)(128)(2,1)}, s32[256]{0:T(256)S(1)}) fusion(%get-tuple-element.585, %copy-done.119), kind=kLoop, calls=%fused_computation.140
+  %exponential_reduce_fusion = f32[256]{0:T(256)S(1)} fusion(%get-tuple-element.585, %get-tuple-element.584), kind=kLoop, calls=%fused_computation.160
+  %iota_reduce_fusion = (bf16[256]{0:T(256)(128)(2,1)}, s32[256]{0:T(256)S(1)}) fusion(%get-tuple-element.585, %copy-done.58), kind=kLoop, calls=%fused_computation.139
+  %subtract_subtract_fusion = f32[256,151936]{1,0:T(8,128)} fusion(%get-tuple-element.585, %get-tuple-element.584, %log.2), kind=kLoop, calls=%fused_computation.161
+  %fusion.57 = f32[256]{0:T(256)S(1)} fusion(%subtract_subtract_fusion, %select_n.300), kind=kLoop, calls=%fused_computation.57
+  %reshape.162 = f32[64,4,151936]{2,1,0:T(4,128)} reshape(%get-tuple-element.585), metadata={op_name="jit(sdar_pass)/reshape"}
+  ROOT %tuple.274 = (f32[64,4,151936]{2,1,0:T(4,128)}) tuple(%reshape.162)
+}
+"""
+    made, read = logits_sized(parent, 256 * 151936)
+    assert made == [("fusion.138", "fusion"),
+                    ("subtract_subtract_fusion", "fusion"),
+                    ("reshape.162", "reshape")]
+    assert read == ["iota_reduce_fusion.1", "exponential_reduce_fusion",
+                    "iota_reduce_fusion", "subtract_subtract_fusion",
+                    "fusion.57", "reshape.162"]
+
+
 #: what a program's code may weigh (``generated_code_size_in_bytes``)
 CODE_MB = {"step": 24, "step_admit": 36}
 #: what reads the slots' cache in each step program
@@ -240,6 +294,9 @@ def lowered_once_a_geometry(program, args, name: str):
     return lowered
 #: the cells whose chip holds a thin share of each layer's experts
 THIN = ("pangu_step", "laguna_step")
+#: the fusions that read the head's ``f32[N, V]`` (``lfm2.choose``: the
+#: choice; the sum of exponentials where the log-probability is used)
+LOGITS_READ = {"sdar_pass": 2, "nemotron_step": 1}
 
 
 @pytest.mark.parametrize("name", sorted(CELLS))
@@ -270,6 +327,21 @@ def test_a_step_compiled_for_a_v5e_copies_no_slot_buffer_whole(
     hlo = compiled.as_text()
     assert READERS[name] in hlo and "grouped_matmul" in hlo
     assert whole_buffer_copies(hlo, min(per_place)) == []
+    if name in LOGITS_READ:
+        # behind the head the logits are read where it left them: no second
+        # array of their size (a copy to another tiling, a ``log_softmax``
+        # written out), one fusion that chooses and, where the confidence
+        # is used, one that sums
+        rows = CELLS[name][2] * backbone.block_length
+        made, read = logits_sized(hlo, rows * backbone.cfg.vocab_size)
+        assert [kind for _, kind in made] == ["fusion"]
+        assert len(read) == LOGITS_READ[name]
+        # the draw's words are the generator's own operation (``rbg``),
+        # read by the choice's fusion alone
+        words, read = logits_sized(hlo, rows * backbone.cfg.vocab_size,
+                                   "u32|s32")
+        assert [kind for _, kind in words] == ["rng-bit-generator"]
+        assert len(read) == 1
     # a thin share's program holds both paths: the short one on the kernel
     # and, for a launch that overflows it, the full-length one
     assert ("conditional" in hlo) == (name in THIN)

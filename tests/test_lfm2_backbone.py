@@ -236,6 +236,68 @@ def test_the_bias_changes_the_selection_but_not_the_weights(raw, u):
     close(weights_1.sum(-1), 1.0, 1e-4)
 
 
+#: a row's temperature in :func:`lfm2.choose`'s tests: 0 is greedy
+TEMPERATURES = {"greedy": [0.0] * 6,
+                "drawn": [0.667, 0.667, 1.0, 2.0, 5.0, 0.1],
+                "mixed": [0.0, 0.667, 0.0, 5.0, 0.0, 0.2]}
+
+
+@pytest.mark.parametrize("impl", ["threefry2x32", "rbg"])
+@pytest.mark.parametrize("rows", sorted(TEMPERATURES))
+def test_choose_gives_an_id_and_its_log_probability(rows, impl):
+    """``choose``: at temperature 0 the arg-max over the allowed ids bit
+    for bit, else what ``jax.random.categorical`` draws from the scaled
+    logits with the key; the log-probability is ``log_softmax`` of the
+    allowed logits over the temperature at that id; ``sample`` is its ids;
+    the stop unit never comes out."""
+    rng = np.random.default_rng(5)
+    logits = jnp.asarray(3.0 * rng.standard_normal((6, 512)), jnp.float32)
+    logits = logits.at[:, 511].set(50.0).at[:, 7].set(60.0)
+    logits = logits.at[0, 300].set(logits[0, 400])      # equals: the first
+    t = jnp.asarray(TEMPERATURES[rows], jnp.float32)
+    key = jax.random.key(11, impl=impl)
+    ids, log_p = jax.jit(lambda x, t, k: lfm2.choose(x, t, k, UNITS))(
+        logits, t, key)
+    assert ids.dtype == jnp.int32 and log_p.dtype == jnp.float32
+    masked = jnp.where(lfm2.allowed_ids(512, UNITS), logits, -jnp.inf)
+    scaled = masked / jnp.where(t > 0, t, 1.0)[:, None]
+    want = jnp.where(t > 0, jax.random.categorical(key, scaled, axis=-1),
+                     jnp.argmax(masked, -1))
+    assert np.array_equal(np.asarray(ids), np.asarray(want))
+    assert 256 <= int(ids.min()) and int(ids.max()) < 511
+    close(log_p, jnp.take_along_axis(jax.nn.log_softmax(scaled, -1),
+                                     ids[:, None], -1)[:, 0], 1e-5)
+    assert np.array_equal(np.asarray(lfm2.sample(logits, t, key, UNITS)),
+                          np.asarray(ids))
+
+
+@pytest.mark.parametrize("impl", ["threefry2x32", "rbg"])
+@pytest.mark.parametrize("temperature", [0.667, 2.0])
+def test_the_draws_follow_the_softmax_over_the_allowed_ids(temperature,
+                                                           impl):
+    """8192 keys, 30 allowed ids of 40: the draws' frequencies against
+    ``softmax(logits / t)`` over the allowed ids by a chi-square bound (29
+    degrees of freedom: 66.2 is passed once in ten thousand); the ids
+    before the first unit, the mask and the stop id are never drawn, high
+    as their logits are."""
+    units = lfm2.UnitIds(8, 39, 20)
+    logits = jnp.asarray(
+        0.7 * np.random.default_rng(7).standard_normal((1, 40)),
+        jnp.float32).at[0, jnp.asarray([3, 20, 39])].set(9.0)
+    keys = jax.random.split(jax.random.key(46, impl=impl), 8192)
+    ids = np.asarray(jax.jit(jax.vmap(lambda k: lfm2.choose(
+        logits, jnp.full((1,), temperature), k, units)[0][0]))(keys))
+    allowed = np.asarray(lfm2.allowed_ids(40, units))
+    assert allowed.sum() == 30
+    counts = np.bincount(ids, minlength=40)
+    assert counts[~allowed].sum() == 0
+    p = np.exp(np.asarray(logits[0], np.float64) / temperature) * allowed
+    expected = len(keys) * p / p.sum()
+    assert expected[allowed].min() > 5
+    chi2 = ((counts - expected)[allowed] ** 2 / expected[allowed]).sum()
+    assert chi2 < 66.2
+
+
 def test_sampling_is_greedy_at_zero_and_never_gives_the_stop_unit():
     rng = np.random.default_rng(3)
     logits = jnp.asarray(rng.standard_normal((6, 512)), jnp.float32)

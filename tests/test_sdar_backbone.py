@@ -17,6 +17,7 @@ import pytest
 from perfbench.harness import lfm2gen, parts, sdargen
 from sonata_tpu.models import lfm2, sdar
 from sonata_tpu.models.unit_voice import routes_of
+from tools import profile_sampler
 
 ROOT = Path(__file__).resolve().parent.parent
 CONFIG = json.loads((ROOT / "tests/perfbench/data/sdar-tiny.json").read_text())
@@ -222,7 +223,7 @@ def test_prefill_and_passes_through_slots_match_the_whole_forward_pass(
             seq = np.concatenate([tokens[:start], np.asarray(x[slot])])
             assert np.array_equal(seq, tokens[:start + B])
             want, routes = whole(seq)
-            close(logits[slot], want[start:])
+            close(logits[slot * B:slot * B + B], want[start:])
             assert np.array_equal(np.asarray(chose[slot]), routes[start:])
             masked = seq[start:] == UNITS.mask_id
             after = np.asarray(cache["tokens"][slot, start:start + B])
@@ -281,7 +282,7 @@ def test_a_whole_row_gives_the_published_loops_units(steps, n, params,
     for k, (seq, logits) in enumerate(passes):
         cache, (x, got, _), _ = block_pass(params, cache, live, k)
         assert np.array_equal(np.asarray(x[1]), seq[-B:])
-        close(got[1], logits)
+        close(got[B:2 * B], logits)
     assert np.array_equal(np.asarray(cache["tokens"][1, n:n + budget]), want)
     assert int(cache["start"][1]) == n // B * B + blocks * B
     # the slot that held no row did not move
@@ -310,6 +311,50 @@ def test_sampling_never_gives_the_mask_or_the_stop_unit():
     drawn = lfm2.sample(logits, jnp.full((6,), 5.0), jax.random.PRNGKey(2),
                         UNITS)
     assert 256 <= int(drawn.min()) and int(drawn.max()) < 510
+
+
+def unmask_until_pr_46(logits, x, temperature, key, pass_no, schedule):
+    """``sdar.unmask`` as PR 45 left it, over logits ``[S, B, V]``: the id
+    of ``sample`` as it was and the confidence gathered from a
+    ``log_softmax`` of the masked logits (``tools/profile_sampler.py``
+    keeps that form to be measured against), then the ranking."""
+    s, b = x.shape
+    chosen, confidence, _ = profile_sampler.before(
+        logits.reshape(s * b, -1), jnp.repeat(temperature, b), key, UNITS, b)
+    masked = x == schedule.mask_id
+    confidence = jnp.where(masked, confidence.reshape(s, b), -jnp.inf)
+    order = jnp.argsort(-confidence, axis=-1, stable=True)
+    rank = jnp.argsort(order, axis=-1, stable=True)
+    count = jnp.asarray(schedule.transfers + (0,), jnp.int32)[pass_no]
+    taken = masked & (rank < count[:, None])
+    return jnp.where(taken, chosen.reshape(s, b), x), taken
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.667])
+@pytest.mark.parametrize("seed", [3, 11, 46])
+def test_unmask_unmasks_what_it_unmasked_before(seed, temperature):
+    """``unmask`` over the head's ``[S * B, V]`` against the function it
+    replaced over ``[S, B, V]``: the same positions unmasked with the same
+    ids, greedy and (the key's bits are the same) drawn, for slots in every
+    pass of the schedule and blocks that open with known tokens."""
+    rng = np.random.default_rng(seed)
+    slots, schedule = 5, sdar.Schedule(B, 2, UNITS.mask_id)
+    logits = jnp.asarray(2.0 * rng.standard_normal((slots * B, 512)),
+                         jnp.float32)
+    x = jnp.asarray(np.where(rng.random((slots, B)) < 0.7, UNITS.mask_id,
+                             rng.integers(256, 510, (slots, B))), jnp.int32)
+    t = jnp.full((slots,), temperature, jnp.float32)
+    pass_no = jnp.asarray(rng.integers(0, 3, (slots,)), jnp.int32)
+    key = jax.random.PRNGKey(seed)
+    after, taken = jax.jit(lambda *a: sdar.unmask(
+        *a, units=UNITS, schedule=schedule))(logits, x, t, key, pass_no)
+    want, want_taken = unmask_until_pr_46(
+        logits.reshape(slots, B, -1), x, t, key, pass_no, schedule)
+    assert np.array_equal(np.asarray(taken), np.asarray(want_taken))
+    assert np.array_equal(np.asarray(after), np.asarray(want))
+    assert taken.any() and not np.asarray(taken)[np.asarray(pass_no) == 2].any()
+    assert not np.isin(np.asarray(after)[np.asarray(taken)],
+                       [UNITS.mask_id, UNITS.stop_id]).any()
 
 
 def test_the_new_fields_leave_the_lfm2_programs_as_they_were():

@@ -186,6 +186,52 @@ def test_the_vocoder_reads_the_units_at_the_prompts_end(voice):
     assert shape["frames_bucket"] >= budget
 
 
+def test_a_flagged_rows_kept_logits_are_its_slots_rows_of_the_pass(voice):
+    """A pass hands its logits out as the head wrote them, ``[S * B, V]``:
+    what a flagged row keeps of it is cut where it is gathered
+    (``take_rows``, 8 slots a call), its slot's ``B`` rows as ``[B, V]``,
+    and ``dump``'s arrays have the shapes ``sdar_check.py`` reads."""
+    from sonata_tpu.synth.steploop import DUMP_ROWS
+
+    voice.set_fallback_synthesis_config(SynthesisConfig(noise_scale=0.0))
+    ids = voice.config.phonemes_to_ids(
+        list(voice.phonemize_text("a longer test."))[0])
+    budget = voice.frame_budget(len(ids))
+    cache = voice.new_cache()
+    for slot in (0, 2):
+        cache = voice.prefill(cache, slot, ids[slot:], 0.0)[0]
+    live = np.array([True, False, True])
+    b, vocab = voice.block_length, voice.params["head"].shape[0]
+    kept = []
+    for k in range(3):
+        cache, gave, _ = voice.step(cache, live, np.zeros((3,), np.float32),
+                                    k)
+        x, logits, chose = gave
+        assert x.shape == (3, b) and logits.shape == (3 * b, vocab)
+        assert logits.dtype == np.float32 and chose.shape[:2] == (3, b)
+        slots = [2, 0] + [0] * (DUMP_ROWS - 2)
+        got = voice.take_rows(gave, slots)
+        assert [a.shape[:2] for a in got] == [(DUMP_ROWS, b)] * 3
+        for j, slot in enumerate(slots[:2]):
+            assert np.array_equal(np.asarray(got[1][j]), np.asarray(
+                logits[slot * b:slot * b + b]))
+            assert np.array_equal(np.asarray(got[0][j]), np.asarray(x[slot]))
+            assert np.array_equal(np.asarray(got[2][j]),
+                                  np.asarray(chose[slot]))
+        kept.append((k, [np.asarray(a)[0] for a in got]))
+    dump = voice.dump(ids[2:], budget, kept, voice.row_record(cache, 2))
+    t = voice.backbone.positions_needed(len(ids) - 2, budget)
+    layers, top = chose.shape[2:]
+    assert {k: v.shape for k, v in dump.items()} == {
+        "tokens": (t,), "routes": (t, layers, top), "unmasked_at": (t,),
+        "passes": (3,), "seen": (3, b), "logits": (3, b, vocab),
+        "pass_routes": (3, b, layers, top), "block_length": (),
+        "denoising_steps": (), "ids": (len(ids) - 2,)}
+    assert dump["logits"].dtype == np.float32
+    # the first block's commit pass saw what its two denoising passes left
+    assert (dump["seen"][2] != voice.units.mask_id).all()
+
+
 def test_the_loop_records_blocks_and_passes(voice):
     """Five callers over three slots: launches counted as the plans say,
     and the group spans and the counters carry the blocks' numbers."""
