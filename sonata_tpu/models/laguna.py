@@ -42,8 +42,10 @@ it) and the gate's sigmoid are float32.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import math
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -51,8 +53,9 @@ import numpy as np
 
 from ..ops.slot_attention import ring_upto, slot_attention, stored_shape, \
     write_rows, write_slot
-from .lfm2 import BF16, F32, UnitIds, _head, advance, advance_and_join, \
-    block_mask, dense_ffn, join, mm, moe_ffn, rms_norm
+from .unit_backbone import Description, TokenRows, token_step_programs
+from .unit_layers import BF16, F32, UnitIds, _head, advance, \
+    advance_and_join, block_mask, dense_ffn, join, mm, moe_ffn, rms_norm
 
 Params = dict
 FULL, SLIDING = "full_attention", "sliding_attention"
@@ -435,3 +438,64 @@ def step_admit(params: Params, cache: dict, live, temperature, step_no, ids,
         params, cache, h, routes, live, temperature, step_no, n, slot,
         row_temperature, row_key, cfg, units, seed)
     return cache, logits, jnp.stack(loads)
+
+
+class LagunaBackbone(TokenRows):
+    """``laguna``: a row gains a token a step; the programs and what a slot
+    holds are its own: keys and values of every position in the full
+    layers, a ring of ``window`` places in the window layers."""
+
+    pack_layer = staticmethod(pack_layer)
+
+    def __init__(self, backbone: dict, units: dict, seed: int):
+        self.cfg = cfg = LagunaConfig.from_dict(backbone)
+        self.units = UnitIds(int(units["first_id"]), int(units["stop_id"]))
+        self.layers = len(cfg.layer_types)
+        self.seed = seed
+        self.held = cfg.held
+
+    def new_cache(self, slots: int, positions: int) -> dict:
+        return new_cache(self.cfg, slots, positions)
+
+    def readers(self, positions: int) -> dict:
+        """Both geometries: a kind's places (a ring's: the window) and its
+        query heads."""
+        cfg = self.cfg
+        kv = cfg.num_key_value_heads
+        return dict(collections.Counter(
+            (cfg.places(kind, positions), kv, heads // kv, cfg.head_dim,
+             self.block_length)
+            for kind, heads in zip(cfg.layer_types, cfg.heads_per_layer)))
+
+    def describe(self, slots: int, positions: int) -> Description:
+        """Full layers keep every position of a slot, window layers a ring
+        of ``window`` places: the bytes of keys and values a row's step
+        reads as held (its positions a full layer, capped at the window a
+        ring), whether the band binds it (its position, the last it
+        attends over, is at or past the window: the ring has wrapped), and
+        the bytes the slots hold in each kind of layer.  A prompt attends
+        inside the band in the window layers."""
+        base = super().describe(slots, positions)
+        cfg = self.cfg
+        full, rings = (len(cfg.layers_of(kind)) for kind in (FULL, SLIDING))
+        if not rings:
+            return base
+        window = cfg.sliding_window
+        full_bytes, ring_bytes = cfg.place_bytes * full, \
+            cfg.place_bytes * rings
+        series = 'sonata_attn_cache_resident_bytes{kind="%s"}'
+        return dataclasses.replace(
+            base, static=dict(base.static, full_layers=full,
+                              window_layers=rings, window=window),
+            row_sums=base.row_sums + ("kv_cache_bytes",
+                                      "window_bound_row_steps"),
+            rows=[(*row, full_bytes * n + ring_bytes * min(n, window),
+                   int(n > window)) for n, row in enumerate(base.rows)],
+            resident={
+                series % "full": slots * full_bytes * positions,
+                series % "ring": slots * ring_bytes * min(window, positions)},
+            prefill=lambda text_bucket: {"window_layers": rings})
+
+    # (70 and 100 MB of code a program without it, 18 and 27 with)
+    build_step, build_prefill, build_step_admit = token_step_programs(
+        sys.modules[__name__], "laguna", layers_once=True)

@@ -46,14 +46,17 @@ thousandth of a prefill).
 from __future__ import annotations
 
 import dataclasses
+import sys
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from ..ops.slot_attention import stored_shape, write_slot
-from .lfm2 import BF16, F32, UnitIds, _head, advance, advance_and_join, \
-    attn_op_seq, attn_op_step, join, mm, moe_ffn, pad_experts, rms_norm
+from .unit_backbone import Description, TokenRows, token_step_programs
+from .unit_layers import BF16, F32, UnitIds, _head, advance, \
+    advance_and_join, attn_op_seq, attn_op_step, join, mm, moe_ffn, \
+    pad_experts, rms_norm
 
 Params = dict
 #: a layer's mixer by its character of the pattern: Mamba-2, attention,
@@ -453,3 +456,51 @@ def step_admit(params: Params, cache: dict, live, temperature, step_no, ids,
         params, cache, h, routes, live, temperature, step_no, n, slot,
         row_temperature, row_key, cfg, units, seed)
     return cache, logits, jnp.stack(loads)
+
+
+class NemotronBackbone(TokenRows):
+    """``nemotron_h``: a row gains a token a step; the programs and what a
+    slot holds are its own."""
+
+    pack_layer = staticmethod(pack_layer)
+
+    def __init__(self, backbone: dict, units: dict, seed: int):
+        self.cfg = NemotronConfig.from_dict(backbone)
+        self.units = UnitIds(int(units["first_id"]), int(units["stop_id"]))
+        self.layers = len(self.cfg.pattern)
+        self.attention_layers = len(self.cfg.layers_of("*"))
+        self.seed = seed
+        self.held = self.cfg.held
+
+    def new_cache(self, slots: int, positions: int) -> dict:
+        return new_cache(self.cfg, slots, positions)
+
+    def record(self, cache, slot: int) -> tuple:
+        return (*super().record(cache, slot), cache["ssm"][-1][slot])
+
+    def dump(self, ids: list, budget: int, kept: list, record) -> dict:
+        """A token row's dump and the recurrent state ``[heads, P, N]`` the
+        row left in the last Mamba layer: what no logit shows apart (the
+        products' bfloat16 inputs cover a state kept in less than
+        float32)."""
+        return dict(super().dump(ids, budget, kept, record[:2]),
+                    state=record[2])
+
+    def describe(self, slots: int, positions: int) -> Description:
+        """Beside the keys and values: the Mamba layers, the recurrent
+        state and convolution columns a slot holds for them (a live row's
+        step reads and writes its slot's), and the chunks their scans run
+        over a prompt padded to its text bucket."""
+        base = super().describe(slots, positions)
+        layers = len(self.cfg.layers_of("M"))
+        state, chunk = self.cfg.ssm_state_bytes, self.cfg.chunk_size
+        return dataclasses.replace(
+            base, static=dict(base.static, ssm_layers=layers),
+            closed=lambda g: dict(base.closed(g), ssm_state_bytes=(
+                2 * state * g["live_slot_steps"])),
+            resident={"sonata_ssm_state_resident_bytes": slots * state},
+            prefill=lambda text_bucket: {
+                "ssm_chunks": -(-text_bucket // chunk) * layers})
+
+    build_step, build_prefill, build_step_admit = token_step_programs(
+        sys.modules[__name__], "nemotron")

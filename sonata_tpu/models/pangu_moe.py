@@ -53,15 +53,19 @@ bfloat16.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import sys
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.slot_attention import latent_attention, stored_shape, write_rows, \
-    write_slot
-from .lfm2 import BF16, F32, UnitIds, _head, advance, advance_and_join, \
-    apply_rope, block_mask, dense_ffn, join, mm, moe_ffn, rms_norm
+from ..ops.slot_attention import latent_attention, latent_implementation, \
+    latent_places, latent_reach, stored_shape, write_rows, write_slot
+from .unit_backbone import Description, TokenRows, token_step_programs
+from .unit_layers import BF16, F32, UnitIds, _head, advance, \
+    advance_and_join, apply_rope, block_mask, dense_ffn, join, mm, moe_ffn, \
+    rms_norm
 
 Params = dict
 
@@ -395,3 +399,59 @@ def step_admit(params: Params, cache: dict, live, temperature, step_no, ids,
         params, cache, h, routes, live, temperature, step_no, n, slot,
         row_temperature, row_key, cfg, units, seed)
     return cache, logits, jnp.stack(loads)
+
+
+class PanguBackbone(TokenRows):
+    """``pangu_ultra_moe``: a row gains a token a step; the programs and
+    what a slot holds (one latent row a position and layer) are its own.  A
+    step runs latent attention's absorbed form, a prompt (apart or carried)
+    the expanded one."""
+
+    def __init__(self, backbone: dict, units: dict, seed: int):
+        self.cfg = PanguConfig.from_dict(backbone)
+        self.units = UnitIds(int(units["first_id"]), int(units["stop_id"]))
+        self.layers = self.cfg.num_hidden_layers
+        self.seed = seed
+        self.held = self.cfg.held
+        self.pack_layer = functools.partial(pack_layer, cfg=self.cfg)
+
+    def new_cache(self, slots: int, positions: int) -> dict:
+        return new_cache(self.cfg, slots, positions)
+
+    def attention(self, positions: int) -> str:
+        cfg = self.cfg
+        return latent_implementation(
+            positions, cfg.num_attention_heads, cfg.latent_width,
+            cfg.kv_lora_rank, self.block_length)
+
+    def latent_chunk(self, positions: int) -> int:
+        """What the step's latent reader rounds a row's places up to
+        (``slot_attention.latent_places``' chunk)."""
+        cfg = self.cfg
+        return latent_reach(
+            positions, cfg.num_attention_heads, cfg.latent_width,
+            cfg.kv_lora_rank, self.block_length)
+
+    def describe(self, slots: int, positions: int) -> Description:
+        """Every layer's cache is one latent row a position: the places a
+        layer's latent reader moves for a row of each length (whole chunks
+        of the kernel's, every position where the einsum reads), the bytes
+        of the rows the live rows' attention read, as stored, and of those
+        the slots hold.  A step runs the absorbed form; a prompt makes
+        every head's keys and values of its own rows."""
+        base = super().describe(slots, positions)
+        chunk, row_bytes = self.latent_chunk(positions), \
+            self.cfg.latent_cache_bytes
+        return dataclasses.replace(
+            base, static=dict(base.static, latent_layers=self.layers,
+                              mla_form="absorbed"),
+            rows=[(latent_places(n, chunk), kv)
+                  for n, (_, kv) in enumerate(base.rows)],
+            closed=lambda g: dict(base.closed(g), latent_cache_bytes=(
+                row_bytes(g["kv_positions"]))),
+            resident={"sonata_mla_cache_resident_bytes":
+                      row_bytes(slots * positions)},
+            prefill=lambda text_bucket: {"mla_form": "expanded"})
+
+    build_step, build_prefill, build_step_admit = token_step_programs(
+        sys.modules[__name__], "pangu")

@@ -48,11 +48,13 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..ops.slot_attention import slot_attention, stored_shape, write_rows, \
     write_slot
-from .lfm2 import BF16, F32, UnitIds, _head, _qkv, attn_op_seq, choose, \
-    mm, moe_ffn, rms_norm, step_key
+from .unit_backbone import Backbone, RowPlan, routes_of
+from .unit_layers import BF16, F32, UnitIds, _head, _qkv, attn_op_seq, \
+    choose, mm, moe_ffn, rms_norm, step_key
 
 Params = dict
 
@@ -304,3 +306,110 @@ def block_pass(params: Params, cache: dict, live, temperature, step_no, *,
     cache["pass"] = jnp.where(live, jnp.where(commit, 0, pass_no + 1),
                               pass_no)
     return cache, (x, logits, chose), jnp.stack(loads)
+
+
+class SdarBackbone(Backbone):
+    """``sdar_moe``: the prefill keeps the prompt's whole blocks, and every
+    ``denoising_steps + 1`` passes give every live row a block of units."""
+
+    pack_layer = staticmethod(pack_layer)
+    #: no step of this backbone carries an arrival (a pass keeps a phase a
+    #: slot): a row's prompt runs apart, in ``sdar_prefill``
+    build_step_admit = None
+    #: a flagged row's logits are ``[B, V]`` a pass: every sixteenth block
+    DUMP_EVERY = 16
+
+    def __init__(self, backbone: dict, units: dict, seed: int):
+        self.cfg = SdarConfig.from_dict(backbone)
+        self.units = UnitIds(int(units["first_id"]), int(units["stop_id"]),
+                             int(units["mask_id"]))
+        self.schedule = Schedule(
+            int(units["block_length"]),
+            int(units.get("denoising_steps", 4)), self.units.mask_id)
+        self.block_length = self.schedule.block_length
+        self.denoising_steps = self.schedule.denoising_steps
+        self.layers = self.attention_layers = self.cfg.num_hidden_layers
+        self.seed = seed
+
+    def new_cache(self, slots: int, positions: int) -> dict:
+        return new_cache(self.cfg, slots, positions)
+
+    def _blocks(self, n_ids: int, budget: int) -> int:
+        """Blocks a row generates: the first one opens with the prompt's
+        last ``n mod B`` ids, the last one may run past the budget."""
+        b = self.block_length
+        return -(-(n_ids % b + budget) // b)
+
+    def positions_needed(self, n_ids: int, budget: int) -> int:
+        b = self.block_length
+        return n_ids // b * b + self._blocks(n_ids, budget) * b
+
+    def plan(self, n_ids: int, budget: int) -> RowPlan:
+        b = self.block_length
+        return RowPlan(
+            launches=self._blocks(n_ids, budget) * self.schedule.passes,
+            budget=budget, block=b, passes=self.schedule.passes,
+            first_units=-(n_ids % b), first_attended=n_ids // b * b + b)
+
+    def dumped(self, plan: RowPlan, done: int) -> bool:
+        """Whether a flagged row keeps what launch number ``done`` gave:
+        every pass of its first block, of its last, and of every
+        :data:`DUMP_EVERY`-th between."""
+        block = done // plan.passes
+        return block % self.DUMP_EVERY == 0 \
+            or block == (plan.launches - 1) // plan.passes
+
+    def build_step(self):
+        cfg, schedule, units, seed = (self.cfg, self.schedule, self.units,
+                                      self.seed)
+
+        def sdar_pass(params, cache, live, temperature, step_no):
+            return block_pass(params, cache, live, temperature, step_no,
+                                   cfg=cfg, schedule=schedule, units=units,
+                                   seed=seed)
+
+        return jax.jit(sdar_pass, donate_argnums=(1,))
+
+    def build_prefill(self):
+        cfg, schedule = self.cfg, self.schedule
+
+        def sdar_prefill(params, cache, ids, n, slot, temperature, row_no):
+            cache, load = prefill(params, cache, ids, n, slot, cfg=cfg,
+                                       schedule=schedule)
+            return cache, None, load
+
+        return jax.jit(sdar_prefill, donate_argnums=(1,))
+
+    def units_of(self, cache, n_ids: int) -> tuple:
+        return cache["tokens"], n_ids
+
+    def take(self, kept: tuple, rows) -> tuple:
+        """A pass leaves its logits ``[S * B, V]`` as the head wrote them:
+        a slot's are the ``B`` rows from ``slot * B`` on, cut here to the
+        ``[B, V]`` a flagged row keeps."""
+        x, logits, chose = kept
+        b = self.block_length
+        return (x[rows], logits[rows[:, None] * b + jnp.arange(b)],
+                chose[rows])
+
+    def record(self, cache, slot: int) -> tuple:
+        return (cache["tokens"][slot], cache["routes"][slot],
+                cache["unmasked_at"][slot])
+
+    def dump(self, ids: list, budget: int, kept: list, record) -> dict:
+        """The row's tokens as committed (prompt, units, the last block's
+        surplus), the experts every position chose in its commit pass, the
+        pass at which every position was unmasked, and for the launches of
+        ``passes`` the block as it went in, its float32 logits ``[B, V]``
+        and the experts it chose."""
+        tokens, routes, unmasked_at = record
+        t = self.positions_needed(len(ids), budget)
+        return {"tokens": tokens[:t],
+                "routes": routes_of(self.cfg, routes)[:t],
+                "unmasked_at": unmasked_at[:t],
+                "passes": np.asarray([d for d, _ in kept], np.int32),
+                "seen": np.stack([a[0] for _, a in kept]),
+                "logits": np.stack([a[1] for _, a in kept]),
+                "pass_routes": np.stack([a[2] for _, a in kept]),
+                "block_length": np.int32(self.block_length),
+                "denoising_steps": np.int32(self.denoising_steps)}

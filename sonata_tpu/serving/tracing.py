@@ -705,20 +705,26 @@ EXPERT_MATMULS = ("grouped", "ragged_dot")
 #: what reads the slots' keys and values in a step program, and a prompt's
 #: own in a prefill program (a span's ``attention``)
 ATTENTION_IMPLS = ("slot_kernel", "einsum")
+#: the gauges of what the slots of step loops hold on the device, as
+#: ``/metrics`` renders them: a loop's engine says its bytes by these names
+RESIDENT_SERIES = ("sonata_ssm_state_resident_bytes",
+                   "sonata_mla_cache_resident_bytes",
+                   'sonata_attn_cache_resident_bytes{kind="full"}',
+                   'sonata_attn_cache_resident_bytes{kind="ring"}')
 
 
 def held_load(load) -> tuple:
-    """Of one expert layer's load (``lfm2.moe_ffn``): the distinct experts
-    chosen among those the chip holds and the assignments that fell on
-    them; a layer that holds every expert reports neither apart."""
+    """Of one expert layer's load (``unit_layers.moe_ffn``): the distinct
+    experts chosen among those the chip holds and the assignments that fell
+    on them; a layer that holds every expert reports neither apart."""
     return (load[3], load[4]) if len(load) > 3 else (load[0], load[2])
 
 
 def held_overflow(loads) -> bool:
     """Whether, in a launch of these loads (one an expert layer), a thin
     share's held experts got more rows than the program's short path
-    takes in any layer (``lfm2.moe_ffn``'s sixth number; a layer that is
-    not thin states none)."""
+    takes in any layer (``unit_layers.moe_ffn``'s sixth number; a layer that
+    is not thin states none)."""
     return any(len(load) > 5 and int(load[5]) for load in loads)
 
 
@@ -746,11 +752,10 @@ class StepStats:
         #: steps, the fullest expert's assignments summed over steps, the
         #: assignments that fell on experts the chip holds
         self.moe: dict = {}
-        #: bytes of recurrent state the loops' slots hold (all slots)
-        self.ssm_state_resident_bytes = 0
-        #: bytes of latent rows (latent attention's keys and values at
-        #: once) the loops' slots hold (all slots, all positions)
-        self.mla_cache_resident_bytes = 0
+        #: bytes the loops' slots hold on the device while they live, by the
+        #: series that exports them (a loop's engine says which, and how
+        #: many: :meth:`record_resident`)
+        self.resident = dict.fromkeys(RESIDENT_SERIES, 0)
         #: places a layer's latent reader moved for the live rows (whole
         #: chunks up to a row's length; every position under the einsum)
         self.mla_places_fetched = 0
@@ -758,10 +763,6 @@ class StepStats:
         #: rows, over the layers that keep such (whole chunks up to a row's
         #: length, a ring's window at most; every place under the einsum)
         self.kv_places_fetched = 0
-        #: bytes of keys and values the loops' slots hold in a backbone
-        #: with window layers: in layers that keep every position, and in
-        #: rings of ``window`` places
-        self.attn_cache_resident_bytes = {"full": 0, "ring": 0}
         #: live rows' steps whose position was at or past the window
         self.window_bound_row_steps = 0
         #: step launches that took the expert layer's full-length path
@@ -851,16 +852,13 @@ class StepStats:
         if new and self._registry is not None:
             self._bind_layers(new)
 
-    def record_resident(self, state_bytes: int, latent_bytes: int = 0,
-                        full_bytes: int = 0, ring_bytes: int = 0) -> None:
-        """A loop's slots were made (or, negative, let go): their recurrent
-        state, their latent rows and, of a backbone with window layers,
-        their keys and values in full layers and in rings."""
+    def record_resident(self, held: dict, let_go: bool = False) -> None:
+        """A loop's slots were made (or let go): the bytes they hold, by
+        the series that exports them."""
         with self._lock:
-            self.ssm_state_resident_bytes += state_bytes
-            self.mla_cache_resident_bytes += latent_bytes
-            self.attn_cache_resident_bytes["full"] += full_bytes
-            self.attn_cache_resident_bytes["ring"] += ring_bytes
+            for series, held_bytes in held.items():
+                self.resident[series] = self.resident.get(series, 0) + (
+                    -held_bytes if let_go else held_bytes)
 
     def record_retired(self) -> None:
         with self._lock:
@@ -961,14 +959,16 @@ class StepStats:
             "step-wise generation loops hold on the device (every slot, "
             "live or not: such state does not grow with a row; 0 for a "
             "backbone that has none)."
-        ).set_function(lambda: float(self.ssm_state_resident_bytes))
+        ).set_function(lambda: float(
+            self.resident["sonata_ssm_state_resident_bytes"]))
         registry.gauge(
             "sonata_mla_cache_resident_bytes",
             "Bytes of latent rows the slots of step-wise generation loops "
             "hold on the device (latent attention: one row a position and "
             "layer, keys and values at once, in whole lanes; every slot and "
             "position, live or not; 0 for a backbone that has none)."
-        ).set_function(lambda: float(self.mla_cache_resident_bytes))
+        ).set_function(lambda: float(
+            self.resident["sonata_mla_cache_resident_bytes"]))
         registry.counter(
             "sonata_mla_places_fetched_total",
             "Places a layer's latent reader moved for the live rows of "
@@ -999,7 +999,8 @@ class StepStats:
             "place p mod window); 0 for a backbone without window layers.")
         for kind in ("full", "ring"):
             resident.labels(kind=kind).set_function(
-                lambda k=kind: float(self.attn_cache_resident_bytes[k]))
+                lambda k=kind: float(self.resident[
+                    'sonata_attn_cache_resident_bytes{kind="%s"}' % k]))
         registry.counter(
             "sonata_attn_window_bound_row_steps_total",
             "Live rows' steps whose position was at or past the window of "

@@ -41,62 +41,39 @@ in, belongs to the engine (``new_cache``).
 **Tracing.**  A step serves every live row, so its span belongs to no one
 request: the loop records its steps on a trace it owns (``ar-steps``,
 closed every few seconds), one ``dispatch`` span per group of
-:data:`STEP_GROUP` steps with ``kind: step`` and the group's sums (``steps``,
-``live_slot_steps`` (slots that held a row in a launch: the rows that
-stepped and the row whose prompt it carried), ``slots``, ``units`` the live rows were left with,
-``positions`` they ran, ``denoise_row_passes`` and ``commit_row_passes``
-(a row's launch that finishes a block commits), ``kv_positions`` the live
-rows attended over, ``admit_steps`` launches that carried an arrival and
-``prompt_tokens`` they carried, ``ssm_state_bytes`` of recurrent state the live rows'
-steps read and wrote, per expert layer ``assignments``, ``experts_touched``,
-``max_expert_assignments`` and, of the experts the chip holds,
-``held_assignments`` and ``held_experts_touched``, ``held_overflow_steps``
-(launches in which a thin share's held experts got more rows than the
-program's short path takes, so that it took the full-length one: none is
-ever left out), ``latent_cache_bytes`` of latent rows the live rows'
-attention read (``kv_positions`` rows a layer, as stored),
-``latent_places_fetched`` the places a layer's latent reader moved for them
-(a row's ``kv_positions`` in whole chunks of the kernel's, every position
-where the einsum reads: ``kv_positions`` over it is the share of what was
-moved that a row held), ``kv_places_fetched`` the places the reader of
-keys and values a head moved for them, summed over the engine's layers that
-keep such (whole chunks of the kernel's a row, a ring read no further than
-its window; every place of a layer's buffer where the einsum reads: a row's
-``kv_positions`` in those layers over it is the share of what was moved
-that a row held), for an engine with window layers
-``kv_cache_bytes`` (keys and values the live rows' steps read as held: a
-row's ``kv_positions`` a full layer, capped at the window a ring layer) and
-``window_bound_row_steps`` (row-steps whose position is at or past the
-window: the ring has wrapped and the band binds), ``host_ms`` by phase,
-and of the loop's turns: ``wall_ms`` and, beside ``host_ms``'s three,
+:data:`STEP_GROUP` steps with ``kind: step`` (``docs/DEPLOY.md``, "Unit
+voices", says what each attribute means).  The loop's own: ``steps``,
+``slots``, the sums of :data:`ROW_SUMS` (``live_slot_steps`` counts the row
+whose prompt a launch carried too), ``admit_steps``, ``prompt_tokens`` and
+``arrivals``; per expert layer ``assignments``, ``experts_touched``,
+``max_expert_assignments``, ``held_assignments`` and
+``held_experts_touched``, and ``held_overflow_steps``; of its turns
+``host_ms`` by phase, ``wall_ms`` and, beside ``host_ms``'s three,
 ``device_wait_ms`` (blocked until the step before had run), ``record_ms``
 (the rest of settling it) and ``other_ms`` (the turn less its phases), which
 sum to ``wall_ms``; of the longest turn ``turn_ms_max``, ``turn_max_phase``
-(which phase held most of it) and ``turn_max_step``; ``arrivals`` (rows
-admitted); ``compile_ms`` and ``compiled`` where a program compiled on the
-loop's thread outside a prefill's or a vocoder's launch)
-beside the engine's ``block_length``, ``denoising_steps``, ``ssm_layers``,
-``latent_layers`` (layers whose cache is one latent row a position) with
-``mla_form`` (``absorbed``: what a step's latent attention runs; a prefill
-span says ``expanded``), ``full_layers``, ``window_layers`` and ``window``
-(layers that keep every position of a slot beside layers that keep a ring
-of ``window`` places; a prefill span says ``window_layers``), and
-``expert_matmul`` (``grouped`` | ``ragged_dot``: what the step program's
-expert products run) and ``attention`` (``slot_kernel`` | ``einsum``: what
-reads the slots' keys and values).  Each
-row admitted (``admit``: ``step``, with the ``step_no`` that carried it, or
+and ``turn_max_step``; ``compile_ms`` and ``compiled`` where a program
+compiled on the loop's thread outside a prefill's or a vocoder's launch; and
+the engine's ``block_length``, ``denoising_steps``, ``expert_matmul`` and
+``attention``.  **What the slots' cache is, the loop does not know**: the
+engine's ``description``
+(:class:`~sonata_tpu.models.unit_backbone.Description`) brings the
+attributes a group's span carries as they are (``static``), the sums a
+launch adds row by row beyond the loop's own (``row_sums``, and for a row
+that attends over ``attended`` positions ``rows[attended]``: one look-up a
+row), what a closed group derives from its sums (``closed(group)``), the
+bytes the slots hold while the loop lives, by the series that exports them
+(``resident``), and what a prefill span says beyond its shape
+(``prefill(text_bucket)``, which the engine puts into ``shape``).  Each row
+admitted (``admit``: ``step``, with the ``step_no`` that carried it, or
 ``apart``; ``blocks`` of the prompt kept whole, ``tail_ids`` left to the
-first generated block, ``expert_matmul`` and ``attention`` of its own
-program, ``ssm_chunks``
-its state-space layers' scans ran) and each vocoder launch is a ``dispatch`` span (``kind: prefill`` |
-``vocode``) in the trace of the request the row belongs to; both end when
-what their program produced is on the host (a prefill's load, a row's
-samples), and a vocoder's says what the row needed and what it was padded
-to (``frames_needed``, ``frames_bucket``) and what the finisher thread
-spent on it (``fetch_wait_ms`` until the program had run, ``finish_ms`` of
-its own work after).  A prefill's and a vocoder's ``compile`` (``cold`` |
-``cached``, with ``compile_ms`` and ``compiled`` where anything compiled) is
-the engine's to say (``shape``); a compile after the warm-up counts against
+first generated block) and each vocoder launch is a ``dispatch`` span
+(``kind: prefill`` | ``vocode``) in the trace of the request the row
+belongs to; both end when what their program produced is on the host, and
+a vocoder's says what the finisher thread spent on it (``fetch_wait_ms``
+until the program had run, ``finish_ms`` of its own work after).  A
+prefill's and a vocoder's ``compile`` (``cold`` | ``cached``) is the
+engine's to say (``shape``); a compile after the warm-up counts against
 the voice (``scope.note_runtime_compile``).  The always-on counters are
 :class:`~sonata_tpu.serving.tracing.StepStats`.  While ``/debug/profile``
 holds a capture the loop's phases are mirrored into it as ``sonata:admit |
@@ -104,18 +81,13 @@ launch | retire | settle`` with ``step_no``.
 
 The engine (a voice: :class:`~sonata_tpu.models.unit_voice.UnitVoice`)
 gives ``slots``, ``expert_layers``, ``block_length``, ``denoising_steps``,
-``expert_matmul``, ``attention``, ``ssm_layers``, ``ssm_state_bytes`` (a
-slot's), where it has latent attention ``latent_layers``, ``mla_form``,
-``latent_cache_bytes(positions)`` and ``latent_places(attended)``, where it
-keeps keys and values a head ``kv_places_fetched(attended)``, where it
-has window layers ``full_layers``, ``window_layers``, ``window``,
-``kv_cache_bytes(attended)`` and ``cache_resident_bytes()``,
-``new_cache()``, ``plan(n_ids, budget)``, ``prefill(cache, slot, ids,
-temperature)``, ``step(cache, live, temperature, step_no)``, where its step
-carries arrivals ``carries(n_ids)`` and ``step_admit(cache, live,
-temperature, step_no, slot, ids, row_temperature)``, ``vocode(cache,
-slot, n_ids, units)``, ``wait_audio(out)``, ``fetch_audio(out, units)`` and,
-for flagged rows, ``dumped(plan, done)`` (which launches a row keeps),
+``expert_matmul``, ``attention``, ``description``, ``new_cache()``,
+``plan(n_ids, budget)``, ``prefill(cache, slot, ids, temperature)``,
+``step(cache, live, temperature, step_no)``, where its step carries
+arrivals ``carries(n_ids)`` and ``step_admit(cache, live, temperature,
+step_no, slot, ids, row_temperature)``, ``vocode(cache, slot, n_ids,
+units)``, ``wait_audio(out)``, ``fetch_audio(out, units)`` and, for flagged
+rows, ``dumped(plan, done)`` (which launches a row keeps),
 ``row_record(cache, slot)``, ``take_rows(kept, rows)`` and ``dump(ids,
 budget, kept, record)``.
 """
@@ -146,12 +118,10 @@ TRACE_SECONDS = 4.0
 #: rows whose launch one gather program takes
 DUMP_ROWS = 8
 
-#: what a launch adds to its group's sums, row by row
+#: what a launch adds to its group's sums, row by row (the engine's
+#: description brings the rest)
 ROW_SUMS = ("live_slot_steps", "units", "positions", "denoise_row_passes",
-            "commit_row_passes", "kv_positions", "latent_places_fetched",
-            "kv_places_fetched")
-#: and row by row too, where the engine has window layers
-WINDOW_SUMS = ("kv_cache_bytes", "window_bound_row_steps")
+            "commit_row_passes", "kv_positions")
 
 DUMP_DIR_ENV = "SONATA_AR_DUMP_DIR"
 DUMP_PREFIX_ENV = "SONATA_AR_DUMP_RID_PREFIX"
@@ -224,28 +194,11 @@ class StepLoop:
         self.name = name
         self.slots = SlotTable(engine.slots)
         self.stats = tracing.step_stats()
-        #: bytes of ``positions`` latent rows over the engine's layers (an
-        #: engine without latent attention: none)
-        self._latent_bytes = getattr(engine, "latent_cache_bytes",
-                                     lambda positions: 0)
-        #: the places its latent reader moves for a row that attends over
-        #: so many
-        self._latent_places = getattr(engine, "latent_places",
-                                      lambda attended: 0)
-        #: the places its reader of keys and values a head moves for such
-        #: a row, over its layers
-        self._kv_places = getattr(engine, "kv_places_fetched",
-                                  lambda attended: 0)
-        #: the ring's places where the engine has window layers, else 0
-        self._window = (getattr(engine, "window", 0)
-                        if getattr(engine, "window_layers", 0) else 0)
-        self._row_sums = ROW_SUMS + (WINDOW_SUMS if self._window else ())
-        self._resident = (
-            engine.slots * engine.ssm_state_bytes,
-            self._latent_bytes(engine.slots * getattr(engine, "positions",
-                                                      0)),
-            *(engine.cache_resident_bytes() if self._window else (0, 0)))
-        self.stats.record_resident(*self._resident)
+        #: what the slots' cache is: the engine's to say
+        self.description = engine.description
+        self._row_sums = ROW_SUMS + self.description.row_sums
+        self._resident = self.description.resident
+        self.stats.record_resident(self._resident)
         self.layers = list(engine.expert_layers)
         dump_dir = os.environ.get(DUMP_DIR_ENV)
         self._dump_dir = Path(dump_dir) if dump_dir else None
@@ -303,8 +256,8 @@ class StepLoop:
         with self._cond:
             self._closed = True
             self._cond.notify_all()
-            resident, self._resident = self._resident, (0, 0, 0, 0)
-        self.stats.record_resident(*(-b for b in resident))
+            resident, self._resident = self._resident, {}
+        self.stats.record_resident(resident, let_go=True)
         self._thread.join(timeout=30.0)
         with self._finish_cond:
             self._finish_cond.notify_all()
@@ -376,6 +329,7 @@ class StepLoop:
             live = np.zeros((engine.slots,), bool)
             temperature = np.zeros((engine.slots,), np.float32)
             sums = dict.fromkeys(self._row_sums, 0)
+            described, looked = self.description.rows, []
             for row in rows:
                 live[row.slot] = True
                 temperature[row.slot] = row.temperature
@@ -384,17 +338,13 @@ class StepLoop:
                 sums["positions"] += row.plan.block
                 attended = row.plan.attended(row.done)
                 sums["kv_positions"] += attended
-                sums["latent_places_fetched"] += self._latent_places(attended)
-                sums["kv_places_fetched"] += self._kv_places(attended)
-                if self._window:
-                    sums["kv_cache_bytes"] += engine.kv_cache_bytes(attended)
-                    # its position is the last it attends over
-                    sums["window_bound_row_steps"] += \
-                        attended > self._window
+                looked.append(described[attended])
                 sums["commit_row_passes"] += commits
                 sums["denoise_row_passes"] += not commits
                 sums["units"] += row.plan.units(row.done + 1) \
                     - row.plan.units(row.done)
+            sums.update(zip(self.description.row_sums,
+                            map(sum, zip(*looked))))
             # the slot of the row this launch carries holds a row too
             sums["live_slot_steps"] += carried is not None
             launched = time.monotonic()
@@ -609,17 +559,7 @@ class StepLoop:
                  denoising_steps=self.engine.denoising_steps,
                  expert_matmul=self.engine.expert_matmul,
                  attention=self.engine.attention,
-                 ssm_layers=self.engine.ssm_layers,
-                 ssm_state_bytes=(2 * self.engine.ssm_state_bytes
-                                  * g["live_slot_steps"]),
-                 latent_layers=getattr(self.engine, "latent_layers", 0),
-                 latent_cache_bytes=self._latent_bytes(g["kv_positions"]))
-        if getattr(self.engine, "mla_form", None):
-            g["mla_form"] = self.engine.mla_form
-        if self._window:
-            g.update(full_layers=self.engine.full_layers,
-                     window_layers=self.engine.window_layers,
-                     window=self._window)
+                 **self.description.static, **self.description.closed(g))
         self.stats.record_steps(g)
         if self._trace is None:
             self._trace = tracing.default_tracer().start_trace(

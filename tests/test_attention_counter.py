@@ -10,6 +10,7 @@ Since PR 45 a step group also states ``kv_places_fetched``, the places that
 reader moved for the live rows over the layers that keep keys and values,
 and ``sonata_kv_places_fetched_total`` sums it."""
 
+import dataclasses
 import functools
 import importlib
 import json
@@ -18,10 +19,11 @@ from pathlib import Path
 import pytest
 
 from perfbench.harness import lfm2gen, sdargen
-from sonata_tpu.models import from_config_path, unit_voice
+from sonata_tpu.models import from_config_path
 from sonata_tpu.models.config import SynthesisConfig
 from sonata_tpu.serving import tracing
 from sonata_tpu.serving.metrics import MetricsRegistry
+from tests.voices import row_sums
 
 sa = importlib.import_module("sonata_tpu.ops.slot_attention")
 DATA = Path(__file__).resolve().parent / "perfbench/data"
@@ -55,13 +57,20 @@ def test_spans_and_series_say_what_the_attention_ran(
             sa.slot_attention_kernel, interpret=True))
     monkeypatch.setenv("SONATA_AR_SLOTS", "2")
     monkeypatch.setenv("SONATA_AR_POSITIONS", "256")
-    # the lengths the loop asks the voice's counting function about
-    asked, counting = [], unit_voice.UnitVoice.kv_places_fetched
-    monkeypatch.setattr(
-        unit_voice.UnitVoice, "kv_places_fetched",
-        lambda self, attended: asked.append(attended) or counting(
-            self, attended))
     voice = from_config_path(writer.write_tensors(tmp_path, config))
+    # the lengths the loop looks up in the description's table
+
+    class Asked(list):
+        def __getitem__(self, attended):
+            asked.append(attended)
+            return super().__getitem__(attended)
+
+    asked, described = [], voice.description
+    voice.description = dataclasses.replace(described,
+                                            rows=Asked(described.rows))
+
+    def counting(attended):
+        return row_sums(described, attended)["kv_places_fetched"]
     voice.set_fallback_synthesis_config(SynthesisConfig(noise_scale=0.0))
     registry = MetricsRegistry()
     stats = tracing.step_stats()
@@ -102,11 +111,11 @@ def test_spans_and_series_say_what_the_attention_ran(
     # 128 a row under the kernel, all 256 of a slot under the einsum
     layers = voice.backbone.attention_layers
     chunk = 128 if impl == "slot_kernel" else 256
-    assert layers > 0 and [counting(voice, n) for n in (0, 1, 128, 129)] == [
+    assert layers > 0 and [counting(n) for n in (0, 1, 128, 129)] == [
         0, layers * chunk, layers * chunk, layers * 256]
     fetched = sum(g["kv_places_fetched"] for g in groups)
     assert sum(asked) == sum(g["kv_positions"] for g in groups)
-    assert fetched == sum(counting(voice, n) for n in asked) \
+    assert fetched == sum(counting(n) for n in asked) \
         >= layers * sum(asked) > 0
     assert stats.kv_places_fetched == fetched_before + fetched
     assert f"sonata_kv_places_fetched_total {stats.kv_places_fetched}\n" \

@@ -28,7 +28,7 @@ import numpy as np
 import pytest
 
 from perfbench.harness import lfm2gen
-from sonata_tpu.models import unit_voice
+from sonata_tpu.models import unit_backbone, unit_voice
 from test_grouped_matmul import STEP_SHAPES
 from test_slot_attention import GEOMETRIES
 from tools.profile_start import inner_jaxprs
@@ -294,7 +294,8 @@ def lowered_once_a_geometry(program, args, name: str):
     return lowered
 #: the cells whose chip holds a thin share of each layer's experts
 THIN = ("pangu_step", "laguna_step")
-#: the fusions that read the head's ``f32[N, V]`` (``lfm2.choose``: the
+#: the fusions that read the head's ``f32[N, V]`` (``unit_layers.choose``:
+#: the
 #: choice; the sum of exponentials where the log-probability is used)
 LOGITS_READ = {"sdar_pass": 2, "nemotron_step": 1}
 
@@ -310,8 +311,8 @@ def test_a_step_compiled_for_a_v5e_copies_no_slot_buffer_whole(
     monkeypatch.setattr(gm, "_tiles_here", gm.tile_rule)
     monkeypatch.setattr(sa, "_tiles_here", sa.tile_rule)
     monkeypatch.setattr(sa, "_latent_tiles_here", sa.latent_tile_rule)
-    monkeypatch.setattr(unit_voice, "_layers_once_here",
-                        lambda: unit_voice.LAYERS_ONCE)
+    monkeypatch.setattr(unit_backbone, "_layers_once_here",
+                        lambda: unit_backbone.LAYERS_ONCE)
     backbone, cache, args = step_shapes(name, one_chip)
     per_place = [int(np.prod(a.shape)) for a in (
         cache["routes"], *cache.get("k", ()), *cache.get("v", ()),
@@ -361,8 +362,8 @@ def test_a_carrying_step_compiled_for_a_v5e_copies_no_slot_buffer_whole(
     monkeypatch.setattr(gm, "_tiles_here", gm.tile_rule)
     monkeypatch.setattr(sa, "_tiles_here", sa.tile_rule)
     monkeypatch.setattr(sa, "_latent_tiles_here", sa.latent_tile_rule)
-    monkeypatch.setattr(unit_voice, "_layers_once_here",
-                        lambda: unit_voice.LAYERS_ONCE)
+    monkeypatch.setattr(unit_backbone, "_layers_once_here",
+                        lambda: unit_backbone.LAYERS_ONCE)
     backbone, cache, args = step_shapes(name, one_chip)
     arrival = (jax.ShapeDtypeStruct((192,), jnp.int32),
                *(jax.ShapeDtypeStruct((), t) for t in (

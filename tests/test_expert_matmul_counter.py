@@ -2,7 +2,7 @@
 and every prefill span states ``expert_matmul``, and
 ``sonata_moe_expert_matmul_total{impl, program}`` counts the launches.  On
 the CPU a program runs ``ragged_dot``; a voice whose programs run this
-repo's kernel is made here by steering the two names ``lfm2`` reads (the
+repo's kernel is made here by steering the two names ``unit_layers`` reads (the
 decision and the product, the kernel in interpret mode), not by an option
 of the program."""
 
@@ -13,7 +13,7 @@ from pathlib import Path
 import pytest
 
 from perfbench.harness import lfm2gen, pangugen, sdargen
-from sonata_tpu.models import from_config_path, lfm2
+from sonata_tpu.models import from_config_path, unit_layers
 from sonata_tpu.models.config import SynthesisConfig
 from sonata_tpu.serving import tracing
 from sonata_tpu.serving.metrics import MetricsRegistry
@@ -42,10 +42,10 @@ def series(registry) -> dict:
 def test_spans_and_series_say_what_the_expert_products_ran(
         tiny, writer, impl, tmp_path, monkeypatch):
     if impl == "grouped":
-        monkeypatch.setattr(lfm2, "implementation",
+        monkeypatch.setattr(unit_layers, "implementation",
                             lambda *shape: "grouped")
         monkeypatch.setattr(
-            lfm2, "grouped_matmul", lambda x, w, sizes, **kw:
+            unit_layers, "grouped_matmul", lambda x, w, sizes, **kw:
             gm.grouped_matmul_kernel(x, w, sizes, gm.Tiles(16, w.shape[2]),
                                      interpret=True, **kw))
     monkeypatch.setenv("SONATA_AR_SLOTS", "2")
@@ -117,13 +117,13 @@ def test_what_a_program_says_of_a_thin_share_at_the_cells_shapes(
                                 "mask_id": 300, "block_length": 4})
     cfg, tokens = built.cfg, slots * built.block_length
     assert built.held == held_of
-    assert lfm2.held_rows(cfg, tokens, built.held) == step
+    assert unit_layers.held_rows(cfg, tokens, built.held) == step
     assert (step == tokens * cfg.num_experts_per_tok) == siblings
     monkeypatch.setattr(gm, "_tiles_here", gm.tile_rule)
-    assert lfm2.expert_matmul(cfg, tokens, built.held) == "grouped"
+    assert unit_layers.expert_matmul(cfg, tokens, built.held) == "grouped"
     if not siblings:
         # told nothing, the layer would hand all 2048 rows to ragged_dot
         assert gm.tile_rule(tokens * cfg.num_experts_per_tok, 8, 7680, 4096,
-                            lfm2.BF16) is None
-        assert {lfm2.expert_matmul(cfg, slots + t, built.held)
+                            unit_layers.BF16) is None
+        assert {unit_layers.expert_matmul(cfg, slots + t, built.held)
                 for t in TEXT_BUCKETS} == {"grouped"}

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 from jax import lax
 
-from sonata_tpu.models import lfm2
+from sonata_tpu.models import lfm2, unit_layers
 
 gm = importlib.import_module("sonata_tpu.ops.grouped_matmul")
 Tiles = gm.Tiles
@@ -106,10 +106,10 @@ def test_moe_ffn_over_the_kernel_is_moe_ffn_over_ragged_dot(monkeypatch,
     u = jnp.asarray(np.random.default_rng(6).standard_normal((24, 128)),
                     jnp.float32)
     valid = jnp.arange(24) < 19
-    want = lfm2.moe_ffn(u, p, cfg, held, valid)
-    monkeypatch.setattr(lfm2, "grouped_matmul", functools.partial(
+    want = unit_layers.moe_ffn(u, p, cfg, held, valid)
+    monkeypatch.setattr(unit_layers, "grouped_matmul", functools.partial(
         gm.grouped_matmul_kernel, tiles=Tiles(16, 128), interpret=True))
-    got = lfm2.moe_ffn(u, p, cfg, held, valid)
+    got = unit_layers.moe_ffn(u, p, cfg, held, valid)
     assert np.all(np.isfinite(np.asarray(got[0])))
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
                                rtol=1e-5, atol=1e-5)

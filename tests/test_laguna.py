@@ -20,12 +20,14 @@ import numpy as np
 import pytest
 
 from perfbench.harness import lagunagen, parts
-from sonata_tpu.models import from_config_path, laguna, lfm2, unit_voice
+from sonata_tpu.models import from_config_path, laguna, unit_backbone, \
+    unit_layers, unit_voice
 from sonata_tpu.models.config import SynthesisConfig
-from sonata_tpu.models.unit_voice import routes_of
+from sonata_tpu.models.unit_backbone import routes_of
 from sonata_tpu.ops import slot_attention
 from sonata_tpu.serving import tracing
 from sonata_tpu.serving.metrics import MetricsRegistry
+from tests.voices import row_sums
 
 ROOT = Path(__file__).resolve().parent.parent
 DATA = ROOT / "tests/perfbench/data"
@@ -34,7 +36,7 @@ REAL = json.loads((ROOT / "perfbench/configs/laguna/laguna-xs.2.json")
                   .read_text())
 BB = lagunagen.backbone(CONFIG)
 CFG = laguna.LagunaConfig.from_dict(BB)
-UNITS = lfm2.UnitIds(256, 511)
+UNITS = unit_layers.UnitIds(256, 511)
 LAYERS = len(CFG.layer_types)
 #: name -> (prompt ids, text bucket, window): shorter than the window's
 #: bucket, a bucket the window holds, prompts longer than the window
@@ -60,7 +62,7 @@ def with_window(window: int):
 def float32_products(monkeypatch):
     """The program's products take bfloat16 inputs; here they take float32
     at ``highest``, so that it can be held to the reference to rounding."""
-    monkeypatch.setattr(lfm2, "BF16", jnp.float32)
+    monkeypatch.setattr(unit_layers, "BF16", jnp.float32)
     monkeypatch.setattr(laguna, "BF16", jnp.float32)
     with jax.default_matmul_precision("highest"):
         yield
@@ -334,7 +336,7 @@ def test_the_shares_add_up_to_the_uncut_layer_the_shared_expert_once(raw):
         share = dict(whole, **{k: whole[k][first:first + 2]
                                for k in ("w1", "w3", "w2")})
         packed = laguna.pack_layer(dict(raw[1], ffn=share))["ffn"]
-        got, took, load = lfm2.moe_ffn(u, packed, CFG, (first, 2))
+        got, took, load = unit_layers.moe_ffn(u, packed, CFG, (first, 2))
         part, _ = ref.experts(u, share, BB, held=(first, 2))
         np.testing.assert_allclose(np.asarray(got), np.asarray(part),
                                    rtol=0, atol=2e-5)
@@ -367,7 +369,7 @@ def test_the_configuration_is_read_as_the_module_says():
     assert (cfg.places(laguna.FULL, 1024), cfg.places(laguna.SLIDING, 1024),
             cfg.places(laguna.SLIDING, 300)) == (1024, 512, 300)
     # the thin path at an eighth: 2048 assignments bounded to 640 rows
-    assert lfm2.held_rows(cfg, 256, cfg.held) == 640
+    assert unit_layers.held_rows(cfg, 256, cfg.held) == 640
     # both geometries have chunks of 128 places: both run the kernel
     for places, g in ((1024, 6), (512, 8)):
         assert slot_attention.tile_rule(places, 8, g, 128, 1) == \
@@ -407,12 +409,14 @@ def test_the_cells_reader_is_counted_in_chunks_and_a_ring_at_its_window(
                                         REAL["voice"]["units"])
     reaches = backbone.kv_reaches(1024)
     assert sorted(reaches) == [(512, 128, 6), (1024, 128, 2)]
-    counted = functools.partial(unit_voice.places_fetched, reaches)
+    counted = functools.partial(unit_backbone.places_fetched, reaches)
     assert [counted(n) for n in (0, 1, 128, 129, 512, 513, 819, 1024)] == [
         0, 8 * 128, 8 * 128, 8 * 256, 8 * 512, 2 * 640 + 6 * 512,
         2 * 896 + 6 * 512, 2 * 1024 + 6 * 512]
+    described = backbone.describe(256, 1024)
     for n in (1, 347, 513, 1024):
-        held = backbone.kv_cache_bytes(n) // backbone.cfg.place_bytes
+        held = row_sums(described, n)["kv_cache_bytes"] \
+            // backbone.cfg.place_bytes
         assert held == 2 * n + 6 * min(n, 512) and 0 < held <= counted(n)
     # the einsum, off a TPU, reads every place of every buffer
     monkeypatch.undo()
@@ -435,22 +439,31 @@ def test_the_voice_runs_and_its_loop_says_what_the_two_caches_cost(
     tracer = tracing.default_tracer()
     tracer.clear()
     place = 2 * 2 * 128                 # 2 heads of 16 in 128 lanes, k and v
-    before = dict(stats.attn_cache_resident_bytes)
+    series = 'sonata_attn_cache_resident_bytes{kind="%s"}'
+    before = {kind: stats.resident[series % kind]
+              for kind in ("full", "ring")}
     bound_before = stats.window_bound_row_steps
     try:
         assert type(voice.backbone).__name__ == "LagunaBackbone"
-        assert (voice.full_layers, voice.window_layers, voice.window,
-                voice.attention, voice.expert_layers,
-                voice.expert_matmul) == (2, 3, 8, "einsum", [1, 2, 3, 4],
-                                         "ragged_dot")
-        assert voice.kv_cache_bytes(5) == place * 5 * 5
-        assert voice.kv_cache_bytes(30) == place * (2 * 30 + 3 * 8)
+        described = voice.description
+        assert (described.static, voice.attention, voice.expert_layers,
+                voice.expert_matmul) == (
+            {"ssm_layers": 0, "latent_layers": 0, "full_layers": 2,
+             "window_layers": 3, "window": 8}, "einsum", [1, 2, 3, 4],
+            "ragged_dot")
+        assert row_sums(described, 5)["kv_cache_bytes"] == place * 5 * 5
+        assert row_sums(described, 30)["kv_cache_bytes"] == place * (
+            2 * 30 + 3 * 8)
         # off a TPU the einsum moves every place of a layer's buffer: a
         # whole cache's 256, a ring's 8
-        assert [voice.kv_places_fetched(n) for n in (0, 5, 30)] == [
-            0, 2 * 256 + 3 * 8, 2 * 256 + 3 * 8]
-        assert voice.cache_resident_bytes() == (3 * place * 2 * 256,
-                                                3 * place * 3 * 8)
+        assert [row_sums(described, n)["kv_places_fetched"]
+                for n in (0, 5, 30)] == [0, 2 * 256 + 3 * 8, 2 * 256 + 3 * 8]
+        # a row's position is the last it attends over: bound from 9 on
+        assert [row_sums(described, n)["window_bound_row_steps"]
+                for n in (8, 9)] == [0, 1]
+        assert described.resident == {
+            series % "full": 3 * place * 2 * 256,
+            series % "ring": 3 * place * 3 * 8}
         assert ("step_admit", 32) in voice.lattice_shapes("full")
         with tracer.trace_request("test", request_id="row-0"):
             audio = voice.speak_batch(
@@ -460,14 +473,14 @@ def test_the_voice_runs_and_its_loop_says_what_the_two_caches_cost(
         assert len(audio[0].samples) == 16 * round(3.5 * len(ids))
         held = {"full": before["full"] + 3 * place * 2 * 256,
                 "ring": before["ring"] + 3 * place * 3 * 8}
-        assert stats.attn_cache_resident_bytes == held
+        assert {kind: stats.resident[series % kind] for kind in held} == held
         text = registry.render()
         for kind in ("full", "ring"):
             assert 'sonata_attn_cache_resident_bytes{kind="%s"} %d\n' % (
                 kind, held[kind]) in text
     finally:
         voice.close()
-    assert stats.attn_cache_resident_bytes == before
+    assert {kind: stats.resident[series % kind] for kind in before} == before
     traces = {t.request_id: t for t in tracer.recent_traces()}
     (prefill,) = [s.attrs for s in traces["row-0"].spans_snapshot()
                   if s.attrs.get("kind") == "prefill"]

@@ -13,14 +13,14 @@ import numpy as np
 import pytest
 
 from perfbench.harness import lfm2gen, parts
-from sonata_tpu.models import lfm2
-from sonata_tpu.models.unit_voice import routes_of
+from sonata_tpu.models import lfm2, unit_layers
+from sonata_tpu.models.unit_backbone import routes_of
 
 ROOT = Path(__file__).resolve().parent.parent
 CONFIG = json.loads((ROOT / "tests/perfbench/data/lfm2-tiny.json").read_text())
 BB = lfm2gen.backbone(CONFIG)
 CFG = lfm2.Lfm2Config.from_dict(BB)
-UNITS = lfm2.UnitIds(256, 511)
+UNITS = unit_layers.UnitIds(256, 511)
 ref = parts.load_file(ROOT / "perfbench/reference/lfm2_ref.py")
 
 
@@ -32,6 +32,7 @@ def wide(tree):
 def float32_products(monkeypatch):
     """The program's products take bfloat16 inputs; here they take float32
     at ``highest``, so that it can be held to the reference to rounding."""
+    monkeypatch.setattr(unit_layers, "BF16", jnp.float32)
     monkeypatch.setattr(lfm2, "BF16", jnp.float32)
     with jax.default_matmul_precision("highest"):
         yield
@@ -70,14 +71,15 @@ def test_each_layer_kind_matches_the_reference(kind, raw, params, u):
         b, _, x = jnp.split(u @ raw[0]["op"]["in_proj"], 3, axis=-1)
         close(state, (b * x)[-3:])
     elif kind == "attn_op":
-        got, k, v = lfm2.attn_op_seq(u, params["layers"][2]["op"], CFG)
+        got, k, v = unit_layers.attn_op_seq(u, params["layers"][2]["op"], CFG)
         close(got, ref.attn_op(u, raw[2]["op"], BB))
         assert k.shape == v.shape == (13, 2, 16)
     elif kind == "dense_ffn":
-        close(lfm2.dense_ffn(u, params["layers"][1]["ffn"]),
+        close(unit_layers.dense_ffn(u, params["layers"][1]["ffn"]),
               ref.dense_ffn(u, raw[1]["ffn"]))
     else:
-        got, chosen, load = lfm2.moe_ffn(u, params["layers"][3]["ffn"], CFG)
+        got, chosen, load = unit_layers.moe_ffn(
+            u, params["layers"][3]["ffn"], CFG)
         want, want_chosen = ref.moe_ffn(u, raw[3]["ffn"], BB)
         close(got, want)
         assert np.array_equal(np.asarray(chosen), np.asarray(want_chosen))
@@ -96,12 +98,12 @@ def test_the_whole_backbone_matches_the_reference_at_every_position(
     routes = []
     for i, kind in enumerate(CFG.layer_types):
         p = params["layers"][i]
-        un = lfm2.rms_norm(h, p["op_norm"], CFG.norm_eps)
+        un = unit_layers.rms_norm(h, p["op_norm"], CFG.norm_eps)
         h = h + (lfm2.conv_op_seq(un, p["op"], 21)[0] if kind == "conv"
-                 else lfm2.attn_op_seq(un, p["op"], CFG)[0])
+                 else unit_layers.attn_op_seq(un, p["op"], CFG)[0])
         h = lfm2._ffn_half(h, p, i, CFG, None, None, routes, [])
     assert np.array_equal(np.stack(routes, 1), np.asarray(want_routes))
-    close(lfm2._head(h, params, CFG), want, 5e-5)
+    close(unit_layers._head(h, params, CFG), want, 5e-5)
     assert want_routes.shape == (21, 4, 2)
 
 
@@ -207,7 +209,7 @@ def test_the_shares_add_up_to_the_uncut_reference_layer(shares, raw, u):
     for first, count in shares:
         packed = lfm2.pack_layer(dict(raw[4], ffn=held_layer(
             raw[4]["ffn"], first, count)))
-        part, chosen, load = lfm2.moe_ffn(u, packed["ffn"], CFG,
+        part, chosen, load = unit_layers.moe_ffn(u, packed["ffn"], CFG,
                                           held=(first, count))
         # every share routes over all the experts and says so
         assert np.array_equal(np.asarray(chosen), np.asarray(want_chosen))
@@ -217,7 +219,8 @@ def test_the_shares_add_up_to_the_uncut_reference_layer(shares, raw, u):
         total = total + part
     close(total, want)
     with pytest.raises(ValueError, match="held"):
-        lfm2.moe_ffn(u, lfm2.pack_layer(raw[4])["ffn"], CFG, held=(0, 2))
+        unit_layers.moe_ffn(u, lfm2.pack_layer(raw[4])["ffn"], CFG,
+                            held=(0, 2))
 
 
 def test_the_bias_changes_the_selection_but_not_the_weights(raw, u):
@@ -225,8 +228,8 @@ def test_the_bias_changes_the_selection_but_not_the_weights(raw, u):
     plain = dict(ffn, expert_bias=jnp.zeros_like(ffn["expert_bias"]))
     pushed = dict(ffn, expert_bias=jnp.zeros_like(
         ffn["expert_bias"]).at[5].set(10.0))
-    chosen_0, weights_0 = lfm2.route(u, plain, CFG)
-    chosen_1, weights_1 = lfm2.route(u, pushed, CFG)
+    chosen_0, weights_0 = unit_layers.route(u, plain, CFG)
+    chosen_1, weights_1 = unit_layers.route(u, pushed, CFG)
     assert not np.array_equal(np.asarray(chosen_0), np.asarray(chosen_1))
     assert np.all(np.asarray(chosen_1)[:, 0] == 5)
     scores = jax.nn.sigmoid(u @ ffn["router"])
@@ -236,7 +239,7 @@ def test_the_bias_changes_the_selection_but_not_the_weights(raw, u):
     close(weights_1.sum(-1), 1.0, 1e-4)
 
 
-#: a row's temperature in :func:`lfm2.choose`'s tests: 0 is greedy
+#: a row's temperature in :func:`unit_layers.choose`'s tests: 0 is greedy
 TEMPERATURES = {"greedy": [0.0] * 6,
                 "drawn": [0.667, 0.667, 1.0, 2.0, 5.0, 0.1],
                 "mixed": [0.0, 0.667, 0.0, 5.0, 0.0, 0.2]}
@@ -256,10 +259,10 @@ def test_choose_gives_an_id_and_its_log_probability(rows, impl):
     logits = logits.at[0, 300].set(logits[0, 400])      # equals: the first
     t = jnp.asarray(TEMPERATURES[rows], jnp.float32)
     key = jax.random.key(11, impl=impl)
-    ids, log_p = jax.jit(lambda x, t, k: lfm2.choose(x, t, k, UNITS))(
+    ids, log_p = jax.jit(lambda x, t, k: unit_layers.choose(x, t, k, UNITS))(
         logits, t, key)
     assert ids.dtype == jnp.int32 and log_p.dtype == jnp.float32
-    masked = jnp.where(lfm2.allowed_ids(512, UNITS), logits, -jnp.inf)
+    masked = jnp.where(unit_layers.allowed_ids(512, UNITS), logits, -jnp.inf)
     scaled = masked / jnp.where(t > 0, t, 1.0)[:, None]
     want = jnp.where(t > 0, jax.random.categorical(key, scaled, axis=-1),
                      jnp.argmax(masked, -1))
@@ -267,8 +270,9 @@ def test_choose_gives_an_id_and_its_log_probability(rows, impl):
     assert 256 <= int(ids.min()) and int(ids.max()) < 511
     close(log_p, jnp.take_along_axis(jax.nn.log_softmax(scaled, -1),
                                      ids[:, None], -1)[:, 0], 1e-5)
-    assert np.array_equal(np.asarray(lfm2.sample(logits, t, key, UNITS)),
-                          np.asarray(ids))
+    assert np.array_equal(
+        np.asarray(unit_layers.sample(logits, t, key, UNITS)),
+        np.asarray(ids))
 
 
 @pytest.mark.parametrize("impl", ["threefry2x32", "rbg"])
@@ -280,14 +284,14 @@ def test_the_draws_follow_the_softmax_over_the_allowed_ids(temperature,
     degrees of freedom: 66.2 is passed once in ten thousand); the ids
     before the first unit, the mask and the stop id are never drawn, high
     as their logits are."""
-    units = lfm2.UnitIds(8, 39, 20)
+    units = unit_layers.UnitIds(8, 39, 20)
     logits = jnp.asarray(
         0.7 * np.random.default_rng(7).standard_normal((1, 40)),
         jnp.float32).at[0, jnp.asarray([3, 20, 39])].set(9.0)
     keys = jax.random.split(jax.random.key(46, impl=impl), 8192)
-    ids = np.asarray(jax.jit(jax.vmap(lambda k: lfm2.choose(
+    ids = np.asarray(jax.jit(jax.vmap(lambda k: unit_layers.choose(
         logits, jnp.full((1,), temperature), k, units)[0][0]))(keys))
-    allowed = np.asarray(lfm2.allowed_ids(40, units))
+    allowed = np.asarray(unit_layers.allowed_ids(40, units))
     assert allowed.sum() == 30
     counts = np.bincount(ids, minlength=40)
     assert counts[~allowed].sum() == 0
@@ -303,12 +307,12 @@ def test_sampling_is_greedy_at_zero_and_never_gives_the_stop_unit():
     logits = jnp.asarray(rng.standard_normal((6, 512)), jnp.float32)
     logits = logits.at[:, 511].set(50.0).at[:, 7].set(60.0)
     key = jax.random.PRNGKey(1)
-    greedy = lfm2.sample(logits, jnp.zeros((6,)), key, UNITS)
+    greedy = unit_layers.sample(logits, jnp.zeros((6,)), key, UNITS)
     assert np.array_equal(np.asarray(greedy),
                           256 + np.asarray(logits)[:, 256:511].argmax(-1))
     mixed = jnp.asarray([0.0, 0.0, 0.0, 5.0, 5.0, 5.0])
-    drawn = [np.asarray(lfm2.sample(logits, mixed, jax.random.PRNGKey(k),
-                                    UNITS)) for k in range(8)]
+    drawn = [np.asarray(unit_layers.sample(
+        logits, mixed, jax.random.PRNGKey(k), UNITS)) for k in range(8)]
     assert all(np.array_equal(d[:3], np.asarray(greedy)[:3]) for d in drawn)
     assert len({tuple(d[3:]) for d in drawn}) > 1
     assert all(256 <= d.min() and d.max() < 511 for d in drawn)

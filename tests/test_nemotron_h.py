@@ -18,9 +18,10 @@ import pytest
 from jax import lax
 
 from perfbench.harness import lfm2gen, nemotrongen, parts, sdargen
-from sonata_tpu.models import from_config_path, lfm2, nemotron_h, sdar
+from sonata_tpu.models import from_config_path, lfm2, nemotron_h, sdar, \
+    unit_layers
 from sonata_tpu.models.config import SynthesisConfig
-from sonata_tpu.models.unit_voice import routes_of
+from sonata_tpu.models.unit_backbone import routes_of
 from sonata_tpu.serving import tracing
 from sonata_tpu.serving.metrics import MetricsRegistry
 
@@ -32,7 +33,7 @@ REAL = json.loads((ROOT / "perfbench/configs/nemotron/"
                    "nemotron-3-nano-30b-a3b.json").read_text())
 BB = nemotrongen.backbone(CONFIG)
 CFG = nemotron_h.NemotronConfig.from_dict(BB)
-UNITS = lfm2.UnitIds(256, 511)
+UNITS = unit_layers.UnitIds(256, 511)
 LAYERS = len(CFG.pattern)
 #: prompts shorter than, equal to and longer than one chunk (8), one that
 #: ends on a chunk's edge, and one that fills its text bucket
@@ -49,7 +50,7 @@ def wide(tree):
 def float32_products(monkeypatch):
     """The program's products take bfloat16 inputs; here they take float32
     at ``highest``, so that it can be held to the reference to rounding."""
-    monkeypatch.setattr(lfm2, "BF16", jnp.float32)
+    monkeypatch.setattr(unit_layers, "BF16", jnp.float32)
     monkeypatch.setattr(nemotron_h, "BF16", jnp.float32)
     with jax.default_matmul_precision("highest"):
         yield
@@ -200,7 +201,7 @@ def test_the_two_shares_add_up_to_the_uncut_layer_the_shared_expert_once(
         half = dict(whole, w_up=whole["w_up"][first:first + 4],
                     w_down=whole["w_down"][first:first + 4])
         cfg = dataclasses.replace(CFG, held=(first, 4))
-        got, routes, load = lfm2.moe_ffn(
+        got, routes, load = unit_layers.moe_ffn(
             u, nemotron_h.pack_layer({"norm": raw[1]["norm"],
                                       "mixer": half})["mixer"], cfg, cfg.held)
         part, _ = ref.experts(u, half, BB, held=(first, 4))
@@ -224,7 +225,7 @@ def test_the_router_is_lfm2s_to_the_letter_but_for_the_published_epsilon(
     u = jnp.asarray(np.random.default_rng(2).standard_normal(
         (9, CFG.hidden_size)), jnp.float32)
     p = nemotron_h.pack_layer(raw[1])["mixer"]
-    chosen, weights = lfm2.route(u, p, CFG)
+    chosen, weights = unit_layers.route(u, p, CFG)
     want, want_weights, _ = ref.route(u, raw[1]["mixer"], BB)
     assert np.array_equal(np.asarray(chosen), np.asarray(want))
     np.testing.assert_allclose(np.asarray(weights), np.asarray(want_weights),
@@ -233,7 +234,7 @@ def test_the_router_is_lfm2s_to_the_letter_but_for_the_published_epsilon(
     np.testing.assert_allclose(np.asarray(weights).sum(-1), 2.5, rtol=1e-5)
     # the bias moves the choice and not the weights
     biased = dict(p, expert_bias=p["expert_bias"].at[0].add(10.0))
-    moved, _ = lfm2.route(u, biased, CFG)
+    moved, _ = unit_layers.route(u, biased, CFG)
     assert (np.asarray(moved) == 0).any(-1).all()
 
 
@@ -245,16 +246,16 @@ def test_experts_lie_in_whole_lanes_and_give_the_same_to_the_last_bit(raw):
     shapes the kernel takes, and nothing moves: ``ragged_dot`` on the
     published width, ``ragged_dot`` and the kernel on the padded one."""
     mixer = raw[1]["mixer"]
-    w13, w2 = lfm2.pad_experts(mixer["w_up"], mixer["w_down"])
+    w13, w2 = unit_layers.pad_experts(mixer["w_up"], mixer["w_down"])
     assert w13.shape == (4, 64, 128) and w2.shape == (4, 128, 64)
     assert gm.lanes(1856) == 1920 and gm.lanes(1920) == 1920
     p = nemotron_h.pack_layer(raw[1])["mixer"]
     assert p["w13"].shape == (4, 64, 128)
     u = jnp.asarray(np.random.default_rng(3).standard_normal(
         (24, CFG.hidden_size)), jnp.float32)
-    padded = lfm2.moe_ffn(u, p, CFG, CFG.held)
-    plain = lfm2.moe_ffn(u, dict(p, w13=mixer["w_up"], w2=mixer["w_down"]),
-                         CFG, CFG.held)
+    padded = unit_layers.moe_ffn(u, p, CFG, CFG.held)
+    plain = unit_layers.moe_ffn(
+        u, dict(p, w13=mixer["w_up"], w2=mixer["w_down"]), CFG, CFG.held)
     for a, b in zip(padded, plain):
         assert np.array_equal(np.asarray(a), np.asarray(b))
     # the kernel itself, bfloat16 in, at the padded width
@@ -356,12 +357,18 @@ def test_the_voice_runs_and_its_loop_says_what_the_state_and_the_share_cost(
     tracer = tracing.default_tracer()
     tracer.clear()
     per_slot = 4 * 3 * (8 * 8 * 16 + 3 * 128)
-    resident = stats.ssm_state_resident_bytes
+    resident = stats.resident["sonata_ssm_state_resident_bytes"]
     try:
         assert type(voice.backbone).__name__ == "NemotronBackbone"
-        assert (voice.ssm_layers, voice.ssm_state_bytes,
-                voice.expert_layers, voice.expert_matmul) == (
-            3, per_slot, [1, 4, 6], "ragged_dot")
+        described = voice.description
+        assert (described.static, described.resident, voice.expert_layers,
+                voice.expert_matmul) == (
+            {"ssm_layers": 3, "latent_layers": 0},
+            {"sonata_ssm_state_resident_bytes": 3 * per_slot}, [1, 4, 6],
+            "ragged_dot")
+        assert described.closed({"live_slot_steps": 5, "kv_positions": 9}) \
+            == {"ssm_state_bytes": 2 * per_slot * 5, "latent_cache_bytes": 0}
+        assert described.prefill(32) == {"ssm_chunks": 3 * 4}
         assert ("step_admit", 32) in voice.lattice_shapes("full")
         with tracer.trace_request("test", request_id="row-0"):
             audio = voice.speak_batch(
@@ -369,12 +376,13 @@ def test_the_voice_runs_and_its_loop_says_what_the_state_and_the_share_cost(
         ids = voice.config.phonemes_to_ids(
             list(voice.phonemize_text("one short row."))[0])
         assert len(audio[0].samples) == 16 * round(3.5 * len(ids))
-        assert stats.ssm_state_resident_bytes == resident + 3 * per_slot
+        assert stats.resident["sonata_ssm_state_resident_bytes"] \
+            == resident + 3 * per_slot
         assert f"sonata_ssm_state_resident_bytes {resident + 3 * per_slot}\n" \
             in registry.render()
     finally:
         voice.close()
-    assert stats.ssm_state_resident_bytes == resident
+    assert stats.resident["sonata_ssm_state_resident_bytes"] == resident
     traces = {t.request_id: t for t in tracer.recent_traces()}
     (prefill,) = [s.attrs for s in traces["row-0"].spans_snapshot()
                   if s.attrs.get("kind") == "prefill"]
@@ -414,7 +422,8 @@ def test_the_warm_up_holds_as_many_caches_as_the_device_has_room_for(
         need = sum(int(np.prod(a.shape)) * a.dtype.itemsize
                    for a in jax.tree_util.tree_leaves(
                        jax.eval_shape(voice.new_cache)))
-        assert need > 3 * voice.ssm_state_bytes
+        assert need > voice.description.resident[
+            "sonata_ssm_state_resident_bytes"] > 0
 
         class Device:
             def __init__(self, stats):

@@ -18,12 +18,13 @@ import numpy as np
 import pytest
 
 from perfbench.harness import pangugen, parts
-from sonata_tpu.models import from_config_path, lfm2, pangu_moe
+from sonata_tpu.models import from_config_path, pangu_moe, unit_layers
 from sonata_tpu.models.config import SynthesisConfig
-from sonata_tpu.models.unit_voice import routes_of
+from sonata_tpu.models.unit_backbone import routes_of
 from sonata_tpu.ops import slot_attention
 from sonata_tpu.serving import tracing
 from sonata_tpu.serving.metrics import MetricsRegistry
+from tests.voices import row_sums
 
 ROOT = Path(__file__).resolve().parent.parent
 DATA = ROOT / "tests/perfbench/data"
@@ -32,7 +33,7 @@ REAL = json.loads((ROOT / "perfbench/configs/pangu/"
                    "openpangu-ultra-moe-718b.json").read_text())
 BB = pangugen.backbone(CONFIG)
 CFG = pangu_moe.PanguConfig.from_dict(BB)
-UNITS = lfm2.UnitIds(256, 511)
+UNITS = unit_layers.UnitIds(256, 511)
 LAYERS = CFG.num_hidden_layers
 PROMPTS = {"short": (5, 16), "whole_bucket": (16, 16), "longer": (19, 32)}
 NORMS = ("input_norm", "post_attn_norm", "pre_mlp_norm", "post_mlp_norm")
@@ -47,7 +48,7 @@ def wide(tree):
 def float32_products(monkeypatch):
     """The program's products take bfloat16 inputs; here they take float32
     at ``highest``, so that it can be held to the reference to rounding."""
-    monkeypatch.setattr(lfm2, "BF16", jnp.float32)
+    monkeypatch.setattr(unit_layers, "BF16", jnp.float32)
     monkeypatch.setattr(pangu_moe, "BF16", jnp.float32)
     with jax.default_matmul_precision("highest"):
         yield
@@ -209,7 +210,7 @@ def test_the_shares_add_up_to_the_uncut_layer_the_shared_expert_once(raw):
         share = dict(whole, **{k: whole[k][first:first + 2]
                                for k in ("w1", "w3", "w2")})
         packed = pangu_moe.pack_layer(dict(raw[1], ffn=share), CFG)["ffn"]
-        got, took, load = lfm2.moe_ffn(u, packed, CFG, (first, 2))
+        got, took, load = unit_layers.moe_ffn(u, packed, CFG, (first, 2))
         part, _ = ref.experts(u, share, BB, held=(first, 2))
         np.testing.assert_allclose(np.asarray(got), np.asarray(part),
                                    rtol=0, atol=2e-5)
@@ -225,9 +226,10 @@ def test_the_vocabulary_slices_side_by_side_are_the_whole_head(params):
     side by side are the whole head's, and this chip's are the first."""
     h = jnp.asarray(np.random.default_rng(8).standard_normal(
         (5, CFG.hidden_size)), jnp.float32)
-    whole = lfm2._head(h, params, CFG)
-    slices = [lfm2._head(h, dict(params, head=params["head"][k:k + 64]), CFG)
-              for k in range(0, 512, 64)]
+    whole = unit_layers._head(h, params, CFG)
+    slices = [unit_layers._head(
+        h, dict(params, head=params["head"][k:k + 64]), CFG)
+        for k in range(0, 512, 64)]
     np.testing.assert_allclose(np.asarray(jnp.concatenate(slices, -1)),
                                np.asarray(whole), rtol=0, atol=1e-5)
     want = ref.head(h, params["head"][:64], params["norm_f"], BB)
@@ -305,10 +307,10 @@ def test_a_thin_share_takes_the_short_path_and_leaves_nothing_out(
     its full-length path, and either way the result is the reference's
     share."""
     cfg, u, raw, packed, bb = thin_layer(1024, skew)
-    assert lfm2.held_rows(cfg, 1024, cfg.held) == 256 < 2048
+    assert unit_layers.held_rows(cfg, 1024, cfg.held) == 256 < 2048
     valid = jnp.arange(1024) < 1000
     got, chosen, load = jax.jit(
-        lambda u, p, valid: lfm2.moe_ffn(u, p, cfg, cfg.held, valid))(
+        lambda u, p, valid: unit_layers.moe_ffn(u, p, cfg, cfg.held, valid))(
         u, packed, valid)
     load = np.asarray(load)
     assert load.shape == (6,) and bool(load[5]) == overflows
@@ -323,7 +325,7 @@ def test_a_thin_share_takes_the_short_path_and_leaves_nothing_out(
                         raw["shared_w2"])
     np.testing.assert_allclose(np.asarray(got)[1000:],
                                np.asarray(shared)[1000:], rtol=0, atol=5e-5)
-    graph = str(jax.make_jaxpr(lambda u, p: lfm2.moe_ffn(
+    graph = str(jax.make_jaxpr(lambda u, p: unit_layers.moe_ffn(
         u, p, cfg, cfg.held))(u, packed))
     assert "cond[" in graph and "bf16[256," not in graph
     assert "f32[256,64]" in graph      # the short path's rows
@@ -332,14 +334,14 @@ def test_a_thin_share_takes_the_short_path_and_leaves_nothing_out(
 def test_the_bound_is_the_shapes_and_a_half_share_is_not_thin():
     real = pangu_moe.PanguConfig.from_dict(pangugen.backbone(REAL))
     # the cell's step: 2048 assignments, 64 expected on the 8 held, 256 taken
-    assert lfm2.held_rows(real, 256, real.held) == 256
-    assert lfm2.held_rows(real, 256 + 192, real.held) == 384
+    assert unit_layers.held_rows(real, 256, real.held) == 256
+    assert unit_layers.held_rows(real, 256 + 192, real.held) == 384
     # all of them where nothing is cut: a whole layer, a share of a half
-    assert lfm2.held_rows(real, 256, None) == 2048
+    assert unit_layers.held_rows(real, 256, None) == 2048
     half = dataclasses.replace(real, num_experts=16, held=(0, 8))
-    assert lfm2.held_rows(half, 256, half.held) == 2048
+    assert unit_layers.held_rows(half, 256, half.held) == 2048
     # a short prompt alone: no shorter than all its rows
-    assert lfm2.held_rows(real, 16, real.held) == 128
+    assert unit_layers.held_rows(real, 16, real.held) == 128
 
 
 # -- the configuration -------------------------------------------------------
@@ -383,8 +385,9 @@ def test_the_voice_runs_and_its_loop_says_what_the_cache_and_the_share_cost(
     monkeypatch.setenv("SONATA_AR_SLOTS", "3")
     monkeypatch.setenv("SONATA_AR_POSITIONS", "256")
     # a short path short enough to overflow at this size: 2 rows
-    monkeypatch.setattr(lfm2, "held_rows", lambda cfg, tokens, held: min(
-        tokens * cfg.num_experts_per_tok, 2))
+    monkeypatch.setattr(
+        unit_layers, "held_rows", lambda cfg, tokens, held: min(
+            tokens * cfg.num_experts_per_tok, 2))
     voice = from_config_path(pangugen.write_tensors(tmp_path, CONFIG))
     voice.set_fallback_synthesis_config(SynthesisConfig(noise_scale=0.0))
     registry = MetricsRegistry()
@@ -393,14 +396,21 @@ def test_the_voice_runs_and_its_loop_says_what_the_cache_and_the_share_cost(
     tracer = tracing.default_tracer()
     tracer.clear()
     row_bytes = 4 * 128 * 2             # 4 layers, 40 values in 128 lanes
-    resident = stats.mla_cache_resident_bytes
+    resident = stats.resident["sonata_mla_cache_resident_bytes"]
     try:
         assert type(voice.backbone).__name__ == "PanguBackbone"
-        assert (voice.latent_layers, voice.mla_form, voice.attention,
-                voice.expert_layers, voice.expert_matmul) == (
-            4, "absorbed", "einsum", [1, 2, 3], "ragged_dot")
-        assert voice.latent_cache_bytes(10) == 10 * row_bytes
-        assert (voice.latent_places(0), voice.latent_places(10)) == (0, 256)
+        described = voice.description
+        assert (described.static, voice.attention, voice.expert_layers,
+                voice.expert_matmul) == (
+            {"ssm_layers": 0, "latent_layers": 4, "mla_form": "absorbed"},
+            "einsum", [1, 2, 3], "ragged_dot")
+        assert described.closed({"kv_positions": 10, "live_slot_steps": 2}) \
+            == {"ssm_state_bytes": 0, "latent_cache_bytes": 10 * row_bytes}
+        assert [row_sums(described, n) for n in (0, 10)] == [
+            {"latent_places_fetched": places, "kv_places_fetched": 0}
+            for places in (0, 256)]
+        assert described.resident == {
+            "sonata_mla_cache_resident_bytes": 3 * 256 * row_bytes}
         assert ("step_admit", 32) in voice.lattice_shapes("full")
         with tracer.trace_request("test", request_id="row-0"):
             audio = voice.speak_batch(
@@ -409,12 +419,12 @@ def test_the_voice_runs_and_its_loop_says_what_the_cache_and_the_share_cost(
             list(voice.phonemize_text("one short row."))[0])
         assert len(audio[0].samples) == 16 * round(3.5 * len(ids))
         held = resident + 3 * 256 * row_bytes
-        assert stats.mla_cache_resident_bytes == held
+        assert stats.resident["sonata_mla_cache_resident_bytes"] == held
         assert f"sonata_mla_cache_resident_bytes {held}\n" \
             in registry.render()
     finally:
         voice.close()
-    assert stats.mla_cache_resident_bytes == resident
+    assert stats.resident["sonata_mla_cache_resident_bytes"] == resident
     traces = {t.request_id: t for t in tracer.recent_traces()}
     (prefill,) = [s.attrs for s in traces["row-0"].spans_snapshot()
                   if s.attrs.get("kind") == "prefill"]

@@ -15,15 +15,15 @@ import numpy as np
 import pytest
 
 from perfbench.harness import lfm2gen, parts, sdargen
-from sonata_tpu.models import lfm2, sdar
-from sonata_tpu.models.unit_voice import routes_of
+from sonata_tpu.models import lfm2, sdar, unit_layers
+from sonata_tpu.models.unit_backbone import routes_of
 from tools import profile_sampler
 
 ROOT = Path(__file__).resolve().parent.parent
 CONFIG = json.loads((ROOT / "tests/perfbench/data/sdar-tiny.json").read_text())
 BB = sdargen.backbone(CONFIG)
 CFG = sdar.SdarConfig.from_dict(BB)
-UNITS = lfm2.UnitIds(256, 511, 510)
+UNITS = unit_layers.UnitIds(256, 511, 510)
 B = 4
 SPAN = 64
 ref = parts.load_file(ROOT / "perfbench/reference/sdar_ref.py")
@@ -37,7 +37,7 @@ def wide(tree):
 def float32_products(monkeypatch):
     """The program's products take bfloat16 inputs; here they take float32
     at ``highest``, so that it can be held to the reference to rounding."""
-    monkeypatch.setattr(lfm2, "BF16", jnp.float32)
+    monkeypatch.setattr(unit_layers, "BF16", jnp.float32)
     monkeypatch.setattr(sdar, "BF16", jnp.float32)
     with jax.default_matmul_precision("highest"):
         yield
@@ -109,19 +109,19 @@ def test_the_mask_against_a_brute_force_loop(block):
     for i in range(t):
         for j in range(t):
             want[i, j] = j // block <= i // block
-    assert np.array_equal(np.asarray(lfm2.block_mask(jnp.arange(t), block)),
-                          want)
+    assert np.array_equal(
+        np.asarray(unit_layers.block_mask(jnp.arange(t), block)), want)
     assert np.array_equal(np.asarray(ref.block_mask(t, block)), want)
     if block == 1:
         assert np.array_equal(want, np.tril(np.ones((t, t), bool)))
 
 
 def test_attention_over_a_prompt_is_whole_inside_a_block(raw, params, u):
-    got, k, v = lfm2.attn_op_seq(u, params["layers"][0]["attn"], CFG, B)
+    got, k, v = unit_layers.attn_op_seq(u, params["layers"][0]["attn"], CFG, B)
     close(got, ref.attn(u, raw[0]["attn"], BB, ref.block_mask(13, B),
                         jnp.arange(13)))
     assert k.shape == v.shape == (13, 2, 32)
-    causal, _, _ = lfm2.attn_op_seq(u, params["layers"][0]["attn"], CFG)
+    causal, _, _ = unit_layers.attn_op_seq(u, params["layers"][0]["attn"], CFG)
     close(causal, ref.attn(u, raw[0]["attn"], BB, ref.block_mask(13, 1),
                            jnp.arange(13)))
     assert not np.allclose(np.asarray(got), np.asarray(causal), atol=1e-3)
@@ -129,7 +129,7 @@ def test_attention_over_a_prompt_is_whole_inside_a_block(raw, params, u):
 
 def test_the_softmax_router_against_the_reference(raw, params, u):
     ffn = params["layers"][1]["moe"]
-    chosen, weights = lfm2.route(u, ffn, CFG)
+    chosen, weights = unit_layers.route(u, ffn, CFG)
     want_chosen, want_weights, scores = ref.route(u, raw[1]["moe"], BB)
     assert np.array_equal(np.asarray(chosen), np.asarray(want_chosen))
     close(weights, want_weights, 1e-6)
@@ -140,7 +140,7 @@ def test_the_softmax_router_against_the_reference(raw, params, u):
     top = np.sort(np.asarray(s), -1)[:, -2:]
     close(np.sort(np.asarray(weights), -1), top / top.sum(-1, keepdims=True),
           1e-6)
-    got, said, load = lfm2.moe_ffn(u, ffn, CFG)
+    got, said, load = unit_layers.moe_ffn(u, ffn, CFG)
     want, _ = ref.moe(u, raw[1]["moe"], BB)
     close(got, want)
     assert np.array_equal(np.asarray(said), np.asarray(want_chosen))
@@ -168,7 +168,7 @@ def test_the_shares_add_up_to_the_uncut_reference_layer(shares, raw, u):
     for first, count in shares:
         packed = sdar.pack_layer(dict(raw[2], moe=held_layer(
             raw[2]["moe"], first, count)))
-        part, chosen, load = lfm2.moe_ffn(u, packed["moe"], CFG,
+        part, chosen, load = unit_layers.moe_ffn(u, packed["moe"], CFG,
                                           held=(first, count))
         # every share routes over all the experts and says so
         assert np.array_equal(np.asarray(chosen), np.asarray(want_chosen))
@@ -304,12 +304,12 @@ def test_sampling_never_gives_the_mask_or_the_stop_unit():
     logits = jnp.asarray(np.random.default_rng(3).standard_normal((6, 512)),
                          jnp.float32)
     logits = logits.at[:, 510].set(60.0).at[:, 511].set(50.0)
-    greedy = lfm2.sample(logits, jnp.zeros((6,)), jax.random.PRNGKey(1),
+    greedy = unit_layers.sample(logits, jnp.zeros((6,)), jax.random.PRNGKey(1),
                          UNITS)
     assert np.array_equal(np.asarray(greedy),
                           256 + np.asarray(logits)[:, 256:510].argmax(-1))
-    drawn = lfm2.sample(logits, jnp.full((6,), 5.0), jax.random.PRNGKey(2),
-                        UNITS)
+    drawn = unit_layers.sample(logits, jnp.full((6,), 5.0),
+                               jax.random.PRNGKey(2), UNITS)
     assert 256 <= int(drawn.min()) and int(drawn.max()) < 510
 
 
@@ -367,7 +367,7 @@ def test_the_new_fields_leave_the_lfm2_programs_as_they_were():
     cfg = lfm2.Lfm2Config.from_dict(lfm2gen.backbone(tiny))
     assert (cfg.head_dim, cfg.router_scoring, cfg.tie_word_embeddings) == (
         16, "sigmoid", True)
-    units = lfm2.UnitIds(256, 511)
+    units = unit_layers.UnitIds(256, 511)
     params = {"embed": jnp.zeros((512, 64)), "norm_f": jnp.ones((64,)),
               "layers": [lfm2.pack_layer(wide(lfm2gen.draw_layer(tiny, i)))
                          for i in range(6)]}
@@ -380,11 +380,11 @@ def test_the_new_fields_leave_the_lfm2_programs_as_they_were():
     plain = graph(cfg, units)
     assert plain == graph(dataclasses.replace(
         cfg, head_dim=16, router_scoring="sigmoid",
-        tie_word_embeddings=True), lfm2.UnitIds(256, 511, None))
+        tie_word_embeddings=True), unit_layers.UnitIds(256, 511, None))
     soft = graph(dataclasses.replace(cfg, router_scoring="softmax"), units)
     # a softmax in each of the four expert layers, in a sigmoid's place
     assert soft.count("reduce_max") == plain.count("reduce_max") + 4
     assert soft.count("logistic") == plain.count("logistic") - 4
-    assert graph(cfg, lfm2.UnitIds(256, 511, 300)) != plain
+    assert graph(cfg, unit_layers.UnitIds(256, 511, 300)) != plain
     with pytest.raises(KeyError, match="head"):
         graph(dataclasses.replace(cfg, tie_word_embeddings=False), units)

@@ -16,8 +16,9 @@ from perfbench.harness import lfm2gen, sdargen
 from sonata_tpu.core import OperationError
 from sonata_tpu.models import from_config_path, voice_family
 from sonata_tpu.models.config import SynthesisConfig
-from sonata_tpu.models.unit_voice import BACKBONES, RowPlan, SdarBackbone, \
-    UnitVoice
+from sonata_tpu.models.sdar import SdarBackbone
+from sonata_tpu.models.unit_backbone import RowPlan
+from sonata_tpu.models.unit_voice import BACKBONES, UnitVoice
 from sonata_tpu.serving import tracing
 from sonata_tpu.synth import SpeechSynthesizer
 

@@ -1,8 +1,8 @@
 """The slots' keys and values and a step's attention over them
 (``sonata_tpu/ops/slot_attention.py``): the kernel in interpret mode (its
 copies and its trip counts from ``upto``) and the fallback against the
-expressions ``lfm2.attn_op_step`` and ``sdar.attn_op_block`` had until PR
-37, at the cells' geometries; the places the kernel moves; the two ways a
+expressions ``unit_layers.attn_op_step`` and ``sdar.attn_op_block`` had until
+PR 37, at the cells' geometries; the places the kernel moves; the two ways a
 slot is written; and the tile rule as a pure function.  What the chip's
 compiler makes of it is in ``test_compiled_for_v5e.py``."""
 

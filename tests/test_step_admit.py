@@ -15,11 +15,12 @@ import numpy as np
 import pytest
 
 from perfbench.harness import lagunagen, lfm2gen, nemotrongen, pangugen
-from sonata_tpu.models import laguna, lfm2, nemotron_h, pangu_moe, unit_voice
+from sonata_tpu.models import laguna, lfm2, nemotron_h, pangu_moe, \
+    unit_layers, unit_voice
 
 ROOT = Path(__file__).resolve().parent.parent
 DATA = ROOT / "tests/perfbench/data"
-UNITS = lfm2.UnitIds(256, 511)
+UNITS = unit_layers.UnitIds(256, 511)
 SLOTS, POSITIONS = 4, 64
 #: the arriving row's slot: it held a row before (its state is stale)
 SLOT = 2
@@ -33,6 +34,7 @@ def wide(tree):
 def float32_products(monkeypatch):
     """The products take float32 at ``highest``, so that two orders of the
     same sums can be held to each other to rounding."""
+    monkeypatch.setattr(unit_layers, "BF16", jnp.float32)
     monkeypatch.setattr(lfm2, "BF16", jnp.float32)
     monkeypatch.setattr(nemotron_h, "BF16", jnp.float32)
     monkeypatch.setattr(pangu_moe, "BF16", jnp.float32)
@@ -211,8 +213,8 @@ def test_which_rows_ride_a_step_is_the_backbones_and_the_shapes_to_say(
     built = unit_voice.make_backbone(gen.backbone(data), units)
     # as on a TPU: the kernel's tile rule decides
     monkeypatch.setattr(grouped_matmul, "_tiles_here", grouped_matmul.tile_rule)
-    step_impl = lfm2.expert_matmul(built.cfg, slots * built.block_length,
-                                   built.held)
+    step_impl = unit_layers.expert_matmul(
+        built.cfg, slots * built.block_length, built.held)
     assert step_impl == "grouped"
     voice = Sized(built, step_impl)
     voice.slots = slots
@@ -222,19 +224,20 @@ def test_which_rows_ride_a_step_is_the_backbones_and_the_shapes_to_say(
         return
     # every text bucket of the cells' lattices rides, on the kernel
     assert all(rides)
-    assert {lfm2.expert_matmul(built.cfg, slots + t, built.held)
+    assert {unit_layers.expert_matmul(built.cfg, slots + t, built.held)
             for t in TEXT_BUCKETS} == {"grouped"}
     # a step whose own products the rule leaves to ragged_dot loses nothing
     # by a prompt; one on the kernel does not give it up for one
     # (a thin share's short path has the step's own 256 rows up to a text
     # bucket of 128: those ride either way)
-    step_rows = lfm2.held_rows(built.cfg, slots * built.block_length,
+    step_rows = unit_layers.held_rows(built.cfg, slots * built.block_length,
                                built.held)
     monkeypatch.setattr(grouped_matmul, "_tiles_here",
                         lambda rows, *a: grouped_matmul.tile_rule(rows, *a)
                         if rows <= step_rows else None)
     more = [t for t in TEXT_BUCKETS
-            if lfm2.held_rows(built.cfg, slots + t, built.held) > step_rows]
+            if unit_layers.held_rows(built.cfg, slots + t, built.held)
+            > step_rows]
     assert {192, 256} <= set(more)
     assert not any(voice.carries(t) for t in more)
     voice.expert_matmul = "ragged_dot"

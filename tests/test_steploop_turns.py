@@ -11,6 +11,8 @@ from __future__ import annotations
 import contextlib
 import threading
 import time
+import types
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -66,8 +68,10 @@ class Engine:
     denoising_steps = 1
     expert_matmul = "ragged_dot"
     attention = "einsum"
-    ssm_layers = 0
-    ssm_state_bytes = 0
+    #: a cache of no kind: nothing beyond the loop's own sums
+    description = types.SimpleNamespace(
+        static={}, row_sums=(), rows=[()] * 64, closed=lambda group: {},
+        resident={}, prefill=lambda text_bucket: {})
 
     def __init__(self, launches=6, prefill_s=0.0, step_s=0.0,
                  prefill_compile=None):
@@ -270,6 +274,59 @@ def test_the_report_reads_the_four_names_of_a_capture():
     assert profile_report.loop_turns(notes[-1:]) is None   # the stock path
 
 
+# -- what the slots' cache is: the engine's description, and no word of the
+# loop's ---------------------------------------------------------------------
+
+def test_the_loop_carries_what_a_description_brings_and_knows_none_of_it():
+    """A row sum, a static attribute, something a closed group derives and a
+    resident series the loop has never heard of: a step group's span and
+    ``StepStats`` carry them under the description's own names."""
+    engine = Engine(launches=5)
+    engine.description = types.SimpleNamespace(
+        static={"crystal_layers": 7},
+        row_sums=("facets_polished", "facets_seen"),
+        rows=[(3 * n, n) for n in range(64)],
+        closed=lambda group: {"facet_bytes": 16 * group["facets_seen"]},
+        resident={"sonata_crystal_resident_bytes": 4096},
+        prefill=lambda text_bucket: {})
+    stats = tracing.step_stats()
+    before = stats.resident.get("sonata_crystal_resident_bytes", 0)
+    known = dict(stats.resident)
+    loop = steploop.StepLoop(engine, name="crystal")
+    try:
+        assert stats.resident["sonata_crystal_resident_bytes"] \
+            == before + 4096
+    finally:
+        loop.close()
+    assert stats.resident["sonata_crystal_resident_bytes"] == before
+    assert {k: stats.resident[k] for k in known} == known
+    groups = run_rows(engine, "crystal", rows=2)
+    # a row's launch number ``d`` attends over ``d + 1`` (``Plan``)
+    seen = 2 * sum(d + 1 for d in range(5))
+    assert sum(g["kv_positions"] for g in groups) == seen
+    assert sum(g["facets_seen"] for g in groups) == seen
+    assert sum(g["facets_polished"] for g in groups) == 3 * seen
+    for g in groups:
+        assert g["crystal_layers"] == 7
+        assert g["facet_bytes"] == 16 * g["facets_seen"]
+        # and nothing a real backbone's description would have brought
+        assert not {"ssm_layers", "latent_layers", "kv_places_fetched",
+                    "window", "mla_form"} & set(g)
+
+
+def test_the_loops_source_names_no_kind_of_cache():
+    """No branch on a kind of cache: the words stand in the module only
+    where they are another thing's own name (the stock voice's window
+    decodes, ``brings``)."""
+    source = Path(steploop.__file__).read_text()
+    assert "WINDOW_SUMS" not in source
+    assert source.count("getattr(") == 1        # ``carries``, the one left
+    for word in ("latent", "ssm", "ring_", "mla", "kv_places",
+                 "kv_cache_bytes", "window_"):
+        assert word not in source, word
+    assert source.count("window") == 1          # "window decodes", line 4
+
+
 # -- admitting rows: in a step, or apart ---------------------------------------
 
 class Held(Engine):
@@ -437,3 +494,72 @@ def test_a_prompt_the_step_does_not_take_is_prefilled_apart_beside_it():
     assert eng.seen[1][1] == {0, 1} and eng.seen[2][1] == {0, 1, 2}
     assert sum(g["admit_steps"] for g in groups) == 3
     assert sum(g["arrivals"] for g in groups) == 4
+
+
+# -- the set of a span's attributes, a backbone ---------------------------------
+
+#: what every unit voice's ``kind: step`` span carried at PR 46 (less
+#: ``compile_ms`` and ``compiled``, which hang on the compile cache)
+STEP_ATTRS = (
+    "admit_steps", "arrivals", "assignments", "attention", "block_length",
+    "commit_row_passes", "denoise_row_passes", "denoising_steps",
+    "device_wait_ms", "expert_matmul", "experts_touched", "held_assignments",
+    "held_experts_touched", "held_overflow_steps", "host_ms", "kind",
+    "kv_places_fetched", "kv_positions", "latent_cache_bytes",
+    "latent_layers", "latent_places_fetched", "layers", "live_slot_steps",
+    "max_expert_assignments", "other_ms", "positions", "prompt_tokens",
+    "record_ms", "slots", "ssm_layers", "ssm_state_bytes", "steps",
+    "turn_max_phase", "turn_max_step", "turn_ms_max", "units", "wall_ms")
+#: and every ``kind: prefill`` span
+PREFILL_ATTRS = (
+    "admit", "attention", "blocks", "compile", "expert_matmul", "kind",
+    "rows", "slot", "tail_ids", "text_bucket", "tokens", "wait_ms")
+
+
+@pytest.mark.parametrize("tiny, step_more, prefill_more", [
+    ("lfm2", (), ("step_no",)),
+    ("sdar", (), ()),
+    ("nemotron", (), ("ssm_chunks", "step_no")),
+    ("pangu", ("mla_form",), ("mla_form", "step_no")),
+    ("laguna", ("full_layers", "kv_cache_bytes", "window",
+                "window_bound_row_steps", "window_layers"),
+     ("step_no", "window_layers")),
+])
+def test_a_voices_spans_carry_the_attributes_they_carried(
+        tiny, step_more, prefill_more, tmp_path, monkeypatch):
+    """The *set* of names on a unit voice's step groups and prefill spans,
+    by backbone, as read at PR 46: what a reader tells cells apart by
+    (``"ssm_state_bytes" in g``, ``g.get("window_layers")``) is present
+    where it was and absent where it was."""
+    import importlib
+    import json
+
+    from sonata_tpu.models import from_config_path
+    from sonata_tpu.models.config import SynthesisConfig
+
+    gen = importlib.import_module(f"perfbench.harness.{tiny}gen")
+    config = json.loads((Path(__file__).parent / "perfbench/data"
+                         / f"{tiny}-tiny.json").read_text())
+    monkeypatch.setenv("SONATA_AR_SLOTS", "3")
+    monkeypatch.setenv("SONATA_AR_POSITIONS", "256")
+    voice = from_config_path(gen.write_tensors(tmp_path, config))
+    voice.set_fallback_synthesis_config(SynthesisConfig(noise_scale=0.0))
+    tracer = tracing.default_tracer()
+    tracer.clear()
+    try:
+        with tracer.trace_request("test", request_id="row-0"):
+            voice.speak_batch(list(voice.phonemize_text("one short row.")))
+    finally:
+        voice.close()
+    traces = {t.request_id: t for t in tracer.recent_traces()}
+    cache_state = {"compile_ms", "compiled"}
+    (prefill,) = [s.attrs for s in traces["row-0"].spans_snapshot()
+                  if s.attrs.get("kind") == "prefill"]
+    assert sorted(set(prefill) - cache_state) == sorted(
+        PREFILL_ATTRS + prefill_more)
+    groups = [s.attrs for rid, t in traces.items()
+              if rid.startswith("ar-steps-") for s in t.spans_snapshot()
+              if s.name == "dispatch"]
+    assert groups
+    for g in groups:
+        assert sorted(set(g) - cache_state) == sorted(STEP_ATTRS + step_more)

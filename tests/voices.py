@@ -75,6 +75,13 @@ def write_tiny_voice(dirpath, seed: int = 0, **overrides):
     return config_path
 
 
+def row_sums(description, attended: int) -> dict:
+    """What a row that attends over ``attended`` positions adds to a step
+    group's sums, by name, as a unit voice's ``description`` has it (the
+    step loop's one look-up a row)."""
+    return dict(zip(description.row_sums, description.rows[attended]))
+
+
 def plan_groups_of_four(monkeypatch) -> None:
     """Let a voice's plan cut a ragged batch of eight into two groups of
     four, so that a test sees the gather and several back programs.  Not

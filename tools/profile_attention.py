@@ -94,9 +94,9 @@ def draw_upto(rng, slots: int, positions: int, b: int):
 
 
 def einsums(buffers: str, q, k_buf, v_buf, upto):
-    """The two einsums ``lfm2.attn_op_step`` and ``sdar.attn_op_block`` had,
-    over buffers whose dimensions ``buffers`` names (``spkd``: as they had
-    them)."""
+    """The two einsums ``unit_layers.attn_op_step`` and ``sdar.attn_op_block``
+    had, over buffers whose dimensions ``buffers`` names (``spkd``: as they
+    had them)."""
     d, span = q.shape[-1], k_buf.shape[buffers.index("p")]
     scores = jnp.einsum(f"sbkgd,{buffers}->skgbp", q.astype(BF16), k_buf,
                         preferred_element_type=F32) / jnp.sqrt(F32(d))

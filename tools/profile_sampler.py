@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """What stands behind the head, alone, on the chip: the head's product and
-the choice of an id a row (``models/lfm2.py:choose``), at the cells' rows
-and vocabularies, each candidate a program of its own under one capture,
+the choice of an id a row (``models/unit_layers.py:choose``), at the cells'
+rows and vocabularies, each candidate a program of its own under one capture,
 read by operation (``tools/profile_by_scope.py``).
 
     python tools/profile_sampler.py [--only PREFIX] [--rehearse] [--out F]
@@ -42,19 +42,20 @@ sys.path[:0] = [str(ROOT), str(Path(__file__).resolve().parent)]
 import jax
 import jax.numpy as jnp
 
-from sonata_tpu.models import lfm2
+from sonata_tpu.models import unit_layers
+from sonata_tpu.models.unit_layers import UnitIds
 
 BF16, F32 = jnp.bfloat16, jnp.float32
 RUNS = 8
 #: cell: rows, block length (0: a step, ids alone), H, V, the unit ids
 SHAPES = {
-    "sdar_pass": (256, 4, 2048, 151936, lfm2.UnitIds(256, 151935, 151669)),
-    "nemotron_step": (256, 0, 2688, 131072, lfm2.UnitIds(256, 131071)),
-    "laguna_step": (256, 0, 2048, 100352, lfm2.UnitIds(256, 100351)),
-    "lfm2_step": (64, 0, 2048, 65536, lfm2.UnitIds(256, 65535)),
+    "sdar_pass": (256, 4, 2048, 151936, UnitIds(256, 151935, 151669)),
+    "nemotron_step": (256, 0, 2688, 131072, UnitIds(256, 131071)),
+    "laguna_step": (256, 0, 2048, 100352, UnitIds(256, 100351)),
+    "lfm2_step": (64, 0, 2048, 65536, UnitIds(256, 65535)),
 }
-TINY = {"sdar_pass": (8, 4, 64, 640, lfm2.UnitIds(16, 639, 600)),
-        "nemotron_step": (8, 0, 64, 512, lfm2.UnitIds(16, 511))}
+TINY = {"sdar_pass": (8, 4, 64, 640, UnitIds(16, 639, 600)),
+        "nemotron_step": (8, 0, 64, 512, UnitIds(16, 511))}
 
 
 def head(h, w):
@@ -64,9 +65,9 @@ def head(h, w):
 
 
 def before(logits, temperature, key, units, block: int):
-    """``lfm2.sample`` and the confidence of ``sdar.unmask`` as PR 45 left
-    them."""
-    allowed = lfm2.allowed_ids(logits.shape[-1], units)
+    """``unit_layers.sample`` and the confidence of ``sdar.unmask`` as PR 45
+    left them."""
+    allowed = unit_layers.allowed_ids(logits.shape[-1], units)
     masked = jnp.where(allowed, logits, -jnp.inf)
     greedy = jnp.argmax(masked, -1)
     safe = jnp.maximum(temperature, 1e-6)[:, None]
@@ -82,8 +83,8 @@ def before(logits, temperature, key, units, block: int):
 
 def chosen(logits, temperature, key, units, block: int):
     if not block:
-        return lfm2.sample(logits, temperature, key, units), logits
-    ids, confidence = lfm2.choose(logits, temperature, key, units)
+        return unit_layers.sample(logits, temperature, key, units), logits
+    ids, confidence = unit_layers.choose(logits, temperature, key, units)
     return ids, confidence, logits
 
 

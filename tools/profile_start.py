@@ -66,12 +66,12 @@ def equations(jaxpr, seen=None) -> int:
 def programs(name: str, one_chip):
     """``(tag, jitted program, argument shapes)`` of a start of the cell
     ``name``, the rules steered as on a TPU (``test_compiled_for_v5e``)."""
-    from sonata_tpu.models import unit_voice
+    from sonata_tpu.models import unit_backbone
     cells = importlib.import_module("test_compiled_for_v5e")
     cells.gm._tiles_here = cells.gm.tile_rule
     cells.sa._tiles_here = cells.sa.tile_rule
     cells.sa._latent_tiles_here = cells.sa.latent_tile_rule
-    unit_voice._layers_once_here = lambda: unit_voice.LAYERS_ONCE
+    unit_backbone._layers_once_here = lambda: unit_backbone.LAYERS_ONCE
     backbone, _, args = cells.step_shapes(name, one_chip)
     yield "step", backbone.build_step, args
     if name == "sdar_pass":     # its prompts are prefilled apart
