@@ -203,23 +203,27 @@ def pack_layer(raw: dict, cfg: PanguConfig) -> Params:
 # latent attention
 # ---------------------------------------------------------------------------
 
-def mla_in(u, p, cfg: PanguConfig, positions):
+def mla_in(u, p, cfg: PanguConfig, positions, rotary=None):
     """What both forms share, row-wise over ``u`` ``[N, H]`` at
     ``positions`` ``[N]``: every head's ``q_nope`` ``[N, heads, nope]`` and
     rotated ``q_rope`` ``[N, heads, rope]`` (float32), and the row to cache,
     ``[c_kv | k_r]`` ``[N, 1, c + rope]`` after its norm and rotary, in the
-    cache's type."""
+    cache's type.  ``rotary(x, positions)`` is the rotary of a backbone
+    that brings its own rule (:mod:`.gigachat`: YaRN's paces); None: plain,
+    by halves, at ``cfg.rope_theta``."""
+    if rotary is None:
+        def rotary(x, at):
+            return apply_rope(x, at, cfg.rope_theta)
     n, heads = u.shape[0], cfg.num_attention_heads
     nope, rope, c = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                      cfg.kv_lora_rank)
     down = mm(u, p["wqkv_a"])
     c_q = rms_norm(down[:, :cfg.q_lora_rank], p["q_norm"], cfg.norm_eps)
     q = mm(c_q, p["wq_b"]).reshape(n, heads, nope + rope)
-    q_rope = apply_rope(q[..., nope:], positions, cfg.rope_theta)
+    q_rope = rotary(q[..., nope:], positions)
     c_kv = rms_norm(down[:, cfg.q_lora_rank:cfg.q_lora_rank + c],
                     p["kv_norm"], cfg.norm_eps)
-    k_r = apply_rope(down[:, None, cfg.q_lora_rank + c:], positions,
-                     cfg.rope_theta)
+    k_r = rotary(down[:, None, cfg.q_lora_rank + c:], positions)
     row = jnp.concatenate([c_kv[:, None], k_r], -1).astype(BF16)
     return q[..., :nope], q_rope, row
 

@@ -1,7 +1,7 @@
 """What the backbones of a unit voice share (:mod:`.lfm2`, :mod:`.sdar`,
-:mod:`.nemotron_h`, :mod:`.pangu_moe`, :mod:`.laguna`): the pieces of a
-layer, the expert layer, the head, the sampler and what a prompt and a step
-leave a slot with beside its layers' state.
+:mod:`.nemotron_h`, :mod:`.pangu_moe`, :mod:`.laguna`, :mod:`.gigachat`):
+the pieces of a layer, the expert layer, the head, the sampler and what a
+prompt and a step leave a slot with beside its layers' state.
 
 What differs between the backbones that call a piece is a field of their
 configuration, read when a program is built: the router's scoring
@@ -179,10 +179,15 @@ def route(u, p, cfg):
 
 
 def _expert_act(up, cfg):
-    """What stands between an expert's two products (``expert_act``)."""
+    """What stands between an expert's two products (``expert_act``):
+    ``relu2``, ``swiglu`` or ``swiglu_clamped`` (the gate's pre-activation
+    at most ``cfg.swiglu_limit``, the up branch within ``+-`` it)."""
     if cfg.expert_act == "relu2":
         return jnp.square(jax.nn.relu(up))
     a, b = jnp.split(up, 2, axis=-1)
+    if cfg.expert_act == "swiglu_clamped":
+        limit = cfg.swiglu_limit
+        a, b = jnp.minimum(a, limit), jnp.clip(b, -limit, limit)
     return jax.nn.silu(a) * b
 
 

@@ -16,7 +16,9 @@ one unit a row a step through Mamba-2 states beside keys and values;
 ``pangu_ultra_moe`` (:mod:`.pangu_moe`) one through latent attention's one
 cached row a position; ``laguna`` (:mod:`.laguna`) one through full layers
 that keep every position of a slot beside window layers that keep a ring of
-``sliding_window`` places.  Each module holds its backbone whole:
+``sliding_window`` places; ``gigachat3_5`` (:mod:`.gigachat`) one through
+delta-rule states, a matrix a head, beside a latent layer's rows.  Each
+module holds its backbone whole:
 configuration, layers, programs and the adapter (:mod:`.unit_backbone`'s
 :class:`~.unit_backbone.Backbone`) that stands behind the engine surface
 :class:`~sonata_tpu.synth.steploop.StepLoop` names and says what its cache
@@ -67,7 +69,8 @@ from ..serving import tracing
 from ..text.phonemizer import text_to_phonemes
 from ..utils.buckets import FRAME_BUCKETS, TEXT_BUCKETS, bucket_for
 from ..utils.transfer import prefetch_to_host
-from . import decode_opts, laguna, lfm2, nemotron_h, pangu_moe, sdar
+from . import decode_opts, gigachat, laguna, lfm2, nemotron_h, pangu_moe, \
+    sdar
 from .config import ModelConfig, SynthesisConfig
 from .serialization import load_params, unflatten_params
 from .unit_backbone import RowPlan
@@ -144,7 +147,8 @@ BACKBONES = {"lfm2_moe": lfm2.Lfm2Backbone,
              "sdar_moe": sdar.SdarBackbone,
              "nemotron_h": nemotron_h.NemotronBackbone,
              "pangu_ultra_moe": pangu_moe.PanguBackbone,
-             "laguna": laguna.LagunaBackbone}
+             "laguna": laguna.LagunaBackbone,
+             "gigachat3_5": gigachat.GigaChatBackbone}
 
 
 def make_backbone(backbone: dict, units: dict, seed: int = 0):
@@ -411,18 +415,38 @@ class UnitVoice(BaseModel):
         program reserves beside it; at least one, and no bound where the
         backend reports no memory (the CPU).  A cache of state that does not
         grow with a row is gigabytes at a few hundred slots: four of them
-        beside the weights do not fit a chip."""
+        beside the weights do not fit a chip, and where not even one fits
+        beside the cache of a step loop that stands idle (the server speaks
+        one utterance before it warms the lattice), that loop is let go
+        first: the first row starts another."""
         with self._jit_lock:
             if self._warm_caches is None:
-                need = sum(int(np.prod(a.shape)) * a.dtype.itemsize
-                           for a in jax.tree_util.tree_leaves(
-                               jax.eval_shape(self.new_cache)))
-                stats = jax.local_devices()[0].memory_stats() or {}
-                free = stats.get("bytes_limit", 0) - stats.get(
-                    "bytes_in_use", 0)
-                fit = max(1, free // max(need, 1) - 1) if free > 0 else 1 << 30
+                need = max(1, sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                                  for a in jax.tree_util.tree_leaves(
+                                      jax.eval_shape(self.new_cache))))
+                free = self._free_bytes()
+                if 0 < free < 2 * need and self._rest_idle_loop():
+                    free = self._free_bytes()
+                fit = max(1, free // need - 1) if free > 0 else 1 << 30
                 self._warm_caches = threading.Semaphore(int(fit))
             return self._warm_caches
+
+    @staticmethod
+    def _free_bytes() -> int:
+        """What the device says is free (0: it keeps no count)."""
+        stats = jax.local_devices()[0].memory_stats() or {}
+        return stats.get("bytes_limit", 0) - stats.get("bytes_in_use", 0)
+
+    def _rest_idle_loop(self) -> bool:
+        """Close the step loop if it holds no row (its cache goes with it);
+        whether it did."""
+        with self._loop_lock:
+            loop = self._loop
+            if loop is None or loop.slots.in_use:
+                return False
+            self._loop = None
+        loop.close()
+        return True
 
     # -- the step loop's engine ------------------------------------------------
     def new_cache(self) -> dict:

@@ -708,6 +708,7 @@ ATTENTION_IMPLS = ("slot_kernel", "einsum")
 #: the gauges of what the slots of step loops hold on the device, as
 #: ``/metrics`` renders them: a loop's engine says its bytes by these names
 RESIDENT_SERIES = ("sonata_ssm_state_resident_bytes",
+                   "sonata_delta_state_resident_bytes",
                    "sonata_mla_cache_resident_bytes",
                    'sonata_attn_cache_resident_bytes{kind="full"}',
                    'sonata_attn_cache_resident_bytes{kind="ring"}')
@@ -961,6 +962,15 @@ class StepStats:
             "backbone that has none)."
         ).set_function(lambda: float(
             self.resident["sonata_ssm_state_resident_bytes"]))
+        registry.gauge(
+            "sonata_delta_state_resident_bytes",
+            "Bytes of delta-rule state (linear attention: a matrix a value "
+            "head and layer) and convolution columns the slots of step-wise "
+            "generation loops hold on the device (every slot, live or not: "
+            "such state does not grow with a row; 0 for a backbone that has "
+            "none)."
+        ).set_function(lambda: float(
+            self.resident["sonata_delta_state_resident_bytes"]))
         registry.gauge(
             "sonata_mla_cache_resident_bytes",
             "Bytes of latent rows the slots of step-wise generation loops "

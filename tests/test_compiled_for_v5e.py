@@ -11,7 +11,8 @@ library, and a second file could land on another worker.
   buffer twice a step), and both kernels are in it, the reader lowered
   once a geometry and not once a layer;
 - the step that carries an arrival (``lfm2_step_admit``,
-  ``nemotron_step_admit``, ``pangu_step_admit``, ``laguna_step_admit``) likewise: a step's scatter and a prompt's slice
+  ``nemotron_step_admit``, ``pangu_step_admit``, ``laguna_step_admit``,
+  ``gigachat_step_admit``) likewise: a step's scatter and a prompt's slice
   land on one donated buffer in one program, which is where a copy could
   come back, and its expert products over both kinds of row are the
   kernel's."""
@@ -50,6 +51,8 @@ CELLS = {
                    "pangugen", 256),
     "laguna_step": ("perfbench/configs/laguna/laguna-xs.2.json", "lagunagen",
                     256),
+    "gigachat_step": ("perfbench/configs/gigachat/gigachat3.5-432b-a28b.json",
+                      "gigachatgen", 256),
 }
 POSITIONS = 1024
 #: the two readers of ``laguna_step``: slots, and the places of a whole
@@ -255,7 +258,8 @@ ENTRY %main.146 (params__embed__.1: bf16[151936,2048]) -> (f32[64,4,151936]) {
 #: what a program's code may weigh (``generated_code_size_in_bytes``)
 CODE_MB = {"step": 24, "step_admit": 36}
 #: what reads the slots' cache in each step program
-READERS = {name: "latent_attention" if name == "pangu_step"
+READERS = {name: "latent_attention" if name in ("pangu_step",
+                                               "gigachat_step")
            else "slot_attention" for name in CELLS}
 #: the traces and lowerings of its reader a step program holds: one a
 #: geometry (a whole cache under 6 query heads a key head, a ring of 512
@@ -263,7 +267,7 @@ READERS = {name: "latent_attention" if name == "pangu_step"
 LOWERINGS = {"laguna_step": 2}
 #: the layers of each that read the slots' cache
 READS = {"lfm2_step": 2, "sdar_pass": 6, "nemotron_step": 1, "pangu_step": 7,
-         "laguna_step": 8}
+         "laguna_step": 8, "gigachat_step": 1}
 
 
 def reader_calls(jaxpr, name: str) -> list:
@@ -293,7 +297,20 @@ def lowered_once_a_geometry(program, args, name: str):
         f'kernel_name = "{READERS[name]}"') == LOWERINGS.get(name, 1)
     return lowered
 #: the cells whose chip holds a thin share of each layer's experts
-THIN = ("pangu_step", "laguna_step")
+THIN = ("pangu_step", "laguna_step", "gigachat_step")
+#: the cells whose share is 8 experts: it keeps its full-length path,
+#: XLA's product, for a launch that overflows the short one
+EIGHT_HELD = ("pangu_step", "gigachat_step")
+
+
+def state_traffic(hlo: str, cache: dict) -> tuple:
+    """Of the arrays the size of one linear layer's delta-rule states
+    (``cache["delta"]``: float32, all the slots'): the operations of an
+    optimised module's entry computation that write one and those that
+    read one, a layer."""
+    states = cache["delta"]
+    made, read = logits_sized(hlo, states[0].size)
+    return len(made) / len(states), len(read) / len(states)
 #: the fusions that read the head's ``f32[N, V]`` (``unit_layers.choose``:
 #: the
 #: choice; the sum of exponentials where the log-probability is used)
@@ -346,10 +363,16 @@ def test_a_step_compiled_for_a_v5e_copies_no_slot_buffer_whole(
     # a thin share's program holds both paths: the short one on the kernel
     # and, for a launch that overflows it, the full-length one
     assert ("conditional" in hlo) == (name in THIN)
+    if "delta" in cache:
+        # a layer's states are read twice (both reductions in one pass,
+        # then the update) and written once, where they lie
+        assert whole_buffer_copies(hlo, cache["delta"][0].size, "f32") == []
+        assert state_traffic(hlo, cache) == (1, 2)
 
 
 @pytest.mark.parametrize("name", ["lfm2_step", "nemotron_step",
-                                  "pangu_step", "laguna_step"])
+                                  "pangu_step", "laguna_step",
+                                  "gigachat_step"])
 def test_a_carrying_step_compiled_for_a_v5e_copies_no_slot_buffer_whole(
         one_chip, no_compile_cache, monkeypatch, name):
     """The step that carries an arrival, at the cells' sizes and their
@@ -384,8 +407,12 @@ def test_a_carrying_step_compiled_for_a_v5e_copies_no_slot_buffer_whole(
     # (a share of 8 experts keeps its full-length path, XLA's product, for
     # a launch that overflows the short one; with 32 held that path's 3584
     # rows are the kernel's too)
-    assert ("%ragged-dot" in hlo) == (name == "pangu_step")
+    assert ("%ragged-dot" in hlo) == (name in EIGHT_HELD)
     assert ("conditional" in hlo) == (name in THIN)
     assert whole_buffer_copies(hlo, cache["routes"].size) == []
-    for state in cache.get("ssm", ())[:1]:
+    for state in (*cache.get("ssm", ())[:1], *cache.get("delta", ())[:1]):
         assert whole_buffer_copies(hlo, state.size, "f32") == []
+    if "delta" in cache:
+        # the prompt's state lands in its slot of the step's result: one
+        # more operation names the array, none moves it whole
+        assert state_traffic(hlo, cache) == (2, 3)

@@ -65,8 +65,8 @@ def step_groups(tracer) -> list:
 def test_the_loader_picks_the_backbone_by_model_type(voice, voice_dir):
     assert isinstance(voice, UnitVoice)
     assert voice_family(voice_dir) == "unit_lm"
-    assert sorted(BACKBONES) == ["laguna", "lfm2_moe", "nemotron_h",
-                                 "pangu_ultra_moe", "sdar_moe"]
+    assert sorted(BACKBONES) == ["gigachat3_5", "laguna", "lfm2_moe",
+                                 "nemotron_h", "pangu_ultra_moe", "sdar_moe"]
     assert isinstance(voice.backbone, SdarBackbone)
     assert (voice.block_length, voice.denoising_steps) == (4, 2)
     assert voice.units.mask_id == 510

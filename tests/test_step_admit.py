@@ -1,5 +1,6 @@
 """A step that carries an arrival (``lfm2.step_admit``,
-``nemotron_h.step_admit``, ``pangu_moe.step_admit``, ``laguna.step_admit``) against the two programs it stands for, at a tiny
+``nemotron_h.step_admit``, ``pangu_moe.step_admit``, ``laguna.step_admit``,
+``gigachat.step_admit``) against the two programs it stands for, at a tiny
 size on the CPU, float32: from one cache with some slots live, the carrying
 form with a prompt for slot ``s`` gives the cache, the live rows' logits and
 the prompt's logits that ``step`` (with ``s`` not live) followed by
@@ -14,8 +15,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from perfbench.harness import lagunagen, lfm2gen, nemotrongen, pangugen
-from sonata_tpu.models import laguna, lfm2, nemotron_h, pangu_moe, \
+from perfbench.harness import gigachatgen, lagunagen, lfm2gen, nemotrongen, \
+    pangugen
+from sonata_tpu.models import gigachat, laguna, lfm2, nemotron_h, pangu_moe, \
     unit_layers, unit_voice
 
 ROOT = Path(__file__).resolve().parent.parent
@@ -39,6 +41,7 @@ def float32_products(monkeypatch):
     monkeypatch.setattr(nemotron_h, "BF16", jnp.float32)
     monkeypatch.setattr(pangu_moe, "BF16", jnp.float32)
     monkeypatch.setattr(laguna, "BF16", jnp.float32)
+    monkeypatch.setattr(gigachat, "BF16", jnp.float32)
     with jax.default_matmul_precision("highest"):
         yield
 
@@ -91,8 +94,23 @@ def laguna_backbone():
     return laguna, cfg, params
 
 
+def gigachat_backbone():
+    """A delta-rule state a head in the linear layers, latent rows in the
+    full one: the prompt's chunked form writes the slot's state whole."""
+    config = json.loads((DATA / "gigachat-tiny.json").read_text())
+    cfg = gigachat.GigaChatConfig.from_dict(gigachatgen.backbone(config))
+    params = {"embed": wide(gigachatgen.draw(config, "embed")),
+              "head": wide(gigachatgen.draw(config, "head")),
+              "norm_f": wide(gigachatgen.draw(config, "norm_f")),
+              "layers": [gigachat.pack_layer(wide(
+                  gigachatgen.draw_layer(config, i)), cfg)
+                  for i in range(cfg.num_hidden_layers)]}
+    return gigachat, cfg, params
+
+
 BACKBONES = {"lfm2_moe": lfm2_backbone, "nemotron_h": nemotron_backbone,
-             "pangu_ultra_moe": pangu_backbone, "laguna": laguna_backbone}
+             "pangu_ultra_moe": pangu_backbone, "laguna": laguna_backbone,
+             "gigachat3_5": gigachat_backbone}
 
 
 @pytest.fixture(scope="module", params=sorted(BACKBONES))
@@ -197,7 +215,9 @@ class Sized:
     ("sdar/sdar-30b-a3b.json", 64),
     ("pangu/openpangu-ultra-moe-718b.json", 256),
     ("laguna/laguna-xs.2.json", 256),
-], ids=["lfm2_moe", "nemotron_h", "sdar_moe", "pangu_ultra_moe", "laguna"])
+    ("gigachat/gigachat3.5-432b-a28b.json", 256),
+], ids=["lfm2_moe", "nemotron_h", "sdar_moe", "pangu_ultra_moe", "laguna",
+        "gigachat3_5"])
 def test_which_rows_ride_a_step_is_the_backbones_and_the_shapes_to_say(
         config, slots, monkeypatch):
     from sonata_tpu.ops import grouped_matmul
@@ -205,7 +225,8 @@ def test_which_rows_ride_a_step_is_the_backbones_and_the_shapes_to_say(
 
     data = json.loads((ROOT / "perfbench/configs" / config).read_text())
     gen = {"lfm2": lfm2gen, "nemotron": nemotrongen, "pangu": pangugen,
-           "laguna": lagunagen}.get(config.split("/")[0])
+           "laguna": lagunagen,
+           "gigachat": gigachatgen}.get(config.split("/")[0])
     if gen is None:
         from perfbench.harness import sdargen as gen
     units = {"first_id": 256, "stop_id": 511, "mask_id": 300,

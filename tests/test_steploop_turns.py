@@ -524,6 +524,8 @@ PREFILL_ATTRS = (
     ("laguna", ("full_layers", "kv_cache_bytes", "window",
                 "window_bound_row_steps", "window_layers"),
      ("step_no", "window_layers")),
+    ("gigachat", ("delta_layers", "delta_state_bytes", "mla_form"),
+     ("delta_chunks", "mla_form", "step_no")),
 ])
 def test_a_voices_spans_carry_the_attributes_they_carried(
         tiny, step_more, prefill_more, tmp_path, monkeypatch):

@@ -27,9 +27,9 @@ sys.path.insert(0, str(Path(__file__).resolve().parent))
 import profile_report as report
 
 #: the scopes the unit voices' programs name (``models/lfm2.py``, ``sdar.py``,
-#: ``nemotron_h.py``, ``pangu_moe.py``)
+#: ``nemotron_h.py``, ``pangu_moe.py``, ``gigachat.py``)
 SCOPES = ("moe_experts", "moe_route", "shared_expert", "attn_op", "mla_op",
-          "ssm_op", "dense_ffn", "conv_op", "head", "unmask")
+          "ssm_op", "delta_op", "dense_ffn", "conv_op", "head", "unmask")
 TOP_OPS = 30
 
 
